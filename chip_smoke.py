@@ -1,0 +1,396 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+One process drives the main path once, through the entry points a user
+calls, at the full width of the Higgs model (28 dense numeric features,
+binary objective, 255 leaves, 255 bins), on rows generated from a seed:
+
+1. device: fail at once unless ``jax.devices()[0].platform == "tpu"``;
+2. kernels: every Pallas kernel the chip path can select, compiled, at
+   the Higgs width, against its oracle
+   (``tools/check_kernels_on_chip.py``);
+3. train: ``lgb.train`` for 17 rounds (the sync first iteration plus
+   one fused block of 16), with a path report — which learner, which
+   kernels, which iteration driver actually ran — and the train AUC
+   against the same data and params on the XLA foil;
+4. serve: the trained booster behind ``ServingEngine`` on the device
+   route with the host fallback off, against the host route.
+
+``--devices 4`` instead trains the same shape data-parallel over four
+chips and checks the sharding and the AUC against the one-chip model.
+
+Every stage asserts; nothing catches a failure to let the run go on.
+The last line of stdout, printed only when every stage passed, is the
+result object and nothing else:
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+The line before it, ``report: {...}``, carries everything observed
+(versions, path report, compile cache, seconds); those seconds are
+observations of one run, not measurements: it ends ``"claim": null``.
+Off a TPU nothing goes to stdout at all.
+
+Where the compile cache goes: ``lightgbm_tpu/utils/compile_cache.py``
+(``JAX_COMPILATION_CACHE_DIR`` if set, else ``.jax_cache_tpu`` next to
+this file).
+"""
+
+import argparse
+import json
+import sys
+import time
+
+ROWS = 500_000
+FEATURES = 28
+ROUNDS = 17
+PARAMS = {"objective": "binary", "num_leaves": 255, "max_bin": 255,
+          "learning_rate": 0.1, "metric": "", "verbosity": -1}
+MIN_AUC = 0.75          # the bar bench.py's fixed baseline uses
+FOIL_AUC_TOL = 1e-3
+SERVE_SIZES = (1, 512, 4096)
+
+
+def device_report() -> dict:
+    """Platform, kind, count and versions as JAX reports them; exits
+    non-zero, before anything is trained, unless the platform is a
+    TPU."""
+    import jax
+    import jaxlib
+    devs = jax.devices()
+    info = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+    from importlib.metadata import PackageNotFoundError, version
+    try:
+        libtpu = version("libtpu")
+    except PackageNotFoundError:    # hosts without a TPU
+        libtpu = None
+    line = (f"device: platform={info['platform']} "
+            f"device_kind={info['kind']!r} count={info['count']} "
+            f"jax={jax.__version__} jaxlib={jaxlib.__version__} "
+            f"libtpu={libtpu}")
+    if info["platform"] != "tpu":
+        sys.stderr.write(
+            f"{line}\nchip_smoke: needs a TPU, JAX reports platform="
+            f"{info['platform']!r}; no result\n")
+        sys.exit(2)
+    print(line, flush=True)
+    info["versions"] = {"jax": jax.__version__,
+                        "jaxlib": jaxlib.__version__, "libtpu": libtpu}
+    return info
+
+
+def higgs_like(n: int, f: int = FEATURES, seed: int = 42):
+    """The generator of ``bench.py:measure``: dense standard-normal
+    features, a label from a noisy interaction logit."""
+    import numpy as np
+    rng = np.random.RandomState(seed)
+    x = rng.randn(n, f).astype(np.float32)
+
+    def c(i):
+        return x[:, i % f]
+
+    logit = (2.0 * c(0) - 1.5 * c(1) + c(2) * c(3)
+             + 0.8 * c(4) * c(5) - c(6))
+    y = (logit + rng.randn(n).astype(np.float32) > 0).astype(np.float32)
+    return x, y
+
+
+def train_auc(bst, x, y) -> float:
+    """In-sample AUC by the repo's own metric, on raw scores."""
+    from types import SimpleNamespace
+
+    import numpy as np
+
+    from lightgbm_tpu.metric.metrics import AUCMetric
+    raw = np.asarray(bst.predict(x, raw_score=True), np.float64).ravel()
+    assert raw.shape == (len(y),) and np.isfinite(raw).all()
+    metric = AUCMetric(bst.config)
+    metric.init(SimpleNamespace(label=y, weights=None), len(y))
+    return float(metric.eval(raw, None)[0])
+
+
+def stage_kernels(interpret: bool = False, **shapes) -> dict:
+    """Each Pallas kernel against its oracle. ``shapes`` narrows a
+    stage's cases (tests); the default is every case, Higgs width
+    first."""
+    from tools import check_kernels_on_chip as ck
+    out = {}
+    for stage in ck.STAGES:
+        t0 = time.perf_counter()
+        failures = ck.STAGE_FNS[stage](interpret=interpret,
+                                       **shapes.get(stage, {}))
+        assert failures == 0, f"kernel stage {stage}: {failures} " \
+            "comparison(s) failed"
+        out[stage] = {"ok": True,
+                      "seconds": round(time.perf_counter() - t0, 1)}
+    return out
+
+
+def stage_train(x, y, params, rounds: int, *, learner: str,
+                interpret: bool, megakernel: bool, shards: int = 1):
+    """``lgb.train`` + the path report. Asserts the run took the path
+    it was meant to take; ``megakernel`` is what the caller expects of
+    the config, the report's value is what the trace counted."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    tel = get_telemetry()
+    tel.ensure_ring()       # counters only, no sink
+    before = {k: tel.counters.get(k, 0) for k in
+              ("fused.block_hits", "learner.megakernel_traces")}
+    t0 = time.perf_counter()
+    bst = lgb.train(dict(params), lgb.Dataset(x, label=y),
+                    num_boost_round=rounds)
+    seconds = time.perf_counter() - t0
+    delta = {k: int(tel.counters.get(k, 0) - v)
+             for k, v in before.items()}
+    gbdt = bst._gbdt
+    ln = gbdt.learner
+    leaves = [int(t.num_leaves) for t in gbdt.models]
+    report = {
+        "learner": type(ln).__name__,
+        "interpret": bool(ln.interpret),
+        "use_scan_kernel": bool(ln.params.use_scan_kernel),
+        "megakernel": "on" if delta["learner.megakernel_traces"]
+        else "off",
+        "megakernel_reason": _megakernel_reason(ln),
+        "fused_block_hits": delta["fused.block_hits"],
+        "trees": len(leaves),
+        "min_leaves": min(leaves),
+        "max_leaves": max(leaves),
+        "num_shards": int(getattr(ln, "num_shards", 1)),
+        "auc": round(train_auc(bst, x, y), 6),
+        "train_seconds": round(seconds, 1),
+    }
+    print(f"path[{learner}]: {json.dumps(report)}", flush=True)
+    assert report["learner"] == learner, report
+    assert report["interpret"] is interpret, report
+    assert report["use_scan_kernel"] is (not interpret), report
+    assert report["megakernel"] == ("on" if megakernel else "off"), \
+        report
+    assert report["fused_block_hits"] > 0, \
+        "_train_fused_blocks did not run"
+    assert report["trees"] == rounds, report
+    assert report["min_leaves"] > 1, report
+    assert report["num_shards"] == shards, report
+    assert report["auc"] >= MIN_AUC, report
+    return bst, report
+
+
+def _megakernel_reason(ln) -> str:
+    """What the static gate says, next to what the trace counted."""
+    from lightgbm_tpu.learner.split_step import fused_split_kernel_mode
+    # the mesh learners have no megakernel gate: their collectives
+    # sit between the per-phase kernels
+    if not hasattr(ln, "_fused_kernel_on"):
+        return "mesh learner: collectives between per-phase kernels"
+    mode = fused_split_kernel_mode(
+        getattr(ln.config, "fused_split_kernel", "auto"))
+    gate = "on" if ln._fused_kernel_on() else "off"
+    return f"fused_split_kernel={mode}: gate {gate} " \
+        "(ops/split_step_pallas.py learner_fused_kernel_on)"
+
+
+def stage_foil(x, y, params, rounds: int) -> dict:
+    """The same data and params on the plain XLA path: the serial
+    leaf-id learner with one-hot histograms and the XLA split scan,
+    no Pallas kernel anywhere. An internal construction (the learner
+    is swapped in before the first iteration), not an option."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.learner.serial import SerialTreeLearner
+    t0 = time.perf_counter()
+    bst = lgb.Booster(dict(params, fused_split_kernel="off"),
+                      lgb.Dataset(x, label=y))
+    gbdt = bst._gbdt
+    foil = SerialTreeLearner(gbdt.train_data, gbdt.config,
+                             hist_method="onehot")
+    foil.params = foil.params._replace(use_scan_kernel=False)
+    assert not foil._fused_kernel_on()
+    gbdt.learner = foil
+    gbdt.train(rounds)
+    report = {"learner": "SerialTreeLearner(hist_method='onehot')",
+              "trees": len(gbdt.models),
+              "auc": round(train_auc(bst, x, y), 6),
+              "train_seconds": round(time.perf_counter() - t0, 1)}
+    print(f"path[foil]: {json.dumps(report)}", flush=True)
+    assert report["trees"] == rounds, report
+    return report
+
+
+def stage_serve(bst, x, sizes=SERVE_SIZES) -> dict:
+    """Device route with the host fallback off, against the host
+    route, one request per size. The device scan sums leaf values in
+    f32 in tree order, the host loop in f64, so equality is exact only
+    where no sum rounds; the report carries the largest difference and
+    the gate is the f32 accumulation bound."""
+    import numpy as np
+
+    from lightgbm_tpu.serving import ServingConfig, ServingEngine
+    trees = len(bst._gbdt.models)
+    out = {"requests": []}
+    with ServingEngine(bst, ServingConfig(
+            device="always", fallback_to_host=False)) as eng, \
+            ServingEngine(bst, ServingConfig(device="never")) as host:
+        mv = eng.registry.current()
+        assert mv.device_ready and mv.stacked is not None, \
+            "registry declined to pin the model on the device"
+        warm = eng.stats()
+        for n in sizes:
+            rows = x[:n]
+            fut = eng.submit(rows, timeout_ms=0)
+            got = np.asarray(fut.result(timeout=600))
+            meta = fut.meta
+            ref = np.asarray(host.predict(rows, timeout_ms=0))
+            assert meta["route"] == "device", meta
+            assert got.shape == ref.shape == (n,), (got.shape, n)
+            assert np.isfinite(got).all()
+            diff = float(np.abs(got - ref).max())
+            # probabilities in (0, 1): |d sigmoid| <= |d raw| / 4, and
+            # T f32 adds of values below max|raw| round by at most
+            # T * eps * max|raw| in total
+            raw_max = float(np.abs(np.asarray(
+                host.predict(rows, kind="raw_score",
+                             timeout_ms=0))).max())
+            bound = trees * np.finfo(np.float32).eps * max(raw_max, 1.0)
+            assert diff <= bound, (n, diff, bound)
+            out["requests"].append({
+                "rows": n, "route": meta["route"],
+                "bit_identical": bool(np.array_equal(got, ref)),
+                "max_abs_diff": diff,
+                "latency_ms": meta["latency_ms"]})
+        stats = eng.stats()
+    assert stats["fallbacks"] == 0 and stats["errors"] == 0, stats
+    assert stats["bucket_misses"] == warm["bucket_misses"], \
+        ("a request compiled a bucket the warm-up had not", warm, stats)
+    out.update(fallbacks=stats["fallbacks"],
+               bucket_misses_warmup=warm["bucket_misses"],
+               bucket_misses_serving=stats["bucket_misses"]
+               - warm["bucket_misses"],
+               bit_identical=all(r["bit_identical"]
+                                 for r in out["requests"]))
+    print(f"serve: {json.dumps(out)}", flush=True)
+    return out
+
+
+def stage_shards(bst, rows: int, devices: int) -> dict:
+    """The mesh learner's training matrix: one shard per device, about
+    rows/devices each, none holding the whole; device memory in use
+    within 2x across the devices."""
+    import jax
+    ln = bst._gbdt.learner
+    shards = ln.mat.addressable_shards
+    devs = sorted({s.device.id for s in shards})
+    shard_rows = [int(s.data.shape[0] * s.data.shape[1])
+                  for s in shards]
+    stats = [d.memory_stats() for d in jax.devices()[:devices]]
+    # a TPU always reports; the CPU test mesh reports nothing
+    assert all(stats) or jax.default_backend() != "tpu", stats
+    in_use = [int(st["bytes_in_use"]) for st in stats if st]
+    out = {"shard_devices": devs, "shard_rows": shard_rows,
+           "bytes_in_use": in_use}
+    print(f"shards: {json.dumps(out)}", flush=True)
+    assert len(devs) == devices == len(shards), out
+    # each shard: its rows/devices share padded to the kernels' block
+    # layout, and less than the whole matrix would take
+    from lightgbm_tpu.ops.hist_pallas import matrix_rows
+    per = matrix_rows(-(-rows // devices))
+    assert all(r == per < matrix_rows(rows) for r in shard_rows), out
+    assert not in_use or max(in_use) <= 2 * min(in_use), out
+    return out
+
+
+def watch_persistent_cache() -> dict:
+    """Count jax's own persistent-cache events from here on (the
+    telemetry counter of the same name also counts the predictor's
+    in-process signature hits)."""
+    import jax.monitoring
+    seen = {"hits": 0, "misses": 0}
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            seen["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            seen["misses"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    return seen
+
+
+def cache_report(seen: dict) -> dict:
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    from lightgbm_tpu.utils.compile_cache import resolve_cache_dir
+    c = get_telemetry().counters
+    return {"dir": resolve_cache_dir(),
+            "compiles": int(c.get("jit.compiles", 0)),
+            "compile_seconds": round(c.get("jit.compile_s", 0.0), 1),
+            "persistent_cache_hits": seen["hits"],
+            "persistent_cache_misses": seen["misses"]}
+
+
+def result_line(device: dict) -> str:
+    """The last line of stdout: exactly ``ok`` and the device as JAX
+    reports it. Everything else observed is on the ``report:`` line."""
+    return json.dumps({
+        "ok": True,
+        "device": {"platform": str(device["platform"]),
+                   "kind": str(device["kind"]),
+                   "count": int(device["count"])}})
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--devices", type=int, default=1, choices=(1, 4),
+                    help="1: the one-chip smoke (default); 4: "
+                    "data-parallel training over four chips")
+    args = ap.parse_args(argv)
+
+    t_start = time.perf_counter()
+    device = device_report()
+    assert device["count"] >= args.devices, device
+
+    # everything below needs the repo: in a directory holding nothing
+    # else of it, the import fails and so does the run
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    from lightgbm_tpu.utils.compile_cache import \
+        maybe_enable_compile_cache
+    from lightgbm_tpu.utils.roofline import device_peaks
+    get_telemetry().ensure_ring()
+    maybe_enable_compile_cache()    # before the first compile
+    cache_events = watch_persistent_cache()
+    peaks = device_peaks()
+    print(f"peaks: {json.dumps(peaks)}", flush=True)
+
+    x, y = higgs_like(ROWS)
+    report = {"versions": device["versions"], "peaks": peaks,
+              "rows": ROWS, "features": FEATURES, "rounds": ROUNDS,
+              "params": PARAMS}
+    if args.devices == 1:
+        report["kernels"] = stage_kernels()
+        # the static rule selects the megakernel for this config
+        bst, report["train"] = stage_train(
+            x, y, PARAMS, ROUNDS, learner="PartitionedTreeLearner",
+            interpret=False, megakernel=True)
+        report["foil"] = stage_foil(x, y, PARAMS, ROUNDS)
+        gap = abs(report["train"]["auc"] - report["foil"]["auc"])
+        assert gap <= FOIL_AUC_TOL, ("chip path vs XLA foil", gap)
+        report["serve"] = stage_serve(bst, x)
+    else:
+        mesh_params = dict(PARAMS, tree_learner="data",
+                           num_machines=args.devices)
+        bst, report["train"] = stage_train(
+            x, y, mesh_params, ROUNDS,
+            learner="MeshPartitionedTreeLearner", interpret=False,
+            megakernel=False, shards=args.devices)
+        report["shards"] = stage_shards(bst, ROWS, args.devices)
+        _, report["one_chip"] = stage_train(
+            x, y, PARAMS, ROUNDS, learner="PartitionedTreeLearner",
+            interpret=False, megakernel=True)
+        gap = abs(report["train"]["auc"] - report["one_chip"]["auc"])
+        assert gap <= FOIL_AUC_TOL, ("four chips vs one chip", gap)
+    report["compile_cache"] = cache_report(cache_events)
+    report["seconds"] = round(time.perf_counter() - t_start, 1)
+    report["claim"] = None
+    print(f"report: {json.dumps(report)}", flush=True)
+    print(result_line(device), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

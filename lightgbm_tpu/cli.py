@@ -273,11 +273,10 @@ def run_serve(params: Dict[str, str]) -> None:
         maybe_start_exporter
     maybe_configure(cfg)
     maybe_start_exporter(cfg)
-    # zero-compile cold start: with compile_cache_dir (or
-    # LGBM_TPU_COMPILE_CACHE) pointing at a warm persistent cache,
-    # warmup replays the serialized bucket programs instead of
-    # compiling them (docs/Serving.md "zero-compile cold start")
-    maybe_enable_compile_cache(cfg)
+    # zero-compile cold start: with a warm persistent cache
+    # (utils/compile_cache.py) warmup replays the serialized bucket
+    # programs instead of compiling them (docs/Serving.md)
+    maybe_enable_compile_cache()
     fleet_mode = int(cfg.serving_replicas) > 1 or cfg.serving_models
     if not cfg.input_model and not cfg.serving_models:
         log_fatal("task=serve requires input_model=<model file> "

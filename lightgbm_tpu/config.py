@@ -134,8 +134,6 @@ _PARAM_ALIASES: Dict[str, str] = {
     "metrics_http_port": "metrics_port",
     "crash_dump_path": "crash_dump",
     "flight_recorder_path": "crash_dump",
-    "compile_cache": "compile_cache_dir",
-    "compilation_cache_dir": "compile_cache_dir",
     "serve_host": "serving_host",
     "serve_port": "serving_port",
     "serving_bucket_sizes": "serving_buckets",
@@ -365,11 +363,6 @@ class Config:
     # (LGBM_TPU_PROFILE_DIR env analog; skip/length via
     # LGBM_TPU_PROFILE_SKIP / LGBM_TPU_PROFILE_SPANS); empty = off
     profile_dir: str = ""
-    # persistent XLA compilation cache directory (docs/Performance.md):
-    # compiled executables are serialized there and reloaded by later
-    # processes, so repeat runs skip the cold-compile bill. Empty =
-    # disabled unless LGBM_TPU_COMPILE_CACHE is set.
-    compile_cache_dir: str = ""
 
     # ---- robustness (lightgbm_tpu/robustness/, docs/Robustness.md):
     # atomic versioned checkpoints + resume, non-finite guards, and the

@@ -72,12 +72,12 @@ def _count_collective(name: str, tree):
 
 
 def _bitcast_f32(x):
-    return jax.lax.bitcast_convert_type(
-        jnp.asarray(x, jnp.int32), jnp.float32)
+    return jax.lax.bitcast_convert_type(x, jnp.float32)
 
 
 def _bitcast_i32(x):
-    return jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jax.lax.bitcast_convert_type(
+        jnp.asarray(x, jnp.float32), jnp.int32)
 
 
 class Comm(NamedTuple):
@@ -122,34 +122,41 @@ SERIAL_COMM = Comm(reduce_hist=lambda x: x, reduce_sums=lambda x: x,
 
 
 # ---------------------------------------------------------------------
-# packed SplitResult exchange: ONE f32 buffer per candidate.
+# packed SplitResult exchange: ONE i32 buffer per candidate. The
+# carrier is the integer type (floats bitcast), like the grow-loop
+# StatePack (learner/split_step.py): integer moves keep every bit
+# pattern, while an f32 carrier loses small and negative ints on the
+# TPU (denormal flush, NaN canonicalization).
 _PACK_WORDS = 10 + MAX_CAT_WORDS
 
 
 def pack_split(res: SplitResult) -> jnp.ndarray:
-    """SplitResult -> f32[10 + MAX_CAT_WORDS]. Ints and the bitset are
-    bitcast (value bits preserved exactly); bools ride as 0/1."""
+    """SplitResult -> i32[10 + MAX_CAT_WORDS]. Floats and the bitset
+    are bitcast (value bits preserved exactly); bools ride as 0/1."""
     scal = jnp.stack([
-        res.gain,
-        _bitcast_f32(res.feature),
-        _bitcast_f32(res.threshold),
-        res.default_left.astype(jnp.float32),
-        res.left_g, res.left_h, res.left_c,
-        res.left_output, res.right_output,
-        res.is_cat.astype(jnp.float32)])
-    bits = jax.lax.bitcast_convert_type(res.cat_bitset, jnp.float32)
+        _bitcast_i32(res.gain),
+        res.feature.astype(jnp.int32),
+        res.threshold.astype(jnp.int32),
+        res.default_left.astype(jnp.int32),
+        _bitcast_i32(res.left_g), _bitcast_i32(res.left_h),
+        _bitcast_i32(res.left_c), _bitcast_i32(res.left_output),
+        _bitcast_i32(res.right_output),
+        res.is_cat.astype(jnp.int32)])
+    bits = jax.lax.bitcast_convert_type(res.cat_bitset, jnp.int32)
     return jnp.concatenate([scal, bits])
 
 
 def unpack_split(row: jnp.ndarray) -> SplitResult:
     return SplitResult(
-        gain=row[0],
-        feature=_bitcast_i32(row[1]),
-        threshold=_bitcast_i32(row[2]),
-        default_left=row[3] > 0.5,
-        left_g=row[4], left_h=row[5], left_c=row[6],
-        left_output=row[7], right_output=row[8],
-        is_cat=row[9] > 0.5,
+        gain=_bitcast_f32(row[0]),
+        feature=row[1],
+        threshold=row[2],
+        default_left=row[3] > 0,
+        left_g=_bitcast_f32(row[4]), left_h=_bitcast_f32(row[5]),
+        left_c=_bitcast_f32(row[6]),
+        left_output=_bitcast_f32(row[7]),
+        right_output=_bitcast_f32(row[8]),
+        is_cat=row[9] > 0,
         cat_bitset=jax.lax.bitcast_convert_type(row[10:], jnp.uint32))
 
 
@@ -161,8 +168,8 @@ def gather_best_split(res: SplitResult, axis: str) -> SplitResult:
     bundled group blocks scramble the shard<->feature-id order."""
     rows = jax.lax.all_gather(
         _count_collective("all_gather", pack_split(res)), axis)
-    gains = rows[:, 0]
-    feats = _bitcast_i32(rows[:, 1])
+    gains = _bitcast_f32(rows[:, 0])
+    feats = rows[:, 1]
     best = jnp.max(gains)
     tied = jnp.where(gains >= best, feats, jnp.iinfo(jnp.int32).max)
     return unpack_split(rows[jnp.argmin(tied)])
@@ -285,12 +292,12 @@ def make_voting_parallel_comm(axis: str, num_machines: int, top_k: int,
                            top_gain * loc[2] / jnp.maximum(mean_cnt, 1.0),
                            -jnp.inf)
         # ONE packed gather for the whole vote: [2k] = gains ++ ids
-        buf = jnp.concatenate([w_gain,
-                               _bitcast_f32(top_ids.astype(jnp.int32))])
+        buf = jnp.concatenate([_bitcast_i32(w_gain),
+                               top_ids.astype(jnp.int32)])
         rows = jax.lax.all_gather(
             _count_collective("all_gather", buf), axis)
-        all_gain = rows[:, :k].reshape(-1)
-        all_ids = _bitcast_i32(rows[:, k:]).reshape(-1)
+        all_gain = _bitcast_f32(rows[:, :k]).reshape(-1)
+        all_ids = rows[:, k:].reshape(-1)
         # per-feature max weighted gain over all candidates, then top-k
         feat_gain = jnp.full((f,), -jnp.inf).at[all_ids].max(
             jnp.where(jnp.isfinite(all_gain), all_gain, -jnp.inf))

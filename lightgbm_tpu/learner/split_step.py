@@ -110,10 +110,14 @@ class StatePack:
     split issues two column writes per state matrix plus one column
     write and two pointer fixups per tree matrix (the r05 layout).
 
-    Fused mode: ONE f32 state matrix (int rows bitcast — gathers,
-    scatters and selects never do arithmetic on the rows, so the bit
-    patterns round-trip exactly) and ONE f32 tree matrix; each split
-    issues one scatter per matrix. Fields listed in ``derived`` are
+    Fused mode: ONE i32 state matrix (float rows bitcast) and ONE i32
+    tree matrix; each split issues one scatter per matrix. The carrier
+    is the INTEGER type on purpose: integer moves keep every bit
+    pattern, while an f32 carrier loses int rows on the TPU, where
+    f32 data paths flush denormals (small ints) to zero and
+    canonicalize NaN patterns (negative ints) — the first chip run
+    trained correctly and returned trees whose split_feature /
+    threshold_bin were all 0 and whose leaf pointers were 0x7FC00000. Fields listed in ``derived`` are
     not carried at all — ``view()`` synthesizes them — and ``pack()``
     drops them on repack. Bool fields ride the int rows; unlisted keys
     pass through the carry unchanged."""
@@ -165,8 +169,8 @@ class StatePack:
         tim = jnp.stack([fields[k].astype(jnp.int32)
                          for k in self.ti_fields])
         if self.merged:
-            st["S"] = jnp.concatenate([sfm, _bitcast_f32(sim)], axis=0)
-            st["T"] = jnp.concatenate([tfm, _bitcast_f32(tim)], axis=0)
+            st["S"] = jnp.concatenate([_bitcast_i32(sfm), sim], axis=0)
+            st["T"] = jnp.concatenate([_bitcast_i32(tfm), tim], axis=0)
         else:
             st.update(SF=sfm, SI=sim, TF=tfm, TI=tim)
         return st
@@ -181,8 +185,8 @@ class StatePack:
         v = {k: val for k, val in st.items() if k not in self._MATS}
         if self.merged:
             nf, nt = len(self.sf_fields), len(self.tf_fields)
-            sfm, sim = st["S"][:nf], _bitcast_i32(st["S"][nf:])
-            tfm, tim = st["T"][:nt], _bitcast_i32(st["T"][nt:])
+            sfm, sim = _bitcast_f32(st["S"][:nf]), st["S"][nf:]
+            tfm, tim = _bitcast_f32(st["T"][:nt]), st["T"][nt:]
         else:
             sfm, sim = st["SF"], st["SI"]
             tfm, tim = st["TF"], st["TI"]
@@ -203,8 +207,9 @@ class StatePack:
     def row_f(self, st: dict, name: str) -> jnp.ndarray:
         """One float state row [L] without materializing a full view
         (the while-loop cond needs only ``bs_gain``)."""
-        m = st["S"] if self.merged else st["SF"]
-        return m[self.sf_idx[name]]
+        if self.merged:
+            return _bitcast_f32(st["S"][self.sf_idx[name]])
+        return st["SF"][self.sf_idx[name]]
 
     def stack_f(self, vals: dict) -> jnp.ndarray:
         """[Ksf] f32 column from a name->scalar dict (extra names are
@@ -223,7 +228,7 @@ class StatePack:
         if self.merged:
             nf = len(self.sf_fields)
             col = st["S"][:, leaf]
-            colf, coli = col[:nf], _bitcast_i32(col[nf:])
+            colf, coli = _bitcast_f32(col[:nf]), col[nf:]
         else:
             colf, coli = st["SF"][:, leaf], st["SI"][:, leaf]
         site = {k: colf[i] for k, i in self.sf_idx.items()}
@@ -244,11 +249,11 @@ class StatePack:
             # column builds; the scalar bitcasts fuse into it
             flat = []
             for k in self.sf_fields:
-                flat += [jnp.asarray(fa[k], jnp.float32),
-                         jnp.asarray(fb[k], jnp.float32)]
+                flat += [_bitcast_i32(jnp.asarray(fa[k], jnp.float32)),
+                         _bitcast_i32(jnp.asarray(fb[k], jnp.float32))]
             for k in self.si_fields:
-                flat += [_bitcast_f32(jnp.asarray(ia[k], jnp.int32)),
-                         _bitcast_f32(jnp.asarray(ib[k], jnp.int32))]
+                flat += [jnp.asarray(ia[k], jnp.int32),
+                         jnp.asarray(ib[k], jnp.int32)]
             cols = jnp.stack(flat).reshape(len(flat) // 2, 2)
             idx2 = jnp.stack([jnp.asarray(idx_a, jnp.int32),
                               jnp.asarray(idx_b, jnp.int32)])
@@ -276,14 +281,12 @@ class StatePack:
             # row pair
             side2 = jnp.arange(2, dtype=jnp.int32)[:, None]
             tm = st["T"].at[:, s].set(
-                jnp.concatenate([colf, _bitcast_f32(coli)]))
+                jnp.concatenate([_bitcast_i32(colf), coli]))
             r0 = len(self.tf_fields) + self.ti_idx["left_child"]
             pn = jnp.asarray(pnode, jnp.int32)
-            old = _bitcast_i32(
-                jax.lax.dynamic_slice(tm, (r0, pn), (2, 1)))
+            old = jax.lax.dynamic_slice(tm, (r0, pn), (2, 1))
             new = jnp.where(upd & (pside == side2), s, old)
-            tm = jax.lax.dynamic_update_slice(
-                tm, _bitcast_f32(new), (r0, pn))
+            tm = jax.lax.dynamic_update_slice(tm, new, (r0, pn))
             return {"T": tm}
         tfm = st["TF"].at[:, s].set(colf)
         tim = st["TI"].at[:, s].set(coli)

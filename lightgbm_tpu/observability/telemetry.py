@@ -514,7 +514,7 @@ def memory_snapshot() -> Dict[str, Any]:
         out["live_bytes"] = int(sum(
             a.size * a.dtype.itemsize for a in arrs
             if hasattr(a, "size") and hasattr(a, "dtype")))
-        stats = getattr(jax.devices()[0], "memory_stats", lambda: None)()
+        stats = jax.devices()[0].memory_stats()   # None on the CPU
         if stats:
             out["device_bytes_in_use"] = int(
                 stats.get("bytes_in_use", 0))

@@ -35,7 +35,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.jit_registry import register_jit
-from .pallas_compat import tpu_compiler_params
 
 ALIGN = 8
 
@@ -250,7 +249,7 @@ def partition_segment(mat, ws, begin, count, feat, thr, default_left,
         # (hist_pallas.VMEM_LIMIT): block intermediates beyond the
         # declared scratch live on the Mosaic stack, and the default
         # 16 MB budget OOMed the hist kernel's first v5e compile
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
             vmem_limit_bytes=100 * 1024 * 1024),
     )(scal, cat_lut, mat, ws)

@@ -80,20 +80,8 @@ def shard_rows(arr, mesh: Mesh, *, axis: str = AXIS,
         n_global = int(global_rows) if global_rows is not None \
             else arr.shape[0] * jax.process_count()
         global_shape = (n_global,) + arr.shape[1:]
-        if hasattr(jax, "make_array_from_process_local_data"):
-            return jax.make_array_from_process_local_data(
-                sharding, arr, global_shape)
-        # older jax: assemble from per-device slices of the local block
-        dev_arrays = []
-        addressable = [d for d in mesh.devices.flat
-                       if d.process_index == jax.process_index()]
-        rows_per_dev = arr.shape[0] // max(len(addressable), 1)
-        for i, dev in enumerate(addressable):
-            lo = i * rows_per_dev
-            dev_arrays.append(jax.device_put(
-                arr[lo:lo + rows_per_dev], dev))
-        return jax.make_array_from_single_device_arrays(
-            global_shape, sharding, dev_arrays)
+        return jax.make_array_from_process_local_data(
+            sharding, arr, global_shape)
     # one call; jax transfers each shard host->device individually —
     # the host-0 path never materializes a replicated device matrix
     return jax.device_put(arr, sharding)

@@ -53,24 +53,26 @@ from ..ops.split import FeatureMeta
 AXIS = "data"  # single mesh axis; rows or features are sharded over it
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-    if hasattr(jax, "shard_map"):  # jax >= 0.8
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-manual-axes check: the
+    grow bodies replicate split choices by construction (collectives
+    in learner/comm.py), which the checker cannot see through the
+    Pallas calls."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def default_mesh(num_devices: Optional[int] = None) -> Mesh:
     devices = jax.devices()
     if num_devices is not None:
         if num_devices > len(devices):
-            from ..utils.log import log_warning
-            log_warning(
+            # the reference treats a machine-count mismatch as fatal
+            # (linkers_socket.cpp), and a silently smaller mesh trains
+            # a different job than the one asked for
+            from ..utils.log import LightGBMError
+            raise LightGBMError(
                 f"num_machines={num_devices} but only {len(devices)} "
-                "devices are visible; using all of them")
-            num_devices = len(devices)
+                "device(s) are visible")
         devices = devices[:num_devices]
     return Mesh(np.asarray(devices), (AXIS,))
 

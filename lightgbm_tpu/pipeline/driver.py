@@ -53,7 +53,7 @@ class PipelineDriver:
         from ..observability.metrics import maybe_start_exporter
         maybe_start_exporter(cfg)
         from ..utils.compile_cache import maybe_enable_compile_cache
-        maybe_enable_compile_cache(cfg)
+        maybe_enable_compile_cache()
         if cfg.faults:
             from ..robustness.faults import set_fault_plan
             set_fault_plan(cfg.faults)

@@ -106,7 +106,7 @@ _RNG_ATTRS = ("_bag_rng", "_feature_rng", "_drop_rng", "_extra_rng",
 _FINGERPRINT_EXCLUDE = frozenset({
     "task", "config", "data", "valid", "input_model", "output_model",
     "output_result", "snapshot_freq", "verbosity", "telemetry_out",
-    "compile_cache_dir", "convert_model", "convert_model_language",
+    "convert_model", "convert_model_language",
     "checkpoint_dir", "checkpoint_freq", "checkpoint_keep",
     "checkpoint_score_cache", "resume", "faults", "guard_policy",
     "guard_loss_spike", "guard_max_rollbacks", "num_iterations",

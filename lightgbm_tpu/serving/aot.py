@@ -89,7 +89,7 @@ def _np_default(o):
 def build_artifact(donor, model_text: str,
                    buckets: Sequence[int] = (),
                    out_dir: Optional[str] = None,
-                   config=None, compile: bool = True) -> str:
+                   compile: bool = True) -> str:
     """Build + AOT-compile the predict artifact for ``model_text``.
 
     ``donor`` supplies the dataset (bin mappers, bundle layout) and the
@@ -148,7 +148,11 @@ def build_artifact(donor, model_text: str,
     mappers = [dataset.feature_mapper(i).to_dict()
                for i in range(dataset.num_features)]
 
-    out_dir = out_dir or artifact_dir(config)
+    out_dir = out_dir or artifact_dir()
+    if out_dir is None:
+        raise AotUnavailable(
+            "no persistent compile cache on this backend "
+            "(utils/compile_cache.py); host route")
     path = os.path.join(out_dir, f"{sha[:16]}.npz")
     payload = {
         "format": np.asarray(AOT_FORMAT),
@@ -192,15 +196,15 @@ def build_artifact(donor, model_text: str,
     # bundle rejects the publish here instead of poisoning the fleet
     art = load_artifact(path, expected_sha=sha)
     if compile:
-        maybe_enable_compile_cache(config)
+        maybe_enable_compile_cache()
         n = art.aot_compile(buckets)
         log_info(f"serving aot: artifact {os.path.basename(path)} "
                  f"({t} trees, k={k}) compiled {n} bucket program(s)")
     return path
 
 
-def maybe_build_artifact(donor, source, buckets: Sequence[int],
-                         config=None) -> Optional[str]:
+def maybe_build_artifact(donor, source,
+                         buckets: Sequence[int]) -> Optional[str]:
     """Fleet-facing convenience: build the artifact for a publish, or
     return None (host route) when the shape is unsupported or the
     build fails — artifact loss must never fail a model publish."""
@@ -208,8 +212,7 @@ def maybe_build_artifact(donor, source, buckets: Sequence[int],
         return None
     try:
         text = publish_text(source)
-        return build_artifact(donor, text, buckets=buckets,
-                              config=config)
+        return build_artifact(donor, text, buckets=buckets)
     except AotUnavailable as e:
         log_info(f"serving aot: artifact unavailable ({e}); workers "
                  "serve the host route")

@@ -15,7 +15,7 @@ supervisor over one length-prefixed JSON socket:
 
 The connect is retried with the bounded deterministic backoff from
 ``robustness/retry.py`` (the socket-linker pattern). The persistent
-compile cache (``LGBM_TPU_COMPILE_CACHE``) is enabled before the
+compile cache (``utils/compile_cache.py``) is enabled before the
 first compile, so a respawned worker's warmup REPLAYS the bucket
 programs instead of recompiling them.
 

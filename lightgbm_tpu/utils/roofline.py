@@ -4,11 +4,9 @@ A raw "Mrow/s" number says nothing about how much headroom remains;
 normalizing to the device's HBM bandwidth (the binding resource for the
 u8-matrix streaming kernels) and listing the MXU peak for context turns
 each measurement into a fraction of physically-possible. The table is
-deliberately small and conservative: published per-chip figures for the
-TPU generations this project targets. Unknown devices (and the CPU
-backend, whose effective bandwidth depends on the host) report peaks of
-``None`` and a fraction of "n/a" — a number we cannot ground is not
-reported as one.
+keyed by the exact ``device_kind`` string JAX reports and holds only
+devices this repo has run on; a device that is not in it is an error,
+never a default or an "n/a".
 
 Byte-cost model (documented here, used by bench.py and
 tools/micro_kernel_bench.py):
@@ -31,17 +29,14 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-# device_kind (jax.devices()[0].device_kind, lowercased substring) ->
-# published per-chip peaks: HBM GB/s, MXU dense bf16 TFLOP/s
+# jax.devices()[0].device_kind -> published per-chip peaks. Source:
+# Google Cloud documentation, "TPU v5e" system architecture page (one
+# chip: 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s).
+# A TPU v5e chip reports the kind "TPU v5 lite" (chip_smoke.py, jax
+# 0.9.0 / libtpu 0.0.34). Add a row only with a kind string read off
+# the device and a cited peak.
 _DEVICE_PEAKS = {
-    "v6e": {"hbm_gbps": 1640.0, "mxu_tflops": 918.0},
-    "v6":  {"hbm_gbps": 1640.0, "mxu_tflops": 918.0},
-    "v5p": {"hbm_gbps": 2765.0, "mxu_tflops": 459.0},
-    "v5e": {"hbm_gbps": 819.0, "mxu_tflops": 197.0},
-    "v5":  {"hbm_gbps": 819.0, "mxu_tflops": 197.0},
-    "v4":  {"hbm_gbps": 1228.0, "mxu_tflops": 275.0},
-    "v3":  {"hbm_gbps": 900.0, "mxu_tflops": 123.0},
-    "v2":  {"hbm_gbps": 700.0, "mxu_tflops": 46.0},
+    "TPU v5 lite": {"hbm_gbps": 819.0, "mxu_tflops": 197.0},
 }
 
 ROW_ID_BYTES = 4  # row ids ride the matrix as 4 u8 columns
@@ -73,45 +68,37 @@ def fused_leaf_bytes_per_row(num_features: int) -> int:
 
 
 def device_peaks(device=None) -> Dict[str, Any]:
-    """Peak table entry for the current (or given) jax device.
+    """Peak table row for the current (or given) jax device:
+    ``{"device_kind", "backend", "hbm_gbps", "mxu_tflops"}``. Raises
+    ``LightGBMError`` for a ``device_kind`` the table does not hold
+    (every CPU host included): a roofline share against a guessed
+    peak is not a measurement."""
+    import jax
 
-    Returns ``{"device_kind", "backend", "hbm_gbps", "mxu_tflops"}``
-    with ``None`` peaks when the device is unknown or a CPU host."""
-    kind, backend = "unknown", "unknown"
-    try:
-        import jax
-        d = device if device is not None else jax.devices()[0]
-        kind = str(getattr(d, "device_kind", "unknown"))
-        backend = str(getattr(d, "platform", jax.default_backend()))
-    except Exception:  # pragma: no cover - no backend at all
-        pass
-    out: Dict[str, Any] = {"device_kind": kind, "backend": backend,
-                           "hbm_gbps": None, "mxu_tflops": None}
-    if backend == "cpu":
-        return out  # host-dependent; reported as n/a by callers
-    low = kind.lower().replace(" ", "")
-    for key, peaks in _DEVICE_PEAKS.items():
-        if key in low:
-            out.update(peaks)
-            break
-    return out
+    from .log import LightGBMError
+    d = device if device is not None else jax.devices()[0]
+    kind = str(d.device_kind)
+    if kind not in _DEVICE_PEAKS:
+        raise LightGBMError(
+            f"no published peaks for device_kind={kind!r} (platform "
+            f"{d.platform!r}); utils/roofline.py holds "
+            f"{sorted(_DEVICE_PEAKS)}")
+    return {"device_kind": kind, "backend": str(d.platform),
+            **_DEVICE_PEAKS[kind]}
 
 
 def normalize(rows_per_s: float, bytes_per_row: float,
               peaks: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """Roofline fields for one measured streaming rate.
-
-    ``achieved_gbps`` is always computed (it only needs the byte
-    model); ``hbm_frac`` is "n/a" without a grounded peak."""
+    """Roofline fields for one measured streaming rate against the
+    device's HBM peak (``peaks`` defaults to :func:`device_peaks`)."""
     if peaks is None:
         peaks = device_peaks()
     achieved = rows_per_s * bytes_per_row / 1e9
-    peak = peaks.get("hbm_gbps")
     return {
         "bytes_per_row": bytes_per_row,
         "achieved_gbps": round(achieved, 3),
-        "hbm_peak_gbps": peak if peak is not None else "n/a",
-        "hbm_frac": round(achieved / peak, 4) if peak else "n/a",
+        "hbm_peak_gbps": peaks["hbm_gbps"],
+        "hbm_frac": round(achieved / peaks["hbm_gbps"], 4),
     }
 
 
@@ -122,7 +109,5 @@ def bench_roofline(rows_per_s: float, num_features: int) -> Dict[str, Any]:
     out = dict(peaks)
     out.update(normalize(rows_per_s, iter_bytes_per_row(num_features),
                          peaks))
-    out.pop("hbm_gbps", None)  # normalize() reports hbm_peak_gbps
-    out["mxu_tflops"] = peaks["mxu_tflops"] \
-        if peaks["mxu_tflops"] is not None else "n/a"
+    out.pop("hbm_gbps")  # normalize() reports hbm_peak_gbps
     return out

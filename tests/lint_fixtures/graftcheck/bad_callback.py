@@ -1,5 +1,5 @@
 """Host callback inside a hot program: every dispatch round-trips
-through the python interpreter (a ~ms-scale sync on a tunnel). The
+through the python interpreter (a host sync per dispatch). The
 compiled module carries a ``custom-call`` to the cpu-callback target
 — GC301."""
 

@@ -21,12 +21,7 @@ def build():
     def summed(x):
         return jax.lax.psum(x, "d")
 
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(summed, mesh=mesh, in_specs=(P("d"),),
-                               out_specs=P())
-    else:
-        from jax.experimental.shard_map import shard_map
-        mapped = shard_map(summed, mesh=mesh, in_specs=(P("d"),),
-                           out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(summed, mesh=mesh, in_specs=(P("d"),),
+                           out_specs=P(), check_vma=False)
     n = jax.device_count()
     return jax.jit(mapped).lower(jnp.zeros((n, 8), jnp.float32))

@@ -34,12 +34,7 @@ def build():
         stray = jax.lax.all_gather(local, "d")
         return winner.sum() + stray.sum()
 
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(grow_body, mesh=mesh, in_specs=(P(),),
-                               out_specs=P())
-    else:
-        from jax.experimental.shard_map import shard_map
-        mapped = shard_map(grow_body, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(grow_body, mesh=mesh, in_specs=(P(),),
+                           out_specs=P(), check_vma=False)
     n = jax.device_count()
     return jax.jit(mapped).lower(jnp.zeros((n * 2, 8), jnp.float32))

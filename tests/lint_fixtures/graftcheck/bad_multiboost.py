@@ -27,13 +27,8 @@ def build():
     def summed(x):
         return jax.lax.psum(x, "d")
 
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(summed, mesh=mesh, in_specs=(P("d"),),
-                               out_specs=P())
-    else:
-        from jax.experimental.shard_map import shard_map
-        mapped = shard_map(summed, mesh=mesh, in_specs=(P("d"),),
-                           out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(summed, mesh=mesh, in_specs=(P("d"),),
+                           out_specs=P(), check_vma=False)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def grow_batch(score):

@@ -1,0 +1,101 @@
+"""chip_smoke.py off the chip: the entry point refuses a CPU, and its
+stage functions run end to end at a tiny size (about 2k rows, 15
+leaves, kernels in interpret mode) on the CPU mesh.
+
+The chip run itself is the builder's and the driver's; this file keeps
+the script's plumbing — path report, serving checks, sharding checks —
+from rotting between chip runs.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke as cs  # noqa: E402
+
+TINY = dict(cs.PARAMS, num_leaves=15)
+
+
+@pytest.fixture
+def fuse_iters(monkeypatch):
+    # the fused-scan driver is the TPU default; this existing switch
+    # turns it on for the CPU backend
+    monkeypatch.setenv("LGBM_TPU_FUSE_ITERS", "1")
+
+
+def test_entry_point_refuses_a_cpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")], env=env,
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert "platform='cpu'" in proc.stderr
+    # no report, no result object: nothing on stdout at all
+    assert proc.stdout == ""
+
+
+def test_result_line_is_exactly_ok_and_device():
+    """What the driver parses off the last line of stdout: these keys
+    and no others (the observations ride on the ``report:`` line)."""
+    got = json.loads(cs.result_line(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+         "versions": {"jax": "0.9.0"}}))
+    assert got == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
+def test_train_and_serve_stages_tiny(fuse_iters):
+    x, y = cs.higgs_like(2000)
+    # auto is TPU-only; forcing the interpret twin on shows the report
+    # reads the megakernel from the trace, not from the gate
+    bst, report = cs.stage_train(
+        x, y, dict(TINY, tree_learner="partitioned",
+                   fused_split_kernel="on"), cs.ROUNDS,
+        learner="PartitionedTreeLearner", interpret=True,
+        megakernel=True)
+    assert report["fused_block_hits"] == 1     # 1 sync + one block of 16
+    serve = cs.stage_serve(bst, x, sizes=(1, 512))
+    assert [r["route"] for r in serve["requests"]] == ["device"] * 2
+    assert serve["fallbacks"] == 0
+    assert serve["bucket_misses_serving"] == 0
+
+
+@pytest.mark.slow
+def test_kernel_and_foil_stages_tiny(fuse_iters):
+    kernels = cs.stage_kernels(
+        interpret=True,
+        hist=dict(shapes=((2100, 28, 256),)),
+        partition_v1=dict(shapes=((2100, 28, 256),)),
+        split_scan=dict(shapes=((28, 256, False),)),
+        fused_split=dict(rows=1500, features=28, leaves=7))
+    assert set(kernels) == {"hist", "partition_v1", "split_scan",
+                            "fused_split"}
+    x, y = cs.higgs_like(2000)
+    params = dict(TINY, tree_learner="partitioned")
+    _, report = cs.stage_train(x, y, params, cs.ROUNDS,
+                               learner="PartitionedTreeLearner",
+                               interpret=True, megakernel=False)
+    foil = cs.stage_foil(x, y, params, cs.ROUNDS)
+    assert abs(report["auc"] - foil["auc"]) <= cs.FOIL_AUC_TOL
+
+
+@pytest.mark.slow
+def test_four_shard_stage_tiny(fuse_iters, monkeypatch):
+    """``--devices 4`` on the virtual CPU mesh: make the learner
+    factory route data-parallel onto the mesh segment-kernel learner
+    as it does on a TPU (the kernels stay in interpret mode)."""
+    import lightgbm_tpu.parallel.learners as learners
+    monkeypatch.setattr(learners, "on_tpu", lambda: True)
+    x, y = cs.higgs_like(4000)
+    bst, report = cs.stage_train(
+        x, y, dict(TINY, tree_learner="data", num_machines=4),
+        cs.ROUNDS, learner="MeshPartitionedTreeLearner",
+        interpret=True, megakernel=False, shards=4)
+    shards = cs.stage_shards(bst, 4000, 4)
+    assert shards["shard_devices"] == [0, 1, 2, 3]

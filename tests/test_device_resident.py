@@ -1,6 +1,6 @@
 """Device-resident boosting loop (ISSUE 2): batched metric eval,
-device bagging, per-iteration dispatch/host-sync accounting, and the
-persistent compile-cache wiring.
+device bagging, per-iteration dispatch/host-sync accounting, and where
+the persistent compile cache goes.
 
 Parity tests here pin the bit-compatibility contract: the device-eval
 path must produce EXACTLY the host path's metric values (same fetched
@@ -245,47 +245,85 @@ def test_run_report_digest_surfaces_counts(tel, tmp_path):
 
 
 # ---------------------------------------------------------------------
-# persistent compile cache wiring (logic only: flipping the real
-# process-global jax cache inside the CPU suite is unsafe, see
-# tests/conftest.py)
-def test_compile_cache_resolution_and_enable(monkeypatch, tmp_path):
+# where the persistent compile cache goes (utils/compile_cache.py).
+# Logic only: flipping the real process-global jax cache inside the
+# CPU suite is unsafe, see tests/conftest.py
+@pytest.fixture
+def cache_rule(monkeypatch):
+    import jax
+
     from lightgbm_tpu.utils import compile_cache as cc
     monkeypatch.setattr(cc, "_STATE", {"enabled_dir": None})
-    monkeypatch.delenv("LGBM_TPU_COMPILE_CACHE", raising=False)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    assert cc.resolve_cache_dir(None) == ""
-    assert cc.maybe_enable_compile_cache(None) is None
-
-    cfg = Config.from_params({"compile_cache_dir": str(tmp_path / "a"),
-                              "verbosity": -1})
-    assert cc.resolve_cache_dir(cfg) == str(tmp_path / "a")
-    # env fallback + config precedence
-    monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE", str(tmp_path / "b"))
-    assert cc.resolve_cache_dir(None) == str(tmp_path / "b")
-    assert cc.resolve_cache_dir(cfg) == str(tmp_path / "a")
-
     calls = []
-    import jax
     monkeypatch.setattr(jax.config, "update",
                         lambda k, v: calls.append((k, v)))
-    assert cc.maybe_enable_compile_cache(cfg) == str(tmp_path / "a")
-    assert ("jax_compilation_cache_dir", str(tmp_path / "a")) in calls
-    # idempotent: the second call is latched, no further config writes
+    return cc, calls
+
+
+def test_compile_cache_env_set_is_left_to_jax(cache_rule, monkeypatch,
+                                              tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: jax owns the directory; the
+    module reports it and never writes jax_compilation_cache_dir."""
+    cc, calls = cache_rule
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cc.resolve_cache_dir() == str(tmp_path)
+    assert cc.maybe_enable_compile_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in dict(calls)
+    assert cc.artifact_dir() == str(tmp_path / "aot")
+    assert (tmp_path / "aot").is_dir()
+
+
+def test_compile_cache_operator_floors_stand(cache_rule, monkeypatch,
+                                             tmp_path):
+    """The "cache everything" floors are defaults: one the operator set
+    through jax's own variable is not overridden; and a cache tree the
+    process cannot write gives no artifact dir (host route), not an
+    OSError."""
+    cc, calls = cache_rule
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2")
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
+                       raising=False)
+    cc.maybe_enable_compile_cache()
+    assert dict(calls) == {
+        "jax_persistent_cache_min_entry_size_bytes": -1}
+    (tmp_path / "aot").write_text("a file where the directory goes")
+    assert cc.artifact_dir() is None
+
+
+def test_compile_cache_unset_on_cpu_is_none(cache_rule, monkeypatch):
+    cc, calls = cache_rule
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert cc.resolve_cache_dir() is None
+    assert cc.maybe_enable_compile_cache() is None
+    assert cc.artifact_dir() is None
+    assert calls == []
+
+
+def test_compile_cache_unset_on_tpu_is_the_fixed_path(
+        cache_rule, monkeypatch, tmp_path):
+    """Unset on a TPU backend: the fixed <checkout>/.jax_cache_tpu —
+    never a temp name, a pid or a time (the path is part of jax's
+    cache key)."""
+    import inspect
+    cc, calls = cache_rule
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cc, "on_tpu", lambda: True)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.TPU_CACHE_DIR == os.path.join(repo, ".jax_cache_tpu")
+    assert cc.resolve_cache_dir() == cc.TPU_CACHE_DIR
+    src = inspect.getsource(cc)
+    assert "tempfile" not in src and "getpid" not in src
+
+    # the enable call hands jax that directory once; later calls are
+    # latched (redirected here so the test writes nothing in the repo)
+    monkeypatch.setattr(cc, "TPU_CACHE_DIR", str(tmp_path / "c"))
+    assert cc.maybe_enable_compile_cache() == str(tmp_path / "c")
+    assert ("jax_compilation_cache_dir", str(tmp_path / "c")) in calls
     n = len(calls)
-    assert cc.maybe_enable_compile_cache(cfg) == str(tmp_path / "a")
+    assert cc.maybe_enable_compile_cache() == str(tmp_path / "c")
     assert len(calls) == n
-
-
-def test_compile_cache_respects_jax_env(monkeypatch):
-    from lightgbm_tpu.utils import compile_cache as cc
-    monkeypatch.setattr(cc, "_STATE", {"enabled_dir": None})
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/already/wired")
-    monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE", "/ours")
-    import jax
-    monkeypatch.setattr(jax.config, "update",
-                        lambda k, v: pytest.fail("must not override "
-                                                 "operator's jax env"))
-    assert cc.maybe_enable_compile_cache(None) == "/already/wired"
+    assert cc.artifact_dir() == str(tmp_path / "c" / "aot")
 
 
 # ---------------------------------------------------------------------
@@ -326,16 +364,26 @@ def test_pipelined_and_bagged_training_guarded():
     assert np.isfinite(np.asarray(raw)).all()
 
 
-def test_bench_json_roofline_fields():
-    from lightgbm_tpu.utils.roofline import bench_roofline, normalize
-    r = bench_roofline(1e6, 28)
-    # CPU backend in the suite: peaks are honestly n/a, model bytes set
-    assert r["backend"] == "cpu"
-    assert r["hbm_frac"] == "n/a" and r["hbm_peak_gbps"] == "n/a"
-    assert r["bytes_per_row"] > 28
-    assert json.loads(json.dumps(r)) == r
-    # a grounded device normalizes to a real fraction
-    fake_peaks = {"hbm_gbps": 819.0, "mxu_tflops": 197.0}
-    rf = normalize(2e9, 40, fake_peaks)  # 80 GB/s of 819
-    assert rf["achieved_gbps"] == 80.0
+def test_roofline_peaks_are_keyed_by_exact_device_kind():
+    from types import SimpleNamespace
+
+    from lightgbm_tpu.utils import LightGBMError
+    from lightgbm_tpu.utils.roofline import (bench_roofline,
+                                             device_peaks, normalize)
+    # the CPU backend of the suite (any kind not in the table) is an
+    # error, never an "n/a" row
+    with pytest.raises(LightGBMError, match="device_kind='cpu'"):
+        device_peaks()
+    with pytest.raises(LightGBMError):
+        bench_roofline(1e6, 28)
+    with pytest.raises(LightGBMError):   # no substring matching
+        device_peaks(SimpleNamespace(device_kind="TPU v5",
+                                     platform="tpu"))
+    v5e = SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+    peaks = device_peaks(v5e)
+    assert peaks == {"device_kind": "TPU v5 lite", "backend": "tpu",
+                     "hbm_gbps": 819.0, "mxu_tflops": 197.0}
+    rf = normalize(2e9, 40, peaks)  # 80 GB/s of 819
+    assert rf["achieved_gbps"] == 80.0 and rf["hbm_peak_gbps"] == 819.0
     assert abs(rf["hbm_frac"] - 80.0 / 819.0) < 1e-4  # 4-decimal round
+    assert json.loads(json.dumps(rf)) == rf

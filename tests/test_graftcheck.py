@@ -12,6 +12,8 @@ import json
 import os
 import warnings
 
+import jax
+
 import pytest
 
 from lightgbm_tpu.utils.jit_registry import JitProgram
@@ -46,8 +48,7 @@ def _fixture_hlo(mod) -> str:
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
         if getattr(mod, "X64", False):
-            from jax.experimental import enable_x64
-            with enable_x64():
+            with jax.enable_x64(True):
                 return mod.build().compile().as_text()
         return mod.build().compile().as_text()
 

@@ -66,6 +66,27 @@ def _train(seed=0, leaves=7, rounds=6):
                      num_boost_round=rounds), X
 
 
+@pytest.fixture
+def started_with_cache(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR as a deployment sets it: before the
+    process starts. The workers spawned by the test read it at their
+    own jax import; this process imported jax long ago (with no cache,
+    tests/conftest.py), so hand its jax the same directory for the
+    test and take it back afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from lightgbm_tpu.utils import compile_cache as cc
+    cache = tmp_path / "xla_cache"
+    cache.mkdir()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
+    monkeypatch.setattr(cc, "_STATE", {"enabled_dir": None})
+    jax.config.update("jax_compilation_cache_dir", str(cache))
+    yield cache
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
+
+
 def _published_ref(bst, X):
     """Host prediction of the PUBLISHED artifact (model text) — the
     bit-parity reference for process-mode serving, same standard the
@@ -417,8 +438,7 @@ def test_rejected_publish_keeps_respawn_state_clean(proc_fleet):
 
 
 @pytest.mark.slow
-def test_warm_respawn_zero_compiles_cache_armed(tmp_path,
-                                                monkeypatch):
+def test_warm_respawn_zero_compiles_cache_armed(started_with_cache):
     """The acceptance bar for respawn cost: a respawned worker warms
     with ZERO compiles, serves bit-identically, compiles nothing in
     steady state, and has the persistent compile cache ARMED
@@ -426,9 +446,7 @@ def test_warm_respawn_zero_compiles_cache_armed(tmp_path,
     artifact (serving/aot.py), so the respawn replays the device
     route's executables too — test_aot_publish_zero_retrace_parity_
     and_shm pins that path explicitly."""
-    cache = tmp_path / "xla_cache"
-    cache.mkdir()
-    monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE", str(cache))
+    cache = started_with_cache
     bst, X = _train()
     fl = FleetEngine(
         models={"alpha": bst},
@@ -495,17 +513,14 @@ def test_config_aot_shm_params():
 
 
 @pytest.mark.slow
-def test_aot_publish_zero_retrace_parity_and_shm(tmp_path,
-                                                 monkeypatch):
+def test_aot_publish_zero_retrace_parity_and_shm(started_with_cache):
     """Acceptance: process-mode serving of an AOT-published model does
     ZERO retraces after replay (compile counter flat across warm-up,
     steady state and one respawn) AND stays bit-identical to host
     prediction of the same model text; batches >= shm_min_bytes
     travel the shm ring, oversized ones fall back to JSON framing
     with identical results."""
-    cache = tmp_path / "xla_cache"
-    cache.mkdir()
-    monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE", str(cache))
+    cache = started_with_cache
     bst, X = _train()
     text = bst.model_to_string()
     ref = _published_ref(bst, X)

@@ -451,7 +451,7 @@ def test_probe_taxonomy_codes():
         "XlaRuntimeError: INTERNAL: Mosaic lowering failed":
             "compile_error",
         "failed to connect to all addresses (grpc)": "transport",
-        "Connection refused dialing tunnel": "transport",
+        "Connection refused by the backend": "transport",
         "something else entirely": "unknown",
         "": "unknown",
     }
@@ -466,12 +466,12 @@ def test_run_report_probe_timeline(tmp_path, capsys):
     recs = [
         {"kind": "probe", "t": 0.0, "verdict": "failed",
          "reason": "hung > 90s", "reason_code": "init_timeout",
-         "cached": False, "dur_s": 90.0},
+         "dur_s": 90.0},
         {"kind": "probe", "t": 0.0, "verdict": "failed",
-         "reason": "Connection refused dialing tunnel",
-         "cached": False, "dur_s": 1.0},   # no code -> classified
+         "reason": "Connection refused by the backend",
+         "dur_s": 1.0},   # no code -> classified
         {"kind": "probe", "t": 0.0, "verdict": "ok", "reason": "",
-         "cached": True, "dur_s": 0.1},
+         "dur_s": 0.1},
     ]
     trace.write_text("".join(json.dumps(r) + "\n" for r in recs))
     assert run_report.main([str(trace)]) == 0

@@ -10,8 +10,7 @@ counting rules, so any change here must keep
 ``tools/hlo_census_budget.json`` green without --update.
 
 Everything operates on the textual form of a compiled module
-(``jitted.lower(...).compile().as_text()``) — the only artifact both
-jax 0.4 and newer releases expose stably.
+(``jitted.lower(...).compile().as_text()``).
 """
 
 from __future__ import annotations
@@ -237,12 +236,15 @@ def wide_dtype_lines(txt: str) -> List[Tuple[int, str]]:
     — the dtype-discipline violations GC2xx reports. ``constant`` ops
     are exempt: XLA embeds s64 scalar constants for machinery (e.g.
     callback target pointers) that never touches the compute path — a
-    REAL f64 leak always surfaces in the converts/arithmetic too. An
+    REAL f64 leak always surfaces in the converts/arithmetic too. So
+    is the threefry counter of ``jax.random`` (jax 0.9.0 builds its
+    2x32 counter from one u64 iota, op_name ``iota_2x32_shape``):
+    library machinery in every program that draws random numbers. An
     f64 parameter still counts: it means an f64 input crossed the jit
     boundary."""
     out = []
     for i, _comp, op, ln in iter_instructions(txt):
-        if op == "constant":
+        if op == "constant" or "/iota_2x32_shape" in ln:
             continue
         dt = result_dtype(ln)
         if dt in WIDE_DTYPES:
@@ -253,16 +255,20 @@ def wide_dtype_lines(txt: str) -> List[Tuple[int, str]]:
 def widening_convert_lines(txt: str) -> List[Tuple[int, str]]:
     """``convert`` instructions whose result element type is one of the
     8-byte x64 family and whose operand is narrower — the classic
-    python-float / np-scalar promotion leak."""
-    out = []
+    python-float / np-scalar promotion leak. This jaxlib prints
+    operands by name only (``convert(%param_0.1)``), so the operand's
+    type is read from the instruction that defines it."""
+    defined: Dict[str, str] = {}
+    converts = []
     for i, _comp, op, ln in iter_instructions(txt):
-        if op != "convert":
-            continue
-        dst = result_dtype(ln)
-        if dst not in WIDE_DTYPES:
-            continue
-        m = re.search(r"convert\(([a-z0-9]+)\[", ln)
-        src = m.group(1) if m else ""
+        name = ln.split(" = ", 1)[0].split()[-1]
+        defined[name] = result_dtype(ln)
+        if op == "convert" and defined[name] in WIDE_DTYPES:
+            converts.append((i, ln, defined[name]))
+    out = []
+    for i, ln, dst in converts:
+        m = re.search(r"convert\((?:([a-z0-9]+)\[\S* )?(%[\w.\-]+)", ln)
+        src = (m.group(1) or defined.get(m.group(2), "")) if m else ""
         if src and DTYPE_BYTES.get(src, 4) < DTYPE_BYTES.get(dst, 8):
             out.append((i, ln.strip()))
     return out

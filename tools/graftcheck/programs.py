@@ -543,8 +543,9 @@ def _fused_step_state(lrn, si_prefix):
     big_l = lrn.num_leaves
     imeta, fmeta = pack_meta_tables(
         lrn.meta, jnp.ones((lrn.meta.num_bins.shape[0],), bool))
-    return (jnp.zeros((ks, big_l), jnp.float32),
-            jnp.zeros((kt, big_l - 1), jnp.float32), imeta, fmeta)
+    # the packed carriers are i32 (float rows bitcast; StatePack)
+    return (jnp.zeros((ks, big_l), jnp.int32),
+            jnp.zeros((kt, big_l - 1), jnp.int32), imeta, fmeta)
 
 
 @builder("fused_split_step_leaf")

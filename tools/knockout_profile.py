@@ -20,9 +20,6 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/repo/.jax_cache_tpu")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
-
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -42,6 +39,9 @@ def main():
     from lightgbm_tpu.ops.hist_pallas import (combine_planes,
                                               histogram_segment_raw)
     from lightgbm_tpu.ops.partition_pallas import partition_segment
+    from lightgbm_tpu.utils.compile_cache import \
+        maybe_enable_compile_cache
+    maybe_enable_compile_cache()
 
     rng = np.random.RandomState(42)
     X = rng.randn(n, f).astype(np.float32)

@@ -143,7 +143,6 @@ def digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             "verdict": r.get("verdict"),
             "reason_code": code,
             "reason": str(r.get("reason", ""))[:120],
-            "cached": r.get("cached"),
             "dur_s": r.get("dur_s"),
             "wall_time": r.get("wall_time")})
 
@@ -256,8 +255,7 @@ def digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         "hists": hists,
         "tpu_probe": None if probe_rec is None else {
             k: probe_rec.get(k) for k in
-            ("verdict", "reason", "reason_code", "dur_s", "cached",
-             "cache_age_s")},
+            ("verdict", "reason", "reason_code", "dur_s")},
         "probe_history": probe_history,
         "jax_version": run.get("jax_version"),
         "config": run.get("config") or {},
@@ -613,11 +611,8 @@ def render(records: List[Dict[str, Any]]) -> str:
         p = d["tpu_probe"]
         L.append("")
         L.append("== tpu probe ==")
-        age = p.get("cache_age_s")
         L.append(f"verdict={p.get('verdict')} "
-                 f"cached={p.get('cached')}"
-                 + (f" age_s={age}" if age is not None else "")
-                 + f" dur_s={p.get('dur_s')}"
+                 f"dur_s={p.get('dur_s')}"
                  + (f" reason_code={p['reason_code']}"
                     if p.get("reason_code") else ""))
         if p.get("reason"):
@@ -628,11 +623,10 @@ def render(records: List[Dict[str, Any]]) -> str:
         L.append("")
         L.append("== tpu probe timeline (all rounds in this trace) ==")
         L.append(f"{'#':>3} {'verdict':<8}{'reason_code':<15}"
-                 f"{'cached':<7}{'dur_s':>7}  cause")
+                 f"{'dur_s':>7}  cause")
         for i, p in enumerate(hist):
             L.append(f"{i:>3} {str(p.get('verdict')):<8}"
                      f"{str(p.get('reason_code') or '-'):<15}"
-                     f"{str(bool(p.get('cached'))):<7}"
                      f"{p.get('dur_s') if p.get('dur_s') is not None else '-':>7}"
                      f"  {str(p.get('reason', ''))[:60]}")
         codes: Dict[str, int] = {}
