@@ -191,16 +191,10 @@ def measure():
         result["roofline"] = bench_roofline(throughput, f)
     # per-phase wall-time decomposition for the trend gate's
     # REGRESSION ATTRIBUTION (tools/bench_trend.py): phase span totals
-    # when the host-stepped spans ran, else the one-shot component
-    # probe's grad/hist/split/partition/update breakdown. Shares (not
-    # absolute seconds) are what the gate compares across rounds.
+    # when the host-stepped spans ran (the fused driver has none: its
+    # device time by scope comes from a profile). Shares (not absolute
+    # seconds) are what the gate compares across rounds.
     phases = tel.phase_totals()
-    if not phases:
-        for rec in reversed(tel.records):
-            if rec.get("kind") == "phase_probe" and rec.get("phases"):
-                phases = {k: float(v)
-                          for k, v in rec["phases"].items()}
-                break
     if phases:
         result["phases"] = {k: round(v, 6)
                             for k, v in sorted(phases.items())}

@@ -34,6 +34,7 @@ import numpy as np
 from ..config import Config
 from ..data.dataset import Dataset
 from ..models.tree import Tree, TreeArrays
+from ..observability import scopes
 from ..utils.device import on_tpu
 from ..utils.jit_registry import register_jit
 from ..ops.hist_pallas import (build_matrix, extract_row_ids,
@@ -319,22 +320,23 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
     b = num_bins_max
     big_l = num_leaves
 
-    # repack the gh payload in current row order (rows carry their id).
-    # ONE row gather of the stacked [N, 3] table instead of three
-    # element gathers: the random-access stream is the cost on TPU, so
-    # fetching 12 contiguous bytes per index beats three 4-byte passes
-    rids = extract_row_ids(mat, f, mat.shape[0])
-    local = jnp.arange(mat.shape[0]) < n        # padding rows: all-zero
-    lrid = rids - row_id_base
-    rid_ok = local & (lrid >= 0) & (lrid < grad.shape[0]) \
-        & (rids < n_total)
-    rc_idx = jnp.clip(lrid, 0, grad.shape[0] - 1)
-    ghb = jnp.stack([grad, hess, bag_weight], axis=1)     # [N, 3]
-    vals = jnp.where(rid_ok[:, None], ghb[rc_idx], 0.0)
-    cp = vals[:, 2]
-    gp = vals[:, 0] * cp
-    hp = vals[:, 1] * cp
-    mat = pack_gh(mat, f, gp, hp, cp)
+    with jax.named_scope(scopes.GROW_PACK):
+        # repack the gh payload in current row order (rows carry their id).
+        # ONE row gather of the stacked [N, 3] table instead of three
+        # element gathers: the random-access stream is the cost on TPU, so
+        # fetching 12 contiguous bytes per index beats three 4-byte passes
+        rids = extract_row_ids(mat, f, mat.shape[0])
+        local = jnp.arange(mat.shape[0]) < n        # padding rows: all-zero
+        lrid = rids - row_id_base
+        rid_ok = local & (lrid >= 0) & (lrid < grad.shape[0]) \
+            & (rids < n_total)
+        rc_idx = jnp.clip(lrid, 0, grad.shape[0] - 1)
+        ghb = jnp.stack([grad, hess, bag_weight], axis=1)     # [N, 3]
+        vals = jnp.where(rid_ok[:, None], ghb[rc_idx], 0.0)
+        cp = vals[:, 2]
+        gp = vals[:, 0] * cp
+        hp = vals[:, 1] * cp
+        mat = pack_gh(mat, f, gp, hp, cp)
 
     def seg_hist(m, begin, count):
         return comm.reduce_hist(histogram_segment(
@@ -450,98 +452,102 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                                             res.gain)),
                 pf._replace(score=raw), blocked)
 
-    # root sums reduce from the LOCAL histogram (voting keeps hists
-    # local, so reduce_hist alone would leave the sums shard-local);
-    # recipes with a packed root reduce carry the sums in the SAME
-    # collective as the histogram (learner/comm.py)
-    local_root = histogram_segment(mat, jnp.int32(0), jnp.int32(n), b, f,
-                                   blk=HIST_BLK, interpret=interpret)
-    root_hist, sums = reduce_root(local_root,
-                                  local_root[0].sum(axis=0))
-    root_g, root_h, root_c = sums[0], sums[1], sums[2]
-    # per-split scan/cache layout of the root histogram (identity for
-    # every recipe except data-parallel's reduce-scatter slice)
-    hist0 = to_scan(root_hist)
-    if params.cegb_on:
-        root_split, root_pf, root_blocked = scan_leaf_pf(
-            root_hist, root_g, root_h, root_c, jnp.int32(0), -inf, inf,
-            jnp.int32(0), cegb_used0)
-    else:
-        root_split = scan_root(root_hist, root_g, root_h, root_c,
-                               jnp.int32(0), -inf, inf, jnp.int32(0))
-    root_out = leaf_output_no_constraint(
-        root_g, root_h + 2e-15, params.lambda_l1, params.lambda_l2,
-        params.max_delta_step)
-
-    def at0(arr, val):
-        return arr.at[0].set(val)
-
-    fields = dict(
-        leaf_begin=jnp.zeros((big_l,), jnp.int32),
-        leaf_cnt=at0(jnp.zeros((big_l,), jnp.int32), jnp.int32(n)),
-        leaf_g=at0(jnp.zeros((big_l,), jnp.float32), root_g),
-        leaf_h=at0(jnp.zeros((big_l,), jnp.float32), root_h),
-        leaf_c=at0(jnp.zeros((big_l,), jnp.float32), root_c),
-        bs_gain=at0(jnp.full((big_l,), -jnp.inf), root_split.gain),
-        bs_feat=at0(jnp.zeros((big_l,), jnp.int32), root_split.feature),
-        bs_thr=at0(jnp.zeros((big_l,), jnp.int32), root_split.threshold),
-        bs_dleft=at0(jnp.zeros((big_l,), bool), root_split.default_left),
-        bs_lg=at0(jnp.zeros((big_l,), jnp.float32), root_split.left_g),
-        bs_lh=at0(jnp.zeros((big_l,), jnp.float32), root_split.left_h),
-        bs_lc=at0(jnp.zeros((big_l,), jnp.float32), root_split.left_c),
-        bs_lout=at0(jnp.zeros((big_l,), jnp.float32),
-                    root_split.left_output),
-        bs_rout=at0(jnp.zeros((big_l,), jnp.float32),
-                    root_split.right_output),
-        bs_iscat=at0(jnp.zeros((big_l,), bool), root_split.is_cat),
-        ref_node=jnp.full((big_l,), -1, jnp.int32),
-        ref_side=jnp.zeros((big_l,), jnp.int32),
-        leaf_cmin=jnp.full((big_l,), -jnp.inf, jnp.float32),
-        leaf_cmax=jnp.full((big_l,), jnp.inf, jnp.float32),
-        split_feature=jnp.zeros((big_l - 1,), jnp.int32),
-        threshold_bin=jnp.zeros((big_l - 1,), jnp.int32),
-        decision_type=jnp.zeros((big_l - 1,), jnp.int32),
-        left_child=jnp.zeros((big_l - 1,), jnp.int32),
-        right_child=jnp.zeros((big_l - 1,), jnp.int32),
-        split_gain_arr=jnp.zeros((big_l - 1,), jnp.float32),
-        internal_value=jnp.zeros((big_l - 1,), jnp.float32),
-        internal_weight=jnp.zeros((big_l - 1,), jnp.float32),
-        internal_count=jnp.zeros((big_l - 1,), jnp.float32),
-        leaf_value=at0(jnp.zeros((big_l,), jnp.float32), root_out),
-        leaf_weight=at0(jnp.zeros((big_l,), jnp.float32), root_h),
-        leaf_count=at0(jnp.zeros((big_l,), jnp.float32), root_c),
-        leaf_parent=jnp.full((big_l,), -1, jnp.int32),
-        leaf_depth=jnp.zeros((big_l,), jnp.int32),
-    )
-    fields.update(
-        k=jnp.int32(1), mat=mat, ws=ws,
-        bs_bitset=at0(jnp.zeros((big_l, MAX_CAT_WORDS), jnp.uint32),
-                      root_split.cat_bitset),
-        cat_bitsets=jnp.zeros((big_l - 1, MAX_CAT_WORDS), jnp.uint32))
-    if cache_hists:
-        if use_fused and not interpret:
-            from ..ops.split_step_pallas import compiled_hist_cache
-            fields["hist"] = compiled_hist_cache(root_hist, big_l)
+    with jax.named_scope(scopes.GROW_ROOT):
+        # root sums reduce from the LOCAL histogram (voting keeps hists
+        # local, so reduce_hist alone would leave the sums shard-local);
+        # recipes with a packed root reduce carry the sums in the SAME
+        # collective as the histogram (learner/comm.py)
+        local_root = histogram_segment(mat, jnp.int32(0), jnp.int32(n),
+                                       b, f, blk=HIST_BLK,
+                                       interpret=interpret)
+        root_hist, sums = reduce_root(local_root,
+                                      local_root[0].sum(axis=0))
+        root_g, root_h, root_c = sums[0], sums[1], sums[2]
+        # per-split scan/cache layout of the root histogram (identity for
+        # every recipe except data-parallel's reduce-scatter slice)
+        hist0 = to_scan(root_hist)
+        if params.cegb_on:
+            root_split, root_pf, root_blocked = scan_leaf_pf(
+                root_hist, root_g, root_h, root_c, jnp.int32(0), -inf, inf,
+                jnp.int32(0), cegb_used0)
         else:
-            fields["hist"] = at0(
-                jnp.zeros((big_l,) + hist0.shape, jnp.float32), hist0)
-    if pool_mode:
-        # bounded LRU pool: slot 0 holds the root; slot_used carries
-        # the split tick of the last touch (-1 = empty, filled first)
+            root_split = scan_root(root_hist, root_g, root_h, root_c,
+                                   jnp.int32(0), -inf, inf, jnp.int32(0))
+        root_out = leaf_output_no_constraint(
+            root_g, root_h + 2e-15, params.lambda_l1, params.lambda_l2,
+            params.max_delta_step)
+
+        def at0(arr, val):
+            return arr.at[0].set(val)
+
+        fields = dict(
+            leaf_begin=jnp.zeros((big_l,), jnp.int32),
+            leaf_cnt=at0(jnp.zeros((big_l,), jnp.int32), jnp.int32(n)),
+            leaf_g=at0(jnp.zeros((big_l,), jnp.float32), root_g),
+            leaf_h=at0(jnp.zeros((big_l,), jnp.float32), root_h),
+            leaf_c=at0(jnp.zeros((big_l,), jnp.float32), root_c),
+            bs_gain=at0(jnp.full((big_l,), -jnp.inf), root_split.gain),
+            bs_feat=at0(jnp.zeros((big_l,), jnp.int32), root_split.feature),
+            bs_thr=at0(jnp.zeros((big_l,), jnp.int32),
+                       root_split.threshold),
+            bs_dleft=at0(jnp.zeros((big_l,), bool),
+                         root_split.default_left),
+            bs_lg=at0(jnp.zeros((big_l,), jnp.float32), root_split.left_g),
+            bs_lh=at0(jnp.zeros((big_l,), jnp.float32), root_split.left_h),
+            bs_lc=at0(jnp.zeros((big_l,), jnp.float32), root_split.left_c),
+            bs_lout=at0(jnp.zeros((big_l,), jnp.float32),
+                        root_split.left_output),
+            bs_rout=at0(jnp.zeros((big_l,), jnp.float32),
+                        root_split.right_output),
+            bs_iscat=at0(jnp.zeros((big_l,), bool), root_split.is_cat),
+            ref_node=jnp.full((big_l,), -1, jnp.int32),
+            ref_side=jnp.zeros((big_l,), jnp.int32),
+            leaf_cmin=jnp.full((big_l,), -jnp.inf, jnp.float32),
+            leaf_cmax=jnp.full((big_l,), jnp.inf, jnp.float32),
+            split_feature=jnp.zeros((big_l - 1,), jnp.int32),
+            threshold_bin=jnp.zeros((big_l - 1,), jnp.int32),
+            decision_type=jnp.zeros((big_l - 1,), jnp.int32),
+            left_child=jnp.zeros((big_l - 1,), jnp.int32),
+            right_child=jnp.zeros((big_l - 1,), jnp.int32),
+            split_gain_arr=jnp.zeros((big_l - 1,), jnp.float32),
+            internal_value=jnp.zeros((big_l - 1,), jnp.float32),
+            internal_weight=jnp.zeros((big_l - 1,), jnp.float32),
+            internal_count=jnp.zeros((big_l - 1,), jnp.float32),
+            leaf_value=at0(jnp.zeros((big_l,), jnp.float32), root_out),
+            leaf_weight=at0(jnp.zeros((big_l,), jnp.float32), root_h),
+            leaf_count=at0(jnp.zeros((big_l,), jnp.float32), root_c),
+            leaf_parent=jnp.full((big_l,), -1, jnp.int32),
+            leaf_depth=jnp.zeros((big_l,), jnp.int32),
+        )
         fields.update(
-            pool=at0(jnp.zeros((pool_slots, f, b, 3), jnp.float32),
-                     root_hist),
-            slot_of_leaf=at0(jnp.full((big_l,), -1, jnp.int32),
-                             jnp.int32(0)),
-            leaf_of_slot=at0(jnp.full((pool_slots,), -1, jnp.int32),
-                             jnp.int32(0)),
-            slot_used=at0(jnp.full((pool_slots,), -1, jnp.int32),
-                          jnp.int32(0)))
-    if params.cegb_on:
-        fields["cegb_used"] = cegb_used0
-        fields.update(cegb_pf_state(big_l, num_features))
-        cegb_store_row(fields, 0, root_pf, root_blocked)
-    state = pack.pack(fields)
+            k=jnp.int32(1), mat=mat, ws=ws,
+            bs_bitset=at0(jnp.zeros((big_l, MAX_CAT_WORDS), jnp.uint32),
+                          root_split.cat_bitset),
+            cat_bitsets=jnp.zeros((big_l - 1, MAX_CAT_WORDS), jnp.uint32))
+        if cache_hists:
+            if use_fused and not interpret:
+                from ..ops.split_step_pallas import compiled_hist_cache
+                fields["hist"] = compiled_hist_cache(root_hist, big_l)
+            else:
+                fields["hist"] = at0(
+                    jnp.zeros((big_l,) + hist0.shape, jnp.float32), hist0)
+        if pool_mode:
+            # bounded LRU pool: slot 0 holds the root; slot_used carries
+            # the split tick of the last touch (-1 = empty, filled first)
+            fields.update(
+                pool=at0(jnp.zeros((pool_slots, f, b, 3), jnp.float32),
+                         root_hist),
+                slot_of_leaf=at0(jnp.full((big_l,), -1, jnp.int32),
+                                 jnp.int32(0)),
+                leaf_of_slot=at0(jnp.full((pool_slots,), -1, jnp.int32),
+                                 jnp.int32(0)),
+                slot_used=at0(jnp.full((pool_slots,), -1, jnp.int32),
+                              jnp.int32(0)))
+        if params.cegb_on:
+            fields["cegb_used"] = cegb_used0
+            fields.update(cegb_pf_state(big_l, num_features))
+            cegb_store_row(fields, 0, root_pf, root_blocked)
+        state = pack.pack(fields)
 
     leaf_range = jnp.arange(big_l)
 
@@ -789,24 +795,25 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
             st2 = pack.pack(vv)
         return st2
 
-    # forced splits: unrolled static pre-pass (ForceSplits analog);
-    # an invalid forced split aborts the rest of the plan
-    st = state
-    force_ok = jnp.bool_(True)
-    for step in forced_plan:
-        v0 = pack.view(st)
-        fh0 = v0["hist"][step[0]] if cache_hists \
-            else leaf_hist_any(v0, step[0])
-        lg_f, lh_f, _ = forced_left_sums(fh0, v0, step, meta, bundled)
-        ph_f = v0["leaf_h"][step[0]]
-        force_ok = force_ok & (lh_f > kEps) & (ph_f - lh_f > kEps) \
-            & (st["k"] < big_l)
-        st = jax.lax.cond(
-            force_ok,
-            functools.partial(body, forced=step, forced_hist=fh0),
-            lambda s: s, st)
+    with jax.named_scope(scopes.GROW_SPLITS):
+        # forced splits: unrolled static pre-pass (ForceSplits analog);
+        # an invalid forced split aborts the rest of the plan
+        st = state
+        force_ok = jnp.bool_(True)
+        for step in forced_plan:
+            v0 = pack.view(st)
+            fh0 = v0["hist"][step[0]] if cache_hists \
+                else leaf_hist_any(v0, step[0])
+            lg_f, lh_f, _ = forced_left_sums(fh0, v0, step, meta, bundled)
+            ph_f = v0["leaf_h"][step[0]]
+            force_ok = force_ok & (lh_f > kEps) & (ph_f - lh_f > kEps) \
+                & (st["k"] < big_l)
+            st = jax.lax.cond(
+                force_ok,
+                functools.partial(body, forced=step, forced_hist=fh0),
+                lambda s: s, st)
 
-    st = jax.lax.while_loop(cond, body, st)
+        st = jax.lax.while_loop(cond, body, st)
     vf = pack.view(st)
 
     tree = TreeArrays(
@@ -828,25 +835,26 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         cat_bitsets=vf["cat_bitsets"],
     )
 
-    # ---- leaf_id reconstruction: segments -> positions -> row ids ----
-    # rows never leave their shard, so local ids = global - row_id_base
-    used = leaf_range < st["k"]
-    begin_eff = jnp.where(used, vf["leaf_begin"], n + 1)
-    order_leaves = jnp.argsort(begin_eff)
-    bounds = begin_eff[order_leaves]
-    pos = jnp.arange(n)
-    seg_idx = jnp.searchsorted(bounds, pos, side="right") - 1
-    pos_leaf = order_leaves[jnp.clip(seg_idx, 0, big_l - 1)].astype(
-        jnp.int32)
-    rids_final = extract_row_ids(st["mat"], f, mat.shape[0])[:n] \
-        - row_id_base
-    if return_leaf_parts:
-        # fused path: (row ids, per-POSITION leaf) lets the caller do
-        # its score update with ONE scatter-add instead of this
-        # scatter + a leaf_value gather (two random [N] passes)
-        return st["mat"], st["ws"], tree, (
-            jnp.clip(rids_final, 0, n - 1), pos_leaf)
-    leaf_id = jnp.zeros((n,), jnp.int32).at[
-        jnp.clip(rids_final, 0, n - 1)].set(pos_leaf)
+    with jax.named_scope(scopes.GROW_LEAF_OF_POS):
+        # ---- leaf_id reconstruction: segments -> positions -> row ids ----
+        # rows never leave their shard, so local ids = global - row_id_base
+        used = leaf_range < st["k"]
+        begin_eff = jnp.where(used, vf["leaf_begin"], n + 1)
+        order_leaves = jnp.argsort(begin_eff)
+        bounds = begin_eff[order_leaves]
+        pos = jnp.arange(n)
+        seg_idx = jnp.searchsorted(bounds, pos, side="right") - 1
+        pos_leaf = order_leaves[jnp.clip(seg_idx, 0, big_l - 1)].astype(
+            jnp.int32)
+        rids_final = extract_row_ids(st["mat"], f, mat.shape[0])[:n] \
+            - row_id_base
+        if return_leaf_parts:
+            # fused path: (row ids, per-POSITION leaf) lets the caller do
+            # its score update with ONE scatter-add instead of this
+            # scatter + a leaf_value gather (two random [N] passes)
+            return st["mat"], st["ws"], tree, (
+                jnp.clip(rids_final, 0, n - 1), pos_leaf)
+        leaf_id = jnp.zeros((n,), jnp.int32).at[
+            jnp.clip(rids_final, 0, n - 1)].set(pos_leaf)
 
     return st["mat"], st["ws"], tree, leaf_id

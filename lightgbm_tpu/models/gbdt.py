@@ -28,6 +28,7 @@ from ..config import Config
 from ..data.dataset import Dataset
 from ..metric import create_metrics
 from ..objective import create_objective
+from ..observability import scopes
 from ..observability.telemetry import get_telemetry, memory_snapshot
 from ..observability.tracing import (get_tracer, profile_boundary,
                                      profile_close)
@@ -194,6 +195,16 @@ def _bag_mask_jit(key0, it, label=None, *, freq, n, frac, pos_frac,
                           pos_frac=pos_frac, neg_frac=neg_frac)
 
 
+def _named(name, fn):
+    """``fn`` as a ``functools.partial`` called ``name``: jax.jit names
+    the compiled module (``jit_<name>`` on a profile's ``XLA Modules``
+    line, ``jit(<name>)/`` at the head of every op path) after the
+    callable, so a registered program shows under its registry name."""
+    named = functools.partial(fn)
+    named.__name__ = name
+    return named
+
+
 def _fused_iter_block(mat, ws, score, vscores, lr, it0, *, learner,
                       grad_fn, bag_fn, valid_data, m, k):
     """``m`` boosting iterations as one device program (lax.scan over
@@ -210,22 +221,29 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, *, learner,
     pinning its device buffers in a process-lifetime module cache."""
     def body(carry, it):
         mat, ws, score, vscores = carry
-        grad, hess = grad_fn(score if k > 1 else score[:, 0])
-        if k == 1:
-            grad = grad[:, None]
-            hess = hess[:, None]
-        bag = None if bag_fn is None else bag_fn(it, grad, hess)
+        with jax.named_scope(scopes.GRADIENTS):
+            grad, hess = grad_fn(score if k > 1 else score[:, 0])
+            if k == 1:
+                grad = grad[:, None]
+                hess = hess[:, None]
+        bag = None
+        if bag_fn is not None:
+            with jax.named_scope(scopes.SAMPLE):
+                bag = bag_fn(it, grad, hess)
         trees_k = []
         ok = None
         for tid in range(k):
-            mat, ws, tree, (row_ids, pos_leaf) = learner.traceable_grow(
-                mat, ws, grad[:, tid], hess[:, tid], bag=bag)
+            with jax.named_scope(scopes.GROW):
+                mat, ws, tree, (row_ids, pos_leaf) = \
+                    learner.traceable_grow(mat, ws, grad[:, tid],
+                                           hess[:, tid], bag=bag)
             ok_t = tree.num_leaves > 1
             scale = jnp.where(ok_t, lr, jnp.float32(0.0))
             # one scatter-add in segment order: row_ids is a
             # permutation of [0, N), pos_leaf the leaf per POSITION
-            score = score.at[row_ids, tid].add(
-                (tree.leaf_value * scale)[pos_leaf])
+            with jax.named_scope(scopes.SCORE_UPDATE):
+                score = score.at[row_ids, tid].add(
+                    (tree.leaf_value * scale)[pos_leaf])
             vscores = tuple(
                 vs.at[:, tid].add(traverse_tree_arrays(
                     tree, vb, learner.meta, scale, vmv))
@@ -292,7 +310,8 @@ class GBDT:
             # objectives with per-call host randomness (rank_xendcg)
             # jit internally instead
             self._grad_fn = register_dynamic(
-                "gbdt_grad", jax.jit(self.objective.gradients)) \
+                "gbdt_grad", jax.jit(_named(
+                    "gbdt_grad", self.objective.gradients))) \
                 if getattr(self.objective, "jittable", True) \
                 else self.objective.gradients
         k = self.num_tree_per_iteration
@@ -456,7 +475,8 @@ class GBDT:
                         fold_finite_check(g, h)
                 return g, h, bag_core(i, g, h)
 
-            fn = register_dynamic("gbdt_grad_bag", jax.jit(_fused))
+            fn = register_dynamic(
+                "gbdt_grad_bag", jax.jit(_named("gbdt_grad_bag", _fused)))
             self._grad_bag_jit = fn
         tel.count_iter("host.dispatches")
         out = fn(score, jnp.int32(it))
@@ -1177,10 +1197,11 @@ class GBDT:
             fused = register_dynamic(
                 "gbdt_fused_block",
                 jax.jit(
-                    functools.partial(_fused_iter_block, learner=ln,
-                                      grad_fn=self._grad_fn,
-                                      bag_fn=self._traceable_bag_fn(),
-                                      valid_data=valid_data, k=k),
+                    _named("gbdt_fused_block", functools.partial(
+                        _fused_iter_block, learner=ln,
+                        grad_fn=self._grad_fn,
+                        bag_fn=self._traceable_bag_fn(),
+                        valid_data=valid_data, k=k)),
                     static_argnames=("m",), donate_argnums=(0, 1, 2, 3)),
                 donate=(0, 1, 2))
             self._fused_jit = fused
@@ -1201,36 +1222,49 @@ class GBDT:
             m = max(m, 1)
             tel = get_telemetry()
             t_blk = time.perf_counter()
-            with tel.span("boosting", trace="boost_block"):
+            with tel.span("boosting", trace=scopes.BLOCK_DISPATCH):
                 tel.count_iter("host.dispatches")
                 tel.count("fused.block_hits")
                 vs = tuple(self.valid_scores)
+                args = (ln.mat, ln.ws, self.train_score, vs, lr,
+                        jnp.int32(self.iter))
+                # avals before the call: the arguments are donated
+                new_prog = tel.enabled and scopes.remember(
+                    "gbdt_fused_block", fused, args, m=m)
                 (ln.mat, ln.ws, self.train_score, vs, trees,
-                 oks) = fused(ln.mat, ln.ws, self.train_score, vs, lr,
-                              jnp.int32(self.iter), m=m)
+                 oks) = fused(*args, m=m)
+                if new_prog:
+                    # first dispatch of this block length: the scope
+                    # table now, while the compiled module is a cache
+                    # lookup and the program is alive
+                    new_prog.scopes()
                 self.valid_scores = list(vs)
-            stack = TreeStack(trees)      # TreeArrays [m, k, ...]
-            for j in range(m):
-                for tid in range(k):
-                    self.models.append(DeferredStackTree(
-                        stack, (j, tid), ln.dataset,
-                        shrinkage=self.shrinkage_rate))
-            self.iter += m
-            with tel.span("device_sync"):
+            # the stop-flag fetch is the block's real device barrier;
+            # the host bookkeeping after it runs with the device idle
+            with tel.span("device_sync", trace=scopes.BLOCK_SYNC):
                 tel.count_iter("host.syncs")
                 flags = [bool(v) for v in jax.device_get(oks)]
+            with tel.span("block_trees", trace=scopes.BLOCK_TREES):
+                stack = TreeStack(trees)      # TreeArrays [m, k, ...]
+                for j in range(m):
+                    for tid in range(k):
+                        self.models.append(DeferredStackTree(
+                            stack, (j, tid), ln.dataset,
+                            shrinkage=self.shrinkage_rate))
+                self.iter += m
+                if tel.enabled:
+                    # from before the dispatch to after the barrier, so
+                    # this wall time covers device execution
+                    dur = time.perf_counter() - t_blk
+                    tel.count("learner.trees", m * k)
+                    tel.count("learner.row_iters", m * self.num_data)
+                    tel.record("block", iter_start=self.iter - m,
+                               iters=m, num_data=self.num_data,
+                               dur_s=round(dur, 6),
+                               rows_per_s=round(
+                                   m * self.num_data / dur, 3)
+                               if dur > 0 else 0.0)
             profile_boundary("block")
-            if tel.enabled:
-                # the stop-flag fetch above is the block's real device
-                # barrier, so this wall time covers device execution
-                dur = time.perf_counter() - t_blk
-                tel.count("learner.trees", m * k)
-                tel.count("learner.row_iters", m * self.num_data)
-                tel.record("block", iter_start=self.iter - m, iters=m,
-                           num_data=self.num_data, dur_s=round(dur, 6),
-                           rows_per_s=round(
-                               m * self.num_data / dur, 3)
-                           if dur > 0 else 0.0)
             if not all(flags):
                 self._truncate_surplus(len(flags) - flags.index(False))
                 log_warning(
@@ -1240,7 +1274,7 @@ class GBDT:
             if eval_every is not None \
                     and (self.iter % eval_every == 0
                          or self.iter >= iters):
-                with tel.span("eval", trace="eval"):
+                with tel.span("eval", trace=scopes.EVAL):
                     # early stopping is gated off on this path
                     # (_train_impl), so output_metric only records
                     self.output_metric(self.iter)
@@ -1275,9 +1309,9 @@ class GBDT:
             self.emit_train_end(it0, time.perf_counter() - t0)
 
     def emit_train_end(self, it0: int, dur: float) -> None:
-        """Emit the ``train_end`` summary record (+ the one-time phase
-        probe) after a training loop; shared with ``engine.train``'s
-        host-stepped path, which bypasses ``GBDT.train``."""
+        """Emit the ``train_end`` summary record after a training loop;
+        shared with ``engine.train``'s host-stepped path, which
+        bypasses ``GBDT.train``."""
         tel = get_telemetry()
         if not tel.enabled:
             return
@@ -1291,19 +1325,6 @@ class GBDT:
             phase_totals=tel.phase_totals(),
             counters=dict(tel.counters),
             memory=memory_snapshot())
-        if not getattr(self, "_tel_probed", False):
-            self._tel_probed = True
-            # the probe compiles a handful of component ops, so it only
-            # runs for full (JSONL) telemetry sessions — never in the
-            # ring-only mode bench uses for its timed region
-            from ..observability.telemetry import JsonlSink
-            if any(isinstance(s, JsonlSink) for s in tel._sinks):
-                from ..observability.probe import run_phase_probe
-                ph = run_phase_probe(self)
-                if ph:
-                    tel.record("phase_probe",
-                               learner=type(self.learner).__name__,
-                               num_data=self.num_data, phases=ph)
         tel.flush()
 
     def _train_impl(self, num_iterations: Optional[int] = None) -> None:

@@ -1,0 +1,195 @@
+"""Device time by program scope: the names, and which compiled
+instruction belongs to which of them.
+
+The fused training block (``models/gbdt.py`` ``_fused_iter_block``) is
+one XLA program; a profile of it shows ``fusion.87``, not "the leaf per
+position after a tree". The program therefore names its parts with
+``jax.named_scope`` under the constants below (the only place the
+strings live), and says which instruction of the *compiled* module each
+name owns: a device event in a profile carries the instruction's name
+and nothing else, so the join has to come from here
+(docs/Observability.md, "Device time by program scope").
+
+Nothing in this module runs on the hot path: ``remember`` is a list
+scan once per dispatched block, and only while telemetry is on. The
+driver builds a program's table once, right after the first dispatch
+of each block length (the compiled module is then a lookup in JAX's
+in-memory caches): a booster's jitted program dies with the booster,
+and whoever reads a profile asks later.
+"""
+
+from __future__ import annotations
+
+import re
+import time
+import weakref
+from collections import Counter
+from typing import Any, Dict, List, Optional
+
+# -- the vocabulary ------------------------------------------------------
+# device scopes of the fused training block (jax.named_scope)
+GRADIENTS = "lgbm.gradients"
+SAMPLE = "lgbm.sample"
+GROW = "lgbm.grow"                          # parent of the next four
+GROW_PACK = "lgbm.grow.pack"
+GROW_ROOT = "lgbm.grow.root"
+GROW_SPLITS = "lgbm.grow.splits"
+GROW_LEAF_OF_POS = "lgbm.grow.leaf_of_pos"
+SCORE_UPDATE = "lgbm.score_update"
+DEVICE_SCOPES = (GRADIENTS, SAMPLE, GROW, GROW_PACK, GROW_ROOT,
+                 GROW_SPLITS, GROW_LEAF_OF_POS, SCORE_UPDATE)
+
+# host spans of the fused driver, on the profiler's clock
+# (Telemetry.span(..., trace=<name>))
+BLOCK_DISPATCH = "lgbm.block.dispatch"
+BLOCK_SYNC = "lgbm.block.sync"
+BLOCK_TREES = "lgbm.block.trees"
+EVAL = "lgbm.eval"
+
+PREFIX = "lgbm."
+
+# -- compiled HLO text -> {instruction: scope} ----------------------------
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def _scope_of(op_name: str) -> Optional[str]:
+    """The last ``lgbm.``-prefixed component of an op path."""
+    for part in reversed(op_name.split("/")):
+        if part.startswith(PREFIX):
+            return part
+    return None
+
+
+def parse_hlo_scopes(hlo_text: str) -> Dict[str, str]:
+    """Instruction short name -> scope, from the text of a compiled
+    module (``compiled.as_text()``). An instruction's scope is the last
+    ``lgbm.`` component of its ``op_name`` (the innermost
+    ``named_scope`` it was traced under). One that has none of its own
+    inherits the commonest scope among the instructions of the
+    computation it ``calls=`` (a fusion whose root lost its metadata);
+    one outside every scope is absent."""
+    table: Dict[str, str] = {}
+    in_computation: Dict[str, Counter] = {}
+    callers: List[tuple] = []               # (instruction, callee)
+    tally: Optional[Counter] = None
+    for line in hlo_text.splitlines():
+        inst = _INSTRUCTION.match(line)
+        if inst is None:
+            comp = _COMPUTATION.match(line)
+            if comp is not None:
+                tally = in_computation.setdefault(comp.group(1),
+                                                  Counter())
+            continue
+        name = inst.group(1)
+        found = _OP_NAME.search(line)
+        scope = _scope_of(found.group(1)) if found else None
+        if scope is not None:
+            table[name] = scope
+            if tally is not None:
+                tally[scope] += 1
+        else:
+            callee = _CALLS.search(line)
+            if callee is not None:
+                callers.append((name, callee.group(1)))
+    for name, callee in callers:
+        inside = in_computation.get(callee)
+        if inside:
+            table[name] = inside.most_common(1)[0][0]
+    return table
+
+
+# -- what the program remembers of its dispatched programs ----------------
+_KEEP = 8       # remembered programs per registered name, newest last
+
+
+class RememberedProgram:
+    """One dispatched instance of a registered jit program: its
+    abstract arguments and static arguments, enough to look the
+    compiled module up again without the (donated) arrays."""
+
+    def __init__(self, fn: Any, avals: Any, static: Dict[str, Any]):
+        self.avals = avals
+        self.static = static
+        self._fn = weakref.ref(fn)      # never pins a booster's program
+        self._table: Optional[Dict[str, str]] = None
+        self.table_s: Optional[float] = None    # what building it took
+
+    def is_of(self, fn: Any, static: Dict[str, Any]) -> bool:
+        return self._fn() is fn and self.static == static
+
+    def scopes(self) -> Optional[Dict[str, str]]:
+        """The scope table of this program, built at the first ask and
+        kept: ``fn.lower(avals).compile()`` is a lookup in JAX's
+        in-memory caches once the program has been called with these
+        avals. ``None`` if the jitted callable went before anyone
+        asked."""
+        if self._table is None:
+            fn = self._fn()
+            if fn is None:
+                return None
+            t0 = time.perf_counter()
+            text = fn.lower(*self.avals, **self.static).compile().as_text()
+            self._table = parse_hlo_scopes(text)
+            self.table_s = time.perf_counter() - t0
+        return self._table
+
+
+_REMEMBERED: Dict[str, List[RememberedProgram]] = {}
+
+
+def remember(name: str, fn: Any, args: Any,
+             **static) -> Optional[RememberedProgram]:
+    """Note that ``fn`` (registered as ``name``) is about to be called
+    with ``args`` and ``static``. Call it before the dispatch (the
+    arguments may be donated) and only while telemetry is on. The
+    avals are taken once per (callable, static), and that once the
+    program is returned, for the caller to build its table after the
+    call; a repeat returns ``None`` and moves the program to the end of
+    the list: the last one is the one dispatched last."""
+    held = _REMEMBERED.setdefault(name, [])
+    for i, prog in enumerate(held):
+        if prog.is_of(fn, static):
+            if i != len(held) - 1:
+                held.append(held.pop(i))
+            return None
+    import jax
+
+    def aval(x):
+        # what the call's cache is keyed on, or the ask compiles anew
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=getattr(x, "weak_type", False),
+            sharding=x.sharding if getattr(x, "committed", False)
+            else None)
+    prog = RememberedProgram(fn, jax.tree.map(aval, args), static)
+    held.append(prog)
+    del held[:-_KEEP]
+    return prog
+
+
+def remembered(name: str) -> List[RememberedProgram]:
+    """The remembered programs of ``name``, the one dispatched last at
+    the end."""
+    return list(_REMEMBERED.get(name, []))
+
+
+def program_scopes(name: str, **static) -> Optional[Dict[str, str]]:
+    """Instruction short name -> scope for the registered program
+    ``name``: of the instance dispatched last, or of the last one whose
+    static arguments include ``static`` (instruction numbers differ
+    between the programs of different block lengths, so
+    ``program_scopes("gbdt_fused_block", m=4)``). ``None`` when
+    nothing was remembered under the name (telemetry was off, or the
+    driver of that program remembers nothing). Memoised per remembered
+    program."""
+    for prog in reversed(remembered(name)):
+        if all(prog.static.get(k) == v for k, v in static.items()):
+            return prog.scopes()
+    return None
+
+
+def forget() -> None:
+    """Test helper: drop everything remembered."""
+    _REMEMBERED.clear()

@@ -1,0 +1,234 @@
+"""Device time by program scope (lightgbm_tpu/observability/scopes.py):
+the vocabulary reaches the compiled module, the scope table joins
+instruction names to it, the fused driver's spans reach the profiler's
+clock, and none of it changes the program (ISSUE 24)."""
+
+import collections
+import contextlib
+import gc
+import re
+
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.observability import scopes
+from lightgbm_tpu.observability.telemetry import _NULL_SPAN, get_telemetry
+from lightgbm_tpu.utils import jit_registry
+
+HLO = '''HloModule jit_gbdt_fused_block, is_scheduled=true
+
+%fused_computation.7 (param_0.1: f32[8]) -> f32[8] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  %gather.3 = f32[8]{0} gather(%param_0.1), metadata={op_name="jit(gbdt_fused_block)/while/body/closed_call/lgbm.grow/lgbm.grow.pack/gather" stack_frame_id=4}
+  %mul.2 = f32[8]{0} multiply(%gather.3, %gather.3), metadata={op_name="jit(gbdt_fused_block)/while/body/closed_call/lgbm.grow/lgbm.grow.pack/mul"}
+  ROOT %copy.9 = f32[8]{0} copy(%mul.2)
+}
+
+%region_0.1 (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %arg = (s32[], f32[8]{0}) parameter(0)
+  %fusion.87 = s32[8]{0} fusion(%arg), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(gbdt_fused_block)/while/body/closed_call/lgbm.grow/lgbm.grow.leaf_of_pos/jit(searchsorted)/while/body/select_n"}
+  %fusion.7 = f32[8]{0} fusion(%arg), kind=kLoop, calls=%fused_computation.7
+  %custom-call.2 = f32[8]{0} custom-call(%fusion.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(gbdt_fused_block)/while/body/closed_call/lgbm.grow/lgbm.grow.splits/while/body/jit(fused_split_step_segment)/pallas_call"}
+  %add.5 = s32[] add(%arg, %arg), metadata={op_name="jit(gbdt_fused_block)/while/body/add"}
+  %copy.4 = f32[8]{0} copy(%fusion.7)
+  ROOT %tuple.1 = (s32[], f32[8]{0}) tuple(%add.5, %copy.4)
+}
+
+ENTRY %main.3 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  ROOT grad.1 = f32[8]{0} negate(%x), metadata={op_name="jit(gbdt_fused_block)/lgbm.gradients/jit(gbdt_grad)/neg"}
+}
+'''
+
+
+@pytest.mark.parametrize("instruction,scope", [
+    # nested scopes: the last lgbm. component, not the first
+    ("gather.3", scopes.GROW_PACK),
+    ("fusion.87", scopes.GROW_LEAF_OF_POS),
+    ("custom-call.2", scopes.GROW_SPLITS),
+    # a name printed without % and behind ROOT is still an instruction
+    ("grad.1", scopes.GRADIENTS),
+    # a fusion without op_name inherits from the computation it calls
+    ("fusion.7", scopes.GROW_PACK),
+    # outside every scope, with and without an op_name: absent
+    ("add.5", None), ("copy.4", None), ("copy.9", None),
+    ("tuple.1", None), ("x", None),
+])
+def test_parse_hlo_scopes_on_a_literal_module(instruction, scope):
+    assert scopes.parse_hlo_scopes(HLO).get(instruction) == scope
+
+
+def test_the_vocabulary_is_eight_scopes_and_four_spans():
+    assert len(set(scopes.DEVICE_SCOPES)) == 8
+    spans = (scopes.BLOCK_DISPATCH, scopes.BLOCK_SYNC, scopes.BLOCK_TREES,
+             scopes.EVAL)
+    assert all(n.startswith(scopes.PREFIX)
+               for n in scopes.DEVICE_SCOPES + spans)
+    # the four parts of a tree's growth are children of lgbm.grow
+    assert sum(n.startswith(scopes.GROW + ".")
+               for n in scopes.DEVICE_SCOPES) == 4
+
+
+# ---------------------------------------------------------------------
+@pytest.fixture
+def tel(monkeypatch):
+    """Fresh telemetry and nothing remembered; the fused-scan driver on
+    (it is the TPU default; this is its CPU switch)."""
+    monkeypatch.setenv("LGBM_TPU_FUSE_ITERS", "1")
+    t = get_telemetry()
+    t.reset()
+    scopes.forget()
+    yield t
+    t.reset()
+    scopes.forget()
+
+
+def _gbdt(bagging=False, seed=0):
+    rng = np.random.RandomState(seed)
+    X = rng.randn(500, 6)
+    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+              "tree_learner": "partitioned", "metric": ""}
+    if bagging:
+        params.update(bagging_fraction=0.5, bagging_freq=1)
+    return lgb.Booster(params, lgb.Dataset(X, label=y))._gbdt
+
+
+def _module_name(jitted, *avals, **static):
+    text = jitted.lower(*avals, **static).compile().as_text()
+    return re.match(r"HloModule (\S+?),", text).group(1)
+
+
+@pytest.mark.parametrize("bagging", [False, True],
+                         ids=["plain", "bagging"])
+def test_scope_table_of_the_fused_block(tel, bagging):
+    tel.ensure_ring()
+    g = _gbdt(bagging)
+    g.train(1)                  # the first iteration's own path
+    g.train(3)                  # one block of 2
+    g.train(5)                  # and another: the same program
+    assert tel.counters["fused.block_hits"] == 2
+    progs = scopes.remembered("gbdt_fused_block")
+    assert [p.static for p in progs] == [{"m": 2}]
+    compiles = tel.counters["jit.compiles"]
+    table = scopes.program_scopes("gbdt_fused_block")
+    assert table and table is scopes.program_scopes("gbdt_fused_block")
+    assert table is scopes.program_scopes("gbdt_fused_block", m=2)
+    assert scopes.program_scopes("gbdt_fused_block", m=4) is None
+    # built at the first dispatch, from JAX's caches: no compile, then
+    # or now
+    assert tel.counters["jit.compiles"] == compiles
+    assert progs[0].table_s is not None
+    owned = collections.Counter(table.values())
+    reachable = set(scopes.DEVICE_SCOPES)
+    if not bagging:
+        reachable.discard(scopes.SAMPLE)
+    assert set(owned) == reachable
+    # the program carries its registry name
+    fn = jit_registry.get("gbdt_fused_block").fn
+    assert _module_name(fn, *progs[0].avals, m=2) \
+        == "jit_gbdt_fused_block"
+
+
+def test_the_table_outlives_the_booster(tel):
+    """A booster's jitted program dies with it; whoever reads a profile
+    asks later (the benchmark asks after its reference check has built
+    another booster)."""
+    tel.ensure_ring()
+    g = _gbdt()
+    g.train(1)
+    g.train(3)
+    first = scopes.program_scopes("gbdt_fused_block")
+    del g
+    gc.collect()
+    other = _gbdt(seed=1)
+    other.train(1)
+    other.train(5)              # a block of 4: another program
+    held = scopes.remembered("gbdt_fused_block")
+    assert [p.static for p in held] == [{"m": 2}, {"m": 4}]
+    assert held[0].scopes() is first
+    assert scopes.program_scopes("gbdt_fused_block") is held[1].scopes()
+    assert scopes.program_scopes("gbdt_fused_block", m=2) is first
+
+
+def test_gradient_programs_carry_their_registry_names(tel):
+    import jax
+    import jax.numpy as jnp
+    g = _gbdt(bagging=True)
+    score = jax.ShapeDtypeStruct((500,), jnp.float32)
+    assert _module_name(jit_registry.get("gbdt_grad").fn, score) \
+        == "jit_gbdt_grad"
+    g._grad_hess_bag(g.train_score[:, 0], 0)    # builds the program
+    assert _module_name(jit_registry.get("gbdt_grad_bag").fn, score,
+                        jax.ShapeDtypeStruct((), jnp.int32)) \
+        == "jit_gbdt_grad_bag"
+
+
+def test_nothing_is_remembered_or_timed_with_telemetry_off(tel):
+    assert not tel.enabled
+    g = _gbdt()
+    g.train(3)
+    assert scopes.remembered("gbdt_fused_block") == []
+    assert scopes.program_scopes("gbdt_fused_block") is None
+    assert scopes.program_scopes("no_such_program") is None
+    assert tel.span("device_sync") is _NULL_SPAN
+    assert tel.span("boosting", trace=scopes.BLOCK_DISPATCH) \
+        is not _NULL_SPAN
+
+
+def test_one_block_opens_its_three_spans_in_order(tel, monkeypatch):
+    import lightgbm_tpu.utils.log as log
+    seen = []
+
+    @contextlib.contextmanager
+    def recorder(name):
+        seen.append(("open", name))
+        yield
+        seen.append(("close", name))
+
+    g = _gbdt()
+    g.train(1)
+    monkeypatch.setattr(log, "annotate", recorder)
+    g.train(3)                  # one block of 2, no eval
+    assert seen == [(what, name)
+                    for name in (scopes.BLOCK_DISPATCH, scopes.BLOCK_SYNC,
+                                 scopes.BLOCK_TREES)
+                    for what in ("open", "close")]
+    assert len(g.models) == 3 and g.iter == 3
+
+
+# the fused block's optimised module at the graftcheck size on the
+# parent of ISSUE 24 (commit 1f5d278, XLA:CPU, jax 0.9.0), by opcode
+PARENT_OPCODES = {
+    "abs": 20, "add": 275, "and": 144, "bitcast": 561,
+    "bitcast-convert": 96, "broadcast": 650, "clamp": 3, "compare": 356,
+    "concatenate": 30, "conditional": 2, "constant": 670, "convert": 213,
+    "copy": 92, "divide": 17, "dot": 12, "dynamic-slice": 68,
+    "dynamic-update-slice": 35, "exponential": 1, "fusion": 269,
+    "gather": 11, "get-tuple-element": 242, "iota": 45, "is-finite": 4,
+    "maximum": 24, "minimum": 13, "multiply": 170, "negate": 95, "not": 2,
+    "or": 44, "pad": 19, "parameter": 743, "reduce": 13,
+    "reduce-window": 8, "remainder": 11, "reverse": 2, "scatter": 3,
+    "select": 368, "shift-left": 33, "shift-right-logical": 36,
+    "sign": 46, "slice": 398, "sort": 1, "subtract": 106, "transpose": 11,
+    "tuple": 12, "while": 1}
+_OPCODE = re.compile(
+    r"^\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(?:\([^=]*?\)|\S+)\s+([a-z\-]+)\(")
+
+
+def test_named_scopes_leave_the_compiled_program_as_it_was():
+    """``jax.named_scope`` and the program's name are metadata: the
+    optimised module has the parent's instructions, opcode by opcode
+    (and so tools/graftcheck/contracts.json needs no new number)."""
+    from tools.graftcheck import load_manifest
+    from tools.graftcheck.programs import BUILDERS
+    text = BUILDERS["gbdt_fused_block"]().compile().as_text()
+    assert text.startswith("HloModule jit_gbdt_fused_block,")
+    got = collections.Counter(
+        m.group(1) for m in map(_OPCODE.match, text.splitlines()) if m)
+    assert dict(got) == PARENT_OPCODES
+    pinned = load_manifest()["programs"]["gbdt_fused_block"]
+    assert got["fusion"] == pinned["fusions"]
+    assert set(scopes.parse_hlo_scopes(text).values()) \
+        == set(scopes.DEVICE_SCOPES) - {scopes.SAMPLE}

@@ -209,25 +209,6 @@ def test_jsonl_roundtrip_through_run_report(tel, tmp_path):
     assert d["counters"]["learner.trees"] == 4
 
 
-def test_phase_probe_decomposes_grow(tel, tmp_path):
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.data import Dataset as InnerDataset
-    from lightgbm_tpu.models.gbdt import GBDT
-    from lightgbm_tpu.observability.probe import run_phase_probe
-    X, y = _toy(500)
-    cfg = Config.from_params({"objective": "binary", "num_leaves": 7,
-                              "metric": "", "verbosity": -1})
-    ds = InnerDataset.from_numpy(np.asarray(X, np.float32), cfg,
-                                 label=np.asarray(y, np.float32))
-    b = GBDT(cfg, ds)
-    b.train(2)
-    phases = run_phase_probe(b)
-    assert phases is not None
-    assert {"grad", "hist", "split", "partition", "update"} \
-        <= set(phases)
-    assert all(v >= 0 for v in phases.values())
-
-
 def test_train_end_record_and_summary_fields(tel, tmp_path):
     path = str(tmp_path / "t.jsonl")
     tel.configure(jsonl_path=path, summary=False)

@@ -13,9 +13,10 @@ last ring records) instead of as a trace.
 Reads the trace written by LGBM_TPU_TELEMETRY / telemetry_out (schema:
 docs/Observability.md) and prints, for the LAST training run in the
 file: backend provenance, compile-vs-steady-state breakdown, the
-per-phase timing table (grad/hist/split/partition/update — host phase
-wall times from the per-iteration records plus the one-shot component
-probe), throughput, counters and final eval results. ``--json`` emits
+per-phase timing table (grad/grow/tree/update — host phase wall times
+from the per-iteration records; the fused driver's device time by
+scope comes from a profile, docs/Observability.md), throughput,
+counters and final eval results. ``--json`` emits
 the same digest as one machine-readable JSON object (used by CI).
 
 Stdlib-only on purpose: the report must render on any box, including
@@ -67,7 +68,6 @@ def digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Aggregate a record list into the report's data model."""
     run = _last(records, "run_start") or {}
     end = _last(records, "train_end") or {}
-    probe = _last(records, "phase_probe") or {}
     iters = [r for r in records if r.get("kind") == "iter"]
     blocks = [r for r in records if r.get("kind") == "block"]
 
@@ -270,8 +270,6 @@ def digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         "fused_block_hits": int((end.get("counters") or {}).get(
             "fused.block_hits", 0)) or len(blocks),
         "phase_totals": end.get("phase_totals") or {},
-        "probe": probe.get("phases") or {},
-        "probe_learner": probe.get("learner"),
         "counters": end.get("counters") or {},
         "memory": end.get("memory") or {},
         "eval": evals,
@@ -324,19 +322,6 @@ def render(records: List[Dict[str, Any]]) -> str:
     else:
         L.append("(no per-iteration records — fused/pipelined run; "
                  "see fused blocks above)")
-
-    if d["probe"]:
-        L.append("")
-        L.append("== grow decomposition (one-shot component probe, "
-                 f"{d['probe_learner']}) ==")
-        L.append("grad/hist/split/partition/update seconds per "
-                 "iteration-equivalent:")
-        tot = sum(d["probe"].values()) or 1.0
-        for name in ("grad", "hist", "split", "partition", "update"):
-            if name in d["probe"]:
-                v = d["probe"][name]
-                L.append(f"{name:<12}{v:>10.6f}"
-                         f"{100 * v / tot:>6.1f}%")
 
     if d["iter_counts"]:
         L.append("")
