@@ -128,13 +128,16 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
                 interpret: bool, megakernel: bool, shards: int = 1):
     """``lgb.train`` + the path report. Asserts the run took the path
     it was meant to take; ``megakernel`` is what the caller expects of
-    the config, the report's value is what the trace counted."""
+    the config, the report's value is what the trace counted, as is
+    ``leaf_of_pos`` (the block pass or the search, by num_leaves)."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.observability.telemetry import get_telemetry
+    from lightgbm_tpu.ops.leaf_of_pos import uses_block_pass
     tel = get_telemetry()
     tel.ensure_ring()       # counters only, no sink
     before = {k: tel.counters.get(k, 0) for k in
-              ("fused.block_hits", "learner.megakernel_traces")}
+              ("fused.block_hits", "learner.megakernel_traces",
+               "learner.leaf_of_pos_dense_traces")}
     t0 = time.perf_counter()
     bst = lgb.train(dict(params), lgb.Dataset(x, label=y),
                     num_boost_round=rounds)
@@ -151,6 +154,8 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "megakernel": "on" if delta["learner.megakernel_traces"]
         else "off",
         "megakernel_reason": _megakernel_reason(ln),
+        "leaf_of_pos": "dense"
+        if delta["learner.leaf_of_pos_dense_traces"] else "search",
         "fused_block_hits": delta["fused.block_hits"],
         "trees": len(leaves),
         "min_leaves": min(leaves),
@@ -165,6 +170,8 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     assert report["use_scan_kernel"] is (not interpret), report
     assert report["megakernel"] == ("on" if megakernel else "off"), \
         report
+    assert report["leaf_of_pos"] == (
+        "dense" if uses_block_pass(ln.num_leaves) else "search"), report
     assert report["fused_block_hits"] > 0, \
         "_train_fused_blocks did not run"
     assert report["trees"] == rounds, report

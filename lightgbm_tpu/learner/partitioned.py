@@ -35,10 +35,12 @@ from ..config import Config
 from ..data.dataset import Dataset
 from ..models.tree import Tree, TreeArrays
 from ..observability import scopes
+from ..observability.telemetry import get_telemetry
 from ..utils.device import on_tpu
 from ..utils.jit_registry import register_jit
 from ..ops.hist_pallas import (build_matrix, extract_row_ids,
                                histogram_segment, pack_gh)
+from ..ops.leaf_of_pos import leaf_of_pos, uses_block_pass
 from ..ops.partition_pallas import bitset_to_lut, partition_segment
 from ..ops.split_scan_pallas import scan_kernel_default as _scan_default
 from ..ops.split import (MAX_CAT_WORDS,
@@ -310,6 +312,16 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
     switches per-split scans onto the column-sharded local context of
     the data-parallel reduce-scatter recipe (learner/comm.py) while
     the root scan stays replicated.
+
+    Returns ``(mat, ws, tree, leaf_id)``, ``leaf_id`` int32 ``[n]`` by
+    LOCAL row. ``return_leaf_parts=True`` returns in its place the pair
+    ``(row_ids, pos_leaf)``, both by POSITION of the partitioned matrix:
+    the local row id at each position and the leaf whose segment
+    ``[leaf_begin, leaf_begin + leaf_cnt)`` holds it, so the caller
+    updates its score with one scatter-add. ``pos_leaf`` is written by
+    one block pass of compares over the positions (ops/leaf_of_pos.py),
+    not searched for; a used leaf without a local row (a mesh shard)
+    owns no position.
     """
     if comm is None:
         from .comm import SERIAL_COMM
@@ -383,7 +395,6 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         serial_comm=comm is _SER, num_leaves=big_l) \
         and (interpret or not forced_plan)
     if use_fused:
-        from ..observability.telemetry import get_telemetry
         from ..ops.split_step_pallas import (fused_split_step_segment,
                                              pack_meta_tables)
         # counted where the megakernel enters a grow program's trace:
@@ -837,15 +848,15 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
 
     with jax.named_scope(scopes.GROW_LEAF_OF_POS):
         # ---- leaf_id reconstruction: segments -> positions -> row ids ----
+        # the leaf of each position is piecewise constant over the live
+        # segments: one block pass of compares, no search and no gather
+        # over the positions (ops/leaf_of_pos.py); counted where it
+        # enters the trace, like the megakernel
+        if uses_block_pass(big_l):
+            get_telemetry().count("learner.leaf_of_pos_dense_traces")
+        pos_leaf = leaf_of_pos(vf["leaf_begin"], vf["leaf_cnt"], st["k"],
+                               n=n, interpret=interpret)
         # rows never leave their shard, so local ids = global - row_id_base
-        used = leaf_range < st["k"]
-        begin_eff = jnp.where(used, vf["leaf_begin"], n + 1)
-        order_leaves = jnp.argsort(begin_eff)
-        bounds = begin_eff[order_leaves]
-        pos = jnp.arange(n)
-        seg_idx = jnp.searchsorted(bounds, pos, side="right") - 1
-        pos_leaf = order_leaves[jnp.clip(seg_idx, 0, big_l - 1)].astype(
-            jnp.int32)
         rids_final = extract_row_ids(st["mat"], f, mat.shape[0])[:n] \
             - row_id_base
         if return_leaf_parts:

@@ -240,3 +240,16 @@ def test_histogram_wide_slices_lower_for_tpu(variant):
                               num_features=f, interpret=False,
                               variant=variant),
             mat, jnp.int32(8), jnp.int32(1024))
+
+
+@pytest.mark.parametrize("num_leaves", [255, 4096])
+def test_leaf_of_pos_block_pass_lowers_for_tpu(num_leaves):
+    """The grow program's last kernel, at the benchmark's row count and
+    at the largest table the block pass takes (two SMEM tables)."""
+    from lightgbm_tpu.ops.leaf_of_pos import (DENSE_MAX_LEAVES,
+                                              leaf_of_pos)
+    assert num_leaves <= DENSE_MAX_LEAVES
+    table = jax.ShapeDtypeStruct((num_leaves,), jnp.int32)
+    _lowers(functools.partial(leaf_of_pos, n=10_500_000,
+                              interpret=False),
+            table, table, jax.ShapeDtypeStruct((), jnp.int32))

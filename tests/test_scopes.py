@@ -84,11 +84,12 @@ def tel(monkeypatch):
     scopes.forget()
 
 
-def _gbdt(bagging=False, seed=0):
+def _gbdt(bagging=False, seed=0, rows=500, num_leaves=7):
     rng = np.random.RandomState(seed)
-    X = rng.randn(500, 6)
+    X = rng.randn(rows, 6)
     y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] > 0).astype(np.float64)
-    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+    params = {"objective": "binary", "num_leaves": num_leaves,
+              "verbosity": -1,
               "tree_learner": "partitioned", "metric": ""}
     if bagging:
         params.update(bagging_fraction=0.5, bagging_freq=1)
@@ -198,21 +199,23 @@ def test_one_block_opens_its_three_spans_in_order(tel, monkeypatch):
     assert len(g.models) == 3 and g.iter == 3
 
 
-# the fused block's optimised module at the graftcheck size on the
-# parent of ISSUE 24 (commit 1f5d278, XLA:CPU, jax 0.9.0), by opcode
+# the fused block's optimised module at the graftcheck size (XLA:CPU,
+# jax 0.9.0), by opcode: the parent of ISSUE 24's (commit 1f5d278) but
+# for the leaf-of-position pass ISSUE 26 replaced (the search's while
+# loop, two gathers and a clamp went; the block pass's slices came)
 PARENT_OPCODES = {
-    "abs": 20, "add": 275, "and": 144, "bitcast": 561,
-    "bitcast-convert": 96, "broadcast": 650, "clamp": 3, "compare": 356,
-    "concatenate": 30, "conditional": 2, "constant": 670, "convert": 213,
-    "copy": 92, "divide": 17, "dot": 12, "dynamic-slice": 68,
-    "dynamic-update-slice": 35, "exponential": 1, "fusion": 269,
-    "gather": 11, "get-tuple-element": 242, "iota": 45, "is-finite": 4,
-    "maximum": 24, "minimum": 13, "multiply": 170, "negate": 95, "not": 2,
-    "or": 44, "pad": 19, "parameter": 743, "reduce": 13,
+    "abs": 20, "add": 273, "and": 145, "bitcast": 565,
+    "bitcast-convert": 96, "broadcast": 650, "clamp": 2, "compare": 356,
+    "concatenate": 30, "conditional": 2, "constant": 669, "convert": 205,
+    "copy": 90, "divide": 17, "dot": 12, "dynamic-slice": 71,
+    "dynamic-update-slice": 35, "exponential": 1, "fusion": 270,
+    "gather": 9, "get-tuple-element": 246, "iota": 47, "is-finite": 4,
+    "maximum": 25, "minimum": 13, "multiply": 172, "negate": 95, "not": 2,
+    "or": 44, "pad": 19, "parameter": 740, "reduce": 14,
     "reduce-window": 8, "remainder": 11, "reverse": 2, "scatter": 3,
-    "select": 368, "shift-left": 33, "shift-right-logical": 36,
-    "sign": 46, "slice": 398, "sort": 1, "subtract": 106, "transpose": 11,
-    "tuple": 12, "while": 1}
+    "select": 366, "shift-left": 33, "shift-right-logical": 33,
+    "sign": 46, "slice": 403, "sort": 1, "subtract": 106, "transpose": 11,
+    "tuple": 10}
 _OPCODE = re.compile(
     r"^\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(?:\([^=]*?\)|\S+)\s+([a-z\-]+)\(")
 
@@ -232,3 +235,49 @@ def test_named_scopes_leave_the_compiled_program_as_it_was():
     assert got["fusion"] == pinned["fusions"]
     assert set(scopes.parse_hlo_scopes(text).values()) \
         == set(scopes.DEVICE_SCOPES) - {scopes.SAMPLE}
+
+
+# ---------------------------------------------------------------------
+# the leaf-of-position pass inside the fused block (ISSUE 26)
+_SHAPE = re.compile(r"\b(?:pred|[suf]\d+|bf16)\[([\d,]+)\]")
+
+
+def _fused_block_text(tel, rows, num_leaves):
+    tel.ensure_ring()
+    g = _gbdt(rows=rows, num_leaves=num_leaves)
+    g.train(1)
+    g.train(3)
+    prog = scopes.remembered("gbdt_fused_block")[-1]
+    fn = jit_registry.get("gbdt_fused_block").fn
+    return fn.lower(*prog.avals, **prog.static).compile().as_text()
+
+
+def test_the_fused_block_holds_no_leaves_by_positions_buffer(tel):
+    """The leaf of each position comes from compares against the few
+    segments that begin inside a block of positions: nothing of the
+    compiled block has a dimension of num_leaves beside one of the
+    rows (which a compare of all positions with all segments, the
+    one-word version of this pass, would need: 10.7 GB at the
+    benchmark's size)."""
+    rows, num_leaves = 1013, 13         # primes: no other table's dims
+    text = _fused_block_text(tel, rows, num_leaves)
+    assert tel.counters["learner.leaf_of_pos_dense_traces"] >= 1
+    dims = {tuple(map(int, m.group(1).split(",")))
+            for m in _SHAPE.finditer(text)}
+    assert any(rows in d for d in dims) and any(num_leaves in d
+                                                for d in dims)
+    assert not [d for d in dims if rows in d and num_leaves in d]
+
+
+def test_the_block_pass_runs_under_the_leaf_of_pos_scope(tel):
+    text = _fused_block_text(tel, 500, 7)
+    table = scopes.parse_hlo_scopes(text)
+    of_the_pass = [m.group(1) for m in map(
+        re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s.*op_name=\"[^\"]*"
+                   r"/leaf_of_pos_blocks[/\"]").match, text.splitlines())
+        if m]
+    assert of_the_pass
+    assert {table.get(name) for name in of_the_pass} \
+        == {scopes.GROW_LEAF_OF_POS}
+    # and the search it replaced is gone from the program
+    assert "searchsorted" not in text

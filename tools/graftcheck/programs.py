@@ -529,6 +529,15 @@ def _b_partition_segment():
     return _spec_fn("partition_segment").lower(*args, **kw)
 
 
+@builder("leaf_of_pos")
+def _b_leaf_of_pos():
+    import jax.numpy as jnp
+    lrn = _partitioned_learner()
+    table = jnp.zeros((lrn.num_leaves,), jnp.int32)
+    return _spec_fn("leaf_of_pos").lower(
+        table, table, jnp.int32(1), n=lrn.num_data, interpret=True)
+
+
 def _fused_step_state(lrn, si_prefix):
     import jax.numpy as jnp
 
