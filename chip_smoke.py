@@ -14,7 +14,15 @@ binary objective, 255 leaves, 255 bins), on rows generated from a seed:
    kernels, which iteration driver actually ran — and the train AUC
    against the same data and params on the XLA foil;
 4. serve: the trained booster behind ``ServingEngine`` on the device
-   route with the host fallback off, against the host route.
+   route with the host fallback off, against the host route;
+5. categorical: 200,000 rows shaped like the benchmark's Expo table
+   (40 columns, twelve of them categories named in ``params``, four
+   of those cut to 255 bins by the binning) for 9 rounds: the route
+   the compiled megakernel refuses, which is the per-phase kernels
+   with the bitset partition and the categorical XLA scan, still in
+   fused blocks; its route counters, its trees' category
+   sets on the host against the scores the device holds, and its AUC
+   against the XLA foil.
 
 ``--devices 4`` instead trains the same shape data-parallel over four
 chips and checks the sharding and the AUC against the one-chip model.
@@ -46,6 +54,15 @@ PARAMS = {"objective": "binary", "num_leaves": 255, "max_bin": 255,
 MIN_AUC = 0.75          # the bar bench.py's fixed baseline uses
 FOIL_AUC_TOL = 1e-3
 SERVE_SIZES = (1, 512, 4096)
+CAT_ROWS = 200_000
+CAT_ROUNDS = 9          # the sync first iteration plus a block of 8
+CAT_SCORE_TOL = 1e-4    # host trees' raw scores against the device's
+# a near-tie between two category sets falls the other way somewhere in
+# nine 255-leaf trees, which moves in-sample AUC more than arithmetic
+# does: 4.8e-4 at the first chip run, up to 1.4e-3 in the benchmark's
+# check (PERF.md, PR 27); a learner that reads the categories as
+# ordered parts by 2.8e-2 and more
+CAT_FOIL_AUC_TOL = 5e-3
 
 
 def device_report() -> dict:
@@ -93,6 +110,21 @@ def higgs_like(n: int, f: int = FEATURES, seed: int = 42):
     return x, y
 
 
+def expo_like(n: int, seed: int = 42):
+    """``(x, y, params)``: rows of the benchmark's categorical table
+    from its own generator and configuration file (``benchmarks/``),
+    and ``PARAMS`` with that file's categorical parameters."""
+    import os
+
+    from benchmarks.generators import expo_like as gen
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "expo-categorical.json")) as fh:
+        cfg = json.load(fh)
+    x, y = gen.make(seed, n, cfg["features"], **cfg["generator"]["params"])
+    return x, y, dict(cfg["params"], **PARAMS)
+
+
 def train_auc(bst, x, y) -> float:
     """In-sample AUC by the repo's own metric, on raw scores."""
     from types import SimpleNamespace
@@ -125,11 +157,16 @@ def stage_kernels(interpret: bool = False, **shapes) -> dict:
 
 
 def stage_train(x, y, params, rounds: int, *, learner: str,
-                interpret: bool, megakernel: bool, shards: int = 1):
+                interpret: bool, megakernel: bool, shards: int = 1,
+                categorical: bool = False):
     """``lgb.train`` + the path report. Asserts the run took the path
     it was meant to take; ``megakernel`` is what the caller expects of
     the config, the report's value is what the trace counted, as is
-    ``leaf_of_pos`` (the block pass or the search, by num_leaves)."""
+    ``leaf_of_pos`` (the block pass or the search, by num_leaves).
+    ``categorical``: ``params`` names categorical columns, so the
+    bitset partition and the categorical scan must have been traced,
+    the Pallas scan kernel not, and the trees must hold category
+    splits that route rows on the host as the device did."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.observability.telemetry import get_telemetry
     from lightgbm_tpu.ops.leaf_of_pos import uses_block_pass
@@ -137,7 +174,9 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     tel.ensure_ring()       # counters only, no sink
     before = {k: tel.counters.get(k, 0) for k in
               ("fused.block_hits", "learner.megakernel_traces",
-               "learner.leaf_of_pos_dense_traces")}
+               "learner.leaf_of_pos_dense_traces",
+               "learner.lut_partition_traces",
+               "learner.cat_scan_traces")}
     t0 = time.perf_counter()
     bst = lgb.train(dict(params), lgb.Dataset(x, label=y),
                     num_boost_round=rounds)
@@ -156,6 +195,9 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "megakernel_reason": _megakernel_reason(ln),
         "leaf_of_pos": "dense"
         if delta["learner.leaf_of_pos_dense_traces"] else "search",
+        "lut_partition": "on" if delta["learner.lut_partition_traces"]
+        else "off",
+        "cat_scan": "on" if delta["learner.cat_scan_traces"] else "off",
         "fused_block_hits": delta["fused.block_hits"],
         "trees": len(leaves),
         "min_leaves": min(leaves),
@@ -167,7 +209,8 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     print(f"path[{learner}]: {json.dumps(report)}", flush=True)
     assert report["learner"] == learner, report
     assert report["interpret"] is interpret, report
-    assert report["use_scan_kernel"] is (not interpret), report
+    assert report["use_scan_kernel"] is (not interpret
+                                         and not categorical), report
     assert report["megakernel"] == ("on" if megakernel else "off"), \
         report
     assert report["leaf_of_pos"] == (
@@ -178,6 +221,26 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     assert report["min_leaves"] > 1, report
     assert report["num_shards"] == shards, report
     assert report["auc"] >= MIN_AUC, report
+    on = "on" if categorical else "off"
+    assert report["cat_scan"] == on, report
+    assert report["lut_partition"] == (
+        "off" if megakernel else on), report
+    if categorical:
+        import numpy as np
+        cat_splits = sum(
+            int((np.asarray(t.decision_type[:t.num_leaves - 1]) & 1).sum())
+            for t in gbdt.models)
+        # the host trees' category sets send every row where the
+        # device's bitset partition sent it
+        gap = float(np.abs(
+            np.asarray(bst.predict(x, raw_score=True), np.float64)
+            - np.asarray(gbdt.train_score[:, 0], np.float64)).max())
+        seen = {"cat_splits": cat_splits, "host_vs_device_score_gap": gap}
+        print(f"path[{learner}]: categorical {json.dumps(seen)}",
+              flush=True)
+        report.update(seen)
+        assert cat_splits > 0, report
+        assert gap <= CAT_SCORE_TOL, report
     return bst, report
 
 
@@ -378,6 +441,19 @@ def main(argv=None) -> int:
         gap = abs(report["train"]["auc"] - report["foil"]["auc"])
         assert gap <= FOIL_AUC_TOL, ("chip path vs XLA foil", gap)
         report["serve"] = stage_serve(bst, x)
+        # the table the megakernel refuses: per-phase kernels, bitset
+        # partition, categorical scan; no scan kernel, still fused
+        cx, cy, cat_params = expo_like(CAT_ROWS)
+        _, report["categorical"] = stage_train(
+            cx, cy, cat_params, CAT_ROUNDS,
+            learner="PartitionedTreeLearner", interpret=False,
+            megakernel=False, categorical=True)
+        report["categorical_foil"] = stage_foil(cx, cy, cat_params,
+                                                CAT_ROUNDS)
+        gap = abs(report["categorical"]["auc"]
+                  - report["categorical_foil"]["auc"])
+        assert gap <= CAT_FOIL_AUC_TOL, ("categorical chip path vs foil",
+                                         gap)
     else:
         mesh_params = dict(PARAMS, tree_learner="data",
                            num_machines=args.devices)

@@ -59,6 +59,20 @@ def _data_from_pandas(data, feature_name, categorical_feature):
     return mat, feature_name, categorical_idx, pandas_categorical
 
 
+def _categorical_from_params(categorical_feature, cfg: Config):
+    """The ``categorical_feature`` keyword, or, where it was left at
+    ``"auto"``, what ``params`` gave under that name or an alias
+    (``cat_feature``, ``categorical_column``, ``cat_column``; basic.py
+    Dataset._lazy_init reads the same keys): column indices
+    ``"0,3,7"``, or names behind ``name:`` as in a config file."""
+    spec = cfg.categorical_feature.strip()
+    if categorical_feature != "auto" or not spec:
+        return categorical_feature
+    if spec.startswith("name:"):
+        return [c for c in spec[len("name:"):].split(",") if c]
+    return [int(c) for c in spec.split(",") if c.strip()]
+
+
 def _resolve_categorical(categorical_feature, feature_name,
                          num_features) -> List[int]:
     if categorical_feature in ("auto", None):
@@ -140,6 +154,8 @@ class Dataset:
         cfg = Config.from_params(self._merged_params())
         data = self.data
         feature_name = self.feature_name
+        categorical = _categorical_from_params(self.categorical_feature,
+                                               cfg)
         cat_idx: List[int] = []
         if isinstance(data, str) \
                 and _InnerDataset.is_binary_file(data):
@@ -196,7 +212,7 @@ class Dataset:
             ref_inner = self.reference._inner \
                 if self.reference is not None else None
             cat_idx = _resolve_categorical(
-                self.categorical_feature, names or feature_name, None)
+                categorical, names or feature_name, None)
             self._inner = _InnerDataset.from_file_two_round(
                 data, cfg, label=self.label, weight=self.weight,
                 group=self.group, init_score=self.init_score,
@@ -232,12 +248,11 @@ class Dataset:
             if feature_name == "auto" and fn:
                 feature_name = fn
             cat_idx = _resolve_categorical(
-                self.categorical_feature, feature_name,
+                categorical, feature_name,
                 data.shape[1])
         elif _is_pandas_df(data):
             data, feature_name, cat_idx, self.pandas_categorical = \
-                _data_from_pandas(data, feature_name,
-                                  self.categorical_feature)
+                _data_from_pandas(data, feature_name, categorical)
         elif _is_sparse(data):
             # stays sparse end to end (Dataset.from_scipy): the raw
             # matrix is never densified (reference CSR/CSC push path,
@@ -245,13 +260,13 @@ class Dataset:
             if feature_name == "auto":
                 feature_name = None
             cat_idx = _resolve_categorical(
-                self.categorical_feature, feature_name, data.shape[1])
+                categorical, feature_name, data.shape[1])
         else:
             data = _to_matrix(data)
             if feature_name == "auto":
                 feature_name = None
             cat_idx = _resolve_categorical(
-                self.categorical_feature, feature_name, data.shape[1])
+                categorical, feature_name, data.shape[1])
 
         ref_inner = self.reference._inner if self.reference is not None \
             else None

@@ -14,8 +14,11 @@ Semantics preserved (file:line refer to the reference):
   * missing handling ``None | Zero | NaN`` (bin.h:26): NaN gets the last
     bin when present and ``use_missing``;
   * forced bounds (``FindBinWithPredefinedBin`` bin.cpp:158-255);
-  * categorical: count-sorted category->bin with 99% mass cutoff and
-    negative values mapped to the NaN bin (bin.cpp:425-497);
+  * categorical: count-sorted category->bin and negative values mapped
+    to the NaN bin (bin.cpp:425-497), with one departure: never more
+    than ``min(max_bin, MAX_CATEGORICAL_BINS)`` bins, where the
+    reference goes on until 99 % of the rows are covered (see the
+    constant);
   * trivial-feature pre-filter (``NeedFilter`` bin.cpp:55-77);
   * ``most_freq_bin`` / ``default_bin`` selection (bin.cpp:511-528);
   * ``ValueToBin`` binary search incl. NaN routing (bin.h:503-540).
@@ -34,6 +37,19 @@ kZeroThreshold = 1e-35
 kSparseThreshold = 0.7
 kMissingZeroMask = 1
 kMissingNaNMask = 2
+
+# A categorical column keeps at most min(max_bin, this) bins. The
+# reference's loop goes on until 99 % of the rows are covered AND
+# max_bin is reached, so a column whose rare categories hold more than
+# 1 % of the rows gets thousands of bins (13,000 Zipf(1) categories:
+# 8,072). Here a categorical split's left set is a bitset of
+# 32 * MAX_CAT_WORDS = 256 bins on every learner (ops/split.py): the
+# scan invalidates a wider column, so it was never split on, and the
+# device route, a byte a bin, refused the table. Cut to the cap, the
+# most frequent categories keep a bin each and the rest share the last
+# bin with unseen and missing values, which is no category and never
+# goes left (docs/ARCHITECTURE.md, "What intentionally differs").
+MAX_CATEGORICAL_BINS = 256
 
 MISSING_NONE = "None"
 MISSING_ZERO = "Zero"
@@ -356,14 +372,17 @@ class BinMapper:
                         dvi.append(dvi[0] + 1)
                     cni[0], cni[1] = cni[1], cni[0]
                     dvi[0], dvi[1] = dvi[1], dvi[0]
-                cut_cnt = int((total_sample_cnt - na_cnt) * 0.99)
-                eff_max_bin = min(len(dvi), max_bin)
                 self.categorical_2_bin = {}
                 self.bin_2_categorical = []
                 used_cnt = 0
                 cur_cat = 0
-                while cur_cat < len(dvi) and (used_cnt < cut_cnt
-                                              or self.num_bin < eff_max_bin):
+                # the reference goes on past max_bin until 99 % of the
+                # rows are covered; under the cap that rule decides
+                # nothing. Room is left for the NaN bin appended below
+                # when every category is kept
+                cap = min(max_bin, MAX_CATEGORICAL_BINS) \
+                    - (1 if na_cnt > 0 else 0)
+                while cur_cat < len(dvi) and self.num_bin < cap:
                     if cni[cur_cat] < min_data_in_bin and cur_cat > 1:
                         break
                     self.bin_2_categorical.append(dvi[cur_cat])
@@ -372,6 +391,13 @@ class BinMapper:
                     cnt_in_bin.append(cni[cur_cat])
                     self.num_bin += 1
                     cur_cat += 1
+                if self.num_bin >= cap and cur_cat < len(dvi) \
+                        and cni[cur_cat] >= min_data_in_bin:
+                    log_warning(
+                        f"A categorical feature has {len(dvi)} categories; "
+                        f"the {self.num_bin - 1} most frequent keep a bin "
+                        "each, the others share the last bin and are "
+                        "never sent left by a split")
                 if cur_cat == len(dvi) and na_cnt > 0:
                     self.bin_2_categorical.append(-1)
                     self.categorical_2_bin[-1] = self.num_bin

@@ -421,6 +421,15 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                 st2.update(bs_bitset=res[5], cat_bitsets=res[6])
             return st2
 
+    # STATIC: only categorical or EFB-bundled splits consult the
+    # partition's LUT; it is compiled out otherwise (hot bench path).
+    # Counted like the megakernel, where the route enters the trace.
+    use_lut_path = bool(params.has_categorical) or bundled
+    if use_lut_path and not use_fused:
+        get_telemetry().count("learner.lut_partition_traces")
+    if params.has_categorical:
+        get_telemetry().count("learner.cat_scan_traces")
+
     # shared scan-leaf composition (learner/split_step.py — the fused
     # megakernel twin calls the SAME maker, keeping both paths
     # bit-identical). Root and per-split scans may differ in layout —
@@ -634,17 +643,15 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         # the 256-entry table encodes "group value -> goes left"
         # including missing handling in feature-bin space (shared with
         # the megakernel twin: partition_decision_lut)
-        grp_col, use_lut, lut = partition_decision_lut(
-            meta, feat, thr, dleft, is_cat, bitset, bundled)
-        mat2, ws2, nl1 = partition_segment(
-            st["mat"], st["ws"], begin, cnt, grp_col, thr,
-            dleft.astype(jnp.int32), meta.missing[feat],
-            meta.default_bin[feat], meta.num_bins[feat],
-            use_lut.astype(jnp.int32), lut, blk=PART_BLK,
-            interpret=interpret,
-            # STATIC: only categorical or EFB-bundled splits consult
-            # the LUT; compile it out otherwise (hot bench path)
-            use_lut_path=bool(params.has_categorical) or bundled)
+        with jax.named_scope(scopes.SPLITS_PARTITION):
+            grp_col, use_lut, lut = partition_decision_lut(
+                meta, feat, thr, dleft, is_cat, bitset, bundled)
+            mat2, ws2, nl1 = partition_segment(
+                st["mat"], st["ws"], begin, cnt, grp_col, thr,
+                dleft.astype(jnp.int32), meta.missing[feat],
+                meta.default_bin[feat], meta.num_bins[feat],
+                use_lut.astype(jnp.int32), lut, blk=PART_BLK,
+                interpret=interpret, use_lut_path=use_lut_path)
         nl = nl1[0]
         nr = cnt - nl
 
@@ -656,12 +663,13 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         # The fused path keeps the pair in (smaller, other) order; the
         # CEGB/pool branches reorder to (left, right)
         if cache_hists:
-            parent_hist = st["hist"][leaf]
             left_small = lc <= rc
             sb = jnp.where(left_small, begin, begin + nl)
             sc = jnp.where(left_small, nl, nr)
-            hist_small = seg_hist(mat2, sb, sc)
-            hist_other = parent_hist - hist_small
+            with jax.named_scope(scopes.SPLITS_HIST):
+                parent_hist = st["hist"][leaf]
+                hist_small = seg_hist(mat2, sb, sc)
+                hist_other = parent_hist - hist_small
             if params.cegb_on:
                 hist_left = jnp.where(left_small, hist_small,
                                       hist_other)
@@ -737,10 +745,11 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                 hist_a, hist_b = hist_left, hist_right
                 begin_a, cnt_a, begin_b, cnt_b = (begin, nl,
                                                   begin + nl, nr)
-            o, split_a, split_b = scan_split_pair(
-                comm, scan_body, a_is_left, k, depth, hist_a, hist_b,
-                lg, lh, lc, rg, rh, rc, lout, rout,
-                cmin_l, cmax_l, cmin_r, cmax_r)
+            with jax.named_scope(scopes.SPLITS_SCAN):
+                o, split_a, split_b = scan_split_pair(
+                    comm, scan_body, a_is_left, k, depth, hist_a,
+                    hist_b, lg, lh, lc, rg, rh, rc, lout, rout,
+                    cmin_l, cmax_l, cmin_r, cmax_r)
 
         # ---- packed column writes (learner/split_step.py) ------------
         fa, ia = child_columns(split_a, o["ga"], o["ha"], o["ca"],

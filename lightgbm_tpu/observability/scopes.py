@@ -38,6 +38,18 @@ GROW_LEAF_OF_POS = "lgbm.grow.leaf_of_pos"
 SCORE_UPDATE = "lgbm.score_update"
 DEVICE_SCOPES = (GRADIENTS, SAMPLE, GROW, GROW_PACK, GROW_ROOT,
                  GROW_SPLITS, GROW_LEAF_OF_POS, SCORE_UPDATE)
+# the parts of the per-phase split body, the one a table the megakernel
+# refuses runs (categorical, bundled): the megakernel's body traces
+# none of them. Three are children of GROW_SPLITS, opened by the
+# learner's loop. The categorical scan is opened by ops/split.py, which
+# knows no learner: it is named for what it is, wherever it runs (the
+# root's one scan a tree, every learner's call of per_feature_splits).
+SPLITS_PARTITION = "lgbm.grow.splits.partition"
+SPLITS_HIST = "lgbm.grow.splits.hist"
+SPLITS_SCAN = "lgbm.grow.splits.scan"
+CAT_SCAN = "lgbm.cat_scan"
+SPLIT_PHASE_SCOPES = (SPLITS_PARTITION, SPLITS_HIST, SPLITS_SCAN,
+                      CAT_SCAN)
 
 # host spans of the fused driver, on the profiler's clock
 # (Telemetry.span(..., trace=<name>))
@@ -55,11 +67,17 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 
 
+# a scope opened inside a transformed function is printed inside the
+# transform's name: ``vmap(lgbm.cat_scan)``
+_COMPONENT = re.compile(r"(?:\w+\()*(" + re.escape(PREFIX) + r"[\w.]+)\)*")
+
+
 def _scope_of(op_name: str) -> Optional[str]:
-    """The last ``lgbm.``-prefixed component of an op path."""
+    """The last ``lgbm.`` component of an op path."""
     for part in reversed(op_name.split("/")):
-        if part.startswith(PREFIX):
-            return part
+        found = _COMPONENT.fullmatch(part)
+        if found is not None:
+            return found.group(1)
     return None
 
 

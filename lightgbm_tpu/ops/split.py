@@ -424,27 +424,29 @@ def per_feature_splits(hist: jnp.ndarray, parent_g, parent_h, parent_c,
                                params, constraint_min, constraint_max,
                                feature_mask, rand_bins)
     if params.has_categorical:
+        from ..observability.scopes import CAT_SCAN
         from .split_categorical import per_feature_categorical
-        cat = per_feature_categorical(hist, parent_g, parent_h, parent_c,
-                                      meta, params, constraint_min,
-                                      constraint_max, feature_mask)
-        use = meta.is_categorical
+        with jax.named_scope(CAT_SCAN):
+            cat = per_feature_categorical(
+                hist, parent_g, parent_h, parent_c, meta, params,
+                constraint_min, constraint_max, feature_mask)
+            use = meta.is_categorical
 
-        def sel(a, b):
-            return jnp.where(use, a, b) if a.ndim == 1 \
-                else jnp.where(use[:, None], a, b)
+            def sel(a, b):
+                return jnp.where(use, a, b) if a.ndim == 1 \
+                    else jnp.where(use[:, None], a, b)
 
-        pf = PerFeatureSplits(
-            score=sel(cat["score"], pf.score),
-            threshold=pf.threshold,
-            left_g=sel(cat["left_g"], pf.left_g),
-            left_h=sel(cat["left_h"], pf.left_h),
-            left_c=sel(cat["left_c"], pf.left_c),
-            default_left=jnp.where(use, False, pf.default_left),
-            left_output=sel(cat["left_output"], pf.left_output),
-            right_output=sel(cat["right_output"], pf.right_output),
-            is_cat=use & jnp.isfinite(cat["score"]),
-            cat_bitset=sel(cat["bitset"], pf.cat_bitset))
+            pf = PerFeatureSplits(
+                score=sel(cat["score"], pf.score),
+                threshold=pf.threshold,
+                left_g=sel(cat["left_g"], pf.left_g),
+                left_h=sel(cat["left_h"], pf.left_h),
+                left_c=sel(cat["left_c"], pf.left_c),
+                default_left=jnp.where(use, False, pf.default_left),
+                left_output=sel(cat["left_output"], pf.left_output),
+                right_output=sel(cat["right_output"], pf.right_output),
+                is_cat=use & jnp.isfinite(cat["score"]),
+                cat_bitset=sel(cat["bitset"], pf.cat_bitset))
     raw_score = pf.score
     if params.cegb_on:
         # CEGB DetlaGain (cost_effective_gradient_boosting.hpp:50-61):

@@ -214,3 +214,116 @@ def test_categorical_prediction_consistency():
     raw = np.asarray(b.predict_raw(X)).ravel()
     from sklearn.metrics import roc_auc_score
     assert roc_auc_score(y, raw) > 0.95
+
+
+@pytest.mark.parametrize("params", [
+    {"categorical_feature": "0,2"}, {"categorical_feature": [0, 2]},
+    {"cat_feature": "0,2"}, {"categorical_column": [2, 0]},
+    {"cat_column": "0,2"}, {"categorical_feature": "name:a,c"}],
+    ids=lambda p: next(iter(p)) + ":" + type(next(iter(p.values()))).__name__)
+def test_dataset_reads_categorical_feature_from_params(params):
+    """``lgb.Dataset(x, params=...)`` honours ``categorical_feature``
+    and its aliases when the keyword is left at "auto", as upstream's
+    ``Dataset._lazy_init`` does (a config file states them this way):
+    one bin layout with the keyword route."""
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(0)
+    x = np.stack([rng.randint(0, 7, 600), rng.randn(600),
+                  rng.randint(0, 300, 600)], axis=1).astype(np.float64)
+    y = (x[:, 0] % 2 == 0).astype(np.float64)
+    names = ["a", "b", "c"]
+    keyword = lgb.Dataset(x, label=y, feature_name=names,
+                          categorical_feature=[0, 2]).construct()._inner
+    through = lgb.Dataset(x, label=y, feature_name=names,
+                          params=dict(params, verbosity=-1)
+                          ).construct()._inner
+    assert [through.feature_mapper(i).bin_type for i in range(3)] \
+        == ["categorical", "numerical", "categorical"]
+    assert through.bin_layout_fingerprint() \
+        == keyword.bin_layout_fingerprint()
+    plain = lgb.Dataset(x, label=y, feature_name=names).construct()._inner
+    assert plain.bin_layout_fingerprint() \
+        != keyword.bin_layout_fingerprint()
+
+
+def test_categorical_keyword_wins_over_params():
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(1)
+    x = rng.randint(0, 5, (400, 2)).astype(np.float64)
+    inner = lgb.Dataset(x, label=x[:, 0] % 2, categorical_feature=[1],
+                        params={"categorical_feature": "0",
+                                "verbosity": -1}).construct()._inner
+    assert [inner.feature_mapper(i).bin_type for i in range(2)] \
+        == ["numerical", "categorical"]
+
+
+def _zipf_column(rng, categories, n):
+    p = 1.0 / np.arange(1, categories + 1)
+    ids = rng.permutation(categories)       # the ids' order says nothing
+    return ids[rng.choice(categories, n, p=p / p.sum())]
+
+
+@pytest.mark.parametrize("max_bin,bins", [(255, 255), (63, 63),
+                                          (1000, 256)])
+def test_a_wide_categorical_column_is_cut_to_max_bin(max_bin, bins):
+    """A categorical column keeps at most min(max_bin, 256) bins
+    (``binning.MAX_CATEGORICAL_BINS``: a split's left set is a 256-bit
+    set on every learner). Read off the raw values, without the
+    mapper's own table: the most frequent categories keep a bin each,
+    all the others share the last bin, which is no category."""
+    from lightgbm_tpu.data.binning import MAX_CATEGORICAL_BINS
+    from lightgbm_tpu.ops.split import MAX_CAT_WORDS
+    assert MAX_CATEGORICAL_BINS == 32 * MAX_CAT_WORDS
+    rng = np.random.RandomState(7)
+    n = 60000
+    col = _zipf_column(rng, 1500, n)
+    x = np.stack([col, rng.randn(n)], axis=1).astype(np.float64)
+    cfg = Config.from_params({"objective": "binary", "max_bin": max_bin,
+                              "verbosity": -1})
+    ds = Dataset.from_numpy(x, cfg, label=(col % 2).astype(np.float64),
+                            categorical_features=[0])
+    m = ds.feature_mapper(0)
+    assert m.num_bin == bins and m.missing_type == "NaN"
+    got = np.asarray(ds.binned[:, 0]).astype(np.int64)
+    ids, counts = np.unique(col, return_counts=True)
+    by_count = ids[np.argsort(-counts, kind="stable")]
+    alone, shared = by_count[:bins - 1], by_count[bins - 1:]
+    own = np.array([np.unique(got[col == c]) for c in alone])
+    assert own.shape == (bins - 1, 1)                 # one bin a category
+    assert len(np.unique(own)) == bins - 1            # and no two alike
+    assert bins - 1 not in own
+    assert (got[np.isin(col, shared)] == bins - 1).all()
+    # a category the table never saw, and a missing value, go there too
+    assert list(m.values_to_bins(np.array([99999.0, np.nan, -1.0]))) \
+        == [bins - 1] * 3
+
+
+@pytest.mark.parametrize("learner", ["serial", "partitioned"])
+def test_a_wide_categorical_column_is_split_on(learner):
+    """Cut to 255 bins, a 1500-category column is split on by both
+    learners (uncut it had about 1,000 bins: the scan invalidated it and
+    the partitioned learner refused the table); the shared last bin
+    never goes left, so the host trees, which send an unlisted category
+    right, agree with the training scores on every row."""
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(11)
+    n = 20000
+    col = _zipf_column(rng, 1500, n)
+    effect = rng.randn(1500)
+    y = (effect[col] + 0.5 * rng.randn(n) > 0).astype(np.float64)
+    x = np.stack([col, rng.randn(n)], axis=1).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+              "categorical_feature": "0", "tree_learner": learner,
+              "metric": ""}
+    gbdt = lgb.Booster(params, lgb.Dataset(x, label=y,
+                                           params=params))._gbdt
+    gbdt.train(3)
+    last = gbdt.train_data.feature_mapper(0).num_bin - 1
+    assert last == 254
+    sets = [_bitset_members(t.cat_bitsets[i]) for t in gbdt.models
+            for i in range(t.num_leaves - 1)
+            if int(t.decision_type[i]) & 1]
+    assert sets and all(last not in s for s in sets)
+    got = np.asarray(gbdt.train_score[:, 0], np.float64)
+    raw = sum(np.asarray(t.predict(x)) for t in gbdt.models)
+    assert np.abs(raw - got).max() <= 1e-5

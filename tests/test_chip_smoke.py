@@ -66,6 +66,24 @@ def test_train_and_serve_stages_tiny(fuse_iters):
     assert serve["bucket_misses_serving"] == 0
 
 
+def test_categorical_stage_tiny(fuse_iters):
+    """The stage of the table the compiled megakernel refuses: the
+    per-phase route with the bitset partition and the categorical scan,
+    in fused blocks, its host trees against the device's scores."""
+    x, y, params = cs.expo_like(3000)
+    assert params["categorical_feature"] == "0,1,2,3,4,5,6,7,8,9,10,11"
+    params = dict(params, num_leaves=15, tree_learner="partitioned")
+    _, report = cs.stage_train(x, y, params, cs.CAT_ROUNDS,
+                               learner="PartitionedTreeLearner",
+                               interpret=True, megakernel=False,
+                               categorical=True)
+    assert report["fused_block_hits"] == 1      # 1 sync + one block of 8
+    assert report["lut_partition"] == "on" and report["cat_scan"] == "on"
+    assert report["cat_splits"] > 0
+    foil = cs.stage_foil(x, y, params, cs.CAT_ROUNDS)
+    assert abs(report["auc"] - foil["auc"]) <= cs.CAT_FOIL_AUC_TOL
+
+
 @pytest.mark.slow
 def test_kernel_and_foil_stages_tiny(fuse_iters):
     kernels = cs.stage_kernels(

@@ -62,6 +62,22 @@ def test_partition_v1_lowers_for_tpu(use_lut):
             jnp.int32(0), jnp.int32(256), jnp.int32(0), lut)
 
 
+def test_lut_partition_lowers_at_the_categorical_width():
+    """The bitset (LUT) partition at the benchmark's categorical table:
+    40 columns in the 128-byte row, a segment that starts off the
+    8-row granule."""
+    from lightgbm_tpu.ops.partition_pallas import (bitset_to_lut,
+                                                   partition_segment)
+    mat = _mat(f=40, b=255)
+    lut = bitset_to_lut(jnp.asarray([0x5, 0, 0, 0, 0, 0, 0, 1 << 30],
+                                    jnp.uint32))
+    _lowers(functools.partial(partition_segment, blk=512,
+                              interpret=False, use_lut_path=True),
+            mat, jnp.zeros_like(mat), jnp.int32(13), jnp.int32(3000),
+            jnp.int32(5), jnp.int32(0), jnp.int32(0), jnp.int32(2),
+            jnp.int32(0), jnp.int32(255), jnp.int32(1), lut)
+
+
 @pytest.mark.parametrize("layout", [
     pytest.param("leaf", marks=pytest.mark.xfail(
         strict=True, raises=LightGBMError,
@@ -226,6 +242,85 @@ def test_full_fused_training_block_lowers_for_tpu(leaves, f):
         static_argnames=("m",))
     fused.trace(ln.mat, ln.ws, b.train_score, (), jnp.float32(0.1),
                 jnp.int32(0), m=4).lower(lowering_platforms=("tpu",))
+
+
+def test_categorical_fused_training_block_lowers_for_tpu():
+    """The fused block of a table with categorical columns, at the
+    benchmark's 40 columns and 255 leaves: the compiled megakernel
+    refuses it (``fused_compiled_ok``), so the grow loop holds the
+    per-phase kernels (bitset partition, segment histogram) and the
+    numeric and categorical XLA scans, and it still rides the fused
+    driver. Lowered with the compiled kernels, as the chip runs it."""
+    import lightgbm_tpu.ops.split_step_pallas as sp
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data import Dataset
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
+    from lightgbm_tpu.models.gbdt import GBDT, _fused_iter_block
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+
+    rng = np.random.RandomState(0)
+    cats = list(range(10))
+    X = rng.randn(2048, 40).astype(np.float32)
+    X[:, :10] = rng.randint(0, 200, (2048, 10)) % np.asarray(
+        [22, 12, 31, 7, 29, 200, 200, 2, 4, 2])
+    y = (X[:, 4] % 2 + X[:, 12] > 0.5).astype(np.float64)
+    cfg = Config.from_params({
+        "objective": "binary", "num_leaves": 255, "verbosity": -1})
+    ds = Dataset.from_numpy(X, cfg, label=y, categorical_features=cats)
+    b = GBDT(cfg, ds)
+    ln = PartitionedTreeLearner(ds, cfg, interpret=False)
+    assert ln.params.has_categorical and not ln.bundled
+    assert not ln.params.use_scan_kernel
+    assert not sp.fused_compiled_ok(ln.params, bundled=ln.bundled,
+                                    num_bins_max=ln.num_bins_max)
+    assert ln.supports_fused_scan and ln.fused_scan_ok()
+    tel = get_telemetry()
+    was_on = tel.enabled
+    tel.ensure_ring()
+    before = {k: tel.counters.get(k, 0) for k in (
+        "learner.lut_partition_traces", "learner.cat_scan_traces",
+        "learner.megakernel_traces")}
+    fused = jax.jit(
+        functools.partial(_fused_iter_block, learner=ln,
+                          grad_fn=b._grad_fn, bag_fn=None,
+                          valid_data=(), k=1),
+        static_argnames=("m",))
+    text = fused.trace(ln.mat, ln.ws, b.train_score, (), jnp.float32(0.1),
+                       jnp.int32(0), m=2).lower(
+        lowering_platforms=("tpu",)).as_text()
+    got = {k: tel.counters.get(k, 0) - v for k, v in before.items()}
+    if not was_on:
+        tel.reset()
+    assert got == {"learner.lut_partition_traces": 1,
+                   "learner.cat_scan_traces": 1,
+                   "learner.megakernel_traces": 0}
+    # the per-phase kernels are Mosaic calls in the lowered module
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_a_column_wider_than_a_byte_raises():
+    """The device route packs a bin in a byte and says so when a column
+    has more than 256 bins: it does not train on another learner. Only
+    a numeric column can: a categorical one is cut to min(max_bin, 256)
+    bins by the binning (``binning.MAX_CATEGORICAL_BINS``), so the same
+    table with the wide column named a category is taken."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data import Dataset
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
+    rng = np.random.RandomState(0)
+    X = rng.randint(0, 600, (8192, 3)).astype(np.float32)
+    y = (X[:, 0] % 2).astype(float)
+    cfg = Config.from_params({"objective": "binary", "max_bin": 400,
+                              "verbosity": -1})
+    ds = Dataset.from_numpy(X[:, :1], cfg, label=y)
+    assert ds.num_bins_array()[0] > 256
+    with pytest.raises(ValueError, match="max 256 bins per feature"):
+        PartitionedTreeLearner(ds, cfg, interpret=False)
+    ds = Dataset.from_numpy(X[:, :1], cfg, label=y,
+                            categorical_features=[0])
+    assert ds.num_bins_array()[0] == 256
+    assert PartitionedTreeLearner(ds, cfg, interpret=False).num_bins_max \
+        == 256
 
 
 @pytest.mark.parametrize("variant", ["grouped", "perfeat"])

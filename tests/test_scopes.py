@@ -68,6 +68,30 @@ def test_the_vocabulary_is_eight_scopes_and_four_spans():
     # the four parts of a tree's growth are children of lgbm.grow
     assert sum(n.startswith(scopes.GROW + ".")
                for n in scopes.DEVICE_SCOPES) == 4
+    # and the per-phase split body's four parts are a list of their
+    # own: the megakernel's body has none of them. Three are children
+    # of the grow loop's scope; the categorical scan belongs to ops/
+    # and is named after no learner's loop
+    assert len(set(scopes.SPLIT_PHASE_SCOPES)) == 4
+    assert sum(n.startswith(scopes.GROW_SPLITS + ".")
+               for n in scopes.SPLIT_PHASE_SCOPES) == 3
+    assert scopes.CAT_SCAN == scopes.PREFIX + "cat_scan"
+    assert not set(scopes.SPLIT_PHASE_SCOPES) & set(scopes.DEVICE_SCOPES)
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    # a scope opened inside a vmapped function is printed inside the
+    # transform's name (both children's scans are one vmapped scan)
+    ("jit(f)/lgbm.grow/lgbm.grow.splits/while/body/"
+     "lgbm.grow.splits.scan/vmap(lgbm.cat_scan)/sort",
+     scopes.CAT_SCAN),
+    ("jit(f)/lgbm.grow/jvp(vmap(lgbm.grow.root))/mul", scopes.GROW_ROOT),
+    ("jit(f)/lgbm.grow/lgbm.grow.splits/while/body/vmap(jit(g))/add",
+     scopes.GROW_SPLITS),
+    ("jit(f)/while/body/vmap(jit(lgbmish))/add", None),
+])
+def test_scope_of_sees_through_a_transform(op_name, scope):
+    assert scopes._scope_of(op_name) == scope
 
 
 # ---------------------------------------------------------------------
@@ -84,13 +108,17 @@ def tel(monkeypatch):
     scopes.forget()
 
 
-def _gbdt(bagging=False, seed=0, rows=500, num_leaves=7):
+def _gbdt(bagging=False, seed=0, rows=500, num_leaves=7, **more):
     rng = np.random.RandomState(seed)
     X = rng.randn(rows, 6)
+    if "categorical_feature" in more:
+        X[:, 3] = rng.randint(0, 9, rows)
+        X[:, 0] += X[:, 3] % 2
     y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] > 0).astype(np.float64)
     params = {"objective": "binary", "num_leaves": num_leaves,
               "verbosity": -1,
               "tree_learner": "partitioned", "metric": ""}
+    params.update(more)
     if bagging:
         params.update(bagging_fraction=0.5, bagging_freq=1)
     return lgb.Booster(params, lgb.Dataset(X, label=y))._gbdt
@@ -122,7 +150,10 @@ def test_scope_table_of_the_fused_block(tel, bagging):
     assert tel.counters["jit.compiles"] == compiles
     assert progs[0].table_s is not None
     owned = collections.Counter(table.values())
-    reachable = set(scopes.DEVICE_SCOPES)
+    # off a TPU the grow loop is the per-phase body, so its three
+    # numeric parts are there; no column is categorical
+    reachable = set(scopes.DEVICE_SCOPES + scopes.SPLIT_PHASE_SCOPES) \
+        - {scopes.CAT_SCAN}
     if not bagging:
         reachable.discard(scopes.SAMPLE)
     assert set(owned) == reachable
@@ -233,8 +264,10 @@ def test_named_scopes_leave_the_compiled_program_as_it_was():
     assert dict(got) == PARENT_OPCODES
     pinned = load_manifest()["programs"]["gbdt_fused_block"]
     assert got["fusion"] == pinned["fusions"]
+    # (the per-phase body: the three numeric split phases are named)
     assert set(scopes.parse_hlo_scopes(text).values()) \
-        == set(scopes.DEVICE_SCOPES) - {scopes.SAMPLE}
+        == set(scopes.DEVICE_SCOPES + scopes.SPLIT_PHASE_SCOPES) \
+        - {scopes.SAMPLE, scopes.CAT_SCAN}
 
 
 # ---------------------------------------------------------------------
@@ -281,3 +314,73 @@ def test_the_block_pass_runs_under_the_leaf_of_pos_scope(tel):
         == {scopes.GROW_LEAF_OF_POS}
     # and the search it replaced is gone from the program
     assert "searchsorted" not in text
+
+
+# ---------------------------------------------------------------------
+# the per-phase split body's four scopes (ISSUE 27)
+_NAMED = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s.*op_name=\"([^\"]*)\"")
+
+
+def _owners(text, table, *needles):
+    """Scopes of the instructions whose op path holds every needle."""
+    return {table.get(m.group(1)) for m in map(_NAMED.match,
+                                               text.splitlines())
+            if m and all(n in m.group(2) for n in needles)}
+
+
+@pytest.mark.parametrize("categorical", [False, True],
+                         ids=["numeric", "categorical"])
+def test_the_split_phases_own_the_per_phase_body(tel, categorical):
+    tel.ensure_ring()
+    more = {"categorical_feature": "3"} if categorical else {}
+    g = _gbdt(rows=600, **more)
+    g.train(1)
+    g.train(3)
+    assert tel.counters.get("learner.megakernel_traces", 0) == 0
+    assert bool(tel.counters.get("learner.cat_scan_traces", 0)) \
+        is categorical
+    assert bool(tel.counters.get("learner.lut_partition_traces", 0)) \
+        is categorical
+    prog = scopes.remembered("gbdt_fused_block")[-1]
+    text = jit_registry.get("gbdt_fused_block").fn.lower(
+        *prog.avals, **prog.static).compile().as_text()
+    table = prog.scopes()
+    in_loop = scopes.GROW_SPLITS + "/while/body"
+    # each kernel's instructions inside the grow loop belong to its
+    # phase; the root histogram stays the root's
+    assert _owners(text, table, in_loop, "partition_segment") \
+        == {scopes.SPLITS_PARTITION}
+    assert _owners(text, table, in_loop, "histogram_segment") \
+        == {scopes.SPLITS_HIST}
+    assert _owners(text, table, scopes.GROW_ROOT + "/",
+                   "histogram_segment") == {scopes.GROW_ROOT}
+    phases = set(table.values()) & set(scopes.SPLIT_PHASE_SCOPES)
+    want = set(scopes.SPLIT_PHASE_SCOPES)
+    if not categorical:
+        want.discard(scopes.CAT_SCAN)
+    assert phases == want
+    if categorical:
+        # both children's scans are one vmapped scan: the categorical
+        # part is found inside the transform's name, and its sort is
+        # not booked to the numeric scan
+        assert _owners(text, table, in_loop,
+                       f"vmap({scopes.CAT_SCAN})") \
+            == {scopes.CAT_SCAN}
+    # what is left to the loop's own scope: the while and its carry
+    assert scopes.GROW_SPLITS in table.values()
+
+
+def test_the_megakernel_body_has_none_of_the_split_phases(tel):
+    """The fused body (the interpret twin here, the compiled kernel on
+    a TPU) traces none of the per-phase code: its table is the eight
+    scopes' it was, so the accepted cells' tables do not change."""
+    tel.ensure_ring()
+    g = _gbdt(rows=600, fused_split_kernel="on")
+    g.train(1)
+    g.train(3)
+    assert tel.counters["learner.megakernel_traces"] >= 1
+    assert tel.counters.get("learner.lut_partition_traces", 0) == 0
+    table = scopes.program_scopes("gbdt_fused_block")
+    assert set(table.values()) \
+        == set(scopes.DEVICE_SCOPES) - {scopes.SAMPLE}
