@@ -176,7 +176,8 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
               ("fused.block_hits", "learner.megakernel_traces",
                "learner.leaf_of_pos_dense_traces",
                "learner.lut_partition_traces",
-               "learner.cat_scan_traces")}
+               "learner.cat_scan_traces",
+               "kernels.partition_pipelined")}
     t0 = time.perf_counter()
     bst = lgb.train(dict(params), lgb.Dataset(x, label=y),
                     num_boost_round=rounds)
@@ -198,6 +199,8 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "lut_partition": "on" if delta["learner.lut_partition_traces"]
         else "off",
         "cat_scan": "on" if delta["learner.cat_scan_traces"] else "off",
+        # kernel traces that took partition_pallas.partition_stream
+        "partition_pipelined": delta["kernels.partition_pipelined"],
         "fused_block_hits": delta["fused.block_hits"],
         "trees": len(leaves),
         "min_leaves": min(leaves),
@@ -225,6 +228,9 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     assert report["cat_scan"] == on, report
     assert report["lut_partition"] == (
         "off" if megakernel else on), report
+    # every compiled partition, the megakernel's phase 0 included, is
+    # the pipelined stream (the megakernel's interpret twin has none)
+    assert report["partition_pipelined"] > 0 or interpret, report
     if categorical:
         import numpy as np
         cat_splits = sum(

@@ -563,8 +563,8 @@ def fused_split_step_segment(k, S, T, mat, ws, hist, imeta, fmeta,
         raise NotImplementedError(
             f"fused split-step Mosaic body: b={b} f={f} exceeds the "
             f"u8-bin / {MAX_FUSED_F}-feature static scope")
+    from .partition_pallas import stream_scratch
     seg_blk = SEG_BLK
-    win = seg_blk + ALIGN
     cols = mat.shape[1]
     fp, bp = hist.shape[2:]        # compiled_hist_cache's padded dims
     imeta, fmeta = _pad_meta_tables(imeta, fmeta, fp)
@@ -586,16 +586,11 @@ def fused_split_step_segment(k, S, T, mat, ws, hist, imeta, fmeta,
                 _whole(imeta.shape), _whole(fmeta.shape)]
     out_specs = [_whole(S.shape), _whole(T.shape), any_spec, any_spec,
                  any_spec]
-    scratch = [
-        pltpu.VMEM((win, cols), jnp.uint8),          # inbuf
-        pltpu.VMEM((win, cols), jnp.float32),        # staged
-        pltpu.VMEM((win, cols), jnp.uint8),          # flushbuf
-        pltpu.VMEM((win, cols), jnp.uint8),          # rbuf
+    scratch = stream_scratch(seg_blk, cols) + [
         pltpu.VMEM((5, fp, bp), jnp.float32),        # hpl planes
         pltpu.VMEM((3, fp, bp), jnp.float32),        # pbuf parent
         pltpu.VMEM((2, 3, fp, bp), jnp.float32),     # cbuf children
         pltpu.SMEM((1,), jnp.int32),                 # nl carry
-        pltpu.SemaphoreType.DMA((3,)),               # sems
         pltpu.SemaphoreType.DMA((2,)),               # sem_w
     ]
     res = pl.pallas_call(
@@ -1045,21 +1040,22 @@ def _leaf_site_scalars(pack, iscal, s_in, imeta_ref, big_l):
 def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,
                         imeta_ref, fmeta_ref,
                         s_out, t_out, mat_out, ws_out, hist_out,
-                        inbuf, staged, flushbuf, rbuf, hpl, pbuf, cbuf,
-                        nl_ref, sems, sem_w,
-                        *, params, si_prefix, big_l, max_depth, b, f,
+                        *scratch,
+                        params, si_prefix, big_l, max_depth, b, f,
                         n, bundled, has_monotone, blk):
     """Mosaic body, segment layout: phase 0 streams the chosen leaf's
     contiguous row segment ONCE — the stable in-place partition
-    (``partition_pallas`` v1 algorithm: tri-matmul prefix sums,
-    permutation matmuls, 8-aligned read-merge-write heads) and the
-    SMALLER child's histogram accumulate from the same window, so
+    (``partition_pallas.partition_stream``: the pipelined block stream
+    ``partition_segment`` runs, imported like ``_decode_block``) and
+    the SMALLER child's histogram accumulate from the same window, so
     partition + histogram cost one read of the rows. Phase 1 is the
     shared subtract/scan/write tail. All lane/row extractions are f32
     select-sums (this Mosaic lowers no integer reductions — the one
     thing that kept partition v1 off-chip)."""
     del mat_in, ws_in, hist_in  # aliased; all access via out refs
     from .hist_pallas import _decode_block
+    from .partition_pallas import partition_stream
+    *stream, hpl, pbuf, cbuf, nl_ref, sem_w = scratch
     pack = _grow_pack(si_prefix, params, has_monotone, big_l)
     pid = pl.program_id(0)
     cols = mat_out.shape[1]
@@ -1081,67 +1077,11 @@ def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,
         for ch in range(5):
             hpl[ch] = jnp.zeros_like(hpl[ch])
 
-        nblk = pl.cdiv(cnt, blk)
-        base = (begin // ALIGN) * ALIGN
-        shift = begin - base
-
         lane_w = _iota_f32((1, cols), 1)
-        row_w = jax.lax.broadcasted_iota(jnp.int32, (win, 1), 0)
-        dst_w8 = jax.lax.broadcasted_iota(jnp.int32, (win, win), 1)
-        row8 = jax.lax.broadcasted_iota(jnp.int32, (win, 1), 0)
         bins_l = _iota_f32((1, hpl.shape[2]), 1)   # pad lanes: no bin
-        tri = (jax.lax.broadcasted_iota(jnp.int32, (win, win), 0)
-               <= jax.lax.broadcasted_iota(jnp.int32, (win, win), 1))
-        tri_bf = jnp.where(tri, jnp.float32(1), 0.0).astype(
-            jnp.bfloat16)
-
-        def copy(src, dst, sem):
-            cp = pltpu.make_async_copy(src, dst, sem)
-            cp.start()
-            cp.wait()
-
-        def compact_and_write(mat_bf, sel, dest, out_hbm):
-            """partition_pallas._partition_kernel's stable compaction:
-            sel rows to ``out_hbm[dest, ...)`` via a permutation
-            matmul + 8-aligned read-merge-write."""
-            sel_bf = sel.astype(jnp.float32).astype(jnp.bfloat16)
-            cs = jax.lax.dot_general(
-                tri_bf, sel_bf, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [win, 1]
-            nsel = cs[win - 1, 0].astype(jnp.int32)
-            wstart = (dest // ALIGN) * ALIGN
-            dshift = dest - wstart
-            slot = jnp.where(sel > 0,
-                             dshift + cs.astype(jnp.int32) - 1, -1)
-            pt = jnp.where(slot == dst_w8, jnp.float32(1),
-                           0.0).astype(jnp.bfloat16)     # [win, win]
-            staged[...] = jax.lax.dot_general(
-                pt, mat_bf, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [win, C]
-            copy(out_hbm.at[pl.ds(pl.multiple_of(wstart, ALIGN), win),
-                            :], rbuf, sems.at[1])
-            keep = (row8 >= dshift) & (row8 < dshift + nsel)
-            flushbuf[...] = jnp.where(
-                keep, staged[...].astype(jnp.int32),
-                rbuf[...].astype(jnp.int32)).astype(jnp.uint8)
-            copy(flushbuf, out_hbm.at[pl.ds(pl.multiple_of(
-                wstart, ALIGN), win), :], sems.at[2])
-            return nsel
-
         fsel = jnp.where(lane_w == feat_f, jnp.float32(1), 0.0)
 
-        def block_body(k_i, carry):
-            dest_l, dest_r = carry
-            copy(mat_out.at[pl.ds(pl.multiple_of(
-                base + k_i * blk, ALIGN), win), :], inbuf, sems.at[0])
-            mat_i32 = inbuf[...].astype(jnp.int32)       # [win, C]
-            mat_f = mat_i32.astype(jnp.float32)
-            mat_bf = mat_f.astype(jnp.bfloat16)
-
-            rem = jnp.minimum(cnt - k_i * blk, blk)
-            valid = jnp.where((row_w >= shift)
-                              & (row_w < shift + rem), 1, 0)
-
+        def decide(mat_i32, mat_f, valid, shift, rem):
             # split feature's bin per row: f32 one-hot lane reduce
             bv = jnp.sum(mat_f * fsel, axis=1,
                          keepdims=True)                  # [win, 1]
@@ -1172,30 +1112,11 @@ def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,
                     preferred_element_type=jnp.float32)  # [8, B]
                 for ch in range(5):
                     hpl[ch, pl.ds(fx, 1), :] += res[ch:ch + 1, :]
+            return gl, gr
 
-            nl_blk = compact_and_write(mat_bf, gl, dest_l, mat_out)
-            nr_blk = compact_and_write(mat_bf, gr, dest_r, ws_out)
-            return dest_l + nl_blk, dest_r + nr_blk
-
-        dest_l, _dest_r = jax.lax.fori_loop(
-            0, nblk, block_body, (begin, jnp.int32(0)))
-        nl_total = dest_l - begin
+        nl_total, _ = partition_stream(mat_out, ws_out, stream, begin,
+                                       cnt, decide, blk=blk)
         nl_ref[0] = nl_total
-
-        # rights from the workspace -> mat[begin+NL, begin+cnt)
-        nr_total = cnt - nl_total
-
-        def back_body(j, _):
-            copy(ws_out.at[pl.ds(pl.multiple_of(j * blk, ALIGN), win),
-                           :], inbuf, sems.at[0])
-            cnt_j = jnp.minimum(nr_total - j * blk, blk)
-            sel = jnp.where((row_w >= 0) & (row_w < cnt_j), 1, 0)
-            mat_bf = inbuf[...].astype(jnp.int32).astype(
-                jnp.float32).astype(jnp.bfloat16)
-            compact_and_write(mat_bf, sel, dest_l + j * blk, mat_out)
-            return 0
-
-        jax.lax.fori_loop(0, pl.cdiv(nr_total, blk), back_body, 0)
 
         # parent slab prefetch for phase 1 (channels-major cache row)
         cp = pltpu.make_async_copy(hist_out.at[leaf], pbuf,

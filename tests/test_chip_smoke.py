@@ -60,6 +60,9 @@ def test_train_and_serve_stages_tiny(fuse_iters):
         learner="PartitionedTreeLearner", interpret=True,
         megakernel=True)
     assert report["fused_block_hits"] == 1     # 1 sync + one block of 16
+    # the megakernel's interpret twin partitions by a prefix-sum
+    # permutation: only a compiled phase 0 traces the pipelined stream
+    assert report["partition_pipelined"] == 0
     serve = cs.stage_serve(bst, x, sizes=(1, 512))
     assert [r["route"] for r in serve["requests"]] == ["device"] * 2
     assert serve["fallbacks"] == 0
@@ -79,6 +82,10 @@ def test_categorical_stage_tiny(fuse_iters):
                                categorical=True)
     assert report["fused_block_hits"] == 1      # 1 sync + one block of 8
     assert report["lut_partition"] == "on" and report["cat_scan"] == "on"
+    # partition_segment's kernel is the pipelined stream on every
+    # platform: counted per kernel trace, so 0 only where an earlier
+    # test of this process already traced the same shapes
+    assert isinstance(report["partition_pipelined"], int)
     assert report["cat_splits"] > 0
     foil = cs.stage_foil(x, y, params, cs.CAT_ROUNDS)
     assert abs(report["auc"] - foil["auc"]) <= cs.CAT_FOIL_AUC_TOL

@@ -348,3 +348,103 @@ def test_leaf_of_pos_block_pass_lowers_for_tpu(num_leaves):
     _lowers(functools.partial(leaf_of_pos, n=10_500_000,
                               interpret=False),
             table, table, jax.ShapeDtypeStruct((), jnp.int32))
+
+
+# ---- PR 28: the pipelined partition stream ---------------------------
+
+def test_both_partition_kernels_lower_the_pipelined_stream():
+    """``partition_segment`` (LUT on and off) and the megakernel's
+    phase 0 lower ONE block step, ``partition_pallas.partition_stream``:
+    the trace-time counter says the helper entered each trace."""
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    from lightgbm_tpu.ops import partition_pallas, split_step_pallas
+    assert not hasattr(split_step_pallas, "compact_and_write")
+    tel = get_telemetry()
+    was_on = tel.enabled
+    tel.ensure_ring()
+    name = "kernels.partition_pipelined"
+    before = tel.counters.get(name, 0)
+    mat = _mat(n=6000)          # a shape no other test traced
+    lut = jnp.zeros((1, 256), jnp.float32)
+    for use_lut in (True, False):
+        _lowers(functools.partial(partition_pallas.partition_segment,
+                                  blk=512, interpret=False,
+                                  use_lut_path=use_lut),
+                mat, jnp.zeros_like(mat), jnp.int32(13),
+                jnp.int32(5000), 14, jnp.int32(128), jnp.int32(0),
+                jnp.int32(0), jnp.int32(0), jnp.int32(256),
+                jnp.int32(0), lut)
+    after_partition = tel.counters.get(name, 0)
+    jax.clear_caches()
+    split_step_pallas.lower_for_tpu("segment")
+    after_mega = tel.counters.get(name, 0)
+    if not was_on:
+        tel.reset()
+    assert after_partition - before == 2
+    assert after_mega - after_partition >= 1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described TPU v5e to compile for (no chip attached): what the
+    chip's compiler refuses, it refuses here. Made inside the fixture
+    so only the worker that runs this file loads the TPU's library."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                         # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("use_lut", [True, False])
+def test_pipelined_partition_compiles_for_v5e(one_chip, use_lut):
+    """The real size: a 1 M-row, 128-byte-row matrix. Dynamic slot
+    indices on the u8 buffers, ``pl.when`` around DMAs inside
+    ``fori_loop`` and the carried heads' 8-row slices all pass the
+    chip's compiler."""
+    from lightgbm_tpu.ops.partition_pallas import partition_segment
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    mat = sds((1_003_528, 128), jnp.uint8)
+    i32 = sds((), jnp.int32)
+    jax.jit(functools.partial(
+        partition_segment, blk=512, use_lut_path=use_lut)).lower(
+        mat, mat, *([i32] * 9), sds((1, 256), jnp.float32)).compile()
+
+
+def test_pipelined_megakernel_compiles_for_v5e(one_chip):
+    """The megakernel at the Higgs cell's shapes (10.5 M x 28, 255
+    leaves, 256 bins) with the shared stream in phase 0."""
+    from lightgbm_tpu.learner.partitioned import SEG_SI_PREFIX
+    from lightgbm_tpu.learner.split_step import make_grow_pack
+    from lightgbm_tpu.ops import split_step_pallas as ssp
+    from lightgbm_tpu.ops.hist_pallas import matrix_cols, matrix_rows
+    from lightgbm_tpu.ops.split import SplitParams
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    big_l, f, b, n = 255, 28, 256, 10_500_000
+    params = SplitParams(
+        lambda_l1=0.0, lambda_l2=1.0, max_delta_step=0.0,
+        min_data_in_leaf=20.0, min_sum_hessian_in_leaf=1e-3,
+        min_gain_to_split=0.0, any_missing=False)
+    pack = make_grow_pack(SEG_SI_PREFIX, merged=True, has_cat=False,
+                          has_monotone=False, big_l=big_l)
+    S = sds((len(pack.sf_fields) + len(pack.si_fields), big_l),
+            jnp.int32)
+    T = sds((len(pack.tf_fields) + len(pack.ti_fields), big_l - 1),
+            jnp.int32)
+    hist = sds((big_l, 3, -(-f // 8) * 8, -(-b // 128) * 128),
+               jnp.float32)
+    mat = sds((matrix_rows(n, ssp.FUSED_BLK), matrix_cols(f)),
+              jnp.uint8)
+    jax.jit(functools.partial(
+        ssp.fused_split_step_segment, params=params,
+        si_prefix=SEG_SI_PREFIX, big_l=big_l, max_depth=-1, b=b, f=f,
+        n=n, bundled=False, has_monotone=False, blk=ssp.FUSED_BLK,
+        interpret=False)).lower(
+        sds((), jnp.int32), S, T, mat, mat, hist,
+        sds((f, 8), jnp.int32), sds((f, 2), jnp.float32)).compile()

@@ -8,6 +8,8 @@ exact coverage the v2 kernel had (stability, missing routing,
 categorical LUT, all-one-side edge cases).
 """
 
+import functools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -105,3 +107,199 @@ def test_partition_all_one_side():
         assert int(nl[0]) == (1200 if side == "left" else 0)
         rid = np.asarray(extract_row_ids(m2, f, mat.shape[0]))
         np.testing.assert_array_equal(rid[:1500], np.arange(1500))
+
+
+# ---- PR 28: the pipelined stream (prefetched input, write heads
+# carried in VMEM, window writes left in flight) -----------------------
+
+from lightgbm_tpu.ops import partition_pallas
+from lightgbm_tpu.ops.partition_pallas import ALIGN, merge_windows
+
+P_BLK, P_F, P_B, P_COL, P_THR = 512, 5, 64, 2, 30
+P_ROWS = 7 + 5 * P_BLK + 3 + 40          # largest begin + count, + slack
+P_SENTINEL = 0xAB
+
+SPLITS = {
+    "all_left": lambda i, rng: np.ones_like(i, bool),
+    "all_right": lambda i, rng: np.zeros_like(i, bool),
+    "one_right_in_100": lambda i, rng: i % 100 != 57,
+    "half": lambda i, rng: rng.rand(i.size) < 0.5,
+    "alternating": lambda i, rng: i % 2 == 0,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _stream_inputs(split):
+    """Matrix whose column P_COL sends row i left as SPLITS[split]
+    says, for the numeric threshold and for the category table."""
+    rng = np.random.RandomState(11)
+    left = SPLITS[split](np.arange(P_ROWS), rng)
+    binned = rng.randint(0, P_B, (P_ROWS, P_F)).astype(np.uint8)
+    binned[:, P_COL] = np.where(left, rng.randint(0, P_THR + 1, P_ROWS),
+                                rng.randint(P_THR + 1, P_B, P_ROWS))
+    mat = build_matrix(jnp.asarray(binned), 2048)
+    mat = pack_gh(mat, P_F,
+                  jnp.asarray(rng.randn(P_ROWS).astype(np.float32)),
+                  jnp.asarray(rng.rand(P_ROWS).astype(np.float32)),
+                  jnp.ones((P_ROWS,), jnp.float32))
+    bits = np.zeros(8, np.uint32)
+    for cv in range(P_THR + 1):
+        bits[cv // 32] |= np.uint32(1) << np.uint32(cv % 32)
+    return left, mat, np.asarray(mat), bitset_to_lut(jnp.asarray(bits))
+
+
+def _run_stream(split, begin, count, use_lut, mat=None):
+    left, mat0, _, lut = _stream_inputs(split)
+    mat = mat0 if mat is None else mat
+    ws = jnp.full(mat.shape, P_SENTINEL, jnp.uint8)
+    # the category table decides where the LUT path is compiled in;
+    # the threshold is then one no row passes
+    thr, is_cat = (-1, 1) if use_lut else (P_THR, 0)
+    return partition_segment(
+        mat, ws, jnp.int32(begin), jnp.int32(count), jnp.int32(P_COL),
+        jnp.int32(thr), jnp.int32(0), jnp.int32(0), jnp.int32(0),
+        jnp.int32(P_B), jnp.int32(is_cat), lut, blk=P_BLK,
+        interpret=True, use_lut_path=use_lut)
+
+
+def _check_stream(split, begin, count, res, mat_np=None):
+    """Stable order, NL, the merge-window count, and every row the
+    call does not own untouched."""
+    left, _, mat0_np, _ = _stream_inputs(split)
+    mat_np = mat0_np if mat_np is None else mat_np
+    m2, w2, nl = (np.asarray(a) for a in res)
+    sl = slice(begin, begin + count)
+    go = left[sl]
+    assert int(nl[0]) == int(go.sum())
+    want = np.concatenate([mat_np[sl][go], mat_np[sl][~go]])
+    np.testing.assert_array_equal(m2[sl], want)      # whole rows, in order
+    np.testing.assert_array_equal(m2[:begin], mat_np[:begin])
+    np.testing.assert_array_equal(m2[begin + count:],
+                                  mat_np[begin + count:])
+    # the workspace is scratch up to the end of the last rights window
+    nr = count - int(go.sum())
+    assert (w2[(nr // ALIGN) * ALIGN + P_BLK + ALIGN:]
+            == P_SENTINEL).all()
+    np.testing.assert_array_equal(w2[:nr], mat_np[sl][~go])
+    nl_by_block = [int(go[k:k + P_BLK].sum())
+                   for k in range(0, count, P_BLK)]
+    assert int(nl[1]) == merge_windows(begin, count, nl_by_block, P_BLK)
+
+
+@pytest.mark.parametrize("use_lut", [False, True])
+@pytest.mark.parametrize("split", list(SPLITS))
+@pytest.mark.parametrize("count", [0, 1, 7, 511, 512, 513, 520,
+                                   5 * 512 + 3])
+@pytest.mark.parametrize("begin", range(8))
+def test_pipelined_stream_property(begin, count, split, use_lut):
+    _check_stream(split, begin, count,
+                  _run_stream(split, begin, count, use_lut))
+
+
+def test_merge_windows_is_the_rule():
+    # 5 blocks + 3 rows, half left: block 0 (no right has gone yet),
+    # back window 0 and the last back window; at an even split the
+    # last left window ends far inside the consumed rows
+    nlb = [256] * 5 + [1]
+    assert merge_windows(3, 5 * 512 + 3, nlb, 512) == 3
+    # all left: no right ever frees a window, every block merges
+    assert merge_windows(0, 2048, [512] * 4, 512) == 4
+    # all right: block 0 only, then back window 0 and the last one
+    assert merge_windows(0, 2048, [0] * 4, 512) == 3
+    assert merge_windows(5, 0, [], 512) == 0
+
+
+class _LateCopies:
+    """``pltpu`` stand-in for the hazard tests: the chosen async copies
+    land when they are WAITED for, not when they are started — the
+    other end of what the hardware may do (the interpreter lands them
+    at ``start``). A kernel that is right at both ends reads no window
+    before its write was waited for, refills no buffer in flight, and
+    leaves no write un-waited (that one never lands)."""
+
+    def __init__(self, real, late_reads, late_writes):
+        self._real, self._r, self._w = real, late_reads, late_writes
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+    def make_async_copy(self, src, dst, sem):
+        cp = self._real.make_async_copy(src, dst, sem)
+        is_write = "any" in str(getattr(dst, "ref", dst).aval)
+        if not (self._w if is_write else self._r):
+            return cp
+
+        class Late:
+            def start(self):
+                pass
+
+            def wait(self):
+                cp.start()
+                cp.wait()
+        return Late()
+
+
+@pytest.fixture
+def late_copies(monkeypatch):
+    import jax
+
+    def arm(reads, writes):
+        jax.clear_caches()
+        monkeypatch.setattr(
+            partition_pallas, "pltpu",
+            _LateCopies(partition_pallas.pltpu, reads, writes))
+    yield arm
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("reads,writes,hazard", [
+    # the prefetched window of block k+1 begins inside block k's rows,
+    # which block k's fast left write overwrites: read late, those
+    # rows hold the NEW content; they must reach nothing
+    (True, False, "prefetch_reads_rows_a_fast_write_touched"),
+    # a merge-path read must see the last write of its side, and no
+    # flush buffer may be refilled while its write is in flight
+    (False, True, "merge_reads_after_its_sides_last_write"),
+    # the back-copy's first workspace read, and whoever runs next,
+    # must see every write
+    (True, True, "every_write_lands_before_the_stream_returns"),
+])
+@pytest.mark.parametrize("begin,count,split", [
+    (5, 5 * 512 + 3, "half"), (3, 5 * 512 + 3, "one_right_in_100"),
+    (7, 1030, "alternating")])
+def test_pipelined_stream_hazards(late_copies, reads, writes, hazard,
+                                  begin, count, split):
+    late_copies(reads, writes)
+    res = _run_stream(split, begin, count, False)
+    _check_stream(split, begin, count, res)
+    # the next split of the same rows sees what this one wrote
+    nl = int(res[2][0])
+    if nl > 1:
+        m1 = np.asarray(res[0])
+        res2 = partition_segment(
+            res[0], res[1], jnp.int32(begin), jnp.int32(nl),
+            jnp.int32(0), jnp.int32(P_B // 2), jnp.int32(0),
+            jnp.int32(0), jnp.int32(0), jnp.int32(P_B), jnp.int32(0),
+            jnp.zeros((1, 256), jnp.float32), blk=P_BLK,
+            interpret=True, use_lut_path=False)
+        go = m1[begin:begin + nl, 0] <= P_B // 2
+        np.testing.assert_array_equal(
+            np.asarray(res2[0])[begin:begin + nl],
+            np.concatenate([m1[begin:begin + nl][go],
+                            m1[begin:begin + nl][~go]]))
+
+
+def test_masked_rows_reach_nothing():
+    """Rows a window holds beside the block's own (the neighbour's
+    before ``begin``, whatever follows the segment) reach neither the
+    decision nor a carried head: filled with 0xFF (bin 255, NaN
+    payload) they change nothing and come back untouched."""
+    begin, count, split = 5, 1030, "half"
+    _, mat, mat_np, _ = _stream_inputs(split)
+    poisoned = mat_np.copy()
+    poisoned[:begin] = 0xFF
+    poisoned[begin + count:begin + count + 600] = 0xFF
+    res = _run_stream(split, begin, count, False,
+                      mat=jnp.asarray(poisoned))
+    _check_stream(split, begin, count, res, mat_np=poisoned)

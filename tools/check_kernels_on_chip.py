@@ -92,7 +92,9 @@ def stage_partition_v1(interpret: bool = False,
     import numpy as np
 
     from lightgbm_tpu.ops.hist_pallas import extract_row_ids
-    from lightgbm_tpu.ops.partition_pallas import partition_segment
+    from lightgbm_tpu.ops.partition_pallas import (merge_windows,
+                                                   partition_segment,
+                                                   stream_windows)
     rng = np.random.RandomState(1)
     failures = 0
     for n, f, b in shapes:
@@ -116,12 +118,22 @@ def stage_partition_v1(interpret: bool = False,
                 rid_orig = np.arange(n)[sl]
                 want = np.concatenate([rid_orig[go_left],
                                        rid_orig[~go_left]])
+                # windows that read their destination back before
+                # writing it: the kernel's count against the host rule
+                merged = merge_windows(
+                    begin, count, [int(go_left[k:k + 512].sum())
+                                   for k in range(0, count, 512)], 512)
+                windows = stream_windows(count, nl_o, 512)
                 ok = (int(nl_c[0]) == nl_o
-                      and np.array_equal(rid_seg[:count], want))
+                      and np.array_equal(rid_seg[:count], want)
+                      and int(nl_c[1]) == merged)
                 print(f"partition [{n}x{f}] "
                       f"seg=({begin},{count}) lut={use_lut}: "
                       f"{'ok ' if ok else 'FAIL'} "
-                      f"left={int(nl_c[0])}/{nl_o}", flush=True)
+                      f"left={int(nl_c[0])}/{nl_o} "
+                      f"merged={int(nl_c[1])}/{windows} windows "
+                      f"({int(nl_c[1]) / max(windows, 1):.1%}; "
+                      f"host rule {merged})", flush=True)
                 failures += 0 if ok else 1
     return failures
 
