@@ -41,7 +41,6 @@ CPU_BASELINE_TIMEOUT_S = 420
 # linear_tree=true to reach the constant-leaf model's validation loss
 # on dense numeric regression, recorded in the bench JSON
 LINEAR_CONV_TIMEOUT_S = 300
-FUSED_SPLIT_TIMEOUT_S = 420
 
 # >=100-iteration fixed-config quality gate (VERDICT r5 weak #5):
 # quality_ok now means "within `tolerance` AUC of the committed
@@ -552,90 +551,6 @@ def measure_linear():
     print(json.dumps(result))
 
 
-def measure_fused_split():
-    """Fused split-step megakernel vs the per-phase foil on the serial
-    learner (ops/split_step_pallas.py): steady-state per-split wall
-    time both ways at a fixed shape, plus the modeled bytes per row
-    of each phase (the kernel reads the row streams ONCE for
-    partition + histogram — the point of the fusion). The parent
-    forces this block onto the CPU, where the kernel is its interpret
-    twin: a structural cost, with no device rate or roofline share."""
-    import time as _time
-
-    import numpy as np
-
-    n = int(os.environ.get("BENCH_FUSED_ROWS", 20_000))
-    f = int(os.environ.get("BENCH_FUSED_FEATURES", 28))
-    leaves = int(os.environ.get("BENCH_FUSED_LEAVES", 63))
-    trees = int(os.environ.get("BENCH_FUSED_TREES", 3))
-
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.data.dataset import Dataset
-    from lightgbm_tpu.learner.serial import SerialTreeLearner
-    from lightgbm_tpu.utils.roofline import (fused_leaf_bytes_per_row,
-                                             hist_bytes_per_row,
-                                             part_bytes_per_row)
-
-    rng = np.random.RandomState(11)
-    x = rng.randn(n, f).astype(np.float32)
-    y = (x[:, 0] - 0.5 * x[:, 1] + 0.3 * rng.randn(n) > 0) \
-        .astype(np.float32)
-    cfg = Config.from_params({"objective": "binary",
-                              "num_leaves": leaves,
-                              "min_data_in_leaf": 20,
-                              "verbosity": -1})
-    grad = jnp.asarray(y - 0.5)
-    hess = jnp.full((n,), 0.25, jnp.float32)
-
-    def time_mode(mode: str) -> float:
-        os.environ["LGBM_TPU_FUSED_SPLIT_KERNEL"] = mode
-        try:
-            ds = Dataset.from_numpy(x, cfg, label=y)
-            lrn = SerialTreeLearner(ds, cfg)
-            res = lrn.train(grad, hess)          # warmup + compile
-            jax.block_until_ready(res.tree.num_leaves)
-            t0 = _time.perf_counter()
-            for _ in range(trees):
-                res = lrn.train(grad, hess)
-            jax.block_until_ready(res.tree.num_leaves)
-            return (_time.perf_counter() - t0) / trees
-        finally:
-            os.environ.pop("LGBM_TPU_FUSED_SPLIT_KERNEL", None)
-
-    t_foil = time_mode("0")
-    t_fused = time_mode("1")
-    splits = leaves - 1
-    per_split_fused = t_fused / splits
-    per_split_foil = t_foil / splits
-    phases = {
-        "stream": fused_leaf_bytes_per_row(f),
-        "hist_equiv": hist_bytes_per_row(f),
-        "partition_equiv": part_bytes_per_row(f),
-    }
-    result = {
-        "metric": "fused_split_kernel",
-        "value": round(per_split_fused * 1e3, 4),
-        "unit": "ms/split",
-        "backend": jax.default_backend(),
-        "baseline_config": f"fused-split-v1-{n}r-{f}f-{leaves}l",
-        "fused_split": {
-            "per_split_ms": round(per_split_fused * 1e3, 4),
-            "foil_per_split_ms": round(per_split_foil * 1e3, 4),
-            "speedup_vs_foil": round(per_split_foil
-                                     / max(per_split_fused, 1e-9), 3),
-            "rows": n, "features": f, "leaves": leaves,
-            # modeled bytes/row per phase: the fused stream reads the
-            # rows ONCE where the per-phase kernels stream them for
-            # the partition AND the histogram build separately
-            "phase_bytes_per_row": phases,
-        },
-    }
-    print(json.dumps(result))
-
-
 def measure_mesh_scaling():
     """Mesh-learner scaling curve on the virtual CPU mesh: for each
     parallel mode and device count, steady-state wall time per split
@@ -766,32 +681,6 @@ def run_mesh_scaling_block(env, remaining):
     return parsed
 
 
-def run_fused_split_block(env, remaining):
-    """Run the fused-split child on the CPU backend (trend-gated
-    structural cost; the on-chip number comes from the perf-sequence
-    promotion run). Prints its JSON line and returns it."""
-    if os.environ.get("BENCH_NO_FUSED_SPLIT") or remaining < 90:
-        return None
-    envc = _cpu_env(env)
-    envc.pop("_BENCH_CHILD", None)
-    envc["_BENCH_CHILD_FUSED"] = "1"
-    try:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__)], env=envc,
-            capture_output=True, text=True,
-            timeout=max(90.0, min(FUSED_SPLIT_TIMEOUT_S, remaining)))
-    except subprocess.TimeoutExpired:
-        sys.stderr.write("fused-split child timed out\n")
-        return None
-    parsed = find_result_line(proc.stdout)
-    if parsed is None:
-        sys.stderr.write("fused-split child failed:\n"
-                         + proc.stderr[-2000:] + "\n")
-        return None
-    print(json.dumps(parsed), flush=True)
-    return parsed
-
-
 def _classify_probe(detail: str) -> str:
     """Structured probe-failure reason code (tools/probe_taxonomy.py:
     no_device / init_timeout / compile_error / transport / unknown);
@@ -834,8 +723,7 @@ def emit_probe_telemetry(ok: bool, detail: str, dur_s: float) -> None:
 
 
 def find_result_line(stdout: str):
-    """Locate and parse the last JSON result line in bench output
-    (shared with tools/bench_sweep.py)."""
+    """Locate and parse the last JSON result line in bench output."""
     found = None
     for line in stdout.splitlines():
         line = line.strip()
@@ -1085,9 +973,6 @@ def main():
     if os.environ.get("_BENCH_CHILD_LINEAR") == "1":
         measure_linear()
         return
-    if os.environ.get("_BENCH_CHILD_FUSED") == "1":
-        measure_fused_split()
-        return
     if os.environ.get("_BENCH_CHILD_MESH") == "1":
         measure_mesh_scaling()
         return
@@ -1140,7 +1025,7 @@ def main():
 
     # fixed-config CPU blocks (forced onto the CPU backend; counts and
     # structural costs, never device metrics). Pinned single-size runs
-    # (tools/bench_sweep.py) skip them.
+    # (BENCH_ROWS) skip them.
     if pinned is None:
         # dispatch census first (cheap, feeds the baseline line)
         census_parsed = run_dispatch_census(
@@ -1149,8 +1034,6 @@ def main():
             env, budget - (time.monotonic() - t_start),
             dispatches=(census_parsed or {}).get("value"))
         run_linear_convergence(
-            env, budget - (time.monotonic() - t_start))
-        run_fused_split_block(
             env, budget - (time.monotonic() - t_start))
         run_mesh_scaling_block(
             env, budget - (time.monotonic() - t_start))
@@ -1165,7 +1048,7 @@ def main():
         remaining = budget - (time.monotonic() - t_start)
         if printed_any and remaining < SIZE_MIN_BUDGET.get(rows, 60):
             break  # keep what we have; don't start a run we can't finish
-        # pinned single-size runs (tools/bench_sweep.py) get the whole
+        # pinned single-size runs (BENCH_ROWS) get the whole
         # budget; the per-size caps only shape the escalation plan
         cap = budget if pinned is not None else SIZE_TIMEOUT.get(rows, 1800)
         parsed, last_err = _run_child(

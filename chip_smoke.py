@@ -251,17 +251,14 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
 
 
 def _megakernel_reason(ln) -> str:
-    """What the static gate says, next to what the trace counted."""
-    from lightgbm_tpu.learner.split_step import fused_split_kernel_mode
-    # the mesh learners have no megakernel gate: their collectives
-    # sit between the per-phase kernels
-    if not hasattr(ln, "_fused_kernel_on"):
-        return "mesh learner: collectives between per-phase kernels"
-    mode = fused_split_kernel_mode(
-        getattr(ln.config, "fused_split_kernel", "auto"))
-    gate = "on" if ln._fused_kernel_on() else "off"
-    return f"fused_split_kernel={mode}: gate {gate} " \
-        "(ops/split_step_pallas.py learner_fused_kernel_on)"
+    """What the plan says, next to what the trace counted. The mesh
+    learners have no megakernel: their collectives sit between the
+    per-phase kernels."""
+    plan = ln.split_plan()
+    return f"fused_split_kernel={ln.config.fused_split_kernel}: " \
+        f"plan {plan.body}" \
+        f"{'' if getattr(ln, 'has_megakernel', False) else ' (learner has no megakernel)'}" \
+        " (learner/split_step.py plan_split_step)"
 
 
 def stage_foil(x, y, params, rounds: int) -> dict:
@@ -278,7 +275,7 @@ def stage_foil(x, y, params, rounds: int) -> dict:
     foil = SerialTreeLearner(gbdt.train_data, gbdt.config,
                              hist_method="onehot")
     foil.params = foil.params._replace(use_scan_kernel=False)
-    assert not foil._fused_kernel_on()
+    assert foil.split_plan().body == "per_phase"
     gbdt.learner = foil
     gbdt.train(rounds)
     report = {"learner": "SerialTreeLearner(hist_method='onehot')",

@@ -262,10 +262,12 @@ class Config:
     # ---- learning control (config.h:236-517)
     force_col_wise: bool = False
     force_row_wise: bool = False
-    # fused split-step megakernel gate (ops/split_step_pallas.py):
-    # auto = on where the Mosaic lowering probe passes (compiled
-    # backends, numerical fast path), on/off force it. The
-    # LGBM_TPU_FUSED_SPLIT_KERNEL env var overrides per process.
+    # split-step megakernel (ops/split_step_pallas.py), one input of
+    # learner/split_step.py plan_split_step: auto = on a TPU where its
+    # compiled body applies (partitioned learner, numeric unbundled
+    # table of byte bins, nothing between the phases); on = wherever
+    # it is eligible (the interpret twin off a TPU), an error on a
+    # learner that has none; off = the per-phase kernels.
     fused_split_kernel: str = "auto"
     histogram_pool_size: float = -1.0
     max_depth: int = -1

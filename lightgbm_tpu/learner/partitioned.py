@@ -41,11 +41,14 @@ from ..utils.jit_registry import register_jit
 from ..ops.hist_pallas import (build_matrix, extract_row_ids,
                                histogram_segment, pack_gh)
 from ..ops.leaf_of_pos import leaf_of_pos, uses_block_pass
-from ..ops.partition_pallas import bitset_to_lut, partition_segment
-from ..ops.split_scan_pallas import scan_kernel_default as _scan_default
+from ..ops.partition_pallas import (partition_decision_lut,
+                                    partition_segment)
 from ..ops.split import (MAX_CAT_WORDS,
-                         _argmax_first, assemble_split,
-                         leaf_output_no_constraint, per_feature_splits)
+                         _argmax_first, assemble_split, child_columns,
+                         child_constraints, leaf_output_no_constraint,
+                         make_scan_leaf, order_child_pair,
+                         per_feature_splits, scan_split_pair,
+                         set_bitsets, split_node_updates)
 from ..models.linear import LinearLeafFitMixin
 from .serial import (CegbStateMixin, GrowResult, NodeRandMixin,
                      cegb_pf_state, cegb_refund,
@@ -54,50 +57,27 @@ from .serial import (CegbStateMixin, GrowResult, NodeRandMixin,
                      feature_meta_from_dataset,
                      forced_left_sums, forced_split_override,
                      make_node_rand, split_params_from_config)
-from .split_step import (StatePack, child_columns, child_constraints,
-                         fused_split_eligible, make_grow_pack,
-                         make_scan_leaf, order_child_pair,
-                         scan_split_pair, set_bitsets,
-                         split_fusion_default, split_node_updates)
+from .split_step import (SplitStepPlan, StatePack, make_grow_pack,
+                         plan_split_step, split_fusion_default)
 
 HIST_BLK = 2048
 PART_BLK = 512
 
 
-def partition_decision_lut(meta, feat, thr, dleft, is_cat, bitset,
-                           bundled: bool):
-    """(grp_col, use_lut, lut) for one split's physical partition —
-    the 256-entry "group value -> goes left" table encoding decode +
-    missing handling in feature-bin space for bundled splits, the raw
-    bin bitset for categorical ones. ONE definition shared by the
-    foil's ``partition_segment`` call and the fused megakernel's
-    interpret twin (bit-exactness-critical)."""
-    lut = jnp.where(is_cat, bitset_to_lut(bitset),
-                    jnp.zeros((1, 256), jnp.float32))
-    grp_col = meta.group[feat] if bundled else feat
-    use_lut = is_cat
-    if bundled:
-        from ..data.bundling import decode_feature_bin
-        off = meta.offset[feat]
-        nbf = meta.num_bins[feat]
-        vals = jnp.arange(256, dtype=jnp.int32)
-        # offset 0 would pass values through; masked by
-        # is_bundled_split below, so raw splits keep the fast path
-        fbin = decode_feature_bin(vals, off, nbf)
-        mcode = meta.missing[feat]
-        is_miss = jnp.where(
-            mcode == 1, fbin == meta.default_bin[feat],
-            jnp.where(mcode == 2, fbin == nbf - 1, False))
-        go_left = jnp.where(is_miss, dleft, fbin <= thr)
-        blut = go_left.astype(jnp.float32).reshape(1, 256)
-        is_bundled_split = (off > 0) & ~is_cat
-        lut = jnp.where(is_bundled_split, blut, lut)
-        use_lut = is_cat | is_bundled_split
-    return grp_col, use_lut, lut
-
 # the partitioned loop's int state additionally carries the physical
 # segment bounds (learner/split_step.py:StatePack)
 SEG_SI_PREFIX = ("leaf_begin", "leaf_cnt")
+
+
+def segment_grow_pack(big_l: int, *, merged: bool = True,
+                      has_cat: bool = False,
+                      has_monotone: bool = False) -> StatePack:
+    """The partitioned grow loop's carry layout for one static config:
+    what ``grow_partitioned`` packs and what the split-step megakernel
+    is handed (tests and tools that call the kernel alone build it
+    here too)."""
+    return make_grow_pack(SEG_SI_PREFIX, merged=merged, has_cat=has_cat,
+                          has_monotone=has_monotone, big_l=big_l)
 
 
 class PartitionedLearnerBase(NodeRandMixin, CegbStateMixin,
@@ -109,6 +89,25 @@ class PartitionedLearnerBase(NodeRandMixin, CegbStateMixin,
     like the serial learner's."""
 
     _count_tree_telemetry = count_tree_telemetry
+    # what ``split_plan`` tells the chooser of this learner: only the
+    # single-device learner has a split-step megakernel; the mesh
+    # learners put collectives between the phases
+    has_megakernel = False
+    serial_comm = False
+
+    def split_plan(self) -> SplitStepPlan:
+        """Which split step this learner's grow program runs
+        (learner/split_step.py ``plan_split_step``, the one place that
+        decides). Resolved per train() / traceable_grow() call and
+        passed on as one static argument."""
+        return plan_split_step(
+            mode=self.config.fused_split_kernel, params=self.params,
+            bundled=self.bundled, num_bins_max=self.num_bins_max,
+            num_leaves=self.num_leaves, forced_plan=self.forced_plan,
+            extra_trees=self.extra_trees, ff_bynode=self.ff_bynode,
+            cache_hists=self.cache_hists, serial_comm=self.serial_comm,
+            interpret=self.interpret,
+            has_megakernel=self.has_megakernel)
 
     def _setup_partitioned(self, dataset: Dataset, config: Config,
                            interpret: Optional[bool]) -> None:
@@ -120,24 +119,12 @@ class PartitionedLearnerBase(NodeRandMixin, CegbStateMixin,
         from .serial import dataset_any_missing
         if interpret is None:
             interpret = not on_tpu()
-        # the fused Pallas split-scan kernel engages on compiled
-        # backends only (interpret mode / CPU tests keep the XLA scan
-        # so cross-learner parity stays bit-exact there; the kernel's
-        # math is covered by test_split_scan_pallas). Like the
-        # reference's GPU learner, the fused scan may differ from the
-        # XLA scan at f32-rounding level (gpu_tree_learner.cpp:299).
-        # Scan calls are collective-free in every comm (collectives
-        # wrap the scan, never sit inside it), so this is safe for the
-        # mesh learners too.
-        base_params = split_params_from_config(config)
         has_cat = any(
             dataset.feature_mapper(i).bin_type == BIN_TYPE_CATEGORICAL
             for i in range(dataset.num_features))
-        self.params = base_params._replace(
+        self.params = split_params_from_config(config)._replace(
             has_categorical=has_cat,
-            any_missing=dataset_any_missing(dataset),
-            use_scan_kernel=not interpret and _scan_default(
-                eligible=not has_cat and not base_params.cegb_on))
+            any_missing=dataset_any_missing(dataset))
         _, _, group_bins = dataset.bundle_maps()
         self.num_bins_max = max(
             int(dataset.num_bins_array().max(initial=2)),
@@ -166,6 +153,16 @@ class PartitionedLearnerBase(NodeRandMixin, CegbStateMixin,
         self.hist_slots = hist_pool_slots(
             config, self.num_leaves, self.num_groups, self.num_bins_max)
         self.cache_hists = self.hist_slots >= self.num_leaves
+        # the Pallas split-scan kernel engages on compiled backends
+        # only (interpret mode / CPU tests keep the XLA scan so
+        # cross-learner parity stays bit-exact there; the kernel's math
+        # is covered by test_split_scan_pallas). Like the reference's
+        # GPU learner, it may differ from the XLA scan at f32-rounding
+        # level (gpu_tree_learner.cpp:299). Scan calls are
+        # collective-free in every comm (collectives wrap the scan,
+        # never sit inside it), so the mesh learners get it too.
+        self.params = self.params._replace(
+            use_scan_kernel=self.split_plan().scan_kernel)
         self._init_cegb()
         self._drop_cegb_lazy("partitioned learners keep rows "
                              "physically reordered")
@@ -180,6 +177,9 @@ class PartitionedLearnerBase(NodeRandMixin, CegbStateMixin,
 
 class PartitionedTreeLearner(PartitionedLearnerBase):
     """Drop-in for SerialTreeLearner backed by the segment kernels."""
+
+    has_megakernel = True
+    serial_comm = True
 
     def __init__(self, dataset: Dataset, config: Config,
                  hist_method: str = "auto", interpret: Optional[bool] = None):
@@ -212,16 +212,10 @@ class PartitionedTreeLearner(PartitionedLearnerBase):
             forced_plan=self.forced_plan, hist_slots=self.hist_slots,
             has_monotone=self.has_monotone,
             split_fusion=split_fusion_default(),
-            fused_kernel=self._fused_kernel_on())
+            plan=self.split_plan())
         res = GrowResult(tree=tree, leaf_id=leaf_id)
         self._cegb_after_tree(res)
         return res
-
-    def _fused_kernel_on(self) -> bool:
-        """Megakernel gate (ops/split_step_pallas.py), read per train()
-        call so env flips retrace."""
-        from ..ops.split_step_pallas import learner_fused_kernel_on
-        return learner_fused_kernel_on(self, "segment")
 
     # -- fused-scan training hook (models/gbdt.py _train_fused_blocks) --
     supports_fused_scan = True
@@ -253,8 +247,7 @@ class PartitionedTreeLearner(PartitionedLearnerBase):
             cache_hists=self.cache_hists, hist_slots=self.hist_slots,
             has_monotone=self.has_monotone,
             split_fusion=split_fusion_default(),
-            fused_kernel=self._fused_kernel_on(),
-            return_leaf_parts=True)
+            plan=self.split_plan(), return_leaf_parts=True)
 
 
 @register_jit("partitioned_grow", donate=(0, 1))
@@ -264,8 +257,7 @@ class PartitionedTreeLearner(PartitionedLearnerBase):
                               "num_groups", "n", "bundled", "interpret",
                               "extra_trees", "ff_bynode", "bynode_count",
                               "forced_plan", "cache_hists", "hist_slots",
-                              "has_monotone", "split_fusion",
-                              "fused_kernel"),
+                              "has_monotone", "split_fusion", "plan"),
     donate_argnums=(0, 1))
 def _grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                       rand_key=None, cegb_used0=None, *, params,
@@ -274,7 +266,7 @@ def _grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                       extra_trees=False, ff_bynode=1.0,
                       bynode_count=2, forced_plan=(), cache_hists=True,
                       hist_slots=None, has_monotone=True,
-                      split_fusion=True, fused_kernel=False):
+                      split_fusion=True, plan):
     return grow_partitioned(
         mat, ws, grad, hess, bag_weight, feature_mask, meta,
         rand_key=rand_key, params=params, num_leaves=num_leaves,
@@ -285,7 +277,7 @@ def _grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         forced_plan=forced_plan, cache_hists=cache_hists,
         cegb_used0=cegb_used0, hist_slots=hist_slots,
         has_monotone=has_monotone, split_fusion=split_fusion,
-        fused_kernel=fused_kernel)
+        plan=plan)
 
 
 def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
@@ -296,9 +288,14 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                      row_id_base=0, n_total=None, cache_hists=True,
                      cegb_used0=None, hist_slots=None,
                      has_monotone=True, split_fusion=None,
-                     fused_kernel=False, return_leaf_parts=False,
+                     plan: SplitStepPlan, return_leaf_parts=False,
                      body_scan=None):
     """Traceable partitioned grow loop.
+
+    ``plan`` (learner/split_step.py ``plan_split_step``, resolved by
+    the learner) says which split step to trace: the megakernel or the
+    per-phase body, and whether the partition consults its LUT. This
+    function obeys it and decides nothing of the kind itself.
 
     ``comm`` injects the parallel-learner collectives (learner/comm.py)
     so the mesh data-/voting-parallel learners run the SAME segment
@@ -375,25 +372,20 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         split_fusion = split_fusion_default()
     # static per-trace packing of the grow-loop carry
     # (learner/split_step.py)
-    pack = make_grow_pack(SEG_SI_PREFIX, merged=split_fusion,
-                          has_cat=params.has_categorical,
-                          has_monotone=has_monotone, big_l=big_l)
+    pack = segment_grow_pack(big_l, merged=split_fusion,
+                             has_cat=params.has_categorical,
+                             has_monotone=has_monotone)
     node_rand = make_node_rand(rand_key, feature_mask, bynode_count,
                                meta.num_bins, extra_trees, ff_bynode)
 
     if params.cegb_on and cegb_used0 is None:
         cegb_used0 = jnp.zeros((num_features,), bool)
 
-    # ---- fused split-step megakernel gate (ops/split_step_pallas.py):
-    # the whole split — leaf pick, physical partition, smaller-child
-    # segment histogram + sibling subtraction, both children's scans,
-    # state/tree/hist writes — becomes ONE pallas_call; ineligible
-    # configs (CEGB / RNG / pool-bounded / mesh comms) keep the foil
-    use_fused = bool(fused_kernel) and fused_split_eligible(
-        params, cache_hists=cache_hists, merged=split_fusion,
-        extra_trees=extra_trees, ff_bynode=ff_bynode,
-        serial_comm=comm is _SER, num_leaves=big_l) \
-        and (interpret or not forced_plan)
+    # ---- split-step megakernel (ops/split_step_pallas.py): the whole
+    # split — leaf pick, physical partition, smaller-child segment
+    # histogram + sibling subtraction, both children's scans,
+    # state/tree/hist writes — is ONE pallas_call where the plan says so
+    use_fused = plan.body == "megakernel"
     if use_fused:
         from ..ops.split_step_pallas import (fused_split_step_segment,
                                              pack_meta_tables)
@@ -409,7 +401,7 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                 st_packed["ws"], st_packed["hist"], imeta_tab,
                 fmeta_tab, st_packed.get("bs_bitset"),
                 st_packed.get("cat_bitsets"), params=params,
-                si_prefix=SEG_SI_PREFIX, big_l=big_l,
+                pack=pack, comm=comm, big_l=big_l,
                 max_depth=max_depth, b=b, f=f, n=n, bundled=bundled,
                 has_monotone=has_monotone, blk=HIST_BLK,
                 interpret=interpret)
@@ -424,13 +416,13 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
     # STATIC: only categorical or EFB-bundled splits consult the
     # partition's LUT; it is compiled out otherwise (hot bench path).
     # Counted like the megakernel, where the route enters the trace.
-    use_lut_path = bool(params.has_categorical) or bundled
+    use_lut_path = plan.lut_partition
     if use_lut_path and not use_fused:
         get_telemetry().count("learner.lut_partition_traces")
-    if params.has_categorical:
+    if plan.cat_scan:
         get_telemetry().count("learner.cat_scan_traces")
 
-    # shared scan-leaf composition (learner/split_step.py — the fused
+    # shared scan-leaf composition (ops/split.py — the fused
     # megakernel twin calls the SAME maker, keeping both paths
     # bit-identical). Root and per-split scans may differ in layout —
     # see grow_tree (learner/serial.py) for the recipe split.
@@ -751,7 +743,7 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                     hist_b, lg, lh, lc, rg, rh, rc, lout, rout,
                     cmin_l, cmax_l, cmin_r, cmax_r)
 
-        # ---- packed column writes (learner/split_step.py) ------------
+        # ---- packed column writes (ops/split.py child_columns) -------
         fa, ia = child_columns(split_a, o["ga"], o["ha"], o["ca"],
                                o["out_a"], o["cmin_a"], o["cmax_a"],
                                s, o["side_a"], depth,

@@ -36,21 +36,18 @@ from ..data.binning import (BIN_TYPE_CATEGORICAL, MISSING_NAN, MISSING_NONE,
 from ..data.dataset import Dataset
 from ..models.linear import LinearLeafFitMixin
 from ..models.tree import Tree, TreeArrays
-from ..utils.device import on_tpu
 from ..utils.jit_registry import register_jit
 from ..ops.histogram import build_histogram, make_ghc
 from ..ops.partition import split_leaf
 from ..ops.split import (MAX_CAT_WORDS, MISSING_NAN_CODE, MISSING_NONE_CODE,
                          MISSING_ZERO_CODE, FeatureMeta, SplitParams,
-                         _argmax_first, assemble_split,
-                         per_feature_splits)
-from ..ops.split_scan_pallas import \
-    scan_kernel_default as _scan_kernel_default
-from .split_step import (StatePack, child_columns, child_constraints,
-                         fused_split_eligible, make_grow_pack,
-                         make_scan_leaf, order_child_pair,
+                         _argmax_first, assemble_split, child_columns,
+                         child_constraints, make_scan_leaf,
+                         order_child_pair, per_feature_splits,
                          scan_split_pair, set_bitsets,
-                         split_fusion_default, split_node_updates)
+                         split_node_updates)
+from .split_step import (SplitStepPlan, StatePack, make_grow_pack,
+                         plan_split_step, split_fusion_default)
 
 _MISSING_CODE = {MISSING_NONE: MISSING_NONE_CODE,
                  MISSING_ZERO: MISSING_ZERO_CODE,
@@ -605,20 +602,12 @@ class SerialTreeLearner(NodeRandMixin, CegbStateMixin,
         self.config = config
         self._init_node_rand(dataset, config)
         self.meta = feature_meta_from_dataset(dataset, config)
-        base_params = split_params_from_config(config)
         has_cat = any(
             dataset.feature_mapper(i).bin_type == BIN_TYPE_CATEGORICAL
             for i in range(dataset.num_features))
-        self.params = base_params._replace(
+        self.params = split_params_from_config(config)._replace(
             has_categorical=has_cat,
-            any_missing=dataset_any_missing(dataset),
-            # fused Pallas split scan on compiled backends (see
-            # learner/partitioned.py rationale; scans are
-            # collective-free in every comm, so the mesh learners
-            # built on this base get it too). Ineligible configs
-            # (categorical/CEGB) skip the probe compile entirely.
-            use_scan_kernel=_scan_kernel_default(
-                eligible=not has_cat and not base_params.cegb_on))
+            any_missing=dataset_any_missing(dataset))
         # the mesh learners defer device placement to the sharded
         # ingest path (parallel/ingest.py): a plain jnp.asarray here
         # would stage the FULL matrix on the default device before the
@@ -641,16 +630,30 @@ class SerialTreeLearner(NodeRandMixin, CegbStateMixin,
         self.cache_hists = use_hist_cache(
             config, self.num_leaves, dataset.num_groups,
             self.num_bins_max)
+        # Pallas split scan on compiled backends (see
+        # learner/partitioned.py for the rationale; scans are
+        # collective-free in every comm, so the mesh learners built on
+        # this base get it too)
+        self.params = self.params._replace(
+            use_scan_kernel=self.split_plan().scan_kernel)
         self._init_cegb()
         # no-sampling defaults, built ONCE (see PartitionedTreeLearner)
         self._ones_rows = jnp.ones((dataset.num_data,), jnp.float32)
         self._all_features = jnp.ones((dataset.num_features,), bool)
 
-    def _fused_kernel_on(self) -> bool:
-        """Megakernel gate (ops/split_step_pallas.py), read per train()
-        call so env flips retrace."""
-        from ..ops.split_step_pallas import learner_fused_kernel_on
-        return learner_fused_kernel_on(self, "leaf")
+    def split_plan(self) -> SplitStepPlan:
+        """Which split step this learner's grow program runs
+        (learner/split_step.py ``plan_split_step``). The leaf_id layout
+        has no megakernel, so the body is always the per-phase one and
+        ``grow_tree`` takes no plan; ``fused_split_kernel=on`` raises
+        here, when the learner is built."""
+        return plan_split_step(
+            mode=self.config.fused_split_kernel, params=self.params,
+            bundled=self.bundled, num_bins_max=self.num_bins_max,
+            num_leaves=self.num_leaves, forced_plan=self.forced_plan,
+            extra_trees=self.extra_trees, ff_bynode=self.ff_bynode,
+            cache_hists=self.cache_hists, mv_groups=self.mv_groups,
+            has_megakernel=False)
 
     def train(self, grad: jnp.ndarray, hess: jnp.ndarray,
               bag_weight: Optional[jnp.ndarray] = None,
@@ -681,8 +684,7 @@ class SerialTreeLearner(NodeRandMixin, CegbStateMixin,
                         mv_slots=self.mv_slots,
                         mv_groups=self.mv_groups,
                         has_monotone=self.has_monotone,
-                        split_fusion=split_fusion_default(),
-                        fused_kernel=self._fused_kernel_on())
+                        split_fusion=split_fusion_default())
         self._cegb_after_tree(res)
         if res.cegb_charged is not None:
             self._cegb_charged = res.cegb_charged
@@ -706,8 +708,7 @@ class SerialTreeLearner(NodeRandMixin, CegbStateMixin,
                               "num_bins_max", "hist_method", "bundled",
                               "extra_trees", "ff_bynode", "bynode_count",
                               "forced_plan", "cache_hists", "mv_groups",
-                              "has_monotone", "split_fusion",
-                              "fused_kernel"),
+                              "has_monotone", "split_fusion"),
     # the CEGB lazy charged matrix [N, F] is replaced by the grow
     # result every tree — the input buffer is dead the moment the
     # program launches, so donate it (the largest state array a CEGB
@@ -719,8 +720,7 @@ def _grow_jit(binned, grad, hess, bag_weight, feature_mask, meta,
               params, num_leaves, max_depth, num_bins_max, hist_method,
               bundled=False, extra_trees=False, ff_bynode=1.0,
               bynode_count=2, forced_plan=(), cache_hists=True,
-              mv_groups=0, has_monotone=True, split_fusion=True,
-              fused_kernel=False):
+              mv_groups=0, has_monotone=True, split_fusion=True):
     return grow_tree(binned, grad, hess, bag_weight, feature_mask,
                      meta=meta, params=params, num_leaves=num_leaves,
                      max_depth=max_depth, num_bins_max=num_bins_max,
@@ -731,8 +731,7 @@ def _grow_jit(binned, grad, hess, bag_weight, feature_mask, meta,
                      cegb_used0=cegb_used0, cegb_charged0=cegb_charged0,
                      mv_slots=mv_slots, mv_groups=mv_groups,
                      has_monotone=has_monotone,
-                     split_fusion=split_fusion,
-                     fused_kernel=fused_kernel)
+                     split_fusion=split_fusion)
 
 
 def grow_tree(binned, grad, hess, bag_weight, feature_mask, *,
@@ -747,7 +746,6 @@ def grow_tree(binned, grad, hess, bag_weight, feature_mask, *,
               mv_slots=None, mv_groups: int = 0,
               has_monotone: bool = True,
               split_fusion: bool | None = None,
-              fused_kernel: bool = False,
               body_scan=None) -> GrowResult:
     """One full leaf-wise tree; jit-compiled once per shape.
 
@@ -825,45 +823,6 @@ def grow_tree(binned, grad, hess, bag_weight, feature_mask, *,
                                meta_hist.num_bins, extra_trees, ff_bynode,
                                bynode_cap=bynode_cap)
 
-    # ---- fused split-step megakernel gate (ops/split_step_pallas.py):
-    # the whole split — leaf pick, partition, smaller-child histogram +
-    # sibling subtraction, both children's scans, state/tree/hist
-    # writes — becomes ONE pallas_call; statically ineligible configs
-    # (CEGB / per-node RNG / pool-bounded hist memory / multi-val /
-    # non-serial comms) keep the per-phase foil
-    from .comm import SERIAL_COMM as _SERIAL_C
-    use_fused = bool(fused_kernel) and fused_split_eligible(
-        params, cache_hists=cache_hists, merged=split_fusion,
-        extra_trees=extra_trees, ff_bynode=ff_bynode,
-        mv_groups=mv_groups, serial_comm=comm is _SERIAL_C,
-        num_leaves=big_l)
-    if use_fused:
-        from ..observability.telemetry import get_telemetry
-        from ..ops.split_step_pallas import (fused_split_step_leaf,
-                                             pack_meta_tables)
-        get_telemetry().count("learner.megakernel_traces")
-        imeta_tab, fmeta_tab = pack_meta_tables(meta_hist,
-                                                feature_mask)
-
-        def body_fused(st_packed):
-            k = st_packed["k"]
-            res = fused_split_step_leaf(
-                k, st_packed["S"], st_packed["T"],
-                st_packed["leaf_id"], st_packed["hist"], binned_hist,
-                ghc, imeta_tab, fmeta_tab,
-                st_packed.get("bs_bitset"),
-                st_packed.get("cat_bitsets"), params=params,
-                si_prefix=(), big_l=big_l, max_depth=max_depth, b=b,
-                bundled=bundled, has_monotone=has_monotone,
-                hist_method=hist_method, interpret=not on_tpu())
-            st2 = dict(st_packed)
-            st2.update(S=res[0], T=res[1], leaf_id=res[2],
-                       hist=res[3], k=k + 1)
-            # static dict-key membership, not a traced condition
-            if "bs_bitset" in st_packed:  # graftlint: allow[GL104]
-                st2.update(bs_bitset=res[4], cat_bitsets=res[5])
-            return st2
-
     f_logical = meta_hist.num_bins.shape[0]
     if params.cegb_on and cegb_used0 is None:
         cegb_used0 = jnp.zeros((f_logical,), bool)
@@ -879,9 +838,9 @@ def grow_tree(binned, grad, hess, bag_weight, feature_mask, *,
         return m.sum() - (charged.astype(jnp.float32)
                           * m[:, None]).sum(axis=0)
 
-    # shared scan-leaf composition (learner/split_step.py — the fused
-    # megakernel's interpret twin calls the SAME maker, which is what
-    # keeps the two paths bit-identical). The root and per-split scans
+    # shared scan-leaf composition (ops/split.py — the partitioned
+    # learner and its megakernel's interpret twin call the SAME maker,
+    # which keeps the paths bit-identical). The root and per-split scans
     # may differ in layout: the root scans ``root_hist`` with the
     # global meta (and the recipe's select_root), per-split scans use
     # the ``body_scan`` shard context when the comm reduces child
@@ -1020,10 +979,6 @@ def grow_tree(binned, grad, hess, bag_weight, feature_mask, *,
         return (st["k"] < big_l) & (open_gain.max() > 0.0)
 
     def body(st_packed, forced=None, forced_hist=None):
-        if use_fused and forced is None:
-            # the whole split is ONE pallas_call (megakernel); forced
-            # pre-steps keep the per-phase foil below
-            return body_fused(st_packed)
         st = pack.view(st_packed)  # row views, folded by XLA
         k = st["k"]
         new = k
@@ -1094,8 +1049,8 @@ def grow_tree(binned, grad, hess, bag_weight, feature_mask, *,
             meta.missing[feat], meta.default_bin[feat],
             meta.num_bins[feat], is_cat, bitset)
 
-        # ---- tree arrays (split_node_updates — the shared helper the
-        # fused megakernel twin also calls) -----------------------------
+        # ---- tree arrays (split_node_updates, shared with the
+        # partitioned learner and its megakernel) ----------------------
         pside = site["ref_side"]
         depth = site["leaf_depth"] + 1
         treef, treei, pnode, upd = split_node_updates(

@@ -1,4 +1,7 @@
-"""Shared per-split step machinery for the fused grow loops.
+"""The split step of the fused grow loops: which one runs
+(``plan_split_step``) and the packed carry it works on (``StatePack``).
+What one split scans and writes, the definitions the grow bodies share
+with the megakernel, is below both, in ``ops/split.py``.
 
 The serial (``learner/serial.py``) and partitioned
 (``learner/partitioned.py``) learners compile the whole
@@ -39,12 +42,16 @@ across bagging, categorical and linear_tree configs.
 
 from __future__ import annotations
 
+import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.split import MAX_CAT_WORDS
+from ..utils import LightGBMError
+from ..utils.device import on_tpu
 
 
 def split_fusion_default() -> bool:
@@ -55,43 +62,95 @@ def split_fusion_default() -> bool:
         not in ("0", "false", "off")
 
 
-def fused_split_kernel_mode(config_value: str = "auto") -> str:
-    """Resolve the fused split-step megakernel gate
-    (ops/split_step_pallas.py) to one of "on" / "off" / "auto".
+class SplitStepPlan(NamedTuple):
+    """Which split step one learner's grow program runs. Resolved by
+    ``plan_split_step`` and obeyed everywhere else: the learners pass
+    it to their grow function as ONE static argument, set
+    ``SplitParams.use_scan_kernel`` from it, and nothing downstream
+    asks the platform or re-derives eligibility."""
+    # "megakernel": the whole split is one pallas_call
+    # (ops/split_step_pallas.py); "per_phase": partition_segment,
+    # histogram_segment and the scans, one after the other
+    body: str = "per_phase"
+    # numeric scans by the Pallas scan kernel
+    # (ops/split_scan_pallas.py), else by XLA
+    scan_kernel: bool = False
+    # the partition decision may come from the 256-entry table
+    # (categorical bitset, EFB decode) instead of the threshold compare
+    lut_partition: bool = False
+    # the scan holds the categorical search (ops/split_categorical.py)
+    cat_scan: bool = False
 
-    The LGBM_TPU_FUSED_SPLIT_KERNEL env var overrides the config param
-    (same kill-switch ergonomics as LGBM_TPU_SPLIT_FUSION): 0/false/off
-    force the per-phase foil, 1/on force the kernel (interpret twin on
-    CPU — the census/test vehicle), anything else keeps "auto" =
-    default on where lowerable (compiled backends whose Mosaic accepts
-    the kernel; the probe emits a reason_code when it cannot lower)."""
-    env = os.environ.get("LGBM_TPU_FUSED_SPLIT_KERNEL", "").lower()
-    if env in ("0", "false", "off"):
-        return "off"
-    if env in ("1", "on", "force"):
-        return "on"
-    if env in ("auto",):
-        return "auto"
-    return config_value if config_value in ("on", "off") else "auto"
 
+def plan_split_step(*, mode: str, params, bundled: bool,
+                    num_bins_max: int, num_leaves: int,
+                    forced_plan=(), extra_trees: bool = False,
+                    ff_bynode: float = 1.0, cache_hists: bool = True,
+                    mv_groups: int = 0, serial_comm: bool = True,
+                    interpret: bool = False,
+                    has_megakernel: bool = False,
+                    merged: bool | None = None,
+                    tpu: bool | None = None) -> SplitStepPlan:
+    """THE decision of which split step runs, from what can be
+    observed: the platform (``tpu``; None asks ``on_tpu()``, the one
+    place this choice asks it), ``mode`` = ``Config.fused_split_kernel``
+    (auto / on / off), the table (``params.has_categorical``,
+    ``bundled``, ``num_bins_max``), the training options that put
+    per-split work between the phases, and the learner (``interpret``,
+    ``has_megakernel``: only the single-device partitioned learner has
+    one; ``serial_comm``: the mesh learners put collectives between the
+    phases). docs/ARCHITECTURE.md holds the same rule as a table.
 
-def fused_split_eligible(params, *, cache_hists: bool, merged: bool,
-                         extra_trees: bool, ff_bynode: float,
-                         mv_groups: int = 0, serial_comm: bool = True,
-                         num_leaves: int = 0) -> bool:
-    """STATIC eligibility of the fused split-step megakernel for one
-    grow trace. The kernel owns the whole split — leaf pick, partition,
+    The megakernel owns the whole split (leaf pick, partition,
     smaller-child histogram + sibling subtraction, both children's
-    scans, state/tree/hist writes — so anything that injects per-split
-    work the kernel does not model falls back to the per-phase foil:
-    CEGB (candidate-cache bookkeeping), per-node RNG (extra-trees /
-    by-node sampling), pool-bounded histogram memory (no parent to
-    subtract from), multi-val pseudo-groups, and non-serial comms
-    (collectives must sit between phases, never inside one kernel)."""
-    return (merged and cache_hists and serial_comm
-            and not params.cegb_on and not extra_trees
-            and ff_bynode >= 1.0 and mv_groups == 0
-            and num_leaves >= 2)
+    scans, state / tree / histogram writes), so whatever injects work
+    it does not model keeps the per-phase body: CEGB's candidate cache,
+    per-node randomness (extra-trees, by-node sampling), a pool-bounded
+    histogram cache (no parent to subtract from), multi-val
+    pseudo-groups, the legacy unpacked carry. ``auto`` takes it on a
+    TPU where its compiled body applies (numeric, unbundled, byte bins,
+    no forced splits); ``on`` takes it wherever it is eligible, as the
+    interpret twin off a TPU, and raises on a learner that has none. A
+    kernel this selects and Mosaic refuses is a compile error, never a
+    quiet run on the other body."""
+    if tpu is None:
+        tpu = on_tpu()
+    if merged is None:
+        merged = split_fusion_default()
+    has_cat = bool(params.has_categorical)
+    if mode == "on" and not has_megakernel:
+        raise LightGBMError(
+            "fused_split_kernel=on: this learner has no split-step "
+            "megakernel, only tree_learner=partitioned on one device "
+            "has (the serial learner's leaf_id layout has no compiled "
+            "body: the TPU compiler refuses its row slabs, \"Slice "
+            "shape along dimension 1 must be aligned to tiling (128), "
+            "but is 28\"; the mesh learners' collectives sit between "
+            "the phases)")
+    # what the megakernel does not model keeps the per-phase body
+    eligible = (has_megakernel and merged and cache_hists
+                and serial_comm and not params.cegb_on
+                and not extra_trees and ff_bynode >= 1.0
+                and mv_groups == 0 and num_leaves >= 2)
+    if mode == "on":
+        # forced pre-steps run the per-phase body, and only the
+        # interpret twin shares its histogram cache layout
+        megakernel = eligible and (interpret or not forced_plan)
+    elif mode == "auto":
+        # the compiled body's static scope
+        megakernel = (eligible and tpu and not forced_plan
+                      and not has_cat and not bundled
+                      and num_bins_max <= 256)
+    else:
+        megakernel = False
+    return SplitStepPlan(
+        body="megakernel" if megakernel else "per_phase",
+        # the scan kernel is numeric-only and compiled-only: the CPU
+        # keeps the XLA scan so learners stay bit-equal there
+        scan_kernel=bool(tpu and not interpret and not has_cat
+                         and not params.cegb_on),
+        lut_partition=has_cat or bool(bundled),
+        cat_scan=has_cat)
 
 
 def _bitcast_f32(x):
@@ -299,6 +358,7 @@ class StatePack:
         return {"TF": tfm, "TI": tim}
 
 
+@functools.lru_cache(maxsize=None)
 def make_grow_pack(si_prefix=(), *, merged: bool, has_cat: bool,
                    has_monotone: bool, big_l: int) -> StatePack:
     """Grow-loop StatePack for one static config. Fused mode drops the
@@ -306,7 +366,9 @@ def make_grow_pack(si_prefix=(), *, merged: bool, has_cat: bool,
     bounds when no feature carries a monotone constraint, and the
     categorical bitsets on numerical-only datasets; ``view()``
     synthesizes them all so the shared helpers and the TreeArrays
-    extraction are layout-blind."""
+    extraction are layout-blind. Cached: the megakernel takes the pack
+    as a static jit argument (hashed by identity), so one static config
+    must give one object."""
     sf = list(StatePack.GROW_SF)
     si = list(si_prefix) + list(StatePack.GROW_SI)
     derived = {}
@@ -331,184 +393,3 @@ def make_grow_pack(si_prefix=(), *, merged: bool, has_cat: bool,
                                     jnp.uint32)
     return StatePack(sf, si, StatePack.GROW_TF, StatePack.GROW_TI,
                      merged=merged, derived=derived)
-
-
-def set_bitsets(pack: StatePack, view: dict, idx_a, idx_b,
-                bits_a, bits_b, s, site_bitset) -> dict:
-    """Bitset carry updates for one split — compiled out entirely when
-    the pack derives the bitsets (numerical-only datasets)."""
-    if "bs_bitset" in pack.derived:
-        return {}
-    idx2 = jnp.stack([jnp.asarray(idx_a, jnp.int32),
-                      jnp.asarray(idx_b, jnp.int32)])
-    return {
-        "bs_bitset": view["bs_bitset"].at[idx2].set(
-            jnp.stack([bits_a, bits_b])),
-        "cat_bitsets": view["cat_bitsets"].at[s].set(site_bitset)}
-
-
-def child_constraints(meta, feat, is_cat, lout, rout, pcmin, pcmax,
-                      has_monotone: bool):
-    """Monotone constraint propagation to both children
-    (LeafConstraints::UpdateConstraints, monotone_constraints.hpp:44).
-    STATICALLY compiled out (inherited parent bounds, which stay ±inf
-    forever) when no feature has a monotone constraint."""
-    if not has_monotone:
-        return pcmin, pcmax, pcmin, pcmax
-    return child_constraints_mono(meta.monotone[feat], is_cat, lout,
-                                  rout, pcmin, pcmax)
-
-
-def child_constraints_mono(mono, is_cat, lout, rout, pcmin, pcmax):
-    """``child_constraints`` on a pre-gathered per-feature monotone
-    direction — the fused megakernel's Mosaic body extracts ``mono``
-    with a select-sum (dynamic gathers do not lower) and shares the
-    rest of the math here."""
-    mid = (lout + rout) * 0.5
-    numerical = ~is_cat
-    cmin_l = jnp.where(numerical & (mono < 0),
-                       jnp.maximum(pcmin, mid), pcmin)
-    cmax_l = jnp.where(numerical & (mono > 0),
-                       jnp.minimum(pcmax, mid), pcmax)
-    cmin_r = jnp.where(numerical & (mono > 0),
-                       jnp.maximum(pcmin, mid), pcmin)
-    cmax_r = jnp.where(numerical & (mono < 0),
-                       jnp.minimum(pcmax, mid), pcmax)
-    return cmin_l, cmax_l, cmin_r, cmax_r
-
-
-def order_child_pair(a_is_left, k, lg, lh, lc, rg, rh, rc, lout, rout,
-                     cmin_l, cmax_l, cmin_r, cmax_r) -> dict:
-    """(left, right) child scalars -> (a, b) storage order for one
-    split step. ``a_is_left`` is True on the (leaf, new) paths and
-    ``small_is_left`` on the (smaller, other) fused path; the salts
-    carry the child identity (left = 2k+1, right = 2k+2) so per-node
-    RNG streams are order-invariant, and ``side_a/b`` keep the
-    ref_side encoding (0 = left child). One definition shared by the
-    serial and partitioned grow bodies — this mapping is
-    bit-exactness-critical and must never diverge between them."""
-    def w(x, y):
-        return jnp.where(a_is_left, x, y)
-
-    side_a = w(jnp.int32(0), jnp.int32(1))
-    return dict(
-        ga=w(lg, rg), ha=w(lh, rh), ca=w(lc, rc),
-        gb=w(rg, lg), hb=w(rh, lh), cb=w(rc, lc),
-        out_a=w(lout, rout), out_b=w(rout, lout),
-        cmin_a=w(cmin_l, cmin_r), cmax_a=w(cmax_l, cmax_r),
-        cmin_b=w(cmin_r, cmin_l), cmax_b=w(cmax_r, cmax_l),
-        salt_a=w(2 * k + 1, 2 * k + 2),
-        salt_b=w(2 * k + 2, 2 * k + 1),
-        side_a=side_a, side_b=jnp.int32(1) - side_a)
-
-
-def child_columns(split, g, h, c, out, cmin, cmax, s, side, depth,
-                  extra_i=None):
-    """One fresh child's state-column field dicts (float, int) for
-    ``StatePack.set_state_cols`` — the single definition of what each
-    split writes per child (the partitioned learner prepends its
-    segment bounds via ``extra_i``)."""
-    f = dict(leaf_g=g, leaf_h=h, leaf_c=c, bs_gain=split.gain,
-             bs_lg=split.left_g, bs_lh=split.left_h,
-             bs_lc=split.left_c, bs_lout=split.left_output,
-             bs_rout=split.right_output, leaf_cmin=cmin,
-             leaf_cmax=cmax, leaf_value=out, leaf_weight=h,
-             leaf_count=c)
-    i = dict(bs_feat=split.feature, bs_thr=split.threshold,
-             bs_dleft=split.default_left, bs_iscat=split.is_cat,
-             ref_node=s, ref_side=side, leaf_parent=s,
-             leaf_depth=depth)
-    if extra_i:
-        i.update(extra_i)
-    return f, i
-
-
-def make_scan_leaf(comm, meta_scan, params, feature_mask, node_rand,
-                   bundled: bool, max_depth: int, select=None):
-    """One leaf's best-split scan (debundle -> per-node randomness ->
-    comm.select_split -> max_depth blocking) — ONE definition shared by
-    the serial and partitioned grow bodies AND the fused megakernel's
-    interpret twin (ops/split_step_pallas.py). The twin's byte-exact
-    parity with the foil rests on this being the same function.
-    ``select`` overrides ``comm.select_split`` where the root and
-    per-split scan layouts differ (the data-parallel reduce-scatter
-    recipe scans the root replicated, learner/comm.py)."""
-    if select is None:
-        select = comm.select_split
-
-    def scan_leaf(hist, g, h, c, depth, cmin, cmax, salt):
-        if bundled:
-            from ..ops.histogram import debundle_leaf_hist
-            hist = debundle_leaf_hist(hist, meta_scan, g, h, c,
-                                      comm.local_hist)
-        rb, nm = node_rand(salt)
-        fm = feature_mask if nm is None else nm  # nm already in-subset
-        res = select(hist, g, h, c, meta_scan, params,
-                     cmin, cmax, fm, rand_bins=rb)
-        blocked = (max_depth > 0) & (depth >= max_depth)
-        return res._replace(gain=jnp.where(blocked, -jnp.inf, res.gain))
-    return scan_leaf
-
-
-def scan_split_pair(comm, scan_leaf, a_is_left, k, depth,
-                    hist_a, hist_b, lg, lh, lc, rg, rh, rc, lout, rout,
-                    cmin_l, cmax_l, cmin_r, cmax_r):
-    """Order the (a, b) child pair and scan both fresh children — the
-    shared non-CEGB composition of ``order_child_pair`` +
-    ``scan_children`` used by both grow bodies and the megakernel
-    twin."""
-    o = order_child_pair(a_is_left, k, lg, lh, lc, rg, rh, rc, lout,
-                         rout, cmin_l, cmax_l, cmin_r, cmax_r)
-    split_a, split_b = scan_children(
-        comm, scan_leaf, hist_a, hist_b, o["ga"], o["ha"], o["ca"],
-        o["gb"], o["hb"], o["cb"], depth, o["cmin_a"], o["cmax_a"],
-        o["cmin_b"], o["cmax_b"], o["salt_a"], o["salt_b"])
-    return o, split_a, split_b
-
-
-def split_node_updates(params, gain, feat, thr, dleft, is_cat,
-                       pg, ph, pc, ref_node, leaf, new):
-    """Tree-array column dicts + parent-pointer fixup scalars of one
-    split — one definition shared by the grow bodies and the fused
-    megakernel twin (``set_tree_col`` consumes the result)."""
-    from ..ops.split import leaf_output_no_constraint
-    dec = jnp.where(is_cat, 1, 0) + jnp.where(dleft, 2, 0)
-    upd = ref_node >= 0
-    pnode = jnp.where(upd, ref_node, 0)
-    parent_out = leaf_output_no_constraint(
-        pg, ph + 2e-15, params.lambda_l1, params.lambda_l2,
-        params.max_delta_step)
-    treef = dict(split_gain_arr=gain, internal_value=parent_out,
-                 internal_weight=ph, internal_count=pc)
-    treei = dict(split_feature=feat, threshold_bin=thr,
-                 decision_type=dec, left_child=~leaf, right_child=~new)
-    return treef, treei, pnode, upd
-
-
-def scan_children(comm, scan_leaf, hist_a, hist_b, ga, ha, ca,
-                  gb, hb, cb, depth, cmin_a, cmax_a, cmin_b, cmax_b,
-                  salt_a, salt_b):
-    """Best splits of both fresh children (order-agnostic pair — the
-    fused bodies pass (smaller, larger), the legacy CEGB path passes
-    (left, right); the salts carry the child identity so node-rand
-    streams stay exact). For vmap_safe comms this is ONE vmapped scan:
-    same math, half the op count inside the while_loop body (each
-    [F, B] scan op is tiny; per-op overhead dominates at bench
-    shapes). Collective-bearing selects stay unbatched. Shared by the
-    serial and partitioned grow loops."""
-    if not comm.vmap_safe:
-        return (scan_leaf(hist_a, ga, ha, ca, depth, cmin_a, cmax_a,
-                          salt_a),
-                scan_leaf(hist_b, gb, hb, cb, depth, cmin_b, cmax_b,
-                          salt_b))
-    res2 = jax.vmap(
-        lambda hh, g_, h_, c_, cm, cx, s_: scan_leaf(
-            hh, g_, h_, c_, depth, cm, cx, s_))(
-        jnp.stack([hist_a, hist_b]),
-        jnp.stack([ga, gb]), jnp.stack([ha, hb]),
-        jnp.stack([ca, cb]),
-        jnp.stack([cmin_a, cmin_b]),
-        jnp.stack([cmax_a, cmax_b]),
-        jnp.stack([salt_a, salt_b]))
-    return (jax.tree.map(lambda x: x[0], res2),
-            jax.tree.map(lambda x: x[1], res2))

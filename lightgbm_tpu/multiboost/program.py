@@ -117,7 +117,7 @@ def build_grow_program(learner, objective, *, use_bagging: bool,
         hist_method=learner.hist_method, bundled=learner.bundled,
         cache_hists=learner.cache_hists, mv_slots=learner.mv_slots,
         mv_groups=learner.mv_groups, has_monotone=learner.has_monotone,
-        split_fusion=split_fusion_default(), fused_kernel=False)
+        split_fusion=split_fusion_default())
     ones_rows = learner._ones_rows
     all_features = learner._all_features
     freq = int(max(bagging_freq, 1))

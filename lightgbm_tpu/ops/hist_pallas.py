@@ -41,7 +41,6 @@ dynamic VMEM slicing is needed anywhere.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -126,31 +125,8 @@ def extract_row_ids(mat, num_features: int, n: int) -> jnp.ndarray:
 
 LO = 8             # low-nibble size (bin = hi * LO + lo)
 PAY = 5            # payload planes: g_hi, g_lo, h_hi, h_lo, cnt
-GRP = 3            # features per MXU tile in the GROUPED nibble variant
+GRP = 3            # features per MXU tile of the nibble kernel
 MAX_NIBBLE_F = 192  # nibble-kernel unroll cap (program size; ~1 MB VMEM)
-
-# Two nibble-kernel mask layouts, selectable for on-chip comparison
-# (tools/micro_kernel_bench.py measures both):
-#   grouped (default) — 3 features per [120, 96] MXU tile. VPU op cost
-#     scales with op COUNT x sublanes, not lanes, so packing 3
-#     features' masks into one ~full-width tile amortizes each
-#     compare/select across 3 features (~10 ops/group/block).
-#   perfeat — one [40, 32] tile per feature; fewer lanes per op buys
-#     nothing on the VPU, but kept for measurement and as the simpler
-#     reference implementation.
-HIST_VARIANT = os.environ.get("LGBM_TPU_HIST_VARIANT", "grouped")
-
-
-def _block_dma(mat_hbm, buf, sems, base, blk, win):
-    """Shared double-buffered input-stream DMA factory (all three
-    histogram kernels stream the same 8-aligned row windows)."""
-    def dma(slot, i):
-        start = pl.multiple_of(base + i * blk, ALIGN)
-        return pltpu.make_async_copy(
-            mat_hbm.at[pl.ds(start, win), :], buf.at[slot],
-            sems.at[slot])
-    return dma
-
 
 PAYB = 9           # payload bytes the hist kernels decode (g4+h4+cnt)
 
@@ -191,8 +167,8 @@ def _nibble_dma(mat_hbm, buf, sems, base, blk, win, *, compact: bool,
 
 def _payload_lanes(g_hi, g_lo, h_hi, h_lo, cnt, lhs_p):
     """Route the 5 payload planes into their (.., p) lane pattern —
-    shared by both nibble variants (the pattern repeats per lo/feature,
-    so one build serves every mask tile of the block)."""
+    the pattern repeats per lo/feature, so one build serves every mask
+    tile of the block."""
     pay = [g_hi.astype(jnp.float32), g_lo.astype(jnp.float32),
            h_hi.astype(jnp.float32), h_lo.astype(jnp.float32), cnt]
     pay_b = pay[PAY - 1]
@@ -202,7 +178,8 @@ def _payload_lanes(g_hi, g_lo, h_hi, h_lo, cnt, lhs_p):
 
 
 def _decode_block(mat_i32, feat0: int, shift, rem, win: int):
-    """Shared block decode for both histogram kernels: validity mask +
+    """Block decode shared by the histogram kernel and the split-step
+    megakernel's phase 0 (ops/split_step_pallas.py): validity mask +
     the payload planes ((g, h) as exact bf16 hi/lo pairs, 0/1 count)
     read back out of the row bytes. Returns
     ``(valid, g_hi, g_lo, h_hi, h_lo, cnt)`` — all [win, 1], cnt f32.
@@ -230,95 +207,6 @@ def _decode_block(mat_i32, feat0: int, shift, rem, win: int):
     return valid, g_hi, g_lo, h_hi, h_lo, cnt
 
 
-def _hist_seg_kernel(scal_ref,          # SMEM [2] (begin, count)
-                     mat_hbm,           # ANY  [N_pad, C] u8
-                     out_ref,           # VMEM [B, 8, C] f32
-                     buf, sems,         # VMEM [2, win, C] u8, DMA sems [2]
-                     *, blk: int, num_bins: int, cols: int, feat0: int):
-    begin = scal_ref[0]
-    count = scal_ref[1]
-    nblk = pl.cdiv(count, blk)
-    base = (begin // ALIGN) * ALIGN
-    shift = begin - base
-    win = blk + ALIGN
-    dma = _block_dma(mat_hbm, buf, sems, base, blk, win)
-
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(nblk > 0)
-    def _():
-        dma(0, 0).start()
-
-    def block_body(i, _):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < nblk)
-        def _():
-            dma(1 - slot, i + 1).start()
-
-        dma(slot, i).wait()
-        # Mosaic only casts to/from 32-bit types: everything hops
-        # through i32/f32.
-        mat_i32 = buf[slot].astype(jnp.int32)        # [win, C]
-
-        rem = jnp.minimum(count - i * blk, blk)
-        _, g_hi, g_lo, h_hi, h_lo, cnt = _decode_block(
-            mat_i32, feat0, shift, rem, win)
-        cnt_bf = cnt.astype(jnp.bfloat16)            # 0/1: exact
-        zero = jnp.zeros_like(cnt_bf)
-        lhs = jnp.concatenate(
-            [g_hi, g_lo, h_hi, h_lo, cnt_bf, zero, zero, zero],
-            axis=1)                                  # [win, 8] bf16
-
-        def bin_body(b, _):
-            mask = jnp.where(mat_i32 == b, jnp.float32(1),
-                             jnp.float32(0)).astype(jnp.bfloat16)
-            res = jax.lax.dot_general(
-                lhs, mask, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [8, C]
-            out_ref[b] += res
-            return 0
-
-        jax.lax.fori_loop(0, num_bins, bin_body, 0, unroll=True)
-        return 0
-
-    jax.lax.fori_loop(0, nblk, block_body, 0)
-
-
-@register_jit("hist_segment_raw")
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_features", "num_bins", "blk", "interpret"))
-def histogram_segment_raw(mat, begin, count, *, num_features: int,
-                          num_bins: int, blk: int = 2048,
-                          interpret: bool = False):
-    """Raw kernel call on the training matrix. Returns [B, 8, C] f32
-    accumulator planes (combine with ``combine_planes``)."""
-    if blk % ALIGN:
-        raise ValueError(f"blk must be a multiple of {ALIGN}, got {blk}")
-    _, cols = mat.shape
-    scal = jnp.stack([jnp.asarray(begin, jnp.int32),
-                      jnp.asarray(count, jnp.int32)])
-    kernel = functools.partial(_hist_seg_kernel, blk=blk,
-                               num_bins=num_bins, cols=cols,
-                               feat0=num_features)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((num_bins, 8, cols), jnp.float32),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, blk + ALIGN, cols), jnp.uint8),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-    )(scal, mat)
-
-
 def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
                                 mat_hbm,   # ANY [N_pad, C] u8
                                 out_ref,   # VMEM [NG, 120, GRP*H] f32
@@ -326,7 +214,8 @@ def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
                                 *, blk: int, cols: int, feat0: int,
                                 ngroups: int, hi_n: int,
                                 f_lo: int = 0, nf: int = 0):
-    """Grouped nibble variant: per group of GRP features,
+    """Hierarchical (hi/lo nibble) histogram build: ``bin = hi*LO +
+    lo``, and per group of GRP features,
 
         out[(f, lo, p), (f', hi)] += lhs[win, GRP*LO*PAY]^T
                                      @ rhs[win, GRP*H]
@@ -334,12 +223,16 @@ def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
     diagonal f == f' blocks are the histogram; cross-feature products
     land in otherwise-idle MXU lanes and are discarded. lo/hi are
     precomputed FULL-WIDTH once per block (3 VPU ops for all features)
-    and routed into mask lanes with two selects per group — the VPU op
-    count per block is ~10 x ngroups + constants, the lowest of the
-    variants when features pack ~120 lanes full.
+    and routed into mask lanes with two selects per group: VPU op cost
+    scales with op COUNT x sublanes, not lanes, so packing 3 features'
+    masks into one ~full-width tile amortizes each compare/select
+    across 3 features (~10 ops per group per block). Payload stays
+    exact: lhs entries are the bf16 hi/lo halves of the f32 grad/hess,
+    accumulated in f32.
 
-    ``f_lo``/``nf`` histogram the feature slice [f_lo, f_lo+nf) (see
-    the per-feature kernel's slice note).
+    ``f_lo``/``nf`` histogram the feature SLICE [f_lo, f_lo+nf):
+    datasets wider than MAX_NIBBLE_F dispatch one kernel call per
+    slice, so program size stays bounded.
     """
     if nf == 0:
         nf = feat0
@@ -417,125 +310,21 @@ def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
     jax.lax.fori_loop(0, nblk, block_body, 0)
 
 
-def _hist_nibble_kernel(scal_ref,       # SMEM [2] (begin, count)
-                        mat_hbm,        # ANY  [N_pad, C] u8
-                        out_ref,        # VMEM [NF, LO*PAY, H] f32
-                        buf, sems,      # VMEM [2, win, C] u8, DMA sems [2]
-                        *, blk: int, cols: int, feat0: int,
-                        hi_n: int, f_lo: int = 0, nf: int = 0):
-    """Hierarchical (hi/lo nibble) histogram build.
-
-    The per-bin one-hot matmul (``_hist_seg_kernel``) issues
-    ``num_bins`` MXU calls per block with an 8-row output tile — ~6% of
-    the systolic array. This kernel decomposes ``bin = hi*LO + lo`` and
-    contracts, per feature,
-
-        out[f, (lo, p), hi] += lhs_f[win, LO*PAY]^T @ rhs_f[win, H]
-
-    where ``lhs_f[r, (lo,p)] = payload_p[r] * [lo(bin_f[r]) == lo]``
-    and ``rhs_f[r, hi] = [hi(bin_f[r]) == hi]``. Payload stays exact:
-    lhs entries are the bf16 hi/lo halves of the f32 grad/hess,
-    accumulated in f32 (same fidelity story as the per-bin kernel).
-
-    VPU cost note (this kernel is VPU-mask-bound, not MXU-bound): the
-    per-feature lo/hi values are extracted on NARROW [win, 1] columns
-    and broadcast against STATIC lane patterns, so each of the
-    LO*PAY + H mask lanes costs one compare + one select — an earlier
-    variant grouped 3 features per tile and paid 2 extra selects plus a
-    div/mod per lane routing features into lanes, ~3x the VPU work,
-    for MXU utilization this kernel doesn't need (measured
-    dispatch-free on v5e: the MXU side has >10x headroom).
-
-    ``f_lo``/``nf`` histogram the feature SLICE [f_lo, f_lo+nf) —
-    datasets wider than MAX_NIBBLE_F dispatch one kernel call per
-    slice (program size stays bounded) instead of falling back to the
-    per-bin kernel, whose VPU mask cost scales with num_bins.
-    """
-    if nf == 0:
-        nf = feat0
-    compact = nf != feat0
-    pay0 = nf if compact else feat0      # payload col base in buf
-    col0 = 0 if compact else f_lo        # feature col base in buf
-    begin = scal_ref[0]
-    count = scal_ref[1]
-    nblk = pl.cdiv(count, blk)
-    base = (begin // ALIGN) * ALIGN
-    shift = begin - base
-    win = blk + ALIGN
-
-    m_lhs = LO * PAY                                 # 40
-    dma_start, dma_wait = _nibble_dma(
-        mat_hbm, buf, sems, base, blk, win, compact=compact,
-        f_lo=f_lo, nf=nf, feat0=feat0)
-
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    # static lane patterns
-    lane_l = jax.lax.broadcasted_iota(jnp.int32, (1, m_lhs), 1)
-    lhs_lo = lane_l // PAY                           # lo value
-    lhs_p = lane_l % PAY                             # payload plane
-    rhs_hi = jax.lax.broadcasted_iota(jnp.int32, (1, hi_n), 1)
-
-    @pl.when(nblk > 0)
-    def _():
-        dma_start(0, 0)
-
-    def block_body(i, _):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < nblk)
-        def _():
-            dma_start(1 - slot, i + 1)
-
-        dma_wait(slot, i)
-        mat_i32 = buf[slot].astype(jnp.int32)        # [win, C']
-
-        rem = jnp.minimum(count - i * blk, blk)
-        _, g_hi, g_lo, h_hi, h_lo, cnt = _decode_block(
-            mat_i32, pay0, shift, rem, win)
-        # payload lane pattern is feature-independent: build once
-        pay_b = _payload_lanes(g_hi, g_lo, h_hi, h_lo, cnt,
-                               lhs_p)                # [win, m_lhs]
-
-        # feature loop unrolled with STATIC column indices: a traced
-        # index would force each feature column out of the [win, C]
-        # tile via a one-hot lane reduction (~full-width VPU pass per
-        # feature per block); a static slice is free. Program size is
-        # bounded by the slice width (<= MAX_NIBBLE_F), so the unroll
-        # cannot blow up Mosaic compile time
-        for f in range(nf):
-            c = col0 + f
-            fcol = mat_i32[:, c:c + 1]               # [win, 1]
-            flo = fcol - (fcol // LO) * LO           # narrow; & and >>
-            fhi = fcol // LO                         # miscompile (i32)
-            lhs = jnp.where(flo == lhs_lo, pay_b,
-                            0.0).astype(jnp.bfloat16)    # [win, 40]
-            rhs = jnp.where(fhi == rhs_hi, jnp.float32(1),
-                            jnp.float32(0)).astype(jnp.bfloat16)
-            out_ref[f] += jax.lax.dot_general(
-                lhs, rhs, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [m_lhs, hi_n]
-        return 0
-
-    jax.lax.fori_loop(0, nblk, block_body, 0)
-
-
 @register_jit("hist_segment_nibble")
 @functools.partial(
     jax.jit,
     static_argnames=("num_features", "num_bins", "blk", "interpret",
-                     "variant", "nibble_cap"))
+                     "nibble_cap"))
 def _histogram_segment_nibble(mat, begin, count, *, num_features: int,
-                              num_bins: int, variant: str,
+                              num_bins: int,
                               nibble_cap: int = MAX_NIBBLE_F,
                               blk: int = 2048,
                               interpret: bool = False):
     """Nibble-kernel call -> [F, B, 3] histogram.
 
-    ``variant`` is REQUIRED and resolved by the caller
-    (histogram_segment), and ``nibble_cap`` rides as a STATIC arg for
-    the same reason: a module global read here would freeze into the
-    jit cache on first trace.
+    ``nibble_cap`` rides as a STATIC arg resolved by the caller
+    (histogram_segment): a module global read here would freeze into
+    the jit cache on first trace.
     """
     if blk % ALIGN:
         raise ValueError(f"blk must be a multiple of {ALIGN}, got {blk}")
@@ -563,34 +352,20 @@ def _histogram_segment_nibble(mat, begin, count, *, num_features: int,
 
     def slice_hist(f_lo: int, nf: int) -> jnp.ndarray:
         """[nf, B, PAY] histogram of features [f_lo, f_lo+nf)."""
-        common = specs(nf)
-        if variant == "grouped":
-            ngroups = -(-nf // GRP)
-            raw = pl.pallas_call(
-                functools.partial(_hist_nibble_kernel_grouped, blk=blk,
-                                  cols=cols, feat0=f, ngroups=ngroups,
-                                  hi_n=hi_n, f_lo=f_lo, nf=nf),
-                out_shape=jax.ShapeDtypeStruct(
-                    (ngroups, GRP * LO * PAY, GRP * hi_n), jnp.float32),
-                **common,
-            )(scal, mat)
-            # [NG, (fl,lo,p), (fr,hi)] -> diagonal fl == fr -> [nf,B,P]
-            raw = raw.reshape(ngroups, GRP, LO, PAY, GRP, hi_n)
-            diag = jnp.einsum("gjlpjh->gjhlp", raw)  # [NG,GRP,H,LO,P]
-            return diag.reshape(ngroups * GRP, hi_n * LO,
-                                PAY)[:nf, :num_bins]
+        ngroups = -(-nf // GRP)
         raw = pl.pallas_call(
-            functools.partial(_hist_nibble_kernel, blk=blk,
-                              cols=cols, feat0=f, hi_n=hi_n,
-                              f_lo=f_lo, nf=nf),
+            functools.partial(_hist_nibble_kernel_grouped, blk=blk,
+                              cols=cols, feat0=f, ngroups=ngroups,
+                              hi_n=hi_n, f_lo=f_lo, nf=nf),
             out_shape=jax.ShapeDtypeStruct(
-                (nf, LO * PAY, hi_n), jnp.float32),
-            **common,
+                (ngroups, GRP * LO * PAY, GRP * hi_n), jnp.float32),
+            **specs(nf),
         )(scal, mat)
-        # [nf, (lo, p), hi] -> [nf, B, P]
-        raw = raw.reshape(nf, LO, PAY, hi_n)
-        return raw.transpose(0, 3, 1, 2).reshape(
-            nf, hi_n * LO, PAY)[:, :num_bins]
+        # [NG, (fl,lo,p), (fr,hi)] -> diagonal fl == fr -> [nf,B,P]
+        raw = raw.reshape(ngroups, GRP, LO, PAY, GRP, hi_n)
+        diag = jnp.einsum("gjlpjh->gjhlp", raw)      # [NG,GRP,H,LO,P]
+        return diag.reshape(ngroups * GRP, hi_n * LO,
+                            PAY)[:nf, :num_bins]
 
     if f <= nibble_cap:
         hist = slice_hist(0, f)
@@ -605,38 +380,17 @@ def _histogram_segment_nibble(mat, begin, count, *, num_features: int,
     return jnp.stack([g, h, hist[..., 4]], axis=-1)  # [F, B, 3]
 
 
-def combine_planes(raw: jnp.ndarray, num_features: int) -> jnp.ndarray:
-    """[B, 8, C] accumulator planes -> [F, B, 3] histogram."""
-    g = raw[:, 0] + raw[:, 1]
-    h = raw[:, 2] + raw[:, 3]
-    c = raw[:, 4]
-    hist = jnp.stack([g, h, c], axis=-1)           # [B, C, 3]
-    return hist.transpose(1, 0, 2)[:num_features]  # [F, B, 3]
-
-
 def histogram_segment(mat, begin, count, num_bins: int, num_features: int,
-                      blk: int = 2048, interpret: bool = False,
-                      variant: str | None = None) -> jnp.ndarray:
-    """Histogram of rows [begin, begin+count) -> [F, B, 3] f32.
-
-    Dispatches to the nibble kernel (grouped/per-feature mask variant,
-    see HIST_VARIANT); datasets wider than its unroll cap
-    (MAX_NIBBLE_F) run one kernel call per feature slice. The per-bin
-    kernel (``variant="perbin"``) is kept for on-chip comparison — its
-    VPU mask cost scales with num_bins, ~B/(LO*PAY + B/LO)x the
-    nibble decomposition's.
-    """
-    v = HIST_VARIANT if variant is None else variant
-    if v != "perbin":
-        return _histogram_segment_nibble(
-            mat, begin, count, num_features=num_features,
-            num_bins=num_bins, blk=blk, interpret=interpret,
-            variant=v, nibble_cap=MAX_NIBBLE_F)
-    raw = histogram_segment_raw(mat, begin, count,
-                                num_features=num_features,
-                                num_bins=num_bins, blk=blk,
-                                interpret=interpret)
-    return combine_planes(raw, num_features)
+                      blk: int = 2048,
+                      interpret: bool = False) -> jnp.ndarray:
+    """Histogram of rows [begin, begin+count) -> [F, B, 3] f32 by the
+    nibble kernel; datasets wider than its unroll cap (MAX_NIBBLE_F)
+    run one kernel call per feature slice. ``ops/histogram.py`` is the
+    reference the tests compare it with."""
+    return _histogram_segment_nibble(
+        mat, begin, count, num_features=num_features,
+        num_bins=num_bins, blk=blk, interpret=interpret,
+        nibble_cap=MAX_NIBBLE_F)
 
 
 def histogram_pallas(binned, ghc, num_bins: int, blk: int = 2048,

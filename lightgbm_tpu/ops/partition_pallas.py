@@ -460,3 +460,35 @@ def bitset_to_lut(cat_bitset) -> jnp.ndarray:
     if w * 32 < 256:
         lut = jnp.pad(lut, ((0, 0), (0, 256 - w * 32)))
     return lut[:, :256]
+
+
+def partition_decision_lut(meta, feat, thr, dleft, is_cat, bitset,
+                           bundled: bool):
+    """(grp_col, use_lut, lut) for one split's physical partition —
+    the 256-entry "group value -> goes left" table encoding decode +
+    missing handling in feature-bin space for bundled splits, the raw
+    bin bitset for categorical ones. ONE definition shared by the
+    per-phase body's ``partition_segment`` call and the split-step
+    megakernel's interpret twin (bit-exactness-critical)."""
+    lut = jnp.where(is_cat, bitset_to_lut(bitset),
+                    jnp.zeros((1, 256), jnp.float32))
+    grp_col = meta.group[feat] if bundled else feat
+    use_lut = is_cat
+    if bundled:
+        from ..data.bundling import decode_feature_bin
+        off = meta.offset[feat]
+        nbf = meta.num_bins[feat]
+        vals = jnp.arange(256, dtype=jnp.int32)
+        # offset 0 would pass values through; masked by
+        # is_bundled_split below, so raw splits keep the fast path
+        fbin = decode_feature_bin(vals, off, nbf)
+        mcode = meta.missing[feat]
+        is_miss = jnp.where(
+            mcode == 1, fbin == meta.default_bin[feat],
+            jnp.where(mcode == 2, fbin == nbf - 1, False))
+        go_left = jnp.where(is_miss, dleft, fbin <= thr)
+        blut = go_left.astype(jnp.float32).reshape(1, 256)
+        is_bundled_split = (off > 0) & ~is_cat
+        lut = jnp.where(is_bundled_split, blut, lut)
+        use_lut = is_cat | is_bundled_split
+    return grp_col, use_lut, lut

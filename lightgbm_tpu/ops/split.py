@@ -543,3 +543,193 @@ def best_split(hist: jnp.ndarray, parent_g, parent_h, parent_c,
                             cegb_uncharged=cegb_uncharged)
     best_f = _argmax_first(pf.score).astype(jnp.int32)
     return assemble_split(pf, best_f)
+
+
+# =====================================================================
+# one split's step: what a chosen split scans and writes. ONE
+# definition of each, shared by the grow bodies (learner/serial.py,
+# learner/partitioned.py) and the split-step megakernel
+# (ops/split_step_pallas.py) — bit-exactness-critical, so they live
+# below both. ``pack`` is the grow loop's ``StatePack``
+# (learner/split_step.py), ``comm`` its ``Comm`` (learner/comm.py);
+# both arrive as arguments.
+# =====================================================================
+
+def set_bitsets(pack, view: dict, idx_a, idx_b,
+                bits_a, bits_b, s, site_bitset) -> dict:
+    """Bitset carry updates for one split — compiled out entirely when
+    the pack derives the bitsets (numerical-only datasets)."""
+    if "bs_bitset" in pack.derived:
+        return {}
+    idx2 = jnp.stack([jnp.asarray(idx_a, jnp.int32),
+                      jnp.asarray(idx_b, jnp.int32)])
+    return {
+        "bs_bitset": view["bs_bitset"].at[idx2].set(
+            jnp.stack([bits_a, bits_b])),
+        "cat_bitsets": view["cat_bitsets"].at[s].set(site_bitset)}
+
+
+def child_constraints(meta, feat, is_cat, lout, rout, pcmin, pcmax,
+                      has_monotone: bool):
+    """Monotone constraint propagation to both children
+    (LeafConstraints::UpdateConstraints, monotone_constraints.hpp:44).
+    STATICALLY compiled out (inherited parent bounds, which stay ±inf
+    forever) when no feature has a monotone constraint."""
+    if not has_monotone:
+        return pcmin, pcmax, pcmin, pcmax
+    return child_constraints_mono(meta.monotone[feat], is_cat, lout,
+                                  rout, pcmin, pcmax)
+
+
+def child_constraints_mono(mono, is_cat, lout, rout, pcmin, pcmax):
+    """``child_constraints`` on a pre-gathered per-feature monotone
+    direction — the fused megakernel's Mosaic body extracts ``mono``
+    with a select-sum (dynamic gathers do not lower) and shares the
+    rest of the math here."""
+    mid = (lout + rout) * 0.5
+    numerical = ~is_cat
+    cmin_l = jnp.where(numerical & (mono < 0),
+                       jnp.maximum(pcmin, mid), pcmin)
+    cmax_l = jnp.where(numerical & (mono > 0),
+                       jnp.minimum(pcmax, mid), pcmax)
+    cmin_r = jnp.where(numerical & (mono > 0),
+                       jnp.maximum(pcmin, mid), pcmin)
+    cmax_r = jnp.where(numerical & (mono < 0),
+                       jnp.minimum(pcmax, mid), pcmax)
+    return cmin_l, cmax_l, cmin_r, cmax_r
+
+
+def order_child_pair(a_is_left, k, lg, lh, lc, rg, rh, rc, lout, rout,
+                     cmin_l, cmax_l, cmin_r, cmax_r) -> dict:
+    """(left, right) child scalars -> (a, b) storage order for one
+    split step. ``a_is_left`` is True on the (leaf, new) paths and
+    ``small_is_left`` on the (smaller, other) fused path; the salts
+    carry the child identity (left = 2k+1, right = 2k+2) so per-node
+    RNG streams are order-invariant, and ``side_a/b`` keep the
+    ref_side encoding (0 = left child). One definition shared by the
+    serial and partitioned grow bodies — this mapping is
+    bit-exactness-critical and must never diverge between them."""
+    def w(x, y):
+        return jnp.where(a_is_left, x, y)
+
+    side_a = w(jnp.int32(0), jnp.int32(1))
+    return dict(
+        ga=w(lg, rg), ha=w(lh, rh), ca=w(lc, rc),
+        gb=w(rg, lg), hb=w(rh, lh), cb=w(rc, lc),
+        out_a=w(lout, rout), out_b=w(rout, lout),
+        cmin_a=w(cmin_l, cmin_r), cmax_a=w(cmax_l, cmax_r),
+        cmin_b=w(cmin_r, cmin_l), cmax_b=w(cmax_r, cmax_l),
+        salt_a=w(2 * k + 1, 2 * k + 2),
+        salt_b=w(2 * k + 2, 2 * k + 1),
+        side_a=side_a, side_b=jnp.int32(1) - side_a)
+
+
+def child_columns(split, g, h, c, out, cmin, cmax, s, side, depth,
+                  extra_i=None):
+    """One fresh child's state-column field dicts (float, int) for
+    ``StatePack.set_state_cols`` — the single definition of what each
+    split writes per child (the partitioned learner prepends its
+    segment bounds via ``extra_i``)."""
+    f = dict(leaf_g=g, leaf_h=h, leaf_c=c, bs_gain=split.gain,
+             bs_lg=split.left_g, bs_lh=split.left_h,
+             bs_lc=split.left_c, bs_lout=split.left_output,
+             bs_rout=split.right_output, leaf_cmin=cmin,
+             leaf_cmax=cmax, leaf_value=out, leaf_weight=h,
+             leaf_count=c)
+    i = dict(bs_feat=split.feature, bs_thr=split.threshold,
+             bs_dleft=split.default_left, bs_iscat=split.is_cat,
+             ref_node=s, ref_side=side, leaf_parent=s,
+             leaf_depth=depth)
+    if extra_i:
+        i.update(extra_i)
+    return f, i
+
+
+def make_scan_leaf(comm, meta_scan, params, feature_mask, node_rand,
+                   bundled: bool, max_depth: int, select=None):
+    """One leaf's best-split scan (debundle -> per-node randomness ->
+    comm.select_split -> max_depth blocking) — ONE definition shared by
+    the serial and partitioned grow bodies AND the fused megakernel's
+    interpret twin (ops/split_step_pallas.py). The twin's byte-exact
+    parity with the foil rests on this being the same function.
+    ``select`` overrides ``comm.select_split`` where the root and
+    per-split scan layouts differ (the data-parallel reduce-scatter
+    recipe scans the root replicated, learner/comm.py)."""
+    if select is None:
+        select = comm.select_split
+
+    def scan_leaf(hist, g, h, c, depth, cmin, cmax, salt):
+        if bundled:
+            from .histogram import debundle_leaf_hist
+            hist = debundle_leaf_hist(hist, meta_scan, g, h, c,
+                                      comm.local_hist)
+        rb, nm = node_rand(salt)
+        fm = feature_mask if nm is None else nm  # nm already in-subset
+        res = select(hist, g, h, c, meta_scan, params,
+                     cmin, cmax, fm, rand_bins=rb)
+        blocked = (max_depth > 0) & (depth >= max_depth)
+        return res._replace(gain=jnp.where(blocked, -jnp.inf, res.gain))
+    return scan_leaf
+
+
+def scan_split_pair(comm, scan_leaf, a_is_left, k, depth,
+                    hist_a, hist_b, lg, lh, lc, rg, rh, rc, lout, rout,
+                    cmin_l, cmax_l, cmin_r, cmax_r):
+    """Order the (a, b) child pair and scan both fresh children — the
+    shared non-CEGB composition of ``order_child_pair`` +
+    ``scan_children`` used by both grow bodies and the megakernel
+    twin."""
+    o = order_child_pair(a_is_left, k, lg, lh, lc, rg, rh, rc, lout,
+                         rout, cmin_l, cmax_l, cmin_r, cmax_r)
+    split_a, split_b = scan_children(
+        comm, scan_leaf, hist_a, hist_b, o["ga"], o["ha"], o["ca"],
+        o["gb"], o["hb"], o["cb"], depth, o["cmin_a"], o["cmax_a"],
+        o["cmin_b"], o["cmax_b"], o["salt_a"], o["salt_b"])
+    return o, split_a, split_b
+
+
+def split_node_updates(params, gain, feat, thr, dleft, is_cat,
+                       pg, ph, pc, ref_node, leaf, new):
+    """Tree-array column dicts + parent-pointer fixup scalars of one
+    split — one definition shared by the grow bodies and the fused
+    megakernel twin (``set_tree_col`` consumes the result)."""
+    dec = jnp.where(is_cat, 1, 0) + jnp.where(dleft, 2, 0)
+    upd = ref_node >= 0
+    pnode = jnp.where(upd, ref_node, 0)
+    parent_out = leaf_output_no_constraint(
+        pg, ph + 2e-15, params.lambda_l1, params.lambda_l2,
+        params.max_delta_step)
+    treef = dict(split_gain_arr=gain, internal_value=parent_out,
+                 internal_weight=ph, internal_count=pc)
+    treei = dict(split_feature=feat, threshold_bin=thr,
+                 decision_type=dec, left_child=~leaf, right_child=~new)
+    return treef, treei, pnode, upd
+
+
+def scan_children(comm, scan_leaf, hist_a, hist_b, ga, ha, ca,
+                  gb, hb, cb, depth, cmin_a, cmax_a, cmin_b, cmax_b,
+                  salt_a, salt_b):
+    """Best splits of both fresh children (order-agnostic pair — the
+    fused bodies pass (smaller, larger), the legacy CEGB path passes
+    (left, right); the salts carry the child identity so node-rand
+    streams stay exact). For vmap_safe comms this is ONE vmapped scan:
+    same math, half the op count inside the while_loop body (each
+    [F, B] scan op is tiny; per-op overhead dominates at bench
+    shapes). Collective-bearing selects stay unbatched. Shared by the
+    serial and partitioned grow loops."""
+    if not comm.vmap_safe:
+        return (scan_leaf(hist_a, ga, ha, ca, depth, cmin_a, cmax_a,
+                          salt_a),
+                scan_leaf(hist_b, gb, hb, cb, depth, cmin_b, cmax_b,
+                          salt_b))
+    res2 = jax.vmap(
+        lambda hh, g_, h_, c_, cm, cx, s_: scan_leaf(
+            hh, g_, h_, c_, depth, cm, cx, s_))(
+        jnp.stack([hist_a, hist_b]),
+        jnp.stack([ga, gb]), jnp.stack([ha, hb]),
+        jnp.stack([ca, cb]),
+        jnp.stack([cmin_a, cmin_b]),
+        jnp.stack([cmax_a, cmax_b]),
+        jnp.stack([salt_a, salt_b]))
+    return (jax.tree.map(lambda x: x[0], res2),
+            jax.tree.map(lambda x: x[1], res2))

@@ -8,7 +8,7 @@ Why: inside the grow ``while_loop`` the XLA formulation of the scan
 lowers to ~100 small ops over [F, B] grids (cumsums, masks, gain
 algebra, argmax, gathers); at bench shapes each op is ~2-8 us of fixed
 issue overhead, so one scan costs ~0.7 ms — the single largest slice of
-the ~1.4 ms/split budget (tools/micro_kernel_bench.py). Fusing the
+the ~1.4 ms/split budget when it was written. Fusing the
 whole scan into one Pallas program removes the per-op overhead: all
 intermediates live in VMEM/registers and the cumulative sums are 8
 Hillis-Steele lane-shift adds.
@@ -34,7 +34,6 @@ grow loop scans both fresh children in one call, learner/serial.py
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -238,21 +237,13 @@ def _scan_call(scal, imeta, fmeta, hg, hh, hc, *, params: SplitParams,
     )(scal, imeta, fmeta, hg, hh, hc)
 
 
-def scan_kernel_default(eligible: bool = True) -> bool:
-    """Learner-level default for SplitParams.use_scan_kernel: a static
-    function of the config and the platform — the learner could use
-    the kernel (``eligible=False`` for categorical/CEGB configs), the
-    LGBM_TPU_NO_SCAN_KERNEL kill switch is unset (any non-empty value
-    disables, like LGBM_TPU_NO_NATIVE) and the backend is a TPU. A
-    kernel this selects and Mosaic then refuses is a compile error at
-    the learner's first grow call, never a quiet switch to the XLA
-    scan."""
-    return bool(eligible) \
-        and not os.environ.get("LGBM_TPU_NO_SCAN_KERNEL") and on_tpu()
-
-
 def scan_kernel_ok(params: SplitParams, rand_bins, cegb_uncharged) -> bool:
-    """Static eligibility of the fused kernel for one scan call."""
+    """Static eligibility of the fused kernel for one scan call.
+    ``params.use_scan_kernel`` is the learner's choice
+    (learner/split_step.py ``plan_split_step``: a TPU, compiled
+    kernels, a numeric table without CEGB); a kernel it selects and
+    Mosaic then refuses is a compile error at the first grow call,
+    never a quiet switch to the XLA scan."""
     return (params.use_scan_kernel and rand_bins is None
             and not params.has_categorical and not params.cegb_on
             and cegb_uncharged is None)

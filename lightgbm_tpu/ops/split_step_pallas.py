@@ -3,57 +3,45 @@
 The reference wins its grow loop by doing almost nothing per split
 beyond one smaller-child histogram plus a subtraction
 (``serial_tree_learner.cpp:434-436``). PR 8 collapsed the XLA analog
-to 44 compiled ops/split (serial); this module collapses it to ONE:
-an entire split — best-leaf pick, leaf partition / row movement,
-smaller-child histogram build, sibling histogram subtraction, and the
+to 44 compiled ops/split; this module collapses it to ONE: an entire
+split — best-leaf pick, leaf partition / row movement, smaller-child
+histogram build, sibling histogram subtraction, and the
 channel-stacked best-split scan of both fresh children — executes as a
 single ``pallas_call`` whose carry (per-leaf state ``S``, tree arrays
 ``T``, the chosen leaf's histograms and every scan intermediate) never
 leaves VMEM between phases. The grow ``while_loop`` body shrinks to
 the kernel call plus the loop counter, measured by
-``tools/hlo_census.py`` (committed budget ``serial_grow_fused`` /
-``partitioned_grow_fused``: <= 10 dispatches/split vs the foil's
-44/78).
+``tools/hlo_census.py`` (committed budget ``partitioned_grow_fused``:
+<= 10 dispatches/split vs the per-phase body's 78).
 
-Two layouts, one contract:
+One layout: the partitioned learner's single row-major u8 training
+matrix (``ops/hist_pallas.py``). ``fused_split_step_segment``
+physically moves the chosen leaf's rows (stable partition,
+``ops/partition_pallas.py`` semantics) and streams the smaller child's
+contiguous segment. Two bodies sit behind the one wrapper:
 
-* **leaf** (``fused_split_step_leaf``) — the serial learner's
-  ``leaf_id[N]`` layout: the kernel streams ``binned``/``ghc``/
-  ``leaf_id`` blocks, updates leaf membership in place and builds the
-  smaller child's histogram in the same pass over the leaf's rows.
-  Interpret twin only: the test reference for the serial learner.
-* **segment** (``fused_split_step_segment``) — the partitioned
-  learner's single row-major u8 training matrix
-  (``ops/hist_pallas.py`` layout): the kernel physically moves the
-  leaf's rows (stable partition, ``ops/partition_pallas.py``
-  semantics) and then streams the smaller child's contiguous segment.
-
-Two kinds of kernel body sit behind the wrappers:
-
-* the **Mosaic TPU body** (segment layout only, ``COMPILED_LAYOUTS``)
-  — real streamed DMA phases grounded in the
-  proven per-phase kernels (hist one-hot matmuls with exact bf16
-  hi/lo payload pairs, f32 one-hot lane selects instead of the i32
-  reductions this jax's Mosaic cannot lower, the split-scan core from
-  ``ops/split_scan_pallas.py``). Numerical-only scope (like
-  ``scan_kernel_ok``): categorical / EFB-bundled / multi-val configs
-  fall back to the per-phase foil.
-* the **interpret-mode CPU twin** (both layouts) — the SAME
-  pallas_call contract, but
-  the body replicates the per-phase foil bit-for-bit by calling the
-  exact shared helpers the foil body calls (``split_leaf``,
-  ``build_histogram``/``histogram_segment``, ``make_scan_leaf``,
-  ``scan_split_pair``, ``StatePack.set_state_cols``/``set_tree_col``)
-  on ref-loaded values. Models trained through the twin are therefore
-  byte-identical to the foil by construction — the contract
+* the **Mosaic TPU body** — real streamed DMA phases grounded in the
+  per-phase kernels (``partition_stream``, hist one-hot matmuls with
+  exact bf16 hi/lo payload pairs, f32 one-hot lane selects instead of
+  the i32 reductions this jax's Mosaic cannot lower, the split-scan
+  core from ``ops/split_scan_pallas.py``). Numerical, unbundled,
+  byte-bin scope; anything else raises here.
+* the **interpret-mode CPU twin** — the SAME pallas_call contract, but
+  the body replicates the per-phase body bit-for-bit by calling the
+  exact shared helpers it calls (``histogram_segment``,
+  ``partition_decision_lut``, ``make_scan_leaf``, ``scan_split_pair``,
+  ``StatePack.set_state_cols``/``set_tree_col``) on ref-loaded values.
+  Models trained through the twin are therefore byte-identical to the
+  per-phase body by construction — the contract
   ``tests/test_split_megakernel.py`` pins across bagging, categorical,
-  linear_tree and monotone configs on both learners. The twin covers
-  the FULL ``ops/split.py`` semantics (categorical + monotone paths).
+  linear_tree and monotone configs. The twin covers the FULL
+  ``ops/split.py`` semantics (categorical + monotone paths).
 
-Gate: ``LGBM_TPU_FUSED_SPLIT_KERNEL`` / ``Config.fused_split_kernel``
-(default ``auto`` = on a TPU at the compiled bodies' static scope,
-``fused_compiled_ok``). The rule is static: when Mosaic rejects a
-kernel the rule selected, the compile error surfaces to the caller.
+This module holds kernels, their wrappers and the limits that are
+facts about the kernel (``MAX_FUSED_F``, ``FUSED_BLK``, ``SEG_BLK``).
+It does not know its caller: the packed carry (``StatePack``) and the
+comm arrive as arguments, and whether the kernel runs at all is
+``learner/split_step.py`` ``plan_split_step``'s decision.
 ``lower_for_tpu`` runs the real Mosaic lowering pass host-side
 (``.trace().lower(lowering_platforms=("tpu",))``) for the CPU tests.
 """
@@ -67,11 +55,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import LightGBMError
-from ..utils.device import on_tpu
 from ..utils.jit_registry import register_jit
 from .split import (MISSING_NAN_CODE, MISSING_ZERO_CODE, FeatureMeta,
-                    kEpsilon)
+                    child_columns, child_constraints,
+                    child_constraints_mono, kEpsilon, make_scan_leaf,
+                    order_child_pair, scan_split_pair, set_bitsets,
+                    split_node_updates)
 
 NEG_INF = float("-inf")  # python scalar: kernels fold it as a constant
 
@@ -123,7 +112,7 @@ def compiled_hist_cache(root_hist, big_l: int):
     (pad rows/lanes stay zero) because Mosaic moves a leaf's slab by
     DMA and a slab's last two dims must fill whole (8, 128) tiles
     ("Slice shape along dimension 2 must be aligned to tiling (8),
-    but is 28"). The interpret twins keep the foil's
+    but is 28"). The interpret twin keeps the foil's
     ``[L, F, B, 3]``."""
     f, b, _ = root_hist.shape
     fp, bp = -(-f // 8) * 8, -(-b // 128) * 128
@@ -155,15 +144,8 @@ def _meta_from_tables(imeta, fmeta):
         global_id=jnp.arange(f, dtype=jnp.int32)), fmeta[:, 1] > 0
 
 
-def _grow_pack(si_prefix, params, has_monotone, big_l):
-    from ..learner.split_step import make_grow_pack
-    return make_grow_pack(si_prefix, merged=True,
-                          has_cat=params.has_categorical,
-                          has_monotone=has_monotone, big_l=big_l)
-
-
 # =====================================================================
-# interpret-mode CPU twin bodies
+# interpret-mode CPU twin body
 # =====================================================================
 
 def _twin_split_site(pack, s_ref, t_ref, bsb_ref, cbs_ref, k, big_l):
@@ -187,12 +169,9 @@ def _twin_finish(pack, params, meta, fmask, comm, st, site, leaf, new,
                  s, k, gain, feat, thr, dleft, is_cat, hist_small,
                  hist_other, small_is_left, *, bundled, has_monotone,
                  max_depth, extra_a=None, extra_b=None):
-    """Shared tail of both twins: both children's scans + the packed
-    state/tree/bitset writes, via the SAME helpers the foil bodies
-    call (learner/split_step.py) so every value is bit-identical."""
-    from ..learner.split_step import (child_columns, child_constraints,
-                                      make_scan_leaf, scan_split_pair,
-                                      set_bitsets, split_node_updates)
+    """Tail of the twin: both children's scans + the packed
+    state/tree/bitset writes, via the SAME helpers the per-phase body
+    calls (ops/split.py) so every value is bit-identical."""
     inf = jnp.float32(jnp.inf)
     lg, lh, lc = site["bs_lg"], site["bs_lh"], site["bs_lc"]
     pg, ph, pc = site["leaf_g"], site["leaf_h"], site["leaf_c"]
@@ -234,85 +213,14 @@ def _twin_finish(pack, params, meta, fmask, comm, st, site, leaf, new,
     return upds, idx_a, idx_b
 
 
-def _leaf_kernel_ref(iscal, s_in, t_in, lid_in, hist_in, binned_ref,
-                     ghc_ref, imeta_ref, fmeta_ref,
-                     s_out, t_out, lid_out, hist_out,
-                     *, params, si_prefix, big_l, max_depth, b,
-                     bundled, has_monotone, hist_method,
-                     bsb_in=None, cbs_in=None, bsb_out=None,
-                     cbs_out=None):
-    """Interpret twin, leaf layout: the serial foil body transliterated
-    onto ref-loaded values (same helpers, same op order -> bit-exact).
-    """
-    del s_in, t_in, lid_in, hist_in  # aliased; all access via out refs
-    from ..learner.comm import SERIAL_COMM
-    from ..ops.histogram import build_histogram
-    from ..ops.partition import split_leaf
-    from ..data.bundling import decode_feature_bin
-
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        pack = _grow_pack(si_prefix, params, has_monotone, big_l)
-        meta, fmask = _meta_from_tables(imeta_ref[...], fmeta_ref[...])
-        k = iscal[0]
-        new = k
-        s = k - 1
-        st, view, leaf, site, bitset = _twin_split_site(
-            pack, s_out, t_out, bsb_out, cbs_out, k, big_l)
-        feat = site["bs_feat"]
-        thr = site["bs_thr"]
-        dleft = site["bs_dleft"]
-        gain = site["bs_gain"]
-        is_cat = site["bs_iscat"]
-        lc = site["bs_lc"]
-        rc = site["leaf_c"] - lc
-
-        # ---- partition (ops/partition.py split_leaf, as the foil) ---
-        binned = binned_ref[...]
-        ghc = ghc_ref[...]
-        bin_col = jnp.take(binned, meta.group[feat], axis=1)
-        if bundled:
-            bin_col = decode_feature_bin(
-                bin_col.astype(jnp.int32), meta.offset[feat],
-                meta.num_bins[feat]).astype(bin_col.dtype)
-        leaf_id = split_leaf(
-            lid_out[...], bin_col, leaf, new, thr, dleft,
-            meta.missing[feat], meta.default_bin[feat],
-            meta.num_bins[feat], is_cat, bitset)
-        lid_out[...] = leaf_id
-
-        # ---- smaller-child histogram + sibling subtraction ----------
-        small_is_left = lc <= rc
-        sm = jnp.where(small_is_left, leaf, new)
-        ghc_small = ghc * (leaf_id == sm).astype(jnp.float32)[:, None]
-        hist_small = build_histogram(binned, ghc_small, b,
-                                     method=hist_method)
-        parent_hist = hist_out[leaf]
-        hist_other = parent_hist - hist_small
-
-        # ---- scans + packed writes (shared tail) --------------------
-        upds, idx_a, idx_b = _twin_finish(
-            pack, params, meta, fmask, SERIAL_COMM, st, site, leaf,
-            new, s, k, gain, feat, thr, dleft, is_cat, hist_small,
-            hist_other, small_is_left, bundled=bundled,
-            has_monotone=has_monotone, max_depth=max_depth)
-        s_out[...] = upds["S"]
-        t_out[...] = upds["T"]
-        hist_out[idx_a] = hist_small
-        hist_out[idx_b] = hist_other
-        if bsb_out is not None:
-            bsb_out[...] = upds["bs_bitset"]
-            cbs_out[...] = upds["cat_bitsets"]
-
-
 def _segment_kernel_ref(iscal, s_in, t_in, mat_in, ws_in, hist_in,
                         imeta_ref, fmeta_ref,
                         s_out, t_out, mat_out, ws_out, hist_out,
-                        *, params, si_prefix, big_l, max_depth, b, f,
+                        *, params, pack, comm, big_l, max_depth, b, f,
                         n, bundled, has_monotone, blk,
                         bsb_in=None, cbs_in=None, bsb_out=None,
                         cbs_out=None):
-    """Interpret twin, segment layout: the partitioned foil body on
+    """Interpret twin: the partitioned per-phase body on
     ref-loaded values. The stable partition is computed as an exact
     prefix-sum permutation (bit-identical row content to
     ``partition_segment``); the smaller child's histogram reuses the
@@ -320,13 +228,11 @@ def _segment_kernel_ref(iscal, s_in, t_in, mat_in, ws_in, hist_in,
     (``hist_pallas.histogram_segment``), so the float accumulation
     order — and therefore the model — is bit-identical."""
     del s_in, t_in, mat_in, ws_in, hist_in
-    from ..learner.comm import SERIAL_COMM
-    from ..learner.partitioned import partition_decision_lut
-    from ..ops.hist_pallas import histogram_segment
+    from .hist_pallas import histogram_segment
+    from .partition_pallas import partition_decision_lut
 
     @pl.when(pl.program_id(0) == 0)
     def _():
-        pack = _grow_pack(si_prefix, params, has_monotone, big_l)
         meta, fmask = _meta_from_tables(imeta_ref[...], fmeta_ref[...])
         k = iscal[0]
         new = k
@@ -389,7 +295,7 @@ def _segment_kernel_ref(iscal, s_in, t_in, mat_in, ws_in, hist_in,
         cnt_b = cnt - sc
 
         upds, idx_a, idx_b = _twin_finish(
-            pack, params, meta, fmask, SERIAL_COMM, st, site, leaf,
+            pack, params, meta, fmask, comm, st, site, leaf,
             new, s, k, gain, feat, thr, dleft, is_cat, hist_small,
             hist_other, small_is_left, bundled=bundled,
             has_monotone=has_monotone, max_depth=max_depth,
@@ -429,88 +335,35 @@ def _call_common(alias_pairs, interpret):
     )
 
 
-@register_jit("fused_split_step_leaf", donate=("S", "T", "lid", "hist"))
-@functools.partial(
-    jax.jit,
-    static_argnames=("params", "si_prefix", "big_l", "max_depth", "b",
-                     "bundled", "has_monotone", "hist_method",
-                     "interpret"),
-    donate_argnames=("S", "T", "lid", "hist"))
-def fused_split_step_leaf(k, S, T, lid, hist, binned, ghc, imeta,
-                          fmeta, bsb=None, cbs=None, *, params,
-                          si_prefix=(), big_l, max_depth, b, bundled,
-                          has_monotone, hist_method, interpret):
-    """ONE whole split of the serial grow loop as one ``pallas_call``
-    (interpret twin only; ``interpret=False`` raises, see
-    ``COMPILED_LAYOUTS``).
-
-    Carry in/out (aliased, donated): merged state ``S`` [Ks, L] i32,
-    tree arrays ``T`` [Kt, L-1] i32 (float rows bitcast), ``lid`` [N]
-    i32 leaf membership, ``hist`` [L, G, B, 3] f32 per-leaf histogram
-    cache (+ the categorical ``bsb``/``cbs`` bitset arrays when the
-    config carries them). Read-only: ``binned`` [N, G], ``ghc``
-    [N, 3], ``imeta``/``fmeta`` metadata tables. ``k`` is the split
-    index (new leaf id).
-    """
-    if not interpret:
-        require_compiled_body("leaf")
-    iscal = jnp.reshape(jnp.asarray(k, jnp.int32), (1,))
-    ins = [iscal, S, T, lid, hist, binned, ghc, imeta, fmeta]
-    out_shape = [jax.ShapeDtypeStruct(S.shape, S.dtype),
-                 jax.ShapeDtypeStruct(T.shape, T.dtype),
-                 jax.ShapeDtypeStruct(lid.shape, lid.dtype),
-                 jax.ShapeDtypeStruct(hist.shape, hist.dtype)]
-    alias = [(1, 0), (2, 1), (3, 2), (4, 3)]
-    kern = functools.partial(
-        _leaf_kernel_ref,
-        params=params, si_prefix=si_prefix, big_l=big_l,
-        max_depth=max_depth, b=b, bundled=bundled,
-        has_monotone=has_monotone, hist_method=hist_method)
-    if bsb is not None:
-        ins += [bsb, cbs]
-        out_shape += [jax.ShapeDtypeStruct(bsb.shape, bsb.dtype),
-                      jax.ShapeDtypeStruct(cbs.shape, cbs.dtype)]
-        alias += [(9, 4), (10, 5)]
-
-        def kern2(iscal, s_i, t_i, l_i, h_i, bn, gh, im, fm,
-                  bsb_i, cbs_i, s_o, t_o, l_o, h_o, bsb_o, cbs_o,
-                  *scr):
-            return kern(iscal, s_i, t_i, l_i, h_i, bn, gh, im, fm,
-                        s_o, t_o, l_o, h_o, *scr, bsb_in=bsb_i,
-                        cbs_in=cbs_i, bsb_out=bsb_o, cbs_out=cbs_o)
-    else:
-        kern2 = kern
-    in_specs = [_smem_spec(iscal.shape)] + \
-        [_whole(x.shape) for x in ins[1:]]
-    out_specs = [_whole(s.shape) for s in out_shape]
-    res = pl.pallas_call(
-        kern2,
-        out_shape=out_shape,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **_call_common(alias, interpret),
-    )(*ins)
-    return tuple(res)
-
-
 @register_jit("fused_split_step_segment",
               donate=("S", "T", "mat", "ws", "hist"))
 @functools.partial(
     jax.jit,
-    static_argnames=("params", "si_prefix", "big_l", "max_depth", "b",
-                     "f", "n", "bundled", "has_monotone", "blk",
+    static_argnames=("params", "pack", "comm", "big_l", "max_depth",
+                     "b", "f", "n", "bundled", "has_monotone", "blk",
                      "interpret"),
     donate_argnames=("S", "T", "mat", "ws", "hist"))
 def fused_split_step_segment(k, S, T, mat, ws, hist, imeta, fmeta,
-                             bsb=None, cbs=None, *, params,
-                             si_prefix, big_l, max_depth, b, f, n,
-                             bundled, has_monotone, blk=FUSED_BLK,
+                             bsb=None, cbs=None, *, params, pack,
+                             big_l, max_depth, b, f, n, bundled,
+                             has_monotone, comm=None, blk=FUSED_BLK,
                              interpret=True):
     """ONE whole split of the partitioned grow loop as one
     ``pallas_call`` over the training matrix (``mat``/``ws`` aliased
-    in place like ``partition_segment``). The interpret twin keeps the
-    foil's ``[L, F, B, 3]`` histogram cache; the compiled path takes
-    ``compiled_hist_cache``'s padded channels-major layout."""
+    in place like ``partition_segment``).
+
+    Carry in/out (aliased, donated): merged state ``S`` [Ks, L] i32,
+    tree arrays ``T`` [Kt, L-1] i32 (float rows bitcast), ``mat`` /
+    ``ws`` the training matrix and its workspace, ``hist`` the per-leaf
+    histogram cache (+ the categorical ``bsb``/``cbs`` bitset arrays
+    when the config carries them). Read-only: ``imeta``/``fmeta``
+    metadata tables. ``k`` is the split index (new leaf id). ``pack``
+    is the grow loop's merged ``StatePack`` (static: the rows of ``S``
+    and ``T`` by name); ``comm`` the learner's comm, read by the
+    interpret twin's scans (the plan admits the serial one only). The
+    interpret twin keeps the per-phase body's ``[L, F, B, 3]``
+    histogram cache; the compiled path takes ``compiled_hist_cache``'s
+    padded channels-major layout."""
     iscal = jnp.reshape(jnp.asarray(k, jnp.int32), (1,))
     has_cat = bsb is not None
     if interpret:
@@ -523,7 +376,7 @@ def fused_split_step_segment(k, S, T, mat, ws, hist, imeta, fmeta,
         alias = [(1, 0), (2, 1), (3, 2), (4, 3), (5, 4)]
         kern = functools.partial(
             _segment_kernel_ref,
-            params=params, si_prefix=si_prefix, big_l=big_l,
+            params=params, pack=pack, comm=comm, big_l=big_l,
             max_depth=max_depth, b=b, f=f, n=n, bundled=bundled,
             has_monotone=has_monotone, blk=blk)
         if has_cat:
@@ -577,7 +430,7 @@ def fused_split_step_segment(k, S, T, mat, ws, hist, imeta, fmeta,
     alias = [(1, 0), (2, 1), (3, 2), (4, 3), (5, 4)]
     kern = functools.partial(
         _segment_kernel_tpu,
-        params=params, si_prefix=si_prefix, big_l=big_l,
+        params=params, pack=pack, big_l=big_l,
         max_depth=max_depth, b=b, f=f, n=n, bundled=bundled,
         has_monotone=has_monotone, blk=seg_blk)
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
@@ -604,56 +457,20 @@ def fused_split_step_segment(k, S, T, mat, ws, hist, imeta, fmeta,
     return tuple(res)
 
 
-# =====================================================================
-# gate: config/env mode + static scope
-# =====================================================================
-
-def fused_compiled_ok(params, *, bundled: bool,
-                      num_bins_max: int) -> bool:
-    """Static scope of the COMPILED Mosaic bodies. The interpret twin
-    covers the full ``ops/split.py`` semantics; the Mosaic bodies keep
-    the numerical fast path (like ``scan_kernel_ok``): no categorical
-    scan, unbundled columns, u8-expressible bins."""
-    return (not params.has_categorical and not bundled
-            and num_bins_max <= 256)
-
-
-# layouts with a Mosaic body. The leaf layout has none: its row slabs
-# ([blk, F] u8 bins, [blk, 3] f32 payload, [blk, 1] i32 leaf ids) are
-# DMA slices narrower than a 128-lane tile, which lower host-side but
-# the chip's compiler refuses — "Slice shape along dimension 1 must be
-# aligned to tiling (128), but is 28" (jax 0.9.0, libtpu 0.0.34, TPU
-# v5 lite). A compiled leaf body needs a feature-major layout (ROADMAP
-# S5/D2); ``auto`` never selects the leaf layout.
-COMPILED_LAYOUTS = ("segment",)
-
-
-def require_compiled_body(layout: str) -> None:
-    if layout not in COMPILED_LAYOUTS:
-        raise LightGBMError(
-            f"the {layout}-layout split-step megakernel has no compiled "
-            "body (the TPU compiler refuses its row slabs: \"Slice "
-            "shape along dimension 1 must be aligned to tiling (128), "
-            "but is 28\"); fused_split_kernel=on needs "
-            "tree_learner=partitioned on a TPU")
-
-
-def lower_for_tpu(layout: str) -> None:
+def lower_for_tpu(pack, *, big_l: int) -> None:
     """Trace + Mosaic-lower the compiled kernel body at a tiny
     canonical shape (no TPU needed — the same mechanism as
-    tests/test_mosaic_lowering.py). Raises what Mosaic raises."""
-    from ..learner.partitioned import SEG_SI_PREFIX
-    from ..learner.split_step import make_grow_pack
+    tests/test_mosaic_lowering.py). ``pack``: the merged ``StatePack``
+    of a numeric table at ``big_l`` leaves
+    (``learner/partitioned.py`` ``segment_grow_pack``). Raises what
+    Mosaic raises."""
     from .hist_pallas import matrix_cols, matrix_rows
     from .split import SplitParams
-    require_compiled_body(layout)
     params = SplitParams(
         lambda_l1=0.0, lambda_l2=1.0, max_delta_step=0.0,
         min_data_in_leaf=1.0, min_sum_hessian_in_leaf=1e-3,
         min_gain_to_split=0.0, any_missing=False)
-    big_l, f, b, n = 15, 8, 16, FUSED_BLK
-    pack = make_grow_pack(SEG_SI_PREFIX, merged=True, has_cat=False,
-                          has_monotone=False, big_l=big_l)
+    f, b, n = 8, 16, FUSED_BLK
     S = jnp.zeros((len(pack.sf_fields) + len(pack.si_fields), big_l),
                   jnp.int32)
     T = jnp.zeros((len(pack.tf_fields) + len(pack.ti_fields),
@@ -663,10 +480,9 @@ def lower_for_tpu(layout: str) -> None:
     mat = jnp.zeros((matrix_rows(n, FUSED_BLK), matrix_cols(f)),
                     jnp.uint8)
     fn = functools.partial(
-        fused_split_step_segment, params=params,
-        si_prefix=SEG_SI_PREFIX, big_l=big_l, max_depth=-1, b=b, f=f,
-        n=n, bundled=False, has_monotone=False, blk=FUSED_BLK,
-        interpret=False)
+        fused_split_step_segment, params=params, pack=pack,
+        big_l=big_l, max_depth=-1, b=b, f=f, n=n, bundled=False,
+        has_monotone=False, blk=FUSED_BLK, interpret=False)
     # probe-only jit: never dispatched, exists to run Mosaic lowering
     jax.jit(fn).trace(  # graftlint: allow[GL506]
         jnp.int32(1), S, T, mat, jnp.zeros_like(mat), hist,
@@ -675,41 +491,8 @@ def lower_for_tpu(layout: str) -> None:
         lowering_platforms=("tpu",))
 
 
-def learner_fused_kernel_on(lrn, layout: str) -> bool:
-    """Resolve the megakernel gate for one learner instance: config
-    param (``fused_split_kernel``) + env override
-    (``LGBM_TPU_FUSED_SPLIT_KERNEL``) + static eligibility. Read per
-    train() call so flipping the env retraces. The rule is a static
-    function of the config and the platform: a kernel it selects and
-    Mosaic refuses is a compile error, never a quiet switch to the
-    per-phase kernels."""
-    from ..learner.split_step import (fused_split_eligible,
-                                      fused_split_kernel_mode,
-                                      split_fusion_default)
-    mode = fused_split_kernel_mode(
-        getattr(lrn.config, "fused_split_kernel", "auto"))
-    if mode == "off":
-        return False
-    if not fused_split_eligible(
-            lrn.params, cache_hists=getattr(lrn, "cache_hists", False),
-            merged=split_fusion_default(),
-            extra_trees=lrn.extra_trees, ff_bynode=lrn.ff_bynode,
-            mv_groups=getattr(lrn, "mv_groups", 0),
-            serial_comm=True, num_leaves=lrn.num_leaves):
-        return False
-    if mode == "on":
-        return True
-    # auto = on a TPU, at the compiled bodies' static scope (the
-    # compiled path also hands forced-split pre-steps to the foil, so
-    # plans keep the per-phase kernels wholesale)
-    return (on_tpu() and layout in COMPILED_LAYOUTS
-            and not getattr(lrn, "forced_plan", ())
-            and fused_compiled_ok(lrn.params, bundled=lrn.bundled,
-                                  num_bins_max=lrn.num_bins_max))
-
-
 # =====================================================================
-# Mosaic TPU bodies (compiled path; numerical-only scope)
+# Mosaic TPU body (compiled path; numerical-only scope)
 # =====================================================================
 #
 # Lowering discipline (this jax's Mosaic): no integer reductions (all
@@ -847,15 +630,10 @@ def _scan_and_write_phase(pack, params, iscal, s_in, t_in, imeta_ref,
                           pbuf, cbuf, hist_out, sem_w, *, big_l,
                           max_depth, b, f, has_monotone,
                           extra_ab=None):
-    """Shared phase-1 tail of both Mosaic bodies: sibling subtraction,
-    both children's scan_core runs, best-feature extraction, and the
-    packed state/tree/hist writes. ``extra_ab(site, leaf_f,
-    small_is_left)`` optionally returns the segment-bound int fields
-    of each child (partitioned layout)."""
-    from ..learner.split_step import (child_columns,
-                                      child_constraints_mono,
-                                      order_child_pair,
-                                      split_node_updates)
+    """Phase-1 tail of the Mosaic body: sibling subtraction, both
+    children's scan_core runs, best-feature extraction, and the packed
+    state/tree/hist writes. ``extra_ab(site, leaf_f, small_is_left)``
+    returns the segment-bound int fields of each child."""
     from .split_scan_pallas import scan_core
 
     k = iscal[0]
@@ -1041,9 +819,9 @@ def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,
                         imeta_ref, fmeta_ref,
                         s_out, t_out, mat_out, ws_out, hist_out,
                         *scratch,
-                        params, si_prefix, big_l, max_depth, b, f,
+                        params, pack, big_l, max_depth, b, f,
                         n, bundled, has_monotone, blk):
-    """Mosaic body, segment layout: phase 0 streams the chosen leaf's
+    """Mosaic body: phase 0 streams the chosen leaf's
     contiguous row segment ONCE — the stable in-place partition
     (``partition_pallas.partition_stream``: the pipelined block stream
     ``partition_segment`` runs, imported like ``_decode_block``) and
@@ -1056,7 +834,6 @@ def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,
     from .hist_pallas import _decode_block
     from .partition_pallas import partition_stream
     *stream, hpl, pbuf, cbuf, nl_ref, sem_w = scratch
-    pack = _grow_pack(si_prefix, params, has_monotone, big_l)
     pid = pl.program_id(0)
     cols = mat_out.shape[1]
     win = blk + ALIGN

@@ -472,6 +472,9 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
         comm = self.comm
         use_rs = self._use_rs
         f = self.num_features
+        # resolved once (learner/split_step.py): per-phase body (the
+        # collectives sit between the phases), LUT partition by table
+        plan = self.split_plan()
         if use_rs:
             meta_l = shard_arrays(self.mesh, self._mode,
                                   {"meta_local": self._plan.meta_local}
@@ -512,7 +515,7 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
                 row_id_base=base, n_total=n_pad,
                 cache_hists=self.cache_hists,
                 cegb_used0=cegb0 if self.params.cegb_on else None,
-                has_monotone=self.has_monotone,
+                has_monotone=self.has_monotone, plan=plan,
                 return_leaf_parts=leaf_parts, body_scan=ctx)
             if leaf_parts:
                 mat_l, ws_l, tree, (rid_l, pos_leaf) = out

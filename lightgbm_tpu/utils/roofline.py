@@ -8,8 +8,7 @@ keyed by the exact ``device_kind`` string JAX reports and holds only
 devices this repo has run on; a device that is not in it is an error,
 never a default or an "n/a".
 
-Byte-cost model (documented here, used by bench.py and
-tools/micro_kernel_bench.py):
+Byte-cost model (documented here, used by bench.py):
 
 * ``histogram_segment`` streams each row's ``F`` bin bytes plus the 12
   gh payload bytes (g, h, count f32) once per call:
@@ -55,16 +54,6 @@ def iter_bytes_per_row(num_features: int) -> int:
     histogram pass + one partition pass of the training matrix)."""
     return hist_bytes_per_row(num_features) \
         + part_bytes_per_row(num_features)
-
-
-def fused_leaf_bytes_per_row(num_features: int) -> int:
-    """HBM traffic per row of ONE fused split step in the leaf layout
-    (ops/split_step_pallas.py): the megakernel streams the u8 bins,
-    the f32 (g, h, c) payload and the i32 leaf_id once, writing the
-    leaf_id back — partition AND histogram ride the same pass, which
-    is the whole point of the fusion (vs hist + part streaming the
-    rows separately)."""
-    return num_features + 12 + 2 * 4
 
 
 def device_peaks(device=None) -> Dict[str, Any]:

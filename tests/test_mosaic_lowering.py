@@ -39,14 +39,12 @@ def _lowers(fn, *args):
     jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
 
 
-@pytest.mark.parametrize("variant", ["grouped", "perfeat"])
-def test_histogram_kernel_lowers_for_tpu(variant):
+def test_histogram_kernel_lowers_for_tpu():
     from lightgbm_tpu.ops.hist_pallas import histogram_segment
     f, b = 28, 256
     mat = _mat(f=f, b=b)
     _lowers(functools.partial(histogram_segment, num_bins=b,
-                              num_features=f, interpret=False,
-                              variant=variant),
+                              num_features=f, interpret=False),
             mat, jnp.int32(8), jnp.int32(2048))
 
 
@@ -78,62 +76,51 @@ def test_lut_partition_lowers_at_the_categorical_width():
             jnp.int32(0), jnp.int32(255), jnp.int32(1), lut)
 
 
-@pytest.mark.parametrize("layout", [
-    pytest.param("leaf", marks=pytest.mark.xfail(
-        strict=True, raises=LightGBMError,
-        reason="no compiled leaf body: the TPU v5 lite compiler "
-        "(jax 0.9.0, libtpu 0.0.34) refuses its row slabs, 'Slice "
-        "shape along dimension 1 must be aligned to tiling (128), but "
-        "is 28' (split_step_pallas.COMPILED_LAYOUTS)")),
-    "segment"])
-def test_fused_split_step_lowers_for_tpu(layout):
+def test_fused_split_step_lowers_for_tpu():
     """The split-step megakernel's Mosaic body lowers on this host.
-    The ``auto`` gate is a static rule (no lowering probe behind it),
-    so a kernel Mosaic refuses would fail every eligible TPU run at
-    its first grow call — CI fails FIRST. Notably the segment body's
-    partition phase keeps all its lane/row extractions as f32
-    select-sums. Lowering is not compiling: only ``chip_smoke.py``
-    shows the chip's compiler accepts the body."""
+    The ``auto`` rule is static (no lowering probe behind it), so a
+    kernel Mosaic refuses would fail every eligible TPU run at its
+    first grow call — CI fails FIRST. Notably the body's partition
+    phase keeps all its lane/row extractions as f32 select-sums.
+    Lowering is not compiling: only ``chip_smoke.py`` shows the chip's
+    compiler accepts the body."""
+    from lightgbm_tpu.learner.partitioned import segment_grow_pack
     from lightgbm_tpu.ops.split_step_pallas import lower_for_tpu
-    lower_for_tpu(layout)
+    lower_for_tpu(segment_grow_pack(15), big_l=15)
 
 
-def test_leaf_layout_on_a_tpu_raises(monkeypatch):
-    """``fused_split_kernel=on`` with the serial learner on a TPU is an
-    error naming the compiler's refusal, not a run on another path."""
-    import lightgbm_tpu.learner.serial as serial
+def test_leaf_layout_on_a_tpu_raises():
+    """``fused_split_kernel=on`` with the serial learner is an error
+    naming the compiler's refusal of its layout, on every platform:
+    not a run on another path."""
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.data import Dataset
+    from lightgbm_tpu.learner.serial import SerialTreeLearner
 
-    monkeypatch.setattr(serial, "on_tpu", lambda: True)
-    monkeypatch.delenv("LGBM_TPU_FUSED_SPLIT_KERNEL", raising=False)
     rng = np.random.RandomState(0)
     X = rng.randn(512, 6).astype(np.float32)
     cfg = Config.from_params({"objective": "binary", "num_leaves": 7,
                               "fused_split_kernel": "on",
                               "verbosity": -1})
     ds = Dataset.from_numpy(X, cfg, label=(X[:, 0] > 0).astype(float))
-    ln = serial.SerialTreeLearner(ds, cfg, hist_method="onehot")
-    g = jnp.zeros((512,), jnp.float32)
     with pytest.raises(LightGBMError, match="aligned to tiling"):
-        ln.train(g, g + 1.0)
+        SerialTreeLearner(ds, cfg, hist_method="onehot")
 
 
 def test_refused_kernel_raises_not_falls_back(monkeypatch):
     """A kernel the gate selects and Mosaic refuses is an error with
     the compiler's message — never a quiet run on the per-phase
     kernels. Force the known refusal (an f32 ``tpu.iota``) into the
-    megakernel body, make the platform read as a TPU so ``auto``
-    selects the kernel, and lower the training block."""
-    import lightgbm_tpu.ops.split_scan_pallas as ssp
+    megakernel body, make the platform read as a TPU (in the ONE
+    module that asks it for this choice) so ``auto`` selects the
+    kernel, and lower the training block."""
+    import lightgbm_tpu.learner.split_step as split_step
     import lightgbm_tpu.ops.split_step_pallas as sp
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.data import Dataset
     from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
 
-    monkeypatch.setattr(sp, "on_tpu", lambda: True)
-    monkeypatch.setattr(ssp, "on_tpu", lambda: True)
-    monkeypatch.delenv("LGBM_TPU_FUSED_SPLIT_KERNEL", raising=False)
+    monkeypatch.setattr(split_step, "on_tpu", lambda: True)
     monkeypatch.setattr(
         sp, "_iota_f32",
         lambda shape, dim: jax.lax.broadcasted_iota(
@@ -144,7 +131,8 @@ def test_refused_kernel_raises_not_falls_back(monkeypatch):
                               "verbosity": -1})
     ds = Dataset.from_numpy(X, cfg, label=(X[:, 0] > 0).astype(float))
     ln = PartitionedTreeLearner(ds, cfg, interpret=False)
-    assert ln.params.use_scan_kernel and ln._fused_kernel_on()
+    assert ln.params.use_scan_kernel
+    assert ln.split_plan().body == "megakernel"
     g = jnp.zeros((512,), jnp.float32)
     with pytest.raises(Exception, match="tpu.iota"):
         jax.jit(ln.traceable_grow).trace(
@@ -246,12 +234,12 @@ def test_full_fused_training_block_lowers_for_tpu(leaves, f):
 
 def test_categorical_fused_training_block_lowers_for_tpu():
     """The fused block of a table with categorical columns, at the
-    benchmark's 40 columns and 255 leaves: the compiled megakernel
-    refuses it (``fused_compiled_ok``), so the grow loop holds the
+    benchmark's 40 columns and 255 leaves: the plan keeps it off the
+    compiled megakernel (``plan_split_step``), so the grow loop holds the
     per-phase kernels (bitset partition, segment histogram) and the
     numeric and categorical XLA scans, and it still rides the fused
     driver. Lowered with the compiled kernels, as the chip runs it."""
-    import lightgbm_tpu.ops.split_step_pallas as sp
+    import lightgbm_tpu.learner.split_step as split_step
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.data import Dataset
     from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
@@ -271,8 +259,11 @@ def test_categorical_fused_training_block_lowers_for_tpu():
     ln = PartitionedTreeLearner(ds, cfg, interpret=False)
     assert ln.params.has_categorical and not ln.bundled
     assert not ln.params.use_scan_kernel
-    assert not sp.fused_compiled_ok(ln.params, bundled=ln.bundled,
-                                    num_bins_max=ln.num_bins_max)
+    # what the chip's plan is for this table: no megakernel even there
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(split_step, "on_tpu", lambda: True)
+        assert ln.split_plan() == split_step.SplitStepPlan(
+            "per_phase", False, True, True)
     assert ln.supports_fused_scan and ln.fused_scan_ok()
     tel = get_telemetry()
     was_on = tel.enabled
@@ -323,17 +314,15 @@ def test_a_column_wider_than_a_byte_raises():
         == 256
 
 
-@pytest.mark.parametrize("variant", ["grouped", "perfeat"])
-def test_histogram_wide_slices_lower_for_tpu(variant):
+def test_histogram_wide_slices_lower_for_tpu():
     """The sliced nibble dispatch at an Epsilon-like width (250
     features -> 192 + 58 slices, compact two-region DMA) lowers for
-    TPU — both mask variants."""
+    TPU."""
     from lightgbm_tpu.ops.hist_pallas import histogram_segment
     f, b = 250, 64
     mat = _mat(n=2048, f=f, b=b)
     _lowers(functools.partial(histogram_segment, num_bins=b,
-                              num_features=f, interpret=False,
-                              variant=variant),
+                              num_features=f, interpret=False),
             mat, jnp.int32(8), jnp.int32(1024))
 
 
@@ -356,6 +345,7 @@ def test_both_partition_kernels_lower_the_pipelined_stream():
     """``partition_segment`` (LUT on and off) and the megakernel's
     phase 0 lower ONE block step, ``partition_pallas.partition_stream``:
     the trace-time counter says the helper entered each trace."""
+    from lightgbm_tpu.learner.partitioned import segment_grow_pack
     from lightgbm_tpu.observability.telemetry import get_telemetry
     from lightgbm_tpu.ops import partition_pallas, split_step_pallas
     assert not hasattr(split_step_pallas, "compact_and_write")
@@ -376,7 +366,7 @@ def test_both_partition_kernels_lower_the_pipelined_stream():
                 jnp.int32(0), lut)
     after_partition = tel.counters.get(name, 0)
     jax.clear_caches()
-    split_step_pallas.lower_for_tpu("segment")
+    split_step_pallas.lower_for_tpu(segment_grow_pack(15), big_l=15)
     after_mega = tel.counters.get(name, 0)
     if not was_on:
         tel.reset()
@@ -420,8 +410,7 @@ def test_pipelined_partition_compiles_for_v5e(one_chip, use_lut):
 def test_pipelined_megakernel_compiles_for_v5e(one_chip):
     """The megakernel at the Higgs cell's shapes (10.5 M x 28, 255
     leaves, 256 bins) with the shared stream in phase 0."""
-    from lightgbm_tpu.learner.partitioned import SEG_SI_PREFIX
-    from lightgbm_tpu.learner.split_step import make_grow_pack
+    from lightgbm_tpu.learner.partitioned import segment_grow_pack
     from lightgbm_tpu.ops import split_step_pallas as ssp
     from lightgbm_tpu.ops.hist_pallas import matrix_cols, matrix_rows
     from lightgbm_tpu.ops.split import SplitParams
@@ -431,8 +420,7 @@ def test_pipelined_megakernel_compiles_for_v5e(one_chip):
         lambda_l1=0.0, lambda_l2=1.0, max_delta_step=0.0,
         min_data_in_leaf=20.0, min_sum_hessian_in_leaf=1e-3,
         min_gain_to_split=0.0, any_missing=False)
-    pack = make_grow_pack(SEG_SI_PREFIX, merged=True, has_cat=False,
-                          has_monotone=False, big_l=big_l)
+    pack = segment_grow_pack(big_l)
     S = sds((len(pack.sf_fields) + len(pack.si_fields), big_l),
             jnp.int32)
     T = sds((len(pack.tf_fields) + len(pack.ti_fields), big_l - 1),
@@ -442,8 +430,8 @@ def test_pipelined_megakernel_compiles_for_v5e(one_chip):
     mat = sds((matrix_rows(n, ssp.FUSED_BLK), matrix_cols(f)),
               jnp.uint8)
     jax.jit(functools.partial(
-        ssp.fused_split_step_segment, params=params,
-        si_prefix=SEG_SI_PREFIX, big_l=big_l, max_depth=-1, b=b, f=f,
+        ssp.fused_split_step_segment, params=params, pack=pack,
+        big_l=big_l, max_depth=-1, b=b, f=f,
         n=n, bundled=False, has_monotone=False, blk=ssp.FUSED_BLK,
         interpret=False)).lower(
         sds((), jnp.int32), S, T, mat, mat, hist,

@@ -632,43 +632,6 @@ def test_bench_trend_fleet_p99_synthetic_regression(tmp_path):
     assert bt.main([a, b, "--quiet"]) == 0
 
 
-def test_bench_trend_fused_split_synthetic_regression(tmp_path):
-    """The fused split-step megakernel per-split time chains per
-    (backend, shape config): a >20% worsening fails the gate, a shape
-    or backend change breaks the chain deliberately."""
-    bt = _load_tool("bench_trend")
-    fs = {"per_split_ms": 2.0, "foil_per_split_ms": 8.0,
-          "speedup_vs_foil": 4.0, "rows": 20000, "features": 28,
-          "leaves": 63, "achieved_gbps": 1.0, "hbm_frac": "n/a"}
-    line = {"metric": "fused_split_kernel", "value": 2.0,
-            "unit": "ms/split", "backend": "cpu",
-            "baseline_config": "fused-split-v1-20000r-28f-63l",
-            "fused_split": fs}
-    a, b = str(tmp_path / "BENCH_r06.json"), \
-        str(tmp_path / "BENCH_r07.json")
-    _mk_round(a, 6, [_FIXED, line])
-    worse = dict(line, fused_split=dict(fs, per_split_ms=2.6))  # +30%
-    _mk_round(b, 7, [_FIXED, worse])
-    rep = str(tmp_path / "rep.json")
-    assert bt.main([a, b, "--quiet", "--report", rep]) == 1
-    with open(rep) as fh:
-        report = json.load(fh)
-    [r] = [r for r in report["regressions"]
-           if r["series"] == "fused_split_ms"]
-    assert r["change_pct"] == 30.0
-    assert report["gated_points"]["fused_split_ms"] == 2
-    # within threshold passes
-    _mk_round(b, 7, [_FIXED, dict(line,
-                                  fused_split=dict(fs,
-                                                   per_split_ms=2.2))])
-    assert bt.main([a, b, "--quiet"]) == 0
-    # a shape-config bump deliberately breaks the chain (no gate)
-    _mk_round(b, 7, [_FIXED, dict(
-        line, baseline_config="fused-split-v1-50000r-28f-63l",
-        fused_split=dict(fs, per_split_ms=9.0))])
-    assert bt.main([a, b, "--quiet"]) == 0
-
-
 def test_bench_trend_single_row_and_shm_leg_attribution(tmp_path):
     """The zero-Python hot path series: single_row_p99_ms and
     shm_large_batch_p99_ms chain from the fleet_isolation block; a

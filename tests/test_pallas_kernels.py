@@ -5,6 +5,8 @@ Mirrors the reference's GPU_DEBUG_COMPARE cross-check
 against the plain-XLA scatter histogram and a literal numpy partition.
 """
 
+import functools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -16,10 +18,10 @@ from lightgbm_tpu.ops.partition_pallas import (bitset_to_lut,
                                                partition_segment)
 
 
-@pytest.fixture(scope="module")
-def packed():
+@functools.lru_cache(maxsize=None)
+def _packed(f=12, b=64):
     rng = np.random.RandomState(0)
-    n, f, b = 3000, 12, 64
+    n = 3000
     binned = rng.randint(0, b, (n, f)).astype(np.uint8)
     grad = rng.randn(n).astype(np.float32)
     hess = np.abs(rng.randn(n)).astype(np.float32) + 0.1
@@ -30,14 +32,21 @@ def packed():
     return binned, ghc, mat, n, f, b
 
 
+@pytest.fixture(scope="module")
+def packed():
+    return _packed()
+
+
+# the cells' widths: 12 x 64 is the small fixture; 28 (Higgs), 67
+# (Criteo) and 40 (Expo) leave a ragged last group of three features,
+# and 255 / 256 bins fill the high nibble
 @pytest.mark.parametrize("begin,count", [(0, 3000), (517, 1234),
                                          (2999, 1), (100, 0)])
-@pytest.mark.parametrize("variant", ["grouped", "perfeat", "perbin"])
-def test_histogram_segment_matches_scatter(packed, begin, count,
-                                           variant):
-    binned, ghc, mat, n, f, b = packed
-    seg = histogram_segment(mat, begin, count, b, f, interpret=True,
-                            variant=variant)
+@pytest.mark.parametrize("f,b", [(12, 64), (28, 255), (67, 255),
+                                 (40, 256)])
+def test_histogram_segment_matches_scatter(f, b, begin, count):
+    binned, ghc, mat, n, f, b = _packed(f, b)
+    seg = histogram_segment(mat, begin, count, b, f, interpret=True)
     if count:
         ref = np.asarray(histogram_scatter(
             jnp.asarray(binned[begin:begin + count]),
@@ -47,8 +56,7 @@ def test_histogram_segment_matches_scatter(packed, begin, count,
     assert np.abs(ref - np.asarray(seg)).max() < 2e-3
 
 
-@pytest.mark.parametrize("variant", ["grouped", "perfeat"])
-def test_histogram_wide_feature_slices(variant, monkeypatch):
+def test_histogram_wide_feature_slices(monkeypatch):
     """F > MAX_NIBBLE_F dispatches one nibble call per feature slice
     (Epsilon-shaped dense-wide data) — parity across the slice seams."""
     import lightgbm_tpu.ops.hist_pallas as hp
@@ -62,8 +70,7 @@ def test_histogram_wide_feature_slices(variant, monkeypatch):
                    jnp.asarray(np.ones(n, np.float32)))
     mat = pack_gh(build_matrix(jnp.asarray(binned)), f,
                   ghc[:, 0], ghc[:, 1], ghc[:, 2])
-    seg = hp.histogram_segment(mat, 13, 700, b, f, interpret=True,
-                               variant=variant)
+    seg = hp.histogram_segment(mat, 13, 700, b, f, interpret=True)
     ref = np.asarray(histogram_scatter(
         jnp.asarray(binned[13:713]), ghc[13:713], b))
     assert np.abs(ref - np.asarray(seg)).max() < 2e-3

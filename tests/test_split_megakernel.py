@@ -2,19 +2,20 @@
 
 Contracts:
 
-* the megakernel path (``LGBM_TPU_FUSED_SPLIT_KERNEL=1`` — on CPU its
+* the megakernel path (``fused_split_kernel=on`` — on CPU its
   interpret-mode twin) trains BYTE-identical models to the per-phase
   lax foil across bagging, categorical, linear_tree and monotone
-  configs, on BOTH the serial and the partitioned learners — the twin
-  replicates the foil's exact helpers, so any divergence is a real
-  semantic drift;
+  configs on the partitioned learner — the twin replicates the foil's
+  exact helpers, so any divergence is a real semantic drift;
 * the fused grow dispatches no implicit host transfers;
-* the committed census budget (``serial_grow_fused`` /
-  ``partitioned_grow_fused``: <= 10 dispatches/split) holds at the
-  tiny config — the megakernel is ONE dispatch per split;
-* the gate is a static rule of config and platform: ineligible
-  configs keep the foil, and a Mosaic body the rule selects and the
-  compiler refuses raises (tests/test_mosaic_lowering.py).
+* the committed census budget (``partitioned_grow_fused``: <= 10
+  dispatches/split) holds at the tiny config — the megakernel is ONE
+  dispatch per split;
+* ONE function decides which split step runs
+  (``learner/split_step.py`` ``plan_split_step``), from what it is
+  given: the decision is tested as a table, the platform passed in;
+  ineligible configs keep the foil, and a Mosaic body the rule selects
+  and the compiler refuses raises (tests/test_mosaic_lowering.py).
 """
 
 import numpy as np
@@ -41,95 +42,75 @@ def _data(n=1200, f=6, seed=3, categorical=False):
     return x.astype(np.float32), y
 
 
-def _model_text(monkeypatch, fused, params, x, y, categorical=False,
-                iters=6):
-    monkeypatch.setenv("LGBM_TPU_FUSED_SPLIT_KERNEL",
-                       "1" if fused else "0")
+def _model_text(fused, params, x, y, categorical=False, iters=6):
     p = {"objective": "binary", "num_leaves": 7, "learning_rate": 0.1,
-         "verbosity": -1, "metric": "", **params}
+         "verbosity": -1, "metric": "", "tree_learner": "partitioned",
+         "fused_split_kernel": "on" if fused else "off", **params}
     cfg = Config.from_params(p)
     ds = Dataset.from_numpy(
         x, cfg, label=y,
         categorical_features=[0] if categorical else [])
     b = create_boosting(cfg, ds)
     b.train(iters)
-    return save_model_to_string(b)
+    # the parameter dump names the mode itself; everything else (the
+    # trees above it included) must be byte-identical
+    return "\n".join(ln for ln in save_model_to_string(b).split("\n")
+                     if not ln.startswith("[fused_split_kernel:"))
 
 
-@pytest.mark.parametrize("learner", ["serial", "partitioned"])
+@pytest.mark.parametrize("learner", ["partitioned"])
 @pytest.mark.parametrize("params,categorical", [
     ({"bagging_freq": 1, "bagging_fraction": 0.7}, False),
     ({}, True),
     ({"linear_tree": True, "linear_lambda": 0.01}, False),
     ({"monotone_constraints": [0, 1, -1, 0, 0, 0]}, False),
 ], ids=["bagging", "categorical", "linear_tree", "monotone"])
-def test_megakernel_vs_foil_models_byte_identical(monkeypatch, params,
-                                                  categorical,
+def test_megakernel_vs_foil_models_byte_identical(params, categorical,
                                                   learner):
     x, y = _data(categorical=categorical)
     p = dict(params, tree_learner=learner)
-    t_foil = _model_text(monkeypatch, False, p, x, y, categorical)
-    t_fused = _model_text(monkeypatch, True, p, x, y, categorical)
+    t_foil = _model_text(False, p, x, y, categorical)
+    t_fused = _model_text(True, p, x, y, categorical)
     assert t_fused == t_foil
 
 
-def test_megakernel_partitioned_leaf_id_bit_identical(monkeypatch):
+def test_megakernel_partitioned_leaf_id_bit_identical():
     import jax.numpy as jnp
 
     from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
     x, y = _data()
-    cfg = Config.from_params({"objective": "binary", "num_leaves": 15,
-                              "min_data_in_leaf": 20, "verbosity": -1})
     grad = jnp.asarray(y - 0.5)
     hess = jnp.full((len(y),), 0.25, jnp.float32)
     results = {}
-    for mode in ("0", "1"):
-        monkeypatch.setenv("LGBM_TPU_FUSED_SPLIT_KERNEL", mode)
+    for mode in ("off", "on"):
+        cfg = Config.from_params({
+            "objective": "binary", "num_leaves": 15,
+            "min_data_in_leaf": 20, "fused_split_kernel": mode,
+            "verbosity": -1})
         ds = Dataset.from_numpy(x, cfg, label=y)
-        results[mode] = PartitionedTreeLearner(ds, cfg).train(grad,
-                                                              hess)
-    for fld in results["0"].tree._fields:
-        a = np.asarray(getattr(results["0"].tree, fld))
-        b = np.asarray(getattr(results["1"].tree, fld))
+        lrn = PartitionedTreeLearner(ds, cfg)
+        assert (lrn.split_plan().body == "megakernel") == (mode == "on")
+        results[mode] = lrn.train(grad, hess)
+    for fld in results["off"].tree._fields:
+        a = np.asarray(getattr(results["off"].tree, fld))
+        b = np.asarray(getattr(results["on"].tree, fld))
         assert a.tobytes() == b.tobytes(), fld
-    assert (np.asarray(results["0"].leaf_id).tobytes()
-            == np.asarray(results["1"].leaf_id).tobytes())
+    assert (np.asarray(results["off"].leaf_id).tobytes()
+            == np.asarray(results["on"].leaf_id).tobytes())
 
 
-def test_megakernel_serial_leaf_id_bit_identical(monkeypatch):
+def test_fused_grow_no_implicit_host_transfers():
     import jax.numpy as jnp
 
-    from lightgbm_tpu.learner.serial import SerialTreeLearner
-    x, y = _data()
-    cfg = Config.from_params({"objective": "binary", "num_leaves": 15,
-                              "min_data_in_leaf": 20, "verbosity": -1})
-    grad = jnp.asarray(y - 0.5)
-    hess = jnp.full((len(y),), 0.25, jnp.float32)
-    results = {}
-    for mode in ("0", "1"):
-        monkeypatch.setenv("LGBM_TPU_FUSED_SPLIT_KERNEL", mode)
-        ds = Dataset.from_numpy(x, cfg, label=y)
-        results[mode] = SerialTreeLearner(ds, cfg).train(grad, hess)
-    for fld in results["0"].tree._fields:
-        a = np.asarray(getattr(results["0"].tree, fld))
-        b = np.asarray(getattr(results["1"].tree, fld))
-        assert a.tobytes() == b.tobytes(), fld
-    assert (np.asarray(results["0"].leaf_id).tobytes()
-            == np.asarray(results["1"].leaf_id).tobytes())
-
-
-def test_fused_grow_no_implicit_host_transfers(monkeypatch):
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.learner.serial import SerialTreeLearner
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
     from tools.graftlint.runtime import no_implicit_host_transfers
-    monkeypatch.setenv("LGBM_TPU_FUSED_SPLIT_KERNEL", "1")
     x, y = _data(n=800)
     cfg = Config.from_params({"objective": "binary", "num_leaves": 7,
+                              "fused_split_kernel": "on",
                               "verbosity": -1})
     ds = Dataset.from_numpy(x, cfg, label=y)
-    lrn = SerialTreeLearner(ds, cfg)
-    assert lrn._fused_kernel_on()
+    lrn = PartitionedTreeLearner(ds, cfg)
+    assert lrn.split_plan().body == "megakernel"
     grad = jnp.asarray(y - 0.5)
     hess = jnp.full((len(y),), 0.25, jnp.float32)
     with no_implicit_host_transfers():
@@ -143,87 +124,201 @@ def test_fused_census_within_budget():
     from tools import hlo_census
     budget = hlo_census.load_budget()
     current = hlo_census.run_census(
-        programs=["serial_grow_fused", "partitioned_grow_fused"],
+        programs=["partitioned_grow_fused"],
         rows=512, features=8, leaves=15)
     ok, msgs = hlo_census.check(
         {"programs": {**budget["programs"],
                       **current["programs"]}}, budget)
     assert ok, "\n".join(msgs)
-    for name in ("serial_grow_fused", "partitioned_grow_fused"):
-        prog = current["programs"][name]
-        assert prog["ops_per_split"] <= 10, (name, prog)
-        assert prog["collectives"] == 0, name
+    assert "serial_grow_fused" not in budget["programs"]
+    prog = current["programs"]["partitioned_grow_fused"]
+    assert prog["ops_per_split"] <= 10, prog
+    assert prog["collectives"] == 0
 
 
 def test_fused_census_cuts_foil_budget():
     """The acceptance bar: the megakernel path's committed budget is
     <= 10 dispatches/split, and its ``pre_pr`` is the lax foil's
-    committed count (73 serial / 110 partitioned on jaxlib 0.9.0)."""
+    committed count (129 on jaxlib 0.9.0)."""
     from tools import hlo_census
     budget = hlo_census.load_budget()["programs"]
-    for name in ("serial_grow", "partitioned_grow"):
-        b = budget[name + "_fused"]
-        assert b["ops_per_split"] + b.get("slack", 0) <= 10, b
-        assert b["pre_pr"] == budget[name]["ops_per_split"], name
+    b = budget["partitioned_grow_fused"]
+    assert b["ops_per_split"] + b.get("slack", 0) <= 10, b
+    assert b["pre_pr"] == budget["partitioned_grow"]["ops_per_split"]
 
 
-def test_gate_ineligible_configs_fall_back(monkeypatch):
+def test_gate_ineligible_configs_fall_back():
     """CEGB / extra-trees / by-node sampling keep the per-phase foil
-    even with the env forced on (the kernel does not model their
-    per-split bookkeeping)."""
-    from lightgbm_tpu.learner.serial import SerialTreeLearner
-    monkeypatch.setenv("LGBM_TPU_FUSED_SPLIT_KERNEL", "1")
+    even with the kernel forced on (it does not model their per-split
+    bookkeeping)."""
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
     x, y = _data(n=400)
     for extra in ({"cegb_tradeoff": 1.0, "cegb_penalty_split": 0.1},
                   {"extra_trees": True},
                   {"feature_fraction_bynode": 0.5}):
         cfg = Config.from_params({"objective": "binary",
                                   "num_leaves": 7, "verbosity": -1,
-                                  **extra})
+                                  "fused_split_kernel": "on", **extra})
         ds = Dataset.from_numpy(x, cfg, label=y)
-        lrn = SerialTreeLearner(ds, cfg)
-        assert not lrn._fused_kernel_on(), extra
+        lrn = PartitionedTreeLearner(ds, cfg)
+        assert lrn.split_plan().body == "per_phase", extra
 
 
-def test_gate_env_and_config_resolution(monkeypatch):
-    from lightgbm_tpu.learner.split_step import fused_split_kernel_mode
-    monkeypatch.delenv("LGBM_TPU_FUSED_SPLIT_KERNEL", raising=False)
-    assert fused_split_kernel_mode("auto") == "auto"
-    assert fused_split_kernel_mode("on") == "on"
-    assert fused_split_kernel_mode("off") == "off"
-    monkeypatch.setenv("LGBM_TPU_FUSED_SPLIT_KERNEL", "0")
-    assert fused_split_kernel_mode("on") == "off"
-    monkeypatch.setenv("LGBM_TPU_FUSED_SPLIT_KERNEL", "1")
-    assert fused_split_kernel_mode("off") == "on"
-    monkeypatch.setenv("LGBM_TPU_FUSED_SPLIT_KERNEL", "auto")
-    assert fused_split_kernel_mode("on") == "auto"
+def test_gate_env_and_config_resolution():
+    """The config parameter alone resolves the mode (no environment
+    variable doubles it any more): its three values reach the plan as
+    they are, and any other value is refused when the config is
+    built."""
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
+    x, y = _data(n=400)
+    for mode, body in (("auto", "per_phase"), ("off", "per_phase"),
+                       ("on", "megakernel")):
+        cfg = Config.from_params({"objective": "binary",
+                                  "num_leaves": 7, "verbosity": -1,
+                                  "fused_split_kernel": mode})
+        assert cfg.fused_split_kernel == mode
+        ds = Dataset.from_numpy(x, cfg, label=y)
+        assert PartitionedTreeLearner(ds, cfg).split_plan().body == body
+    assert Config().fused_split_kernel == "auto"
+    with pytest.raises(Exception, match="auto, on or off"):
+        Config.from_params({"fused_split_kernel": "force"})
 
 
 def test_gate_auto_is_a_static_rule(monkeypatch):
-    """auto = on a TPU, at the compiled body's static scope, for the
-    layouts that have a Mosaic body. Off on the CPU (the
-    per-phase XLA path IS the CPU fast path, so auto never engages the
-    twin outside tests); no lowering probe stands behind the rule."""
-    import lightgbm_tpu.ops.split_step_pallas as sp
+    """auto = on a TPU, at the compiled body's static scope. Off on the
+    CPU (the per-phase XLA path IS the CPU fast path, so auto never
+    engages the twin outside tests); no lowering probe stands behind
+    the rule, and the platform is asked in ONE module."""
+    import lightgbm_tpu.learner.split_step as split_step
     from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
     from lightgbm_tpu.learner.serial import SerialTreeLearner
-    monkeypatch.delenv("LGBM_TPU_FUSED_SPLIT_KERNEL", raising=False)
     x, y = _data(n=400)
     cfg = Config.from_params({"objective": "binary", "num_leaves": 7,
                               "verbosity": -1})
     ds = Dataset.from_numpy(x, cfg, label=y)
     serial = SerialTreeLearner(ds, cfg)
     part = PartitionedTreeLearner(ds, cfg)
-    assert not serial._fused_kernel_on()
-    assert not part._fused_kernel_on()
-    monkeypatch.setattr(sp, "on_tpu", lambda: True)
-    assert part._fused_kernel_on()
-    assert "leaf" not in sp.COMPILED_LAYOUTS
-    assert not serial._fused_kernel_on()
+    assert serial.split_plan().body == "per_phase"
+    assert part.split_plan() == split_step.SplitStepPlan()
+    monkeypatch.setattr(split_step, "on_tpu", lambda: True)
+    assert part.split_plan().body == "megakernel"
+    assert serial.split_plan() == split_step.SplitStepPlan(
+        scan_kernel=True)
 
 
-def test_forced_splits_keep_foil_for_forced_steps(monkeypatch,
-                                                  tmp_path):
+# ---- the decision as a table -----------------------------------------
+# one row = what the function is given -> (body, scan_kernel,
+# lut_partition, cat_scan). Defaults: a single-device partitioned
+# learner on a TPU with compiled kernels, a numeric unbundled table of
+# byte bins, 255 leaves, nothing between the phases.
+
+def _plan(**over):
+    from lightgbm_tpu.learner.split_step import plan_split_step
+    from lightgbm_tpu.ops.split import SplitParams
+    params = SplitParams(
+        lambda_l1=0.0, lambda_l2=0.0, max_delta_step=0.0,
+        min_data_in_leaf=20.0, min_sum_hessian_in_leaf=1e-3,
+        min_gain_to_split=0.0,
+        has_categorical=over.pop("has_categorical", False),
+        cegb_on=over.pop("cegb_on", False))
+    kw = dict(mode="auto", params=params, bundled=False,
+              num_bins_max=255, num_leaves=255, forced_plan=(),
+              extra_trees=False, ff_bynode=1.0, cache_hists=True,
+              mv_groups=0, serial_comm=True, interpret=False,
+              has_megakernel=True, merged=True, tpu=True)
+    kw.update(over)
+    return plan_split_step(**kw)
+
+
+MEGA, PHASE = "megakernel", "per_phase"
+
+PLAN_ROWS = [
+    # the three cells, as PERF.md section 4 says of them
+    ("higgs-10m-train", dict(num_bins_max=255),
+     (MEGA, True, False, False)),
+    ("criteo-7m-train", dict(num_bins_max=255),
+     (MEGA, True, False, False)),
+    ("expo-10m-train", dict(has_categorical=True, num_bins_max=256),
+     (PHASE, False, True, True)),
+    ("bundled", dict(bundled=True), (PHASE, True, True, False)),
+    ("257-bins", dict(num_bins_max=257), (PHASE, True, False, False)),
+    ("forced-plan", dict(forced_plan=((0, 1, 3, False),)),
+     (PHASE, True, False, False)),
+    ("cegb", dict(cegb_on=True), (PHASE, False, False, False)),
+    ("extra-trees", dict(extra_trees=True),
+     (PHASE, True, False, False)),
+    ("ff-bynode", dict(ff_bynode=0.5), (PHASE, True, False, False)),
+    ("pool-bounded-hists", dict(cache_hists=False),
+     (PHASE, True, False, False)),
+    ("mesh-comm", dict(serial_comm=False, has_megakernel=False),
+     (PHASE, True, False, False)),
+    ("one-leaf", dict(num_leaves=1), (PHASE, True, False, False)),
+    ("legacy-carry", dict(merged=False), (PHASE, True, False, False)),
+    ("off", dict(mode="off"), (PHASE, True, False, False)),
+    ("on-cpu-twin", dict(mode="on", tpu=False, interpret=True),
+     (MEGA, False, False, False)),
+    ("on-cpu-twin-categorical-forced",
+     dict(mode="on", tpu=False, interpret=True, has_categorical=True,
+          forced_plan=((0, 1, 3, False),)),
+     (MEGA, False, True, True)),
+    ("on-compiled-forced-plan",
+     dict(mode="on", forced_plan=((0, 1, 3, False),)),
+     (PHASE, True, False, False)),
+    ("auto-cpu", dict(tpu=False, interpret=True),
+     (PHASE, False, False, False)),
+    ("auto-cpu-categorical",
+     dict(tpu=False, interpret=True, has_categorical=True),
+     (PHASE, False, True, True)),
+    ("serial-learner-tpu", dict(has_megakernel=False),
+     (PHASE, True, False, False)),
+    ("tpu-interpret-kernels", dict(interpret=True),
+     (MEGA, False, False, False)),
+]
+
+
+@pytest.mark.parametrize("given,want", [r[1:] for r in PLAN_ROWS],
+                         ids=[r[0] for r in PLAN_ROWS])
+def test_split_step_plan_table(given, want):
+    from lightgbm_tpu.learner.split_step import SplitStepPlan
+    assert _plan(**given) == SplitStepPlan(*want)
+
+
+@pytest.mark.parametrize("given", [
+    dict(has_megakernel=False),                       # serial, TPU
+    dict(has_megakernel=False, tpu=False),            # serial, CPU
+    dict(has_megakernel=False, serial_comm=False),    # mesh
+], ids=["serial-tpu", "serial-cpu", "mesh"])
+def test_split_step_plan_on_without_a_megakernel_raises(given):
+    from lightgbm_tpu.utils import LightGBMError
+    with pytest.raises(LightGBMError, match="no split-step megakernel"):
+        _plan(mode="on", **given)
+
+
+def test_cells_plans_from_real_learners(monkeypatch):
+    """The rows that are cells, from learners built on tables of the
+    cells' shapes (Higgs: 28 numeric; Expo: categorical columns), the
+    platform standing in through the ONE module that asks it."""
+    import lightgbm_tpu.learner.split_step as split_step
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
+    monkeypatch.setattr(split_step, "on_tpu", lambda: True)
+    cfg = Config.from_params({"objective": "binary", "num_leaves": 255,
+                              "verbosity": -1})
+    x, y = _data(n=600, f=28)
+    higgs = PartitionedTreeLearner(Dataset.from_numpy(x, cfg, label=y),
+                                   cfg, interpret=False)
+    assert higgs.split_plan() == split_step.SplitStepPlan(
+        MEGA, True, False, False)
+    assert higgs.params.use_scan_kernel
+    x, y = _data(n=600, categorical=True)
+    expo = PartitionedTreeLearner(
+        Dataset.from_numpy(x, cfg, label=y, categorical_features=[0]),
+        cfg, interpret=False)
+    assert expo.split_plan() == split_step.SplitStepPlan(
+        PHASE, False, True, True)
+    assert not expo.params.use_scan_kernel
+
+
+def test_forced_splits_keep_foil_for_forced_steps(tmp_path):
     """A forcedsplits plan coexists with the fused while-loop body:
     forced pre-steps run the foil, the remaining splits the kernel —
     byte-identical models either way."""
@@ -232,6 +327,50 @@ def test_forced_splits_keep_foil_for_forced_steps(monkeypatch,
     fn = tmp_path / "forced.json"
     fn.write_text(json.dumps({"feature": 1, "threshold": 0.0}))
     params = {"forcedsplits_filename": str(fn)}
-    t_foil = _model_text(monkeypatch, False, params, x, y)
-    t_fused = _model_text(monkeypatch, True, params, x, y)
+    t_foil = _model_text(False, params, x, y)
+    t_fused = _model_text(True, params, x, y)
     assert t_fused == t_foil
+
+
+def _package_heads(node):
+    """First package under ``lightgbm_tpu`` that an import statement
+    written in ``lightgbm_tpu/ops/`` reaches (``from ..learner.x import
+    y``, ``from .. import learner``, ``import lightgbm_tpu.learner``)."""
+    import ast
+    if isinstance(node, ast.ImportFrom):
+        mod = node.module.split(".") if node.module else []
+        if node.level >= 2:
+            return mod[:1] or [a.name for a in node.names]
+        if node.level == 0 and mod[:1] == ["lightgbm_tpu"]:
+            return mod[1:2] or [a.name for a in node.names]
+    elif isinstance(node, ast.Import):
+        return [a.name.split(".")[1] for a in node.names
+                if a.name.startswith("lightgbm_tpu.")]
+    return []
+
+
+def test_ops_import_nothing_from_the_layers_above():
+    """A kernel module does not know its caller: nothing under
+    ``lightgbm_tpu/ops/`` imports ``learner``, ``models`` or
+    ``parallel`` (what a kernel shares with the learners lives in
+    ``ops/``; the carry and the comm arrive as arguments)."""
+    import ast
+    import pathlib
+
+    import lightgbm_tpu.ops as ops
+    above = {"learner", "models", "parallel"}
+    # the walker sees each way of writing such an import
+    for text in ("from ..learner.comm import SERIAL_COMM",
+                 "from .. import models", "import lightgbm_tpu.parallel",
+                 "from lightgbm_tpu.learner import serial",
+                 "def f():\n    from ..learner import split_step"):
+        assert above & {h for n in ast.walk(ast.parse(text))
+                        for h in _package_heads(n)}, text
+    assert not _package_heads(ast.parse("from .split import x").body[0])
+    files = sorted(pathlib.Path(ops.__file__).parent.glob("*.py"))
+    assert len(files) > 10
+    found = [(path.name, node.lineno)
+             for path in files
+             for node in ast.walk(ast.parse(path.read_text()))
+             if above & set(_package_heads(node))]
+    assert not found, found

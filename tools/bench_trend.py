@@ -388,30 +388,6 @@ def _mesh_scaling_point(lines: List[Dict]) -> Optional[Dict[str, Any]]:
     return found
 
 
-def _fused_split_point(lines: List[Dict]) -> Optional[Dict[str, Any]]:
-    """The round's fused split-step megakernel per-split wall time
-    (bench.py run_fused_split_block), keyed by (backend, shape id) so
-    only like-for-like measurements chain — lower is better; a CPU
-    point tracks the interpret twin's structural cost, a TPU point the
-    compiled megakernel."""
-    found = None
-    for ln in lines:
-        fs = ln.get("fused_split")
-        if ln.get("metric") != "fused_split_kernel" \
-                or not isinstance(fs, dict) \
-                or fs.get("per_split_ms") is None:
-            continue
-        key = json.dumps({
-            "backend": ln.get("backend"),
-            "config": ln.get("baseline_config"),
-        }, sort_keys=True)
-        found = {"value": float(fs["per_split_ms"]), "key": key,
-                 "foil_per_split_ms": fs.get("foil_per_split_ms"),
-                 "speedup_vs_foil": fs.get("speedup_vs_foil"),
-                 "achieved_gbps": fs.get("achieved_gbps")}
-    return found
-
-
 def _headline_point(lines: List[Dict]) -> Optional[Dict[str, Any]]:
     for ln in reversed(lines):
         if ln.get("metric") == HEADLINE_METRIC \
@@ -458,7 +434,7 @@ def _gate(series: List[Tuple[str, Dict]], higher_is_better: bool,
 def analyze(rounds: List[Dict[str, Any]],
             threshold: float = DEFAULT_THRESHOLD) -> Dict[str, Any]:
     fixed, serving, headline, dispatch, fleet = [], [], [], [], []
-    fused, mesh, fleet_iso = [], [], []
+    mesh, fleet_iso = [], []
     single_row, shm_batch, mboost = [], [], []
     for rnd in rounds:
         p = _fixed_point(rnd["lines"])
@@ -476,9 +452,6 @@ def analyze(rounds: List[Dict[str, Any]],
         p = _fleet_point(rnd["lines"])
         if p is not None:
             fleet.append((rnd["label"], p))
-        p = _fused_split_point(rnd["lines"])
-        if p is not None:
-            fused.append((rnd["label"], p))
         p = _mesh_scaling_point(rnd["lines"])
         if p is not None:
             mesh.append((rnd["label"], p))
@@ -500,7 +473,6 @@ def analyze(rounds: List[Dict[str, Any]],
     regressions += _gate(serving, False, threshold, "serving_p99_ms")
     regressions += _gate(dispatch, False, threshold, DISPATCH_METRIC)
     regressions += _gate(fleet, False, threshold, "fleet_p99_ms")
-    regressions += _gate(fused, False, threshold, "fused_split_ms")
     regressions += _gate(mesh, False, threshold, "mesh_scaling_ms")
     regressions += _gate(fleet_iso, False, threshold,
                          "fleet_isolation_p99_ms")
@@ -531,8 +503,6 @@ def analyze(rounds: List[Dict[str, Any]],
                 {"round": lb, **pt} for lb, pt in serving],
             "fleet_p99_ms": [
                 {"round": lb, **pt} for lb, pt in fleet],
-            "fused_split_ms": [
-                {"round": lb, **pt} for lb, pt in fused],
             "mesh_scaling_ms": [
                 {"round": lb, **pt} for lb, pt in mesh],
             "fleet_isolation_p99_ms": [
@@ -552,7 +522,6 @@ def analyze(rounds: List[Dict[str, Any]],
         "gated_points": {FIXED_METRIC: len(fixed),
                          "serving_p99_ms": len(serving),
                          "fleet_p99_ms": len(fleet),
-                         "fused_split_ms": len(fused),
                          "mesh_scaling_ms": len(mesh),
                          "fleet_isolation_p99_ms": len(fleet_iso),
                          "single_row_p99_ms": len(single_row),

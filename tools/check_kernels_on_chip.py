@@ -212,11 +212,9 @@ def stage_split_scan(interpret: bool = False,
 
 def stage_fused_split(interpret: bool = False, rows: int = 20000,
                       features: int = 28, leaves: int = 31) -> int:
-    """Split-step megakernel vs the per-phase foil — both layouts in
-    interpret mode, compiled the layouts ``auto`` can select
-    (``split_step_pallas.COMPILED_LAYOUTS`` says why not both): the
-    same learner grows one tree from the same gradients with the
-    kernel forced on and forced off. The kernel's histogram/scan
+    """Split-step megakernel vs the per-phase foil: the same
+    partitioned learner grows one tree from the same gradients with
+    the kernel forced on and forced off. The kernel's histogram/scan
     roundings differ from the foil's at f32 level (like the
     reference's GPU learner), so the gate is identical leaf counts +
     close per-row outputs, not byte-equality (the interpret twin owns
@@ -227,8 +225,6 @@ def stage_fused_split(interpret: bool = False, rows: int = 20000,
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.data import Dataset
     from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
-    from lightgbm_tpu.learner.serial import SerialTreeLearner
-    from lightgbm_tpu.ops.split_step_pallas import COMPILED_LAYOUTS
 
     rng = np.random.RandomState(7)
     x = rng.randn(rows, features).astype("float32")
@@ -237,38 +233,31 @@ def stage_fused_split(interpret: bool = False, rows: int = 20000,
     grad = jnp.asarray(0.5 - y)
     hess = jnp.full((rows,), 0.25, jnp.float32)
 
-    def grow(make, mode):
+    def grow(mode):
         cfg = Config.from_params({
             "objective": "binary", "num_leaves": leaves,
             "max_bin": 255, "fused_split_kernel": mode,
             "verbosity": -1})
-        lrn = make(Dataset.from_numpy(x, cfg, label=y), cfg)
-        assert lrn._fused_kernel_on() == (mode == "on"), mode
+        lrn = PartitionedTreeLearner(
+            Dataset.from_numpy(x, cfg, label=y), cfg,
+            interpret=interpret)
+        assert (lrn.split_plan().body == "megakernel") \
+            == (mode == "on"), mode
         res = lrn.train(grad, hess)
         tree = res.tree
         return (int(tree.num_leaves),
                 np.asarray(tree.leaf_value)[np.asarray(res.leaf_id)])
 
-    makers = {
-        "leaf": lambda ds, cfg: SerialTreeLearner(
-            ds, cfg, hist_method="onehot"),
-        "segment": lambda ds, cfg: PartitionedTreeLearner(
-            ds, cfg, interpret=interpret),
-    }
-    failures = 0
-    for layout in (makers if interpret else COMPILED_LAYOUTS):
-        make = makers[layout]
-        nl_on, out_on = grow(make, "on")
-        nl_off, out_off = grow(make, "off")
-        err = float(np.abs(out_on - out_off).max())
-        ok = nl_on == nl_off and nl_on > 1 and np.allclose(
-            out_on, out_off, rtol=1e-3, atol=1e-3)
-        print(f"fused_split[{layout}] [{rows}x{features}] "
-              f"kernel-vs-foil tree: {'ok ' if ok else 'FAIL'} "
-              f"leaves={nl_on}/{nl_off} max|dout|={err:.2e}",
-              flush=True)
-        failures += 0 if ok else 1
-    return failures
+    nl_on, out_on = grow("on")
+    nl_off, out_off = grow("off")
+    err = float(np.abs(out_on - out_off).max())
+    ok = nl_on == nl_off and nl_on > 1 and np.allclose(
+        out_on, out_off, rtol=1e-3, atol=1e-3)
+    print(f"fused_split [{rows}x{features}] "
+          f"kernel-vs-foil tree: {'ok ' if ok else 'FAIL'} "
+          f"leaves={nl_on}/{nl_off} max|dout|={err:.2e}",
+          flush=True)
+    return 0 if ok else 1
 
 
 STAGE_FNS = {"hist": stage_hist, "partition_v1": stage_partition_v1,
@@ -280,12 +269,10 @@ STAGES = tuple(STAGE_FNS)
 def main() -> int:
     argv = sys.argv[1:]
     if "--lowering" in argv:
-        from lightgbm_tpu.ops.split_step_pallas import (
-            COMPILED_LAYOUTS, lower_for_tpu)
-        for layout in COMPILED_LAYOUTS:
-            lower_for_tpu(layout)
-            print(f"fused_split[{layout}] mosaic-lowering: ok",
-                  flush=True)
+        from lightgbm_tpu.learner.partitioned import segment_grow_pack
+        from lightgbm_tpu.ops.split_step_pallas import lower_for_tpu
+        lower_for_tpu(segment_grow_pack(15), big_l=15)
+        print("fused_split mosaic-lowering: ok", flush=True)
         return 0
     import jax
 

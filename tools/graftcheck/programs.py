@@ -470,18 +470,6 @@ def _b_partitioned_grow():
 
 
 # --- pallas kernel wrappers (interpret mode on CPU) ------------------
-@builder("hist_segment_raw")
-def _b_hist_segment_raw():
-    import jax.numpy as jnp
-    from lightgbm_tpu.learner.partitioned import HIST_BLK
-    lrn = _partitioned_learner()
-    mat = lrn.mat
-    return _spec_fn("hist_segment_raw").lower(
-        mat, jnp.int32(0), jnp.int32(lrn.num_data),
-        num_features=lrn.num_groups, num_bins=lrn.num_bins_max,
-        blk=HIST_BLK, interpret=True)
-
-
 @builder("hist_segment_nibble")
 def _b_hist_segment_nibble():
     import jax.numpy as jnp
@@ -507,7 +495,7 @@ def _b_hist_segment_nibble():
     return _spec_fn("hist_segment_nibble").lower(
         lrn.mat, jnp.int32(0), jnp.int32(lrn.num_data),
         num_features=lrn.num_groups, num_bins=lrn.num_bins_max,
-        variant="grouped", nibble_cap=MAX_NIBBLE_F, blk=HIST_BLK,
+        nibble_cap=MAX_NIBBLE_F, blk=HIST_BLK,
         interpret=True)
 
 
@@ -538,56 +526,37 @@ def _b_leaf_of_pos():
         table, table, jnp.int32(1), n=lrn.num_data, interpret=True)
 
 
-def _fused_step_state(lrn, si_prefix):
+def _fused_step_state(lrn):
     import jax.numpy as jnp
 
-    from lightgbm_tpu.learner.split_step import make_grow_pack
+    from lightgbm_tpu.learner.partitioned import segment_grow_pack
     from lightgbm_tpu.ops.split_step_pallas import pack_meta_tables
-    pack = make_grow_pack(si_prefix, merged=True,
-                          has_cat=lrn.params.has_categorical,
-                          has_monotone=lrn.has_monotone,
-                          big_l=lrn.num_leaves)
+    pack = segment_grow_pack(lrn.num_leaves,
+                             has_cat=lrn.params.has_categorical,
+                             has_monotone=lrn.has_monotone)
     ks = len(pack.sf_fields) + len(pack.si_fields)
     kt = len(pack.tf_fields) + len(pack.ti_fields)
     big_l = lrn.num_leaves
     imeta, fmeta = pack_meta_tables(
         lrn.meta, jnp.ones((lrn.meta.num_bins.shape[0],), bool))
     # the packed carriers are i32 (float rows bitcast; StatePack)
-    return (jnp.zeros((ks, big_l), jnp.int32),
+    return (pack, jnp.zeros((ks, big_l), jnp.int32),
             jnp.zeros((kt, big_l - 1), jnp.int32), imeta, fmeta)
-
-
-@builder("fused_split_step_leaf")
-def _b_fused_split_step_leaf():
-    import jax.numpy as jnp
-    lrn = _serial_learner()
-    S, T, imeta, fmeta = _fused_step_state(lrn, ())
-    n = lrn.dataset.num_data
-    g = lrn.dataset.num_groups
-    b = lrn.num_bins_max
-    hist = jnp.zeros((lrn.num_leaves, g, b, 3), jnp.float32)
-    return _spec_fn("fused_split_step_leaf").lower(
-        jnp.int32(1), S, T, jnp.zeros((n,), jnp.int32), hist,
-        lrn.binned, jnp.zeros((n, 3), jnp.float32), imeta, fmeta,
-        params=lrn.params, si_prefix=(), big_l=lrn.num_leaves,
-        max_depth=lrn.max_depth, b=b, bundled=lrn.bundled,
-        has_monotone=lrn.has_monotone, hist_method=lrn.hist_method,
-        interpret=True)
 
 
 @builder("fused_split_step_segment")
 def _b_fused_split_step_segment():
     import jax.numpy as jnp
-    from lightgbm_tpu.learner.partitioned import (HIST_BLK,
-                                                  SEG_SI_PREFIX)
+    from lightgbm_tpu.learner.comm import SERIAL_COMM
+    from lightgbm_tpu.learner.partitioned import HIST_BLK
     lrn = _partitioned_learner()
-    S, T, imeta, fmeta = _fused_step_state(lrn, SEG_SI_PREFIX)
+    pack, S, T, imeta, fmeta = _fused_step_state(lrn)
     g = lrn.num_groups
     b = lrn.num_bins_max
     hist = jnp.zeros((lrn.num_leaves, g, b, 3), jnp.float32)
     return _spec_fn("fused_split_step_segment").lower(
         jnp.int32(1), S, T, lrn.mat, lrn.ws, hist, imeta, fmeta,
-        params=lrn.params, si_prefix=SEG_SI_PREFIX,
+        params=lrn.params, pack=pack, comm=SERIAL_COMM,
         big_l=lrn.num_leaves, max_depth=lrn.max_depth, b=b, f=g,
         n=lrn.num_data, bundled=lrn.bundled,
         has_monotone=lrn.has_monotone, blk=HIST_BLK, interpret=True)

@@ -74,12 +74,9 @@ def _build_dataset(rows=CENSUS_ROWS, features=CENSUS_FEATURES,
     return Dataset.from_numpy(x, cfg, label=y), cfg
 
 
-def lower_serial(ds, cfg, fused_kernel: bool = False):
+def lower_serial(ds, cfg):
     """jax Lowered of the serial grow program at this dataset/config
-    (shared with tools/graftcheck's serial_grow example builder).
-    ``fused_kernel=True`` lowers the megakernel path
-    (ops/split_step_pallas.py — on CPU its interpret twin), the
-    ``serial_grow_fused`` census program."""
+    (shared with tools/graftcheck's serial_grow example builder)."""
     import jax.numpy as jnp
 
     from lightgbm_tpu.learner.serial import SerialTreeLearner, _grow_jit
@@ -97,7 +94,7 @@ def lower_serial(ds, cfg, fused_kernel: bool = False):
         forced_plan=(), cache_hists=lrn.cache_hists,
         mv_slots=lrn.mv_slots, mv_groups=lrn.mv_groups,
         has_monotone=lrn.has_monotone,
-        split_fusion=_fusion_mode(), fused_kernel=fused_kernel)
+        split_fusion=_fusion_mode())
 
 
 def _compiled_serial(ds, cfg) -> str:
@@ -106,11 +103,19 @@ def _compiled_serial(ds, cfg) -> str:
 
 def lower_partitioned(ds, cfg, fused_kernel: bool = False):
     """jax Lowered of the partitioned grow program (shared with
-    tools/graftcheck's partitioned_grow example builder)."""
+    tools/graftcheck's partitioned_grow example builder).
+    ``fused_kernel=True`` lowers the megakernel path
+    (``fused_split_kernel=on``: on CPU the interpret twin of
+    ops/split_step_pallas.py), the ``partitioned_grow_fused`` census
+    program."""
+    import dataclasses
+
     import jax.numpy as jnp
 
     from lightgbm_tpu.learner.partitioned import (PartitionedTreeLearner,
                                                   _grow_partitioned)
+    if fused_kernel:
+        cfg = dataclasses.replace(cfg, fused_split_kernel="on")
     lrn = PartitionedTreeLearner(ds, cfg)
     n = ds.num_data
     grad = jnp.zeros((n,), jnp.float32)
@@ -124,15 +129,11 @@ def lower_partitioned(ds, cfg, fused_kernel: bool = False):
         interpret=lrn.interpret, extra_trees=False, ff_bynode=1.0,
         bynode_count=2, forced_plan=(), cache_hists=lrn.cache_hists,
         hist_slots=lrn.hist_slots, has_monotone=lrn.has_monotone,
-        split_fusion=_fusion_mode(), fused_kernel=fused_kernel)
+        split_fusion=_fusion_mode(), plan=lrn.split_plan())
 
 
 def _compiled_partitioned(ds, cfg) -> str:
     return lower_partitioned(ds, cfg).compile().as_text()
-
-
-def _compiled_serial_fused(ds, cfg) -> str:
-    return lower_serial(ds, cfg, fused_kernel=True).compile().as_text()
 
 
 def _compiled_partitioned_fused(ds, cfg) -> str:
@@ -151,7 +152,6 @@ PROGRAMS = {
     # the megakernel path (ops/split_step_pallas.py): the whole split
     # as ONE pallas_call — the lax per-phase programs above stay the
     # bit-exactness foil with their budgets unchanged
-    "serial_grow_fused": _compiled_serial_fused,
     "partitioned_grow_fused": _compiled_partitioned_fused,
 }
 
