@@ -177,7 +177,8 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
                "learner.leaf_of_pos_dense_traces",
                "learner.lut_partition_traces",
                "learner.cat_scan_traces",
-               "kernels.partition_pipelined")}
+               "kernels.partition_pipelined",
+               "kernels.hist_child_stream")}
     t0 = time.perf_counter()
     bst = lgb.train(dict(params), lgb.Dataset(x, label=y),
                     num_boost_round=rounds)
@@ -201,6 +202,9 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "cat_scan": "on" if delta["learner.cat_scan_traces"] else "off",
         # kernel traces that took partition_pallas.partition_stream
         "partition_pipelined": delta["kernels.partition_pipelined"],
+        # megakernel traces whose histogram is the second, short
+        # stream over the smaller child's segment
+        "hist_child_stream": delta["kernels.hist_child_stream"],
         "fused_block_hits": delta["fused.block_hits"],
         "trees": len(leaves),
         "min_leaves": min(leaves),
@@ -231,6 +235,10 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     # every compiled partition, the megakernel's phase 0 included, is
     # the pipelined stream (the megakernel's interpret twin has none)
     assert report["partition_pipelined"] > 0 or interpret, report
+    # the compiled megakernel histograms through the child stream (the
+    # per-phase body and the twin call ``histogram_segment``)
+    assert report["hist_child_stream"] > 0 or not megakernel \
+        or interpret, report
     if categorical:
         import numpy as np
         cat_splits = sum(

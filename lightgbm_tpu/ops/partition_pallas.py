@@ -46,8 +46,7 @@ The pipeline (PR 28): no DMA wait on the stream's critical path.
     two overlapping writes in flight could land in either order.
   * The prefetched window of block k+1 begins ``shift`` (< 8) rows
     inside block k's rows, which a fast left write may be touching;
-    ``valid`` masks them out of the decision, the histogram and the
-    carried head (both versions are real rows: finite payloads).
+    ``valid`` masks them out of the decision and the carried head.
 The block size is unchanged, so every f32 sum keeps its order and the
 trees are byte-identical to the unpipelined kernel's.
 
@@ -107,8 +106,11 @@ def partition_stream(mat_hbm, ws_hbm, scratch, begin, count, decide,
     partition of ``mat_hbm[begin, begin+count)`` in place, rights via
     ``ws_hbm``. ``decide(mat_i32, mat_f, valid, shift, rem)`` returns
     the block's ``(go_left, go_right)`` [win, 1] i32 0/1 masks (already
-    masked by ``valid``); the megakernel's also accumulates the smaller
-    child's histogram there. Returns ``(NL, merge-path windows)``.
+    masked by ``valid``): the decision alone, in both kernels (the
+    megakernel histograms the smaller child in a stream of its own,
+    after this one has returned). Returns ``(NL, merge-path windows)``.
+    Every write has landed by then, the back-copy's included, and the
+    ``inbuf`` slots and their semaphores are free.
 
     No DMA wait sits on the critical path (module docstring): block
     k+1 is read while block k computes; each window write is waited

@@ -21,11 +21,15 @@ physically moves the chosen leaf's rows (stable partition,
 contiguous segment. Two bodies sit behind the one wrapper:
 
 * the **Mosaic TPU body** — real streamed DMA phases grounded in the
-  per-phase kernels (``partition_stream``, hist one-hot matmuls with
-  exact bf16 hi/lo payload pairs, f32 one-hot lane selects instead of
-  the i32 reductions this jax's Mosaic cannot lower, the split-scan
-  core from ``ops/split_scan_pallas.py``). Numerical, unbundled,
-  byte-bin scope; anything else raises here.
+  per-phase kernels: ``partition_stream`` over the parent's rows, then
+  ``hist_child_stream`` over the smaller child's compact segment alone
+  (one-hot matmuls with exact bf16 hi/lo payload pairs; the kernels
+  are VPU-bound, not HBM-bound, so a second read of the child's rows
+  is cheap and a one-hot over the rows of the other child is not),
+  f32 one-hot lane selects instead of the i32 reductions this jax's
+  Mosaic cannot lower, the split-scan core from
+  ``ops/split_scan_pallas.py``. Numerical, unbundled, byte-bin scope;
+  anything else raises here.
 * the **interpret-mode CPU twin** — the SAME pallas_call contract, but
   the body replicates the per-phase body bit-for-bit by calling the
   exact shared helpers it calls (``histogram_segment``,
@@ -55,6 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.telemetry import get_telemetry
 from ..utils.jit_registry import register_jit
 from .split import (MISSING_NAN_CODE, MISSING_ZERO_CODE, FeatureMeta,
                     child_columns, child_constraints,
@@ -64,8 +69,9 @@ from .split import (MISSING_NAN_CODE, MISSING_ZERO_CODE, FeatureMeta,
 
 NEG_INF = float("-inf")  # python scalar: kernels fold it as a constant
 
-# the megakernel runs a static 2-step grid (phase 0: partition +
-# smaller-child histogram; phase 1: sibling subtraction + both
+# the megakernel runs a static 2-step grid (phase 0: the partition
+# stream over the parent's rows, then the histogram stream over the
+# smaller child's segment; phase 1: sibling subtraction + both
 # children's scans + state/tree writes). Two steps also keep the
 # interpret twin's grid loop a real ``while`` in the compiled CPU HLO
 # (a 1-trip loop is inlined by XLA's simplifier), so the whole split
@@ -815,28 +821,131 @@ def _leaf_site_scalars(pack, iscal, s_in, imeta_ref, big_l):
         nbins_f
 
 
+def hist_child_stream(mat_hbm, buf, sems, hpl, begin, count, *,
+                      f: int, blk: int):
+    """The smaller child's histogram: a pipelined block stream over
+    the rows ``mat_hbm[begin, begin+count)`` alone, accumulated into
+    the five ``hpl`` planes ``[5, F8, B128]`` f32 (g hi, g lo, h hi,
+    h lo, count; zeroed here first). Phase 0 of the megakernel
+    runs it after ``partition_stream`` has returned, on the child's
+    compact segment; ``histogram_child_stream`` wraps it alone.
+
+    Windows start at the 8-aligned floor of ``begin``; rows outside
+    ``[shift, shift+rem)`` of a window are masked through the payload
+    (``_decode_block``). Block k+1 is read into ``buf``'s other slot
+    (``buf`` [2, blk+8, C] u8, ``sems`` two DMA semaphores or more)
+    while block k computes. Per block and feature: a ``[win, B128]``
+    one-hot of the bin byte on the VPU, one matmul with the exact bf16
+    hi/lo payload pairs, f32 accumulation."""
+    from .hist_pallas import _decode_block
+    # counted where the stream enters a kernel's trace, like
+    # ``kernels.partition_pipelined``
+    get_telemetry().count("kernels.hist_child_stream")
+    win = blk + ALIGN
+    nblk = pl.cdiv(count, blk)
+    base = (begin // ALIGN) * ALIGN
+    shift = begin - base
+    bins_l = _iota_f32((1, hpl.shape[2]), 1)       # pad lanes: no bin
+    hpl[...] = jnp.zeros_like(hpl)
+
+    def read(k, slot):
+        start = pl.multiple_of(base + k * blk, ALIGN)
+        return pltpu.make_async_copy(mat_hbm.at[pl.ds(start, win), :],
+                                     buf.at[slot], sems.at[slot])
+
+    @pl.when(nblk > 0)
+    def _():
+        read(0, 0).start()
+
+    def block_body(k, _):
+        slot = jax.lax.rem(k, 2)
+
+        @pl.when(k + 1 < nblk)
+        def _():
+            read(k + 1, 1 - slot).start()
+
+        read(k, slot).wait()
+        mat_i32 = buf[slot].astype(jnp.int32)            # [win, C]
+        mat_f = mat_i32.astype(jnp.float32)
+        rem = jnp.minimum(count - k * blk, blk)
+        _, g_hi, g_lo, h_hi, h_lo, c_ch = _decode_block(
+            mat_i32, f, shift, rem, win)
+        zero = jnp.zeros_like(g_hi)
+        pay = jnp.concatenate(
+            [g_hi, g_lo, h_hi, h_lo, c_ch.astype(jnp.bfloat16), zero,
+             zero, zero], axis=1)                        # [win, 8]
+        for fx in range(f):
+            fcol = mat_f[:, fx:fx + 1]                   # [win, 1]
+            onehot = jnp.where(fcol == bins_l, jnp.float32(1),
+                               0.0).astype(jnp.bfloat16)
+            res = jax.lax.dot_general(
+                pay, onehot, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [8, B]
+            for ch in range(5):
+                hpl[ch, pl.ds(fx, 1), :] += res[ch:ch + 1, :]
+        return 0
+
+    jax.lax.fori_loop(0, nblk, block_body, 0)
+
+
+def _child_stream_kernel(scal_ref, mat_hbm, hpl, buf, sems, *, f, blk):
+    hist_child_stream(mat_hbm, buf, sems, hpl, scal_ref[0], scal_ref[1],
+                      f=f, blk=blk)
+
+
+def histogram_child_stream(mat, begin, count, *, num_bins: int,
+                           num_features: int, blk: int = SEG_BLK,
+                           interpret: bool = False):
+    """``hist_child_stream`` alone, as ``partition_segment`` wraps
+    ``partition_stream``: histogram of rows [begin, begin+count) ->
+    [F, B, 3] f32. What the CPU tests and the on-chip checks compare
+    with ``ops/histogram.py``; no learner calls it, so it is no
+    registered program."""
+    f, b = num_features, num_bins
+    fp, bp = -(-f // 8) * 8, -(-b // 128) * 128
+    scal = jnp.stack([jnp.asarray(begin, jnp.int32),
+                      jnp.asarray(count, jnp.int32)])
+    planes = pl.pallas_call(  # graftlint: allow[GL506]
+        functools.partial(_child_stream_kernel, f=f, blk=blk),
+        out_shape=jax.ShapeDtypeStruct((5, fp, bp), jnp.float32),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, blk + ALIGN, mat.shape[1]), jnp.uint8),
+            pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(scal, mat)[:, :f, :b]
+    return jnp.stack([planes[0] + planes[1], planes[2] + planes[3],
+                      planes[4]], axis=-1)
+
+
 def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,
                         imeta_ref, fmeta_ref,
                         s_out, t_out, mat_out, ws_out, hist_out,
                         *scratch,
                         params, pack, big_l, max_depth, b, f,
                         n, bundled, has_monotone, blk):
-    """Mosaic body: phase 0 streams the chosen leaf's
-    contiguous row segment ONCE — the stable in-place partition
+    """Mosaic body. Phase 0 streams the chosen leaf's contiguous row
+    segment through the stable in-place partition
     (``partition_pallas.partition_stream``: the pipelined block stream
-    ``partition_segment`` runs, imported like ``_decode_block``) and
-    the SMALLER child's histogram accumulate from the same window, so
-    partition + histogram cost one read of the rows. Phase 1 is the
-    shared subtract/scan/write tail. All lane/row extractions are f32
-    select-sums (this Mosaic lowers no integer reductions — the one
-    thing that kept partition v1 off-chip)."""
+    ``partition_segment`` runs), and then, the left count known, the
+    SMALLER child's compact segment alone through the histogram
+    (``hist_child_stream``): the per-feature one-hot visits the
+    child's rows only, 0.28-0.35 of the parent's in the benchmark's
+    cells, for a second read of them (0.05 us a parent block against
+    0.13 us a feature a block of one-hot, PERF.md section 5). Phase 1
+    is the shared subtract/scan/write tail. All lane/row extractions
+    are f32 select-sums (this Mosaic lowers no integer reductions —
+    the one thing that kept partition v1 off-chip)."""
     del mat_in, ws_in, hist_in  # aliased; all access via out refs
-    from .hist_pallas import _decode_block
     from .partition_pallas import partition_stream
     *stream, hpl, pbuf, cbuf, nl_ref, sem_w = scratch
+    inbuf, sems = stream[0], stream[-1]
     pid = pl.program_id(0)
     cols = mat_out.shape[1]
-    win = blk + ALIGN
 
     @pl.when(pid == 0)
     def _phase0():
@@ -851,55 +960,36 @@ def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,
         pc = site.f("leaf_c", leaf_f)
         small_is_left = lc <= (pc - lc)
 
-        for ch in range(5):
-            hpl[ch] = jnp.zeros_like(hpl[ch])
+        # parent slab for phase 1 (channels-major cache row), read
+        # behind the two streams
+        parent = pltpu.make_async_copy(hist_out.at[leaf], pbuf,
+                                       sem_w.at[1])
+        parent.start()
 
         lane_w = _iota_f32((1, cols), 1)
-        bins_l = _iota_f32((1, hpl.shape[2]), 1)   # pad lanes: no bin
         fsel = jnp.where(lane_w == feat_f, jnp.float32(1), 0.0)
 
         def decide(mat_i32, mat_f, valid, shift, rem):
+            del mat_i32, shift, rem
             # split feature's bin per row: f32 one-hot lane reduce
             bv = jnp.sum(mat_f * fsel, axis=1,
                          keepdims=True)                  # [win, 1]
             go_left = _go_left01(bv, thr_f, dleft_f, miss_f, defbin_f,
                                  nbins_f)
-            gl = valid * go_left
-            gr = valid * (1 - go_left)
-
-            # smaller child's histogram from the SAME window (exact
-            # bf16 hi/lo payload pairs, f32 accumulation)
-            sel_small = jnp.where(small_is_left, gl, gr) \
-                .astype(jnp.float32)                     # [win, 1]
-            _, g_hi, g_lo, h_hi, h_lo, c_ch = _decode_block(
-                mat_i32, f, shift, rem, win)
-            sel_bf = sel_small.astype(jnp.bfloat16)
-            zero = jnp.zeros_like(g_hi)
-            pay = jnp.concatenate(
-                [g_hi * sel_bf, g_lo * sel_bf, h_hi * sel_bf,
-                 h_lo * sel_bf, (c_ch * sel_small).astype(
-                     jnp.bfloat16), zero, zero, zero],
-                axis=1)                                  # [win, 8]
-            for fx in range(f):
-                fcol = mat_f[:, fx:fx + 1]               # [win, 1]
-                onehot = jnp.where(fcol == bins_l, jnp.float32(1),
-                                   0.0).astype(jnp.bfloat16)
-                res = jax.lax.dot_general(
-                    pay, onehot, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)  # [8, B]
-                for ch in range(5):
-                    hpl[ch, pl.ds(fx, 1), :] += res[ch:ch + 1, :]
-            return gl, gr
+            return valid * go_left, valid * (1 - go_left)
 
         nl_total, _ = partition_stream(mat_out, ws_out, stream, begin,
                                        cnt, decide, blk=blk)
         nl_ref[0] = nl_total
 
-        # parent slab prefetch for phase 1 (channels-major cache row)
-        cp = pltpu.make_async_copy(hist_out.at[leaf], pbuf,
-                                   sem_w.at[1])
-        cp.start()
-        cp.wait()
+        # the smaller child's segment, as phase 1's ``extra_ab`` has
+        # it; every write of the partition has landed, the stream's
+        # input slots and their semaphores are free
+        sb = jnp.where(small_is_left, begin, begin + nl_total)
+        sc = jnp.where(small_is_left, nl_total, cnt - nl_total)
+        hist_child_stream(mat_out, inbuf, sems, hpl, sb, sc, f=f,
+                          blk=blk)
+        parent.wait()
 
     @pl.when(pid == 1)
     def _phase1():

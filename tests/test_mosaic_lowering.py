@@ -353,7 +353,9 @@ def test_both_partition_kernels_lower_the_pipelined_stream():
     was_on = tel.enabled
     tel.ensure_ring()
     name = "kernels.partition_pipelined"
+    child = "kernels.hist_child_stream"
     before = tel.counters.get(name, 0)
+    child_before = tel.counters.get(child, 0)
     mat = _mat(n=6000)          # a shape no other test traced
     lut = jnp.zeros((1, 256), jnp.float32)
     for use_lut in (True, False):
@@ -365,13 +367,19 @@ def test_both_partition_kernels_lower_the_pipelined_stream():
                 jnp.int32(0), jnp.int32(0), jnp.int32(256),
                 jnp.int32(0), lut)
     after_partition = tel.counters.get(name, 0)
+    child_after_partition = tel.counters.get(child, 0)
     jax.clear_caches()
     split_step_pallas.lower_for_tpu(segment_grow_pack(15), big_l=15)
     after_mega = tel.counters.get(name, 0)
+    child_after_mega = tel.counters.get(child, 0)
     if not was_on:
         tel.reset()
     assert after_partition - before == 2
     assert after_mega - after_partition >= 1
+    # PR 30: only the megakernel's phase 0 holds the histogram stream
+    # over the smaller child's segment, behind its partition stream
+    assert child_after_partition == child_before
+    assert child_after_mega - child_after_partition >= 1
 
 
 @pytest.fixture(scope="module")
@@ -407,15 +415,19 @@ def test_pipelined_partition_compiles_for_v5e(one_chip, use_lut):
         mat, mat, *([i32] * 9), sds((1, 256), jnp.float32)).compile()
 
 
-def test_pipelined_megakernel_compiles_for_v5e(one_chip):
-    """The megakernel at the Higgs cell's shapes (10.5 M x 28, 255
-    leaves, 256 bins) with the shared stream in phase 0."""
+@pytest.mark.parametrize("f,n", [(28, 10_500_000), (67, 7_000_000)],
+                         ids=["higgs-10m", "criteo-7m"])
+def test_pipelined_megakernel_compiles_for_v5e(one_chip, f, n):
+    """The megakernel at the two megakernel cells' shapes (10.5 M x 28
+    and 7 M x 67, 255 leaves, 256 bins): the shared partition stream
+    and, behind it, the histogram stream over the smaller child's
+    segment (PR 30) in phase 0."""
     from lightgbm_tpu.learner.partitioned import segment_grow_pack
     from lightgbm_tpu.ops import split_step_pallas as ssp
     from lightgbm_tpu.ops.hist_pallas import matrix_cols, matrix_rows
     from lightgbm_tpu.ops.split import SplitParams
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    big_l, f, b, n = 255, 28, 256, 10_500_000
+    big_l, b = 255, 256
     params = SplitParams(
         lambda_l1=0.0, lambda_l2=1.0, max_delta_step=0.0,
         min_data_in_leaf=20.0, min_sum_hessian_in_leaf=1e-3,
@@ -436,3 +448,18 @@ def test_pipelined_megakernel_compiles_for_v5e(one_chip):
         interpret=False)).lower(
         sds((), jnp.int32), S, T, mat, mat, hist,
         sds((f, 8), jnp.int32), sds((f, 2), jnp.float32)).compile()
+
+
+@pytest.mark.parametrize("f", [28, 67])
+def test_hist_child_stream_compiles_for_v5e(one_chip, f):
+    """The histogram stream alone (the checks' thin wrapper) at the
+    megakernel's block size over a 1 M-row matrix, both cells' widths:
+    what step 0 of PR 30 timed."""
+    from lightgbm_tpu.ops.split_step_pallas import (SEG_BLK,
+                                                    histogram_child_stream)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = sds((), jnp.int32)
+    jax.jit(functools.partial(
+        histogram_child_stream, num_bins=256, num_features=f,
+        blk=SEG_BLK)).lower(
+        sds((1_003_528, 128), jnp.uint8), i32, i32).compile()

@@ -56,6 +56,58 @@ def test_histogram_segment_matches_scatter(f, b, begin, count):
     assert np.abs(ref - np.asarray(seg)).max() < 2e-3
 
 
+# ---- PR 30: the megakernel's histogram stream over the child --------
+
+@functools.lru_cache(maxsize=None)
+def _child_stream(f, b, blk):
+    """One compiled (interpreted) program a width and block size; the
+    segment stays dynamic, as it is inside the megakernel."""
+    import jax
+    from lightgbm_tpu.ops.split_step_pallas import histogram_child_stream
+    return jax.jit(functools.partial(
+        histogram_child_stream, num_bins=b, num_features=f, blk=blk,
+        interpret=True))
+
+
+# a parent [40, 2040) split at NL = 700: its left child starts where
+# the parent does, its right child ends where the parent does
+_PARENT = (40, 2000, 700)
+CHILD_SEGMENTS = [
+    pytest.param(0, 3000, id="whole"),
+    pytest.param(517, 1234, id="unaligned-ragged"),
+    pytest.param(8, 1500, id="aligned-ragged"),
+    pytest.param(13, 300, id="one-block"),
+    pytest.param(5, 1024, id="empty-tail"),
+    pytest.param(2999, 1, id="one-row"),
+    pytest.param(100, 0, id="no-row"),
+    pytest.param(_PARENT[0], _PARENT[2], id="left-child"),
+    pytest.param(_PARENT[0] + _PARENT[2], _PARENT[1] - _PARENT[2],
+                 id="right-child"),
+]
+
+
+@pytest.mark.parametrize("begin,count", CHILD_SEGMENTS)
+@pytest.mark.parametrize("f,b,blk", [(28, 255, 512), (67, 255, 512),
+                                     (28, 256, 256)])
+def test_hist_child_stream_matches_scatter(f, b, blk, begin, count):
+    """``split_step_pallas.hist_child_stream`` through its thin wrapper
+    against ``ops/histogram.py``: rows before ``begin`` in its granule
+    and rows past the count are masked through the payload, the tail
+    block is short, neighbours' rows stay out."""
+    binned, ghc, mat, n, f, b = _packed(f, b)
+    seg = np.asarray(_child_stream(f, b, blk)(mat, begin, count))
+    assert seg.shape == (f, b, 3)
+    if count:
+        ref = np.asarray(histogram_scatter(
+            jnp.asarray(binned[begin:begin + count]),
+            ghc[begin:begin + count], b))
+    else:
+        ref = np.zeros((f, b, 3), np.float32)
+    assert np.abs(ref - seg).max() < 2e-3
+    # counts are sums of 0/1: exact, so no neighbour's row leaked in
+    np.testing.assert_array_equal(seg[..., 2], ref[..., 2])
+
+
 def test_histogram_wide_feature_slices(monkeypatch):
     """F > MAX_NIBBLE_F dispatches one nibble call per feature slice
     (Epsilon-shaped dense-wide data) — parity across the slice seams."""

@@ -99,6 +99,49 @@ def test_megakernel_partitioned_leaf_id_bit_identical():
             == np.asarray(results["on"].leaf_id).tobytes())
 
 
+@pytest.mark.parametrize("features,leaves", [(6, 8), (28, 15)])
+def test_mosaic_body_under_the_interpreter_vs_foil(monkeypatch, features,
+                                                   leaves):
+    """The WHOLE Mosaic body (not the twin) grows a tree under the
+    Pallas interpreter, every ``pallas_call`` of the process forced to
+    interpret: phase 0's partition stream, the histogram stream over
+    the smaller child's compact segment (left and right children both
+    come up as the smaller one), phase 1's scans and writes. Gate as on
+    the chip (``tools/check_kernels_on_chip.py fused_split``): equal
+    leaf counts, outputs within 1e-3 of the per-phase foil, and the
+    stream handed at most half of the partitioned rows."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    from tools.check_kernels_on_chip import stage_fused_split
+    real_call = pl.pallas_call
+
+    def interpreted(*args, **kw):
+        kw["interpret"] = True
+        return real_call(*args, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", interpreted)
+    tel = get_telemetry()
+    was_on = tel.enabled
+    tel.ensure_ring()
+    name = "kernels.hist_child_stream"
+    before = tel.counters.get(name, 0)
+    try:
+        # interpret=False: the learner asks for the compiled body
+        failures = stage_fused_split(interpret=False, rows=3000,
+                                     features=features, leaves=leaves)
+        traced = tel.counters.get(name, 0) - before
+    finally:
+        # programs traced here hold interpreted kernels under the
+        # compiled path's cache keys
+        jax.clear_caches()
+        if not was_on:
+            tel.reset()
+    assert failures == 0
+    assert traced >= 1
+
+
 def test_fused_grow_no_implicit_host_transfers():
     import jax.numpy as jnp
 

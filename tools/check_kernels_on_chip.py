@@ -210,6 +210,27 @@ def stage_split_scan(interpret: bool = False,
     return failures
 
 
+def histogrammed_share(tree) -> float:
+    """Rows histogrammed ÷ rows partitioned over one tree's splits,
+    from the tree's own counts: each split partitions its parent and
+    hands the histogram stream its smaller child's segment
+    (``split_step_pallas.hist_child_stream``; the megakernel has no
+    spare output word to count the rows itself). 0.28-0.35 on the
+    benchmark's tables, never above 0.5."""
+    import numpy as np
+    n = int(tree.num_leaves) - 1
+    internal = np.asarray(tree.internal_count[:n], np.float64)
+    leaf = np.asarray(tree.leaf_count, np.float64)
+
+    def child_rows(child):
+        child = np.asarray(child[:n])
+        return np.where(child >= 0, internal[np.maximum(child, 0)],
+                        leaf[np.maximum(~child, 0)])
+    smaller = np.minimum(child_rows(tree.left_child),
+                         child_rows(tree.right_child))
+    return float(smaller.sum() / max(internal.sum(), 1.0))
+
+
 def stage_fused_split(interpret: bool = False, rows: int = 20000,
                       features: int = 28, leaves: int = 31) -> int:
     """Split-step megakernel vs the per-phase foil: the same
@@ -218,7 +239,10 @@ def stage_fused_split(interpret: bool = False, rows: int = 20000,
     roundings differ from the foil's at f32 level (like the
     reference's GPU learner), so the gate is identical leaf counts +
     close per-row outputs, not byte-equality (the interpret twin owns
-    byte-equality in CI). ``max_bin=255`` gives the 256-bin width."""
+    byte-equality in CI). ``max_bin=255`` gives the 256-bin width.
+    Also prints the share of the partitioned rows that the kernel's
+    histogram stream was handed, and fails above 0.5: the stream
+    visits the smaller child, never the parent."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -246,16 +270,18 @@ def stage_fused_split(interpret: bool = False, rows: int = 20000,
         res = lrn.train(grad, hess)
         tree = res.tree
         return (int(tree.num_leaves),
-                np.asarray(tree.leaf_value)[np.asarray(res.leaf_id)])
+                np.asarray(tree.leaf_value)[np.asarray(res.leaf_id)],
+                histogrammed_share(tree))
 
-    nl_on, out_on = grow("on")
-    nl_off, out_off = grow("off")
+    nl_on, out_on, share = grow("on")
+    nl_off, out_off, _ = grow("off")
     err = float(np.abs(out_on - out_off).max())
-    ok = nl_on == nl_off and nl_on > 1 and np.allclose(
+    ok = nl_on == nl_off and nl_on > 1 and share <= 0.5 and np.allclose(
         out_on, out_off, rtol=1e-3, atol=1e-3)
     print(f"fused_split [{rows}x{features}] "
           f"kernel-vs-foil tree: {'ok ' if ok else 'FAIL'} "
-          f"leaves={nl_on}/{nl_off} max|dout|={err:.2e}",
+          f"leaves={nl_on}/{nl_off} max|dout|={err:.2e} "
+          f"histogrammed/partitioned rows={share:.3f}",
           flush=True)
     return 0 if ok else 1
 
