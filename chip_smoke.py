@@ -22,7 +22,14 @@ binary objective, 255 leaves, 255 bins), on rows generated from a seed:
    with the bitset partition and the categorical XLA scan, still in
    fused blocks; its route counters, its trees' category
    sets on the host against the scores the device holds, and its AUC
-   against the XLA foil.
+   against the XLA foil;
+6. wide: 2,000 rows shaped like the benchmark's Epsilon table (2,000
+   dense numeric columns, rows of unit length), 31 leaves, for 5
+   rounds: more columns than the megakernel's unrolled body takes, so
+   the plan keeps the per-phase kernels, which cut their work by
+   columns (the histogram a 128-column slice at a time, the Pallas
+   scan a 128-feature block at a time), still in fused blocks; its
+   route counters and its AUC against the XLA foil.
 
 ``--devices 4`` instead trains the same shape data-parallel over four
 chips and checks the sharding and the AUC against the one-chip model.
@@ -63,6 +70,16 @@ CAT_SCORE_TOL = 1e-4    # host trees' raw scores against the device's
 # check (PERF.md, PR 27); a learner that reads the categories as
 # ordered parts by 2.8e-2 and more
 CAT_FOIL_AUC_TOL = 5e-3
+# small and shallow for the foil's sake: its scatter-add histogram
+# over 2,000 columns took 427 s at 4,000 rows and 255 leaves on the
+# chip (PR 31), the chip path 33 s
+WIDE_ROWS = 2_000
+WIDE_LEAVES = 31
+WIDE_ROUNDS = 5         # the sync first iteration plus a block of 4
+# 2,000 weak columns on a few thousand rows: near-ties between columns
+# fall the other way under another summation order, as between category
+# sets (0.998747 against the foil's 0.996756 at that first size)
+WIDE_FOIL_AUC_TOL = 5e-3
 
 
 def device_report() -> dict:
@@ -110,19 +127,31 @@ def higgs_like(n: int, f: int = FEATURES, seed: int = 42):
     return x, y
 
 
-def expo_like(n: int, seed: int = 42):
-    """``(x, y, params)``: rows of the benchmark's categorical table
-    from its own generator and configuration file (``benchmarks/``),
-    and ``PARAMS`` with that file's categorical parameters."""
+def _benchmark_rows(config: str, generator: str, n: int, seed: int):
+    """``(x, y, the configuration)``: ``n`` rows of a benchmark table
+    from its own generator and configuration file (``benchmarks/``)."""
+    import importlib
     import os
-
-    from benchmarks.generators import expo_like as gen
     here = os.path.dirname(os.path.abspath(__file__))
     with open(os.path.join(here, "benchmarks", "configs",
-                           "expo-categorical.json")) as fh:
+                           config + ".json")) as fh:
         cfg = json.load(fh)
-    x, y = gen.make(seed, n, cfg["features"], **cfg["generator"]["params"])
+    gen = importlib.import_module("benchmarks.generators." + generator)
+    x, y = gen.make(seed, n, cfg["features"],
+                    **cfg["generator"]["params"])
+    return x, y, cfg
+
+
+def expo_like(n: int, seed: int = 42):
+    """``(x, y, params)``: rows of the benchmark's categorical table,
+    and ``PARAMS`` with that file's categorical parameters."""
+    x, y, cfg = _benchmark_rows("expo-categorical", "expo_like", n, seed)
     return x, y, dict(cfg["params"], **PARAMS)
+
+
+def epsilon_like(n: int, seed: int = 42):
+    """``(x, y)``: rows of the benchmark's dense-wide table."""
+    return _benchmark_rows("epsilon-wide", "epsilon_like", n, seed)[:2]
 
 
 def train_auc(bst, x, y) -> float:
@@ -158,7 +187,7 @@ def stage_kernels(interpret: bool = False, **shapes) -> dict:
 
 def stage_train(x, y, params, rounds: int, *, learner: str,
                 interpret: bool, megakernel: bool, shards: int = 1,
-                categorical: bool = False):
+                categorical: bool = False, wide: bool = False):
     """``lgb.train`` + the path report. Asserts the run took the path
     it was meant to take; ``megakernel`` is what the caller expects of
     the config, the report's value is what the trace counted, as is
@@ -166,7 +195,10 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     ``categorical``: ``params`` names categorical columns, so the
     bitset partition and the categorical scan must have been traced,
     the Pallas scan kernel not, and the trees must hold category
-    splits that route rows on the host as the device did."""
+    splits that route rows on the host as the device did. ``wide``:
+    the table has more columns than the megakernel takes, so the plan
+    must have refused it for the width alone and every histogram call
+    must have been cut into column slices."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.observability.telemetry import get_telemetry
     from lightgbm_tpu.ops.leaf_of_pos import uses_block_pass
@@ -177,8 +209,10 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
                "learner.leaf_of_pos_dense_traces",
                "learner.lut_partition_traces",
                "learner.cat_scan_traces",
+               "learner.wide_table_traces",
                "kernels.partition_pipelined",
-               "kernels.hist_child_stream")}
+               "kernels.hist_child_stream",
+               "kernels.hist_feature_slices")}
     t0 = time.perf_counter()
     bst = lgb.train(dict(params), lgb.Dataset(x, label=y),
                     num_boost_round=rounds)
@@ -200,6 +234,12 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "lut_partition": "on" if delta["learner.lut_partition_traces"]
         else "off",
         "cat_scan": "on" if delta["learner.cat_scan_traces"] else "off",
+        # the plan refused the megakernel for the table's width alone
+        "wide_table": "on" if delta["learner.wide_table_traces"]
+        else "off",
+        # column slices over the histogram calls traced (1 a call
+        # where the nibble kernel takes the whole width)
+        "hist_feature_slices": delta["kernels.hist_feature_slices"],
         # kernel traces that took partition_pallas.partition_stream
         "partition_pipelined": delta["kernels.partition_pipelined"],
         # megakernel traces whose histogram is the second, short
@@ -236,9 +276,19 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     # the pipelined stream (the megakernel's interpret twin has none)
     assert report["partition_pipelined"] > 0 or interpret, report
     # the compiled megakernel histograms through the child stream (the
-    # per-phase body and the twin call ``histogram_segment``)
+    # per-phase body and the twin call ``histogram_segment``, which
+    # takes the same stream a column slice at a time on a wide table)
     assert report["hist_child_stream"] > 0 or not megakernel \
         or interpret, report
+    # a TPU's plan alone refuses for width: off one, ``auto`` never
+    # picks the megakernel
+    assert report["wide_table"] == (
+        "on" if wide and not interpret else "off"), report
+    if wide:
+        from lightgbm_tpu.ops.hist_pallas import SLICE_F
+        # the root's call and the split body's, each cut into slices
+        assert report["hist_feature_slices"] \
+            >= 2 * -(-ln.num_groups // SLICE_F) > 2, report
     if categorical:
         import numpy as np
         cat_splits = sum(
@@ -269,11 +319,14 @@ def _megakernel_reason(ln) -> str:
         " (learner/split_step.py plan_split_step)"
 
 
-def stage_foil(x, y, params, rounds: int) -> dict:
+def stage_foil(x, y, params, rounds: int,
+               hist_method: str = "onehot") -> dict:
     """The same data and params on the plain XLA path: the serial
-    leaf-id learner with one-hot histograms and the XLA split scan,
-    no Pallas kernel anywhere. An internal construction (the learner
-    is swapped in before the first iteration), not an option."""
+    leaf-id learner with one-hot histograms (``scatter`` for a wide
+    table, whose rows x columns x bins one-hot would not fit) and the
+    XLA split scan, no Pallas kernel anywhere. An internal construction
+    (the learner is swapped in before the first iteration), not an
+    option."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.learner.serial import SerialTreeLearner
     t0 = time.perf_counter()
@@ -281,12 +334,12 @@ def stage_foil(x, y, params, rounds: int) -> dict:
                       lgb.Dataset(x, label=y))
     gbdt = bst._gbdt
     foil = SerialTreeLearner(gbdt.train_data, gbdt.config,
-                             hist_method="onehot")
+                             hist_method=hist_method)
     foil.params = foil.params._replace(use_scan_kernel=False)
     assert foil.split_plan().body == "per_phase"
     gbdt.learner = foil
     gbdt.train(rounds)
-    report = {"learner": "SerialTreeLearner(hist_method='onehot')",
+    report = {"learner": f"SerialTreeLearner(hist_method={hist_method!r})",
               "trees": len(gbdt.models),
               "auc": round(train_auc(bst, x, y), 6),
               "train_seconds": round(time.perf_counter() - t0, 1)}
@@ -465,6 +518,19 @@ def main(argv=None) -> int:
                   - report["categorical_foil"]["auc"])
         assert gap <= CAT_FOIL_AUC_TOL, ("categorical chip path vs foil",
                                          gap)
+        # the table too wide for the megakernel: per-phase kernels cut
+        # by columns, the Pallas scan by feature blocks, still fused
+        wx, wy = epsilon_like(WIDE_ROWS)
+        wide_params = dict(PARAMS, num_leaves=WIDE_LEAVES)
+        _, report["wide"] = stage_train(
+            wx, wy, wide_params, WIDE_ROUNDS,
+            learner="PartitionedTreeLearner", interpret=False,
+            megakernel=False, wide=True)
+        report["wide_foil"] = stage_foil(wx, wy, wide_params,
+                                         WIDE_ROUNDS,
+                                         hist_method="scatter")
+        gap = abs(report["wide"]["auc"] - report["wide_foil"]["auc"])
+        assert gap <= WIDE_FOIL_AUC_TOL, ("wide chip path vs foil", gap)
     else:
         mesh_params = dict(PARAMS, tree_learner="data",
                            num_machines=args.devices)

@@ -95,29 +95,57 @@ def greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray,
     rest_bin_cnt = max_bin
     rest_sample_cnt = total_cnt
     is_big = counts >= mean_bin_size
-    rest_bin_cnt -= int(is_big.sum())
-    rest_sample_cnt -= int(counts[is_big].sum())
-    mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
     upper_bounds = [math.inf] * max_bin
     lower_bounds = [math.inf] * max_bin
     bin_cnt = 0
     lower_bounds[0] = float(distinct_values[0])
-    cur = 0
-    for i in range(num_distinct - 1):
-        if not is_big[i]:
-            rest_sample_cnt -= int(counts[i])
-        cur += int(counts[i])
-        if (is_big[i] or cur >= mean_bin_size
-                or (is_big[i + 1] and cur >= max(1.0, mean_bin_size * 0.5))):
-            upper_bounds[bin_cnt] = float(distinct_values[i])
-            bin_cnt += 1
-            lower_bounds[bin_cnt] = float(distinct_values[i + 1])
-            if bin_cnt >= max_bin - 1:
+
+    def close_bin(i: int) -> bool:
+        """Value ``i`` ends the open bin; True once the last bin is
+        the open one."""
+        nonlocal bin_cnt
+        upper_bounds[bin_cnt] = float(distinct_values[i])
+        bin_cnt += 1
+        lower_bounds[bin_cnt] = float(distinct_values[i + 1])
+        return bin_cnt >= max_bin - 1
+
+    if not is_big.any():
+        # no value fills a bin alone (a continuous column): a bin ends
+        # at the first value where its count reaches the mean, which
+        # the running sums find without walking the values. Same
+        # boundaries as the walk below, one step a bin
+        csum = np.cumsum(counts, dtype=np.int64)
+        start, base = 0, 0          # the open bin's first value; rows
+        while True:                 # before it
+            # cur >= mean_bin_size, whole numbers on both sides
+            i = start + int(np.searchsorted(
+                csum[start:], base + math.ceil(mean_bin_size),
+                side="left"))
+            if i >= num_distinct - 1 or close_bin(i):
                 break
-            cur = 0
-            if not is_big[i]:
-                rest_bin_cnt -= 1
-                mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
+            start, base = i + 1, int(csum[i])
+            rest_bin_cnt -= 1
+            mean_bin_size = (total_cnt - base) / max(rest_bin_cnt, 1)
+    else:
+        rest_bin_cnt -= int(is_big.sum())
+        rest_sample_cnt -= int(counts[is_big].sum())
+        mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
+        # plain lists: a numpy scalar a step costs more than the step
+        big, cnts = is_big.tolist(), np.asarray(counts).tolist()
+        cur = 0
+        for i in range(num_distinct - 1):
+            if not big[i]:
+                rest_sample_cnt -= cnts[i]
+            cur += cnts[i]
+            if (big[i] or cur >= mean_bin_size
+                    or (big[i + 1]
+                        and cur >= max(1.0, mean_bin_size * 0.5))):
+                if close_bin(i):
+                    break
+                cur = 0
+                if not big[i]:
+                    rest_bin_cnt -= 1
+                    mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
     bin_cnt += 1
     for i in range(bin_cnt - 1):
         val = _next_after_up((upper_bounds[i] + lower_bounds[i + 1]) / 2.0)
@@ -125,6 +153,50 @@ def greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray,
             bounds.append(val)
     bounds.append(math.inf)
     return bounds
+
+
+def _count_in_bins(dv: np.ndarray, cn: np.ndarray,
+                   upper_bounds: Sequence[float]) -> List[int]:
+    """Rows per bin as the reference counts them (bin.cpp:411-423): it
+    walks the distinct values and moves on by at most ONE bin a value,
+    so past an empty bin (the zero bin of a column without zeros) the
+    counts lag the true bins by one. With ``t`` the true bin of each
+    value, that walk is ``b[i] = min(t[i], b[i-1] + 1)`` from
+    ``b[-1] = 0``, which the running minimum of ``t[i] - i`` gives
+    without the walk."""
+    ub = np.asarray(upper_bounds, np.float64)
+    steps = np.arange(len(dv))
+    true_bin = np.searchsorted(ub, dv, side="left")   # bounds below dv
+    walked = steps + np.minimum(
+        np.minimum.accumulate(true_bin - steps), 1)
+    return np.bincount(walked, weights=cn,
+                       minlength=len(ub)).astype(np.int64).tolist()
+
+
+def _distinct_values(values: np.ndarray, zero_cnt: int):
+    """``(distinct values, their counts)`` of a column's non-NaN sample
+    with its implicit zeros merged in (bin.cpp:354-390): consecutive
+    values within one float ulp are one value ("use the large value",
+    CheckDoubleEqualOrdered), and the ``zero_cnt`` rows the sample left
+    out are an entry of their own where no sampled value is a zero."""
+    values = np.sort(values, kind="stable")
+    if len(values) == 0:
+        return np.zeros(1), np.asarray([zero_cnt], np.int64)
+    new_grp = np.concatenate(
+        [[True], values[1:] > np.nextafter(values[:-1], np.inf)])
+    starts = np.nonzero(new_grp)[0]
+    ends = np.concatenate([starts[1:], [len(values)]])
+    dv = values[ends - 1]
+    cn = (ends - starts).astype(np.int64)
+    if zero_cnt > 0:
+        # the implicit-zero entry at its sorted position
+        pos = int(np.searchsorted(dv, 0.0))
+        if dv[0] > 0.0 or dv[-1] < 0.0 \
+                or (0 < pos < len(dv) and dv[pos - 1] < 0.0
+                    and dv[pos] > 0.0):
+            dv = np.insert(dv, pos, 0.0)
+            cn = np.insert(cn, pos, zero_cnt)
+    return dv, cn
 
 
 def find_bin_with_zero_as_one_bin(distinct_values: np.ndarray,
@@ -270,48 +342,9 @@ class BinMapper:
         num_sample_values = len(values)
         zero_cnt = total_sample_cnt - num_sample_values - na_cnt
 
-        # distinct values with implicit zeros merged in (bin.cpp:354-390),
-        # vectorized: consecutive values within one float ulp are merged
-        # ("use the large value"), matching CheckDoubleEqualOrdered.
-        values = np.sort(values, kind="stable")
-        distinct_values: List[float] = []
-        counts: List[int] = []
-        if num_sample_values > 0:
-            new_grp = np.concatenate(
-                [[True], values[1:] > np.nextafter(values[:-1], np.inf)])
-            starts = np.nonzero(new_grp)[0]
-            ends = np.concatenate([starts[1:], [num_sample_values]])
-            dvals = values[ends - 1]
-            dcnts = (ends - starts).astype(np.int64)
-            distinct_values = dvals.tolist()
-            counts = dcnts.tolist()
-            # insert the implicit-zero entry at its sorted position
-            if zero_cnt > 0 or not distinct_values:
-                if distinct_values and distinct_values[0] > 0.0:
-                    distinct_values.insert(0, 0.0)
-                    counts.insert(0, zero_cnt)
-                elif distinct_values and distinct_values[-1] < 0.0:
-                    distinct_values.append(0.0)
-                    counts.append(zero_cnt)
-                else:
-                    pos = int(np.searchsorted(dvals, 0.0))
-                    if 0 < pos < len(distinct_values) \
-                            and distinct_values[pos - 1] < 0.0 \
-                            and distinct_values[pos] > 0.0:
-                        distinct_values.insert(pos, 0.0)
-                        counts.insert(pos, zero_cnt)
-        else:
-            distinct_values = [0.0]
-            counts = [zero_cnt]
-
-        if not distinct_values:
-            self.num_bin = 1
-            self.is_trivial = True
-            return
-        self.min_val = distinct_values[0]
-        self.max_val = distinct_values[-1]
-        dv = np.asarray(distinct_values)
-        cn = np.asarray(counts)
+        dv, cn = _distinct_values(values, zero_cnt)
+        self.min_val = float(dv[0])
+        self.max_val = float(dv[-1])
 
         cnt_in_bin: List[int] = []
         if bin_type == BIN_TYPE_NUMERICAL:
@@ -335,12 +368,7 @@ class BinMapper:
                 self.bin_upper_bound.append(math.nan)
             self.num_bin = len(self.bin_upper_bound)
             # count per bin (bin.cpp:411-423)
-            cnt_in_bin = [0] * self.num_bin
-            i_bin = 0
-            for i in range(len(dv)):
-                if dv[i] > self.bin_upper_bound[i_bin]:
-                    i_bin += 1
-                cnt_in_bin[i_bin] += int(cn[i])
+            cnt_in_bin = _count_in_bins(dv, cn, self.bin_upper_bound)
             if self.missing_type == MISSING_NAN:
                 cnt_in_bin[self.num_bin - 1] = na_cnt
             assert self.num_bin <= max_bin
@@ -348,7 +376,7 @@ class BinMapper:
             # categorical (bin.cpp:425-497)
             dvi: List[int] = []
             cni: List[int] = []
-            for v, c in zip(distinct_values, counts):
+            for v, c in zip(dv.tolist(), cn.tolist()):
                 iv = int(v)
                 if iv < 0:
                     na_cnt += int(c)

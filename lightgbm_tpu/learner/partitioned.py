@@ -103,7 +103,8 @@ class PartitionedLearnerBase(NodeRandMixin, CegbStateMixin,
         return plan_split_step(
             mode=self.config.fused_split_kernel, params=self.params,
             bundled=self.bundled, num_bins_max=self.num_bins_max,
-            num_leaves=self.num_leaves, forced_plan=self.forced_plan,
+            num_leaves=self.num_leaves, num_features=self.num_groups,
+            forced_plan=self.forced_plan,
             extra_trees=self.extra_trees, ff_bynode=self.ff_bynode,
             cache_hists=self.cache_hists, serial_comm=self.serial_comm,
             interpret=self.interpret,
@@ -421,6 +422,8 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         get_telemetry().count("learner.lut_partition_traces")
     if plan.cat_scan:
         get_telemetry().count("learner.cat_scan_traces")
+    if plan.wide:
+        get_telemetry().count("learner.wide_table_traces")
 
     # shared scan-leaf composition (ops/split.py — the fused
     # megakernel twin calls the SAME maker, keeping both paths
@@ -658,8 +661,9 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
             left_small = lc <= rc
             sb = jnp.where(left_small, begin, begin + nl)
             sc = jnp.where(left_small, nl, nr)
-            with jax.named_scope(scopes.SPLITS_HIST):
+            with jax.named_scope(scopes.SPLITS_CACHE):
                 parent_hist = st["hist"][leaf]
+            with jax.named_scope(scopes.SPLITS_HIST):
                 hist_small = seg_hist(mat2, sb, sc)
                 hist_other = parent_hist - hist_small
             if params.cegb_on:
@@ -765,9 +769,19 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                                split_a.cat_bitset, split_b.cat_bitset,
                                s, bitset))
         if cache_hists:
-            st2["hist"] = st["hist"].at[
-                jnp.stack([idx_a, idx_b])].set(
-                jnp.stack([hist_a, hist_b]))
+            # one in-place row write a child (a scatter of the stacked
+            # pair is a 2-step loop on the TPU whose instructions carry
+            # no scope, and a copy of both histograms before it). The
+            # barrier keeps the sibling's subtraction, which reads the
+            # parent's row of this buffer, out of the writes' fusions:
+            # fused, the compiled v5e program copies the whole cache a
+            # write (1.57 GB at 255 leaves x 2,000 columns)
+            with jax.named_scope(scopes.SPLITS_CACHE):
+                wa, wb = jax.lax.optimization_barrier((hist_a, hist_b))
+                st2["hist"] = jax.lax.dynamic_update_index_in_dim(
+                    jax.lax.dynamic_update_index_in_dim(
+                        st["hist"], wa, idx_a, 0),
+                    wb, idx_b, 0)
         elif pool_mode:
             # children claim slots: the left child reuses the parent's
             # slot (HistogramPool::Move semantics), the right evicts

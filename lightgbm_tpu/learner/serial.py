@@ -650,7 +650,9 @@ class SerialTreeLearner(NodeRandMixin, CegbStateMixin,
         return plan_split_step(
             mode=self.config.fused_split_kernel, params=self.params,
             bundled=self.bundled, num_bins_max=self.num_bins_max,
-            num_leaves=self.num_leaves, forced_plan=self.forced_plan,
+            num_leaves=self.num_leaves,
+            num_features=self.dataset.num_groups,
+            forced_plan=self.forced_plan,
             extra_trees=self.extra_trees, ff_bynode=self.ff_bynode,
             cache_hists=self.cache_hists, mv_groups=self.mv_groups,
             has_megakernel=False)

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.split import MAX_CAT_WORDS
+from ..ops.split_step_pallas import MAX_FUSED_F
 from ..utils import LightGBMError
 from ..utils.device import on_tpu
 
@@ -80,11 +81,16 @@ class SplitStepPlan(NamedTuple):
     lut_partition: bool = False
     # the scan holds the categorical search (ops/split_categorical.py)
     cat_scan: bool = False
+    # the table's width alone kept the megakernel away (more columns
+    # than its unrolled body takes): what the trace-time counter
+    # ``learner.wide_table_traces`` counts
+    wide: bool = False
 
 
 def plan_split_step(*, mode: str, params, bundled: bool,
                     num_bins_max: int, num_leaves: int,
-                    forced_plan=(), extra_trees: bool = False,
+                    num_features: int, forced_plan=(),
+                    extra_trees: bool = False,
                     ff_bynode: float = 1.0, cache_hists: bool = True,
                     mv_groups: int = 0, serial_comm: bool = True,
                     interpret: bool = False,
@@ -95,7 +101,8 @@ def plan_split_step(*, mode: str, params, bundled: bool,
     observed: the platform (``tpu``; None asks ``on_tpu()``, the one
     place this choice asks it), ``mode`` = ``Config.fused_split_kernel``
     (auto / on / off), the table (``params.has_categorical``,
-    ``bundled``, ``num_bins_max``), the training options that put
+    ``bundled``, ``num_bins_max``, ``num_features`` = its physical
+    columns), the training options that put
     per-split work between the phases, and the learner (``interpret``,
     ``has_megakernel``: only the single-device partitioned learner has
     one; ``serial_comm``: the mesh learners put collectives between the
@@ -109,7 +116,7 @@ def plan_split_step(*, mode: str, params, bundled: bool,
     histogram cache (no parent to subtract from), multi-val
     pseudo-groups, the legacy unpacked carry. ``auto`` takes it on a
     TPU where its compiled body applies (numeric, unbundled, byte bins,
-    no forced splits); ``on`` takes it wherever it is eligible, as the
+    at most ``MAX_FUSED_F`` columns, no forced splits); ``on`` takes it wherever it is eligible, as the
     interpret twin off a TPU, and raises on a learner that has none. A
     kernel this selects and Mosaic refuses is a compile error, never a
     quiet run on the other body."""
@@ -132,15 +139,21 @@ def plan_split_step(*, mode: str, params, bundled: bool,
                 and serial_comm and not params.cegb_on
                 and not extra_trees and ff_bynode >= 1.0
                 and mv_groups == 0 and num_leaves >= 2)
+    wide = False
     if mode == "on":
         # forced pre-steps run the per-phase body, and only the
         # interpret twin shares its histogram cache layout
         megakernel = eligible and (interpret or not forced_plan)
     elif mode == "auto":
-        # the compiled body's static scope
-        megakernel = (eligible and tpu and not forced_plan
-                      and not has_cat and not bundled
-                      and num_bins_max <= 256)
+        # the compiled body's static scope; its per-feature loops are
+        # unrolled, so a wide table keeps the per-phase kernels, which
+        # cut their work by columns (ops/hist_pallas.py SLICE_F,
+        # ops/split_scan_pallas.py SCAN_BLOCK_F)
+        in_scope = (eligible and tpu and not forced_plan
+                    and not has_cat and not bundled
+                    and num_bins_max <= 256)
+        wide = in_scope and num_features > MAX_FUSED_F
+        megakernel = in_scope and not wide
     else:
         megakernel = False
     return SplitStepPlan(
@@ -150,7 +163,7 @@ def plan_split_step(*, mode: str, params, bundled: bool,
         scan_kernel=bool(tpu and not interpret and not has_cat
                          and not params.cegb_on),
         lut_partition=has_cat or bool(bundled),
-        cat_scan=has_cat)
+        cat_scan=has_cat, wide=wide)
 
 
 def _bitcast_f32(x):

@@ -40,16 +40,19 @@ DEVICE_SCOPES = (GRADIENTS, SAMPLE, GROW, GROW_PACK, GROW_ROOT,
                  GROW_SPLITS, GROW_LEAF_OF_POS, SCORE_UPDATE)
 # the parts of the per-phase split body, the one a table the megakernel
 # refuses runs (categorical, bundled): the megakernel's body traces
-# none of them. Three are children of GROW_SPLITS, opened by the
-# learner's loop. The categorical scan is opened by ops/split.py, which
+# none of them. Four are children of GROW_SPLITS, opened by the
+# learner's loop (the cache: the parent's histogram read from the
+# per-leaf cache and the two children's written into it; the sibling's
+# subtraction is the histogram phase's). The categorical scan is opened by ops/split.py, which
 # knows no learner: it is named for what it is, wherever it runs (the
 # root's one scan a tree, every learner's call of per_feature_splits).
 SPLITS_PARTITION = "lgbm.grow.splits.partition"
 SPLITS_HIST = "lgbm.grow.splits.hist"
 SPLITS_SCAN = "lgbm.grow.splits.scan"
+SPLITS_CACHE = "lgbm.grow.splits.cache"
 CAT_SCAN = "lgbm.cat_scan"
 SPLIT_PHASE_SCOPES = (SPLITS_PARTITION, SPLITS_HIST, SPLITS_SCAN,
-                      CAT_SCAN)
+                      SPLITS_CACHE, CAT_SCAN)
 
 # host spans of the fused driver, on the profiler's clock
 # (Telemetry.span(..., trace=<name>))

@@ -36,6 +36,14 @@ scans only the leaf's rows via DataPartition, data_partition.hpp:161).
 DMA windows start at the 8-aligned floor of `begin` (Mosaic granule
 for u8 rows); the in-window shift is masked via the gh operand, so no
 dynamic VMEM slicing is needed anywhere.
+
+**Two forms, picked by the table's width alone** (``histogram_segment``).
+Up to ``MAX_NIBBLE_F`` columns the grouped nibble kernel above streams
+whole rows. Past it the kernel cannot be compiled for the chip, and
+the plain one-hot stream (``hist_child_stream``: per feature a
+[rows, 256] one-hot and one matmul, the form the split-step
+megakernel runs over the smaller child) takes the table ``SLICE_F``
+columns at a time: one ``pallas_call`` whose grid axis is the slice.
 """
 
 from __future__ import annotations
@@ -47,6 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.telemetry import get_telemetry
 from ..utils.device import on_tpu
 from ..utils.jit_registry import register_jit
 
@@ -126,43 +135,19 @@ def extract_row_ids(mat, num_features: int, n: int) -> jnp.ndarray:
 LO = 8             # low-nibble size (bin = hi * LO + lo)
 PAY = 5            # payload planes: g_hi, g_lo, h_hi, h_lo, cnt
 GRP = 3            # features per MXU tile of the nibble kernel
-MAX_NIBBLE_F = 192  # nibble-kernel unroll cap (program size; ~1 MB VMEM)
+# widest table the nibble kernel takes: its group loop is unrolled
+# and the chip's compiler keeps every group's block intermediates on
+# the VMEM stack. At blk=2048, compiled for the v5e: 67 and 68 columns
+# pass, 69 need 115.72 MB of the 100 MB scoped limit, 96 need 137 MB
+# of the chip's 128 (PERF.md section 6, PR 31). Wider tables take
+# ``hist_child_stream`` a column slice at a time.
+MAX_NIBBLE_F = 68
 
-PAYB = 9           # payload bytes the hist kernels decode (g4+h4+cnt)
-
-
-def _nibble_dma(mat_hbm, buf, sems, base, blk, win, *, compact: bool,
-                f_lo: int, nf: int, feat0: int):
-    """Input DMA for the nibble kernels. Non-compact streams the full
-    row window; compact (feature-sliced wide datasets) copies ONLY the
-    slice's columns plus the payload columns into a narrow buffer, so
-    HBM read traffic per slice is ~nf+9 columns instead of C — without
-    this, an Epsilon-like C=2048 would re-read the whole matrix once
-    per slice. Returns (start, wait) taking (slot, i)."""
-    def copies(slot, i):
-        s = pl.multiple_of(base + i * blk, ALIGN)
-        if not compact:
-            return [pltpu.make_async_copy(
-                mat_hbm.at[pl.ds(s, win), :], buf.at[slot],
-                sems.at[slot, 0])]
-        return [
-            pltpu.make_async_copy(
-                mat_hbm.at[pl.ds(s, win), pl.ds(f_lo, nf)],
-                buf.at[slot, :, pl.ds(0, nf)], sems.at[slot, 0]),
-            pltpu.make_async_copy(
-                mat_hbm.at[pl.ds(s, win), pl.ds(feat0, PAYB)],
-                buf.at[slot, :, pl.ds(nf, PAYB)], sems.at[slot, 1]),
-        ]
-
-    def start(slot, i):
-        for cp in copies(slot, i):
-            cp.start()
-
-    def wait(slot, i):
-        for cp in copies(slot, i):
-            cp.wait()
-
-    return start, wait
+# a wide table's histogram is cut into slices of this many columns:
+# one lane tile of the u8 matrix, so a slice's DMA starts on a tile
+# boundary whatever its index
+SLICE_F = 128
+SLICE_BLK = 512    # row block of the sliced stream (the megakernel's)
 
 
 def _payload_lanes(g_hi, g_lo, h_hi, h_lo, cnt, lhs_p):
@@ -211,9 +196,8 @@ def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
                                 mat_hbm,   # ANY [N_pad, C] u8
                                 out_ref,   # VMEM [NG, 120, GRP*H] f32
                                 buf, sems,
-                                *, blk: int, cols: int, feat0: int,
-                                ngroups: int, hi_n: int,
-                                f_lo: int = 0, nf: int = 0):
+                                *, blk: int, feat0: int,
+                                ngroups: int, hi_n: int):
     """Hierarchical (hi/lo nibble) histogram build: ``bin = hi*LO +
     lo``, and per group of GRP features,
 
@@ -229,16 +213,7 @@ def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
     across 3 features (~10 ops per group per block). Payload stays
     exact: lhs entries are the bf16 hi/lo halves of the f32 grad/hess,
     accumulated in f32.
-
-    ``f_lo``/``nf`` histogram the feature SLICE [f_lo, f_lo+nf):
-    datasets wider than MAX_NIBBLE_F dispatch one kernel call per
-    slice, so program size stays bounded.
     """
-    if nf == 0:
-        nf = feat0
-    compact = nf != feat0
-    pay0 = nf if compact else feat0      # payload col base in buf
-    col0 = 0 if compact else f_lo        # feature col base in buf
     begin = scal_ref[0]
     count = scal_ref[1]
     nblk = pl.cdiv(count, blk)
@@ -248,9 +223,11 @@ def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
 
     m_lhs = GRP * LO * PAY                           # 120
     n_rhs = GRP * hi_n
-    dma_start, dma_wait = _nibble_dma(
-        mat_hbm, buf, sems, base, blk, win, compact=compact,
-        f_lo=f_lo, nf=nf, feat0=feat0)
+
+    def read(slot, i):
+        s = pl.multiple_of(base + i * blk, ALIGN)
+        return pltpu.make_async_copy(mat_hbm.at[pl.ds(s, win), :],
+                                     buf.at[slot], sems.at[slot])
 
     out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -264,32 +241,32 @@ def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
 
     @pl.when(nblk > 0)
     def _():
-        dma_start(0, 0)
+        read(0, 0).start()
 
     def block_body(i, _):
         slot = jax.lax.rem(i, 2)
 
         @pl.when(i + 1 < nblk)
         def _():
-            dma_start(1 - slot, i + 1)
+            read(1 - slot, i + 1).start()
 
-        dma_wait(slot, i)
-        mat_i32 = buf[slot].astype(jnp.int32)        # [win, C']
+        read(slot, i).wait()
+        mat_i32 = buf[slot].astype(jnp.int32)        # [win, C]
         # full-width nibble split ONCE for every feature column
-        mat_hi = mat_i32 // LO                       # [win, C']
+        mat_hi = mat_i32 // LO                       # [win, C]
         mat_lo = mat_i32 - mat_hi * LO
 
         rem = jnp.minimum(count - i * blk, blk)
         _, g_hi, g_lo, h_hi, h_lo, cnt = _decode_block(
-            mat_i32, pay0, shift, rem, win)
+            mat_i32, feat0, shift, rem, win)
         pay_b = _payload_lanes(g_hi, g_lo, h_hi, h_lo, cnt,
                                lhs_p)                # [win, m_lhs]
 
         for gidx in range(ngroups):
-            # tail group clamps past-slice columns onto the last
+            # tail group clamps past-the-end columns onto the last
             # feature; garbage lanes are sliced off in the epilogue
             def fcol(m, j):
-                c = col0 + min(gidx * GRP + j, nf - 1)
+                c = min(gidx * GRP + j, feat0 - 1)
                 return m[:, c:c + 1]                 # [win, 1]
 
             def pick3(m, fl):
@@ -313,84 +290,215 @@ def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
 @register_jit("hist_segment_nibble")
 @functools.partial(
     jax.jit,
-    static_argnames=("num_features", "num_bins", "blk", "interpret",
-                     "nibble_cap"))
+    static_argnames=("num_features", "num_bins", "blk", "interpret"))
 def _histogram_segment_nibble(mat, begin, count, *, num_features: int,
-                              num_bins: int,
-                              nibble_cap: int = MAX_NIBBLE_F,
-                              blk: int = 2048,
+                              num_bins: int, blk: int = 2048,
                               interpret: bool = False):
-    """Nibble-kernel call -> [F, B, 3] histogram.
-
-    ``nibble_cap`` rides as a STATIC arg resolved by the caller
-    (histogram_segment): a module global read here would freeze into
-    the jit cache on first trace.
-    """
+    """Nibble-kernel call -> [F, B, 3] histogram."""
     if blk % ALIGN:
         raise ValueError(f"blk must be a multiple of {ALIGN}, got {blk}")
     _, cols = mat.shape
     f = num_features
     hi_n = -(-num_bins // LO)                        # ceil(B / LO)
-    scal = jnp.stack([jnp.asarray(begin, jnp.int32),
+    ngroups = -(-f // GRP)
+    raw = pl.pallas_call(
+        functools.partial(_hist_nibble_kernel_grouped, blk=blk,
+                          feat0=f, ngroups=ngroups, hi_n=hi_n),
+        out_shape=jax.ShapeDtypeStruct(
+            (ngroups, GRP * LO * PAY, GRP * hi_n), jnp.float32),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, blk + ALIGN, cols), jnp.uint8),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(_segment_scalars(begin, count), mat)
+    # [NG, (fl,lo,p), (fr,hi)] -> diagonal fl == fr -> [F, B, P]
+    raw = raw.reshape(ngroups, GRP, LO, PAY, GRP, hi_n)
+    diag = jnp.einsum("gjlpjh->gjhlp", raw)          # [NG,GRP,H,LO,P]
+    hist = diag.reshape(ngroups * GRP, hi_n * LO, PAY)[:f, :num_bins]
+    return _sum_planes(hist[..., 0], hist[..., 1], hist[..., 2],
+                       hist[..., 3], hist[..., 4])
+
+
+def _segment_scalars(begin, count):
+    return jnp.stack([jnp.asarray(begin, jnp.int32),
                       jnp.asarray(count, jnp.int32)])
-    def specs(nf: int) -> dict:
-        # sliced (compact) calls stream only nf+PAYB columns per block
-        buf_cols = (nf + PAYB) if nf != f else cols
-        return dict(
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[
-                pltpu.VMEM((2, blk + ALIGN, buf_cols), jnp.uint8),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
-            compiler_params=_COMPILER_PARAMS,
-            interpret=interpret,
-        )
 
-    def slice_hist(f_lo: int, nf: int) -> jnp.ndarray:
-        """[nf, B, PAY] histogram of features [f_lo, f_lo+nf)."""
-        ngroups = -(-nf // GRP)
-        raw = pl.pallas_call(
-            functools.partial(_hist_nibble_kernel_grouped, blk=blk,
-                              cols=cols, feat0=f, ngroups=ngroups,
-                              hi_n=hi_n, f_lo=f_lo, nf=nf),
-            out_shape=jax.ShapeDtypeStruct(
-                (ngroups, GRP * LO * PAY, GRP * hi_n), jnp.float32),
-            **specs(nf),
-        )(scal, mat)
-        # [NG, (fl,lo,p), (fr,hi)] -> diagonal fl == fr -> [nf,B,P]
-        raw = raw.reshape(ngroups, GRP, LO, PAY, GRP, hi_n)
-        diag = jnp.einsum("gjlpjh->gjhlp", raw)      # [NG,GRP,H,LO,P]
-        return diag.reshape(ngroups * GRP, hi_n * LO,
-                            PAY)[:nf, :num_bins]
 
-    if f <= nibble_cap:
-        hist = slice_hist(0, f)
+def _sum_planes(g_hi, g_lo, h_hi, h_lo, cnt):
+    """The kernels' five payload planes -> [F, B, 3] (g, h, count)."""
+    return jnp.stack([g_hi + g_lo, h_hi + h_lo, cnt], axis=-1)
+
+
+def hist_child_stream(mat_hbm, buf, sems, hpl, begin, count, *,
+                      f: int, blk: int, col0=None):
+    """The plain one-hot histogram: a pipelined block stream over the
+    rows ``mat_hbm[begin, begin+count)`` alone, accumulated into the
+    five ``hpl`` planes ``[5, F8, B128]`` f32 (g hi, g lo, h hi, h lo,
+    count; zeroed here first). Phase 0 of the split-step megakernel
+    (ops/split_step_pallas.py) runs it after ``partition_stream`` has
+    returned, on the smaller child's compact segment, and a table too
+    wide for the nibble kernel runs it a column slice at a time
+    (``_histogram_segment_slices``).
+
+    ``col0`` None: whole rows are streamed (``buf`` [2, blk+8, C] u8,
+    ``sems`` two DMA semaphores or more) and columns ``[0, f)``
+    histogrammed. ``col0`` a traced multiple of ``SLICE_F``: only the
+    ``SLICE_F`` columns from ``col0`` on and the lane tiles that hold
+    the payload are streamed (``buf`` [2, blk+8, SLICE_F + C - tile0],
+    four semaphores), and all ``SLICE_F`` columns are histogrammed,
+    whatever lies past the table's ``f`` among them: the caller cuts
+    them off, so every slice runs one kernel body.
+
+    Windows start at the 8-aligned floor of ``begin``; rows outside
+    ``[shift, shift+rem)`` of a window are masked through the payload
+    (``_decode_block``). Block k+1 is read into ``buf``'s other slot
+    while block k computes. Per block and feature: a ``[win, B128]``
+    one-hot of the bin byte on the VPU, one matmul with the exact bf16
+    hi/lo payload pairs, f32 accumulation."""
+    # counted where the stream enters a kernel's trace, like
+    # ``kernels.partition_pipelined``
+    get_telemetry().count("kernels.hist_child_stream")
+    win = blk + ALIGN
+    nblk = pl.cdiv(count, blk)
+    base = (begin // ALIGN) * ALIGN
+    shift = begin - base
+    cols = mat_hbm.shape[1]
+    if col0 is None:
+        nf, pay0 = f, f
     else:
-        # wide datasets: one bounded-program kernel call per feature
-        # slice (at most 2 distinct compiled widths: full + tail)
-        hist = jnp.concatenate(
-            [slice_hist(lo, min(nibble_cap, f - lo))
-             for lo in range(0, f, nibble_cap)], axis=0)
-    g = hist[..., 0] + hist[..., 1]
-    h = hist[..., 2] + hist[..., 3]
-    return jnp.stack([g, h, hist[..., 4]], axis=-1)  # [F, B, 3]
+        tile0 = f // SLICE_F * SLICE_F      # the payload's lane tile
+        nf, pay0 = SLICE_F, SLICE_F + f - tile0
+    # pad lanes: no bin
+    bins_l = jax.lax.broadcasted_iota(
+        jnp.int32, (1, hpl.shape[2]), 1).astype(jnp.float32)
+    hpl[...] = jnp.zeros_like(hpl)
+
+    def reads(k, slot):
+        rows = pl.ds(pl.multiple_of(base + k * blk, ALIGN), win)
+        if col0 is None:
+            return [pltpu.make_async_copy(
+                mat_hbm.at[rows, :], buf.at[slot], sems.at[slot])]
+        return [
+            pltpu.make_async_copy(
+                mat_hbm.at[rows, pl.ds(col0, nf)],
+                buf.at[slot, :, pl.ds(0, nf)], sems.at[slot]),
+            pltpu.make_async_copy(
+                mat_hbm.at[rows, pl.ds(tile0, cols - tile0)],
+                buf.at[slot, :, pl.ds(nf, cols - tile0)],
+                sems.at[2 + slot])]
+
+    @pl.when(nblk > 0)
+    def _():
+        for cp in reads(0, 0):
+            cp.start()
+
+    def block_body(k, _):
+        slot = jax.lax.rem(k, 2)
+
+        @pl.when(k + 1 < nblk)
+        def _():
+            for cp in reads(k + 1, 1 - slot):
+                cp.start()
+
+        for cp in reads(k, slot):
+            cp.wait()
+        mat_i32 = buf[slot].astype(jnp.int32)            # [win, C]
+        mat_f = mat_i32.astype(jnp.float32)
+        rem = jnp.minimum(count - k * blk, blk)
+        _, g_hi, g_lo, h_hi, h_lo, c_ch = _decode_block(
+            mat_i32, pay0, shift, rem, win)
+        zero = jnp.zeros_like(g_hi)
+        pay = jnp.concatenate(
+            [g_hi, g_lo, h_hi, h_lo, c_ch.astype(jnp.bfloat16), zero,
+             zero, zero], axis=1)                        # [win, 8]
+        for fx in range(nf):
+            fcol = mat_f[:, fx:fx + 1]                   # [win, 1]
+            onehot = jnp.where(fcol == bins_l, jnp.float32(1),
+                               0.0).astype(jnp.bfloat16)
+            res = jax.lax.dot_general(
+                pay, onehot, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [8, B]
+            for ch in range(5):
+                hpl[ch, pl.ds(fx, 1), :] += res[ch:ch + 1, :]
+        return 0
+
+    jax.lax.fori_loop(0, nblk, block_body, 0)
+
+
+def _hist_slices_kernel(scal_ref, mat_hbm, hpl, buf, sems, *, f, blk):
+    # one grid step a column slice; ``hpl`` is the slice's block of
+    # the output planes
+    col0 = pl.multiple_of(pl.program_id(0) * SLICE_F, SLICE_F)
+    hist_child_stream(mat_hbm, buf, sems, hpl, scal_ref[0], scal_ref[1],
+                      f=f, blk=blk, col0=col0)
+
+
+@register_jit("hist_segment_slices")
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_features", "num_bins", "blk", "interpret"))
+def _histogram_segment_slices(mat, begin, count, *, num_features: int,
+                              num_bins: int, blk: int = SLICE_BLK,
+                              interpret: bool = False):
+    """A wide table's histogram -> [F, B, 3]: ONE ``pallas_call`` whose
+    grid axis is the column slice (``SLICE_F`` columns each), one
+    Mosaic body for every slice. A slice streams its own columns and
+    the payload's lane tile, not the whole row; the last slice runs
+    past ``F`` into the payload and padding columns, which are cut off
+    here (``matrix_cols`` rounds the row up to whole tiles, so the
+    slice is always inside the matrix)."""
+    f, cols = num_features, mat.shape[1]
+    n_slices = -(-f // SLICE_F)
+    bp = _round_up(num_bins, 128)
+    pay_cols = cols - f // SLICE_F * SLICE_F
+    planes = pl.pallas_call(
+        functools.partial(_hist_slices_kernel, f=f, blk=blk),
+        grid=(n_slices,),
+        out_shape=jax.ShapeDtypeStruct((5, n_slices * SLICE_F, bp),
+                                       jnp.float32),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((5, SLICE_F, bp), lambda s: (0, s, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, blk + ALIGN, SLICE_F + pay_cols), jnp.uint8),
+            pltpu.SemaphoreType.DMA((4,))],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(_segment_scalars(begin, count), mat)[:, :f, :num_bins]
+    return _sum_planes(*planes)
 
 
 def histogram_segment(mat, begin, count, num_bins: int, num_features: int,
                       blk: int = 2048,
                       interpret: bool = False) -> jnp.ndarray:
-    """Histogram of rows [begin, begin+count) -> [F, B, 3] f32 by the
-    nibble kernel; datasets wider than its unroll cap (MAX_NIBBLE_F)
-    run one kernel call per feature slice. ``ops/histogram.py`` is the
-    reference the tests compare it with."""
+    """Histogram of rows [begin, begin+count) -> [F, B, 3] f32: by the
+    nibble kernel up to ``MAX_NIBBLE_F`` columns, by the plain one-hot
+    stream a ``SLICE_F``-column slice at a time above. The table's
+    width alone decides. ``blk`` is the row block the matrix was padded
+    for (``matrix_rows``): the nibble kernel's block, and the most the
+    stream's may be (``SLICE_BLK`` where ``blk`` allows it, so that a
+    window never leaves the matrix). ``ops/histogram.py`` is the
+    reference the tests compare both with."""
+    f = num_features
+    wide = f > MAX_NIBBLE_F
+    # counted where a histogram call is traced: the column slices it
+    # is cut into
+    get_telemetry().count("kernels.hist_feature_slices",
+                          -(-f // SLICE_F) if wide else 1)
+    if wide:
+        return _histogram_segment_slices(
+            mat, begin, count, num_features=f, num_bins=num_bins,
+            blk=min(blk, SLICE_BLK), interpret=interpret)
     return _histogram_segment_nibble(
-        mat, begin, count, num_features=num_features,
-        num_bins=num_bins, blk=blk, interpret=interpret,
-        nibble_cap=MAX_NIBBLE_F)
+        mat, begin, count, num_features=f, num_bins=num_bins, blk=blk,
+        interpret=interpret)
 
 
 def histogram_pallas(binned, ghc, num_bins: int, blk: int = 2048,

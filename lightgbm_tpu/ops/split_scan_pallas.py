@@ -214,27 +214,50 @@ def scan_core(pg, ph, pc, cmin, cmax, nb, missing, defbin, mono,
          dleft, wl_f, wr_f], axis=1)                           # [F, 8]
 
 
+# features a grid step of the scan holds: every row of the [F, B]
+# planes scans on its own, so a wide table is a grid over blocks of
+# rows; the kernel keeps dozens of [rows, B] f32 intermediates in VMEM
+# (2,000 x 256 would be 2 MB each)
+SCAN_BLOCK_F = 128
+
+
 @register_jit("split_scan_kernel")
 @functools.partial(
     jax.jit, static_argnames=("params", "interpret"))
 def _scan_call(scal, imeta, fmeta, hg, hh, hc, *, params: SplitParams,
                interpret: bool):
     f, b = hg.shape
-    kernel = functools.partial(_scan_kernel, f=f, b=b, p=params)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    if f <= SCAN_BLOCK_F:
+        # one block holds the table
+        whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+        return pl.pallas_call(
+            functools.partial(_scan_kernel, f=f, b=b, p=params),
+            out_shape=jax.ShapeDtypeStruct((f, 8), jnp.float32),
+            in_specs=[smem, whole, whole, whole, whole, whole],
+            out_specs=whole,
+            interpret=interpret,
+        )(scal, imeta, fmeta, hg, hh, hc)
+    # a grid over blocks of features, the table padded to whole blocks
+    # (a padded row has no bin and is cut off below)
+    fb = SCAN_BLOCK_F
+    pad = -f % fb
+
+    def rows(x):
+        return jnp.pad(x, ((0, pad), (0, 0)))
+
+    def block(width):
+        return pl.BlockSpec((fb, width), lambda i: (i, 0))
+
     return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((f, 8), jnp.float32),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        functools.partial(_scan_kernel, f=fb, b=b, p=params),
+        grid=((f + pad) // fb,),
+        out_shape=jax.ShapeDtypeStruct((f + pad, 8), jnp.float32),
+        in_specs=[smem, block(imeta.shape[1]), block(fmeta.shape[1]),
+                  block(b), block(b), block(b)],
+        out_specs=block(8),
         interpret=interpret,
-    )(scal, imeta, fmeta, hg, hh, hc)
+    )(scal, rows(imeta), rows(fmeta), rows(hg), rows(hh), rows(hc))[:f]
 
 
 def scan_kernel_ok(params: SplitParams, rand_bins, cegb_uncharged) -> bool:

@@ -59,8 +59,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..observability.telemetry import get_telemetry
 from ..utils.jit_registry import register_jit
+from .hist_pallas import (_segment_scalars, _sum_planes,
+                          hist_child_stream)
 from .split import (MISSING_NAN_CODE, MISSING_ZERO_CODE, FeatureMeta,
                     child_columns, child_constraints,
                     child_constraints_mono, kEpsilon, make_scan_leaf,
@@ -821,73 +822,6 @@ def _leaf_site_scalars(pack, iscal, s_in, imeta_ref, big_l):
         nbins_f
 
 
-def hist_child_stream(mat_hbm, buf, sems, hpl, begin, count, *,
-                      f: int, blk: int):
-    """The smaller child's histogram: a pipelined block stream over
-    the rows ``mat_hbm[begin, begin+count)`` alone, accumulated into
-    the five ``hpl`` planes ``[5, F8, B128]`` f32 (g hi, g lo, h hi,
-    h lo, count; zeroed here first). Phase 0 of the megakernel
-    runs it after ``partition_stream`` has returned, on the child's
-    compact segment; ``histogram_child_stream`` wraps it alone.
-
-    Windows start at the 8-aligned floor of ``begin``; rows outside
-    ``[shift, shift+rem)`` of a window are masked through the payload
-    (``_decode_block``). Block k+1 is read into ``buf``'s other slot
-    (``buf`` [2, blk+8, C] u8, ``sems`` two DMA semaphores or more)
-    while block k computes. Per block and feature: a ``[win, B128]``
-    one-hot of the bin byte on the VPU, one matmul with the exact bf16
-    hi/lo payload pairs, f32 accumulation."""
-    from .hist_pallas import _decode_block
-    # counted where the stream enters a kernel's trace, like
-    # ``kernels.partition_pipelined``
-    get_telemetry().count("kernels.hist_child_stream")
-    win = blk + ALIGN
-    nblk = pl.cdiv(count, blk)
-    base = (begin // ALIGN) * ALIGN
-    shift = begin - base
-    bins_l = _iota_f32((1, hpl.shape[2]), 1)       # pad lanes: no bin
-    hpl[...] = jnp.zeros_like(hpl)
-
-    def read(k, slot):
-        start = pl.multiple_of(base + k * blk, ALIGN)
-        return pltpu.make_async_copy(mat_hbm.at[pl.ds(start, win), :],
-                                     buf.at[slot], sems.at[slot])
-
-    @pl.when(nblk > 0)
-    def _():
-        read(0, 0).start()
-
-    def block_body(k, _):
-        slot = jax.lax.rem(k, 2)
-
-        @pl.when(k + 1 < nblk)
-        def _():
-            read(k + 1, 1 - slot).start()
-
-        read(k, slot).wait()
-        mat_i32 = buf[slot].astype(jnp.int32)            # [win, C]
-        mat_f = mat_i32.astype(jnp.float32)
-        rem = jnp.minimum(count - k * blk, blk)
-        _, g_hi, g_lo, h_hi, h_lo, c_ch = _decode_block(
-            mat_i32, f, shift, rem, win)
-        zero = jnp.zeros_like(g_hi)
-        pay = jnp.concatenate(
-            [g_hi, g_lo, h_hi, h_lo, c_ch.astype(jnp.bfloat16), zero,
-             zero, zero], axis=1)                        # [win, 8]
-        for fx in range(f):
-            fcol = mat_f[:, fx:fx + 1]                   # [win, 1]
-            onehot = jnp.where(fcol == bins_l, jnp.float32(1),
-                               0.0).astype(jnp.bfloat16)
-            res = jax.lax.dot_general(
-                pay, onehot, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [8, B]
-            for ch in range(5):
-                hpl[ch, pl.ds(fx, 1), :] += res[ch:ch + 1, :]
-        return 0
-
-    jax.lax.fori_loop(0, nblk, block_body, 0)
-
-
 def _child_stream_kernel(scal_ref, mat_hbm, hpl, buf, sems, *, f, blk):
     hist_child_stream(mat_hbm, buf, sems, hpl, scal_ref[0], scal_ref[1],
                       f=f, blk=blk)
@@ -903,8 +837,6 @@ def histogram_child_stream(mat, begin, count, *, num_bins: int,
     registered program."""
     f, b = num_features, num_bins
     fp, bp = -(-f // 8) * 8, -(-b // 128) * 128
-    scal = jnp.stack([jnp.asarray(begin, jnp.int32),
-                      jnp.asarray(count, jnp.int32)])
     planes = pl.pallas_call(  # graftlint: allow[GL506]
         functools.partial(_child_stream_kernel, f=f, blk=blk),
         out_shape=jax.ShapeDtypeStruct((5, fp, bp), jnp.float32),
@@ -917,9 +849,8 @@ def histogram_child_stream(mat, begin, count, *, num_bins: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(scal, mat)[:, :f, :b]
-    return jnp.stack([planes[0] + planes[1], planes[2] + planes[3],
-                      planes[4]], axis=-1)
+    )(_segment_scalars(begin, count), mat)[:, :f, :b]
+    return _sum_planes(*planes)
 
 
 def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,

@@ -92,6 +92,27 @@ def test_categorical_stage_tiny(fuse_iters):
     assert abs(report["auc"] - foil["auc"]) <= cs.CAT_FOIL_AUC_TOL
 
 
+def test_wide_stage_tiny(fuse_iters):
+    """The stage of the table too wide for the megakernel (2,000
+    columns): the per-phase route with every histogram call cut into
+    column slices, in fused blocks, against the scatter foil. Off a
+    TPU the plan never refuses for width (``auto`` picks no megakernel
+    there), so ``wide_table`` reads off."""
+    x, y = cs.epsilon_like(1200)
+    assert x.shape == (1200, 2000)
+    params = dict(TINY, tree_learner="partitioned")
+    _, report = cs.stage_train(x, y, params, cs.WIDE_ROUNDS,
+                               learner="PartitionedTreeLearner",
+                               interpret=True, megakernel=False,
+                               wide=True)
+    assert report["fused_block_hits"] == 1      # 1 sync + one block of 4
+    assert report["wide_table"] == "off"
+    assert report["hist_feature_slices"] % 16 == 0
+    foil = cs.stage_foil(x, y, params, cs.WIDE_ROUNDS,
+                         hist_method="scatter")
+    assert abs(report["auc"] - foil["auc"]) <= cs.WIDE_FOIL_AUC_TOL
+
+
 @pytest.mark.slow
 def test_kernel_and_foil_stages_tiny(fuse_iters):
     kernels = cs.stage_kernels(

@@ -314,16 +314,49 @@ def test_a_column_wider_than_a_byte_raises():
         == 256
 
 
-def test_histogram_wide_slices_lower_for_tpu():
-    """The sliced nibble dispatch at an Epsilon-like width (250
-    features -> 192 + 58 slices, compact two-region DMA) lowers for
-    TPU."""
+@pytest.mark.parametrize("f", [69, 250, 2000])
+def test_histogram_wide_slices_lower_for_tpu(f):
+    """The sliced one-hot stream (one ``pallas_call``, the column
+    slice a grid axis, two-region DMA: the slice's columns and the
+    payload's lane tile) lowers for TPU: the narrowest table past the
+    nibble kernel, two slices, the Epsilon table's sixteen."""
     from lightgbm_tpu.ops.hist_pallas import histogram_segment
-    f, b = 250, 64
+    b = 256
     mat = _mat(n=2048, f=f, b=b)
     _lowers(functools.partial(histogram_segment, num_bins=b,
                               num_features=f, interpret=False),
             mat, jnp.int32(8), jnp.int32(1024))
+
+
+def test_partition_lowers_at_the_wide_row():
+    """``partition_segment`` over 2,048-byte rows (2,000 columns)."""
+    from lightgbm_tpu.ops.partition_pallas import partition_segment
+    mat = _mat(n=2048, f=2000)
+    assert mat.shape[1] == 2048
+    _lowers(functools.partial(partition_segment, blk=512,
+                              interpret=False, use_lut_path=False),
+            mat, jnp.zeros_like(mat), jnp.int32(13), jnp.int32(1500),
+            jnp.int32(1999), jnp.int32(128), jnp.int32(0), jnp.int32(0),
+            jnp.int32(0), jnp.int32(256), jnp.int32(0),
+            jnp.zeros((1, 256), jnp.float32))
+
+
+@pytest.mark.parametrize("vmapped", [False, True],
+                         ids=["one-leaf", "both-children"])
+def test_feature_blocked_scan_lowers_for_tpu(vmapped):
+    """The scan at 2,000 features: a grid over blocks of 128 features,
+    alone and under the grow loop's vmap over both children."""
+    from lightgbm_tpu.ops.split_scan_pallas import \
+        per_feature_numerical_pallas
+    (hist, pg, ph, pc, lo, hi, fm), meta, params = _scan_args(f=2000)
+
+    def one(hh):
+        return per_feature_numerical_pallas(
+            hh, pg, ph, pc, meta, params, lo, hi, fm, interpret=False)
+    if vmapped:
+        _lowers(jax.vmap(one), jnp.stack([hist, hist * 0.5]))
+    else:
+        _lowers(one, hist)
 
 
 @pytest.mark.parametrize("num_leaves", [255, 4096])
@@ -463,3 +496,123 @@ def test_hist_child_stream_compiles_for_v5e(one_chip, f):
         histogram_child_stream, num_bins=256, num_features=f,
         blk=SEG_BLK)).lower(
         sds((1_003_528, 128), jnp.uint8), i32, i32).compile()
+
+
+# ---- PR 31: the wide table's kernels at the Epsilon cell's shape ------
+EPSILON = dict(n=400_000, f=2000, b=256)
+
+
+def test_sliced_histogram_compiles_for_v5e(one_chip):
+    """``histogram_segment`` at 2,000 columns: sixteen grid steps of
+    one Mosaic body, a dynamic 128-aligned column offset in the DMA."""
+    from lightgbm_tpu.ops.hist_pallas import (histogram_segment,
+                                              matrix_cols, matrix_rows)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    n, f, b = (EPSILON[k] for k in "nfb")
+    i32 = sds((), jnp.int32)
+    jax.jit(functools.partial(
+        histogram_segment, num_bins=b, num_features=f)).lower(
+        sds((matrix_rows(n), matrix_cols(f)), jnp.uint8), i32,
+        i32).compile()
+
+
+@pytest.mark.parametrize("f,passes", [
+    pytest.param(68, True, marks=pytest.mark.slow),    # 48 s here
+    (69, False)])
+def test_the_nibble_kernels_widest_table_on_v5e(one_chip, f, passes):
+    """What ``MAX_NIBBLE_F`` states: at the learner's block of 2,048
+    rows the chip's compiler takes the nibble kernel at 68 columns and
+    refuses it at 69 (its unrolled groups' intermediates pass the
+    scoped VMEM limit), which is why a wider table is sliced."""
+    from lightgbm_tpu.ops import hist_pallas as hp
+    assert hp.MAX_NIBBLE_F == 68
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = sds((), jnp.int32)
+    lowered = jax.jit(functools.partial(
+        hp._histogram_segment_nibble, num_bins=256, num_features=f,
+        blk=2048)).lower(
+        sds((hp.matrix_rows(100_000), hp.matrix_cols(f)), jnp.uint8),
+        i32, i32)
+    if passes:
+        lowered.compile()
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
+
+
+def test_wide_partition_compiles_for_v5e(one_chip):
+    """``partition_segment`` over the cell's 2,048-byte rows."""
+    from lightgbm_tpu.ops.hist_pallas import matrix_cols, matrix_rows
+    from lightgbm_tpu.ops.partition_pallas import partition_segment
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    n, f = EPSILON["n"], EPSILON["f"]
+    mat = sds((matrix_rows(n), matrix_cols(f)), jnp.uint8)
+    i32 = sds((), jnp.int32)
+    jax.jit(functools.partial(
+        partition_segment, blk=512, use_lut_path=False)).lower(
+        mat, mat, *([i32] * 9), sds((1, 256), jnp.float32)).compile()
+
+
+def test_feature_blocked_scan_compiles_for_v5e(one_chip):
+    """The scan of both children at 2,000 x 256."""
+    from lightgbm_tpu.ops.split_scan_pallas import _scan_call
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    f, b = EPSILON["f"], EPSILON["b"]
+    _, _, params = _scan_args(f=8)
+    plane = sds((2, f, b), jnp.float32)
+    jax.jit(jax.vmap(functools.partial(
+        _scan_call, params=params, interpret=False),
+        in_axes=(0, None, None, 0, 0, 0))).lower(
+        sds((2, 1, 5), jnp.float32), sds((f, 4), jnp.int32),
+        sds((f, 2), jnp.float32), plane, plane, plane).compile()
+
+
+@pytest.mark.parametrize("f,categorical", [(40, 10), (2000, 0)],
+                         ids=["expo-40-categorical", "epsilon-2000"])
+def test_histogram_cache_is_written_in_place_on_v5e(one_chip, monkeypatch,
+                                                    f, categorical):
+    """The per-phase body's two writes into the per-leaf histogram
+    cache ``[255, F, B, 3]`` (``lgbm.grow.splits.cache``), in the fused
+    block as compiled for the v5e under the chip's plan: two
+    ``dynamic-update-slice`` on the carried buffer and no copy of it.
+    Without the ``optimization_barrier`` before the writes the
+    sibling's subtraction fuses into them and the compiler copies the
+    whole cache a write (1.57 GB at 2,000 columns; PERF.md, PR 31)."""
+    import re
+
+    import lightgbm_tpu.learner.split_step as split_step
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data import Dataset
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
+    from lightgbm_tpu.models.gbdt import GBDT, _fused_iter_block
+
+    monkeypatch.setattr(split_step, "on_tpu", lambda: True)
+    rng = np.random.RandomState(0)
+    x = rng.randn(2048, f).astype(np.float32)
+    x[:, :categorical] = rng.randint(0, 200, (2048, categorical))
+    y = (x[:, 4] % 2 + x[:, 12] > 0.5).astype(np.float64)
+    cfg = Config.from_params({
+        "objective": "binary", "num_leaves": 255, "verbosity": -1})
+    ds = Dataset.from_numpy(x, cfg, label=y,
+                            categorical_features=list(range(categorical)))
+    b = GBDT(cfg, ds)
+    ln = PartitionedTreeLearner(ds, cfg, interpret=False)
+    assert ln.split_plan().body == "per_phase" and ln.cache_hists
+    sds = lambda a: jax.ShapeDtypeStruct(               # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    compiled = jax.jit(
+        functools.partial(_fused_iter_block, learner=ln,
+                          grad_fn=b._grad_fn, bag_fn=None,
+                          valid_data=(), k=1),
+        static_argnames=("m",)).lower(
+        sds(ln.mat), sds(ln.ws), sds(b.train_score), (),
+        sds(jnp.float32(0.1)), sds(jnp.int32(0)), m=2).compile()
+    cache = r"f32\[255,%d,%d,3\]" % (f, ln.num_bins_max)
+    text = compiled.as_text()
+    writes = re.findall(r"= %s\S* dynamic-update-slice\(" % cache, text)
+    copies = re.findall(r"= %s\S* copy\(" % cache, text)
+    assert len(writes) >= 2 and not copies, (len(writes), copies)
+    # temporaries hold the cache once, not a second copy of it
+    cache_bytes = 255 * f * ln.num_bins_max * 3 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.5 * cache_bytes + (64 << 20)

@@ -90,7 +90,7 @@ CHILD_SEGMENTS = [
 @pytest.mark.parametrize("f,b,blk", [(28, 255, 512), (67, 255, 512),
                                      (28, 256, 256)])
 def test_hist_child_stream_matches_scatter(f, b, blk, begin, count):
-    """``split_step_pallas.hist_child_stream`` through its thin wrapper
+    """``hist_pallas.hist_child_stream`` through its thin wrapper
     against ``ops/histogram.py``: rows before ``begin`` in its granule
     and rows past the count are masked through the payload, the tail
     block is short, neighbours' rows stay out."""
@@ -109,10 +109,11 @@ def test_hist_child_stream_matches_scatter(f, b, blk, begin, count):
 
 
 def test_histogram_wide_feature_slices(monkeypatch):
-    """F > MAX_NIBBLE_F dispatches one nibble call per feature slice
-    (Epsilon-shaped dense-wide data) — parity across the slice seams."""
+    """A table wider than the nibble kernel takes goes through the
+    sliced one-hot stream, whatever the cap (here 7, so a 19-column
+    table does): one slice, the payload in the slice's own lane tile."""
     import lightgbm_tpu.ops.hist_pallas as hp
-    monkeypatch.setattr(hp, "MAX_NIBBLE_F", 7)   # tiny cap -> 3 slices
+    monkeypatch.setattr(hp, "MAX_NIBBLE_F", 7)
     rng = np.random.RandomState(4)
     n, f, b = 800, 19, 32
     binned = rng.randint(0, b, (n, f)).astype(np.uint8)
@@ -125,6 +126,109 @@ def test_histogram_wide_feature_slices(monkeypatch):
     seg = hp.histogram_segment(mat, 13, 700, b, f, interpret=True)
     ref = np.asarray(histogram_scatter(
         jnp.asarray(binned[13:713]), ghc[13:713], b))
+    assert np.abs(ref - np.asarray(seg)).max() < 2e-3
+
+
+# ---- PR 31: a wide table's histogram, a column slice at a time -------
+# widths on and off the slice boundary (SLICE_F = 128): the narrowest
+# table past the nibble kernel (one slice), one whole slice, a slice
+# and one column, no multiple of the slice with the payload past a
+# tile boundary (300 + 13 columns end in the third tile), and the
+# Epsilon table's 2,000 (15 slices and 80 columns, the payload in the
+# last slice's own tile)
+WIDE_WIDTHS = [69, 128, 129, 300, 2000]
+
+
+@functools.lru_cache(maxsize=None)
+def _packed_wide(f, b=255, n=1300):
+    rng = np.random.RandomState(f)
+    binned = rng.randint(0, b, (n, f)).astype(np.uint8)
+    ghc = make_ghc(
+        jnp.asarray(rng.randn(n).astype(np.float32)),
+        jnp.asarray(np.abs(rng.randn(n)).astype(np.float32) + 0.1),
+        jnp.asarray((rng.rand(n) < 0.8).astype(np.float32)))
+    mat = pack_gh(build_matrix(jnp.asarray(binned)), f,
+                  ghc[:, 0], ghc[:, 1], ghc[:, 2])
+    return binned, ghc, mat
+
+
+@pytest.mark.parametrize("begin,count", [(0, 1300), (517, 700),
+                                         (1299, 1), (100, 0)],
+                         ids=["whole", "unaligned-ragged", "one-row",
+                              "no-row"])
+@pytest.mark.parametrize("f", WIDE_WIDTHS)
+def test_histogram_segment_slices_match_scatter(f, begin, count):
+    """``histogram_segment`` past ``MAX_NIBBLE_F`` against
+    ``ops/histogram.py``: every slice's columns land in their own rows
+    of the result, the columns past the table's width in the last
+    slice are cut off, neighbours' rows stay out."""
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    from lightgbm_tpu.ops.hist_pallas import MAX_NIBBLE_F, SLICE_F
+    assert f > MAX_NIBBLE_F
+    binned, ghc, mat = _packed_wide(f)
+    b = 255
+    tel = get_telemetry()
+    tel.ensure_ring()
+    slices0 = tel.counters.get("kernels.hist_feature_slices", 0)
+    seg = np.asarray(histogram_segment(mat, begin, count, b, f,
+                                       interpret=True))
+    assert tel.counters["kernels.hist_feature_slices"] - slices0 \
+        == -(-f // SLICE_F)
+    assert seg.shape == (f, b, 3)
+    if count:
+        ref = np.asarray(histogram_scatter(
+            jnp.asarray(binned[begin:begin + count]),
+            ghc[begin:begin + count], b))
+    else:
+        ref = np.zeros((f, b, 3), np.float32)
+    assert np.abs(ref - seg).max() < 2e-3
+    # counts are sums of 0/1: exact, so no neighbour's row and no
+    # other slice's column leaked in
+    np.testing.assert_array_equal(seg[..., 2], ref[..., 2])
+
+
+@pytest.mark.parametrize("blk,stream_blk", [(256, 256), (512, 512),
+                                            (2048, 512)])
+def test_the_sliced_streams_row_block_follows_the_matrix(monkeypatch, blk,
+                                                         stream_blk):
+    """``blk`` is the row block the matrix was padded for: the sliced
+    stream takes ``SLICE_BLK`` rows a block where that fits and ``blk``
+    where the matrix has less slack, so no window leaves the matrix;
+    the histogram is the same."""
+    import lightgbm_tpu.ops.hist_pallas as hp
+    f, b, n = 129, 255, 700
+    rng = np.random.RandomState(blk)
+    binned = rng.randint(0, b, (n, f)).astype(np.int32)
+    ghc = jnp.asarray(np.stack(
+        [rng.randn(n), rng.rand(n) + 0.1, np.ones(n)], 1).astype(np.float32))
+    mat = pack_gh(build_matrix(jnp.asarray(binned), blk), f,
+                  ghc[:, 0], ghc[:, 1], ghc[:, 2])
+    seen = []
+    plain = hp._histogram_segment_slices
+    monkeypatch.setattr(
+        hp, "_histogram_segment_slices",
+        lambda *a, **kw: seen.append(kw["blk"]) or plain(*a, **kw))
+    seg = np.asarray(hp.histogram_segment(mat, 3, 600, b, f, blk=blk,
+                                          interpret=True))
+    assert seen == [stream_blk]
+    ref = np.asarray(histogram_scatter(jnp.asarray(binned[3:603]),
+                                       ghc[3:603], b))
+    assert np.abs(ref - seg).max() < 2e-3
+    np.testing.assert_array_equal(seg[..., 2], ref[..., 2])
+
+
+@pytest.mark.parametrize("f", [28, 67, 68])
+def test_a_narrow_table_is_one_slice_of_the_nibble_kernel(f):
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    binned, ghc, mat, n, f, b = _packed(f, 255)
+    tel = get_telemetry()
+    tel.ensure_ring()
+    before = dict(tel.counters)
+    seg = histogram_segment(mat, 5, 1000, b, f, interpret=True)
+    assert tel.counters["kernels.hist_feature_slices"] \
+        - before.get("kernels.hist_feature_slices", 0) == 1
+    ref = np.asarray(histogram_scatter(
+        jnp.asarray(binned[5:1005]), ghc[5:1005], b))
     assert np.abs(ref - np.asarray(seg)).max() < 2e-3
 
 

@@ -68,13 +68,15 @@ def test_the_vocabulary_is_eight_scopes_and_four_spans():
     # the four parts of a tree's growth are children of lgbm.grow
     assert sum(n.startswith(scopes.GROW + ".")
                for n in scopes.DEVICE_SCOPES) == 4
-    # and the per-phase split body's four parts are a list of their
-    # own: the megakernel's body has none of them. Three are children
-    # of the grow loop's scope; the categorical scan belongs to ops/
-    # and is named after no learner's loop
-    assert len(set(scopes.SPLIT_PHASE_SCOPES)) == 4
+    # and the per-phase split body's five parts are a list of their
+    # own: the megakernel's body has none of them. Four are children
+    # of the grow loop's scope (the histogram cache's traffic among
+    # them, ISSUE 31); the categorical scan belongs to ops/ and is
+    # named after no learner's loop
+    assert len(set(scopes.SPLIT_PHASE_SCOPES)) == 5
     assert sum(n.startswith(scopes.GROW_SPLITS + ".")
-               for n in scopes.SPLIT_PHASE_SCOPES) == 3
+               for n in scopes.SPLIT_PHASE_SCOPES) == 4
+    assert scopes.SPLITS_CACHE == scopes.GROW_SPLITS + ".cache"
     assert scopes.CAT_SCAN == scopes.PREFIX + "cat_scan"
     assert not set(scopes.SPLIT_PHASE_SCOPES) & set(scopes.DEVICE_SCOPES)
 
@@ -150,7 +152,7 @@ def test_scope_table_of_the_fused_block(tel, bagging):
     assert tel.counters["jit.compiles"] == compiles
     assert progs[0].table_s is not None
     owned = collections.Counter(table.values())
-    # off a TPU the grow loop is the per-phase body, so its three
+    # off a TPU the grow loop is the per-phase body, so its four
     # numeric parts are there; no column is categorical
     reachable = set(scopes.DEVICE_SCOPES + scopes.SPLIT_PHASE_SCOPES) \
         - {scopes.CAT_SCAN}
@@ -237,19 +239,23 @@ def test_one_block_opens_its_three_spans_in_order(tel, monkeypatch):
 # and for the interpret-mode expansion of the partition kernel, which
 # ISSUE 28 pipelined (two input slots, a write in flight a side, a
 # fast and a merge branch per window: 13 more conditionals; on a TPU
-# the kernel is one Mosaic call either way)
+# the kernel is one Mosaic call either way), and for the per-leaf
+# histogram cache's write, which ISSUE 31 made two in-place row updates
+# in place of one scatter of the stacked pair (a scatter and a
+# concatenate went, two dynamic-update-slices and their index clamps
+# came; as many fusions as before)
 PARENT_OPCODES = {
-    "abs": 20, "add": 342, "and": 175, "bitcast": 603,
-    "bitcast-convert": 96, "broadcast": 659, "clamp": 2, "compare": 436,
-    "concatenate": 30, "conditional": 15, "constant": 857, "convert": 219,
-    "copy": 106, "divide": 17, "dot": 12, "dynamic-slice": 83,
-    "dynamic-update-slice": 67, "exponential": 1, "fusion": 335,
+    "abs": 20, "add": 345, "and": 175, "bitcast": 608,
+    "bitcast-convert": 98, "broadcast": 659, "clamp": 2, "compare": 441,
+    "concatenate": 29, "conditional": 15, "constant": 859, "convert": 219,
+    "copy": 108, "divide": 17, "dot": 12, "dynamic-slice": 85,
+    "dynamic-update-slice": 69, "exponential": 1, "fusion": 335,
     "gather": 9, "get-tuple-element": 338, "iota": 48, "is-finite": 4,
     "maximum": 25, "minimum": 16, "multiply": 193, "negate": 141, "not": 4,
-    "or": 44, "pad": 20, "parameter": 886, "reduce": 14,
-    "reduce-window": 8, "remainder": 11, "reverse": 2, "scatter": 3,
-    "select": 449, "shift-left": 33, "shift-right-logical": 47, "sign": 60,
-    "slice": 438, "sort": 1, "subtract": 118, "transpose": 11, "tuple": 41}
+    "or": 44, "pad": 20, "parameter": 889, "reduce": 14,
+    "reduce-window": 8, "remainder": 11, "reverse": 2, "scatter": 2,
+    "select": 454, "shift-left": 33, "shift-right-logical": 47, "sign": 60,
+    "slice": 444, "sort": 1, "subtract": 120, "transpose": 11, "tuple": 41}
 _OPCODE = re.compile(
     r"^\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(?:\([^=]*?\)|\S+)\s+([a-z\-]+)\(")
 
@@ -267,7 +273,7 @@ def test_named_scopes_leave_the_compiled_program_as_it_was():
     assert dict(got) == PARENT_OPCODES
     pinned = load_manifest()["programs"]["gbdt_fused_block"]
     assert got["fusion"] == pinned["fusions"]
-    # (the per-phase body: the three numeric split phases are named)
+    # (the per-phase body: the four numeric split phases are named)
     assert set(scopes.parse_hlo_scopes(text).values()) \
         == set(scopes.DEVICE_SCOPES + scopes.SPLIT_PHASE_SCOPES) \
         - {scopes.SAMPLE, scopes.CAT_SCAN}
@@ -356,6 +362,10 @@ def test_the_split_phases_own_the_per_phase_body(tel, categorical):
         == {scopes.SPLITS_PARTITION}
     assert _owners(text, table, in_loop, "histogram_segment") \
         == {scopes.SPLITS_HIST}
+    # the children's histograms go into the per-leaf cache under a
+    # scope of their own
+    assert _owners(text, table, in_loop, scopes.SPLITS_CACHE + "/") \
+        == {scopes.SPLITS_CACHE}
     assert _owners(text, table, scopes.GROW_ROOT + "/",
                    "histogram_segment") == {scopes.GROW_ROOT}
     phases = set(table.values()) & set(scopes.SPLIT_PHASE_SCOPES)

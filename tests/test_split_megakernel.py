@@ -265,7 +265,8 @@ def _plan(**over):
         has_categorical=over.pop("has_categorical", False),
         cegb_on=over.pop("cegb_on", False))
     kw = dict(mode="auto", params=params, bundled=False,
-              num_bins_max=255, num_leaves=255, forced_plan=(),
+              num_bins_max=255, num_leaves=255, num_features=28,
+              forced_plan=(),
               extra_trees=False, ff_bynode=1.0, cache_hists=True,
               mv_groups=0, serial_comm=True, interpret=False,
               has_megakernel=True, merged=True, tpu=True)
@@ -279,10 +280,30 @@ PLAN_ROWS = [
     # the three cells, as PERF.md section 4 says of them
     ("higgs-10m-train", dict(num_bins_max=255),
      (MEGA, True, False, False)),
-    ("criteo-7m-train", dict(num_bins_max=255),
+    ("criteo-7m-train", dict(num_bins_max=255, num_features=67),
      (MEGA, True, False, False)),
-    ("expo-10m-train", dict(has_categorical=True, num_bins_max=256),
+    ("expo-10m-train", dict(has_categorical=True, num_bins_max=256,
+                            num_features=40),
      (PHASE, False, True, True)),
+    # 2,000 columns: more than the megakernel's unrolled body takes,
+    # so the per-phase kernels, which cut their work by columns; the
+    # fifth field says the width alone decided
+    ("epsilon-400k-train", dict(num_features=2000),
+     (PHASE, True, False, False, True)),
+    ("widest-megakernel", dict(num_features=192),
+     (MEGA, True, False, False)),
+    ("narrowest-wide", dict(num_features=193),
+     (PHASE, True, False, False, True)),
+    # width is not the reason where something else refuses first, or
+    # off a TPU, or where the megakernel is forced on
+    ("wide-categorical", dict(num_features=2000, has_categorical=True,
+                              num_bins_max=256),
+     (PHASE, False, True, True)),
+    ("wide-cpu", dict(num_features=2000, tpu=False, interpret=True),
+     (PHASE, False, False, False)),
+    ("wide-on-cpu-twin", dict(num_features=2000, mode="on", tpu=False,
+                              interpret=True),
+     (MEGA, False, False, False)),
     ("bundled", dict(bundled=True), (PHASE, True, True, False)),
     ("257-bins", dict(num_bins_max=257), (PHASE, True, False, False)),
     ("forced-plan", dict(forced_plan=((0, 1, 3, False),)),
@@ -359,6 +380,15 @@ def test_cells_plans_from_real_learners(monkeypatch):
     assert expo.split_plan() == split_step.SplitStepPlan(
         PHASE, False, True, True)
     assert not expo.params.use_scan_kernel
+    # Epsilon: 2,000 numeric columns (every column kept: the learner's
+    # width is the table's)
+    x, y = _data(n=600, f=2000)
+    wide = PartitionedTreeLearner(Dataset.from_numpy(x, cfg, label=y),
+                                  cfg, interpret=False)
+    assert wide.num_groups == 2000
+    assert wide.split_plan() == split_step.SplitStepPlan(
+        PHASE, True, False, False, wide=True)
+    assert wide.params.use_scan_kernel
 
 
 def test_forced_splits_keep_foil_for_forced_steps(tmp_path):

@@ -214,7 +214,7 @@ def histogrammed_share(tree) -> float:
     """Rows histogrammed ÷ rows partitioned over one tree's splits,
     from the tree's own counts: each split partitions its parent and
     hands the histogram stream its smaller child's segment
-    (``split_step_pallas.hist_child_stream``; the megakernel has no
+    (``hist_pallas.hist_child_stream``; the megakernel has no
     spare output word to count the rows itself). 0.28-0.35 on the
     benchmark's tables, never above 0.5."""
     import numpy as np

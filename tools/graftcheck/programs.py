@@ -491,12 +491,24 @@ def _b_hist_segment_nibble():
         return PartitionedTreeLearner(
             Dataset.from_numpy(x, cfg, label=y), cfg)
     lrn = _env("partitioned_learner_nibble", make)
-    from lightgbm_tpu.ops.hist_pallas import MAX_NIBBLE_F
     return _spec_fn("hist_segment_nibble").lower(
         lrn.mat, jnp.int32(0), jnp.int32(lrn.num_data),
         num_features=lrn.num_groups, num_bins=lrn.num_bins_max,
-        nibble_cap=MAX_NIBBLE_F, blk=HIST_BLK,
-        interpret=True)
+        blk=HIST_BLK, interpret=True)
+
+
+@builder("hist_segment_slices")
+def _b_hist_segment_slices():
+    """The wide table's histogram (one call, the column slice a grid
+    axis) on the same learner's matrix: one slice there."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.hist_pallas import SLICE_BLK
+    _b_hist_segment_nibble()        # builds the shared learner
+    lrn = _ENV["partitioned_learner_nibble"]
+    return _spec_fn("hist_segment_slices").lower(
+        lrn.mat, jnp.int32(0), jnp.int32(lrn.num_data),
+        num_features=lrn.num_groups, num_bins=lrn.num_bins_max,
+        blk=SLICE_BLK, interpret=True)
 
 
 def _partition_args(blk: int):
