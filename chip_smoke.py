@@ -238,12 +238,13 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "wide_table": "on" if delta["learner.wide_table_traces"]
         else "off",
         # column slices over the histogram calls traced (1 a call
-        # where the nibble kernel takes the whole width)
+        # where the stream takes whole rows: up to MAX_FUSED_F columns)
         "hist_feature_slices": delta["kernels.hist_feature_slices"],
         # kernel traces that took partition_pallas.partition_stream
         "partition_pipelined": delta["kernels.partition_pipelined"],
-        # megakernel traces whose histogram is the second, short
-        # stream over the smaller child's segment
+        # kernel traces through hist_pallas.hist_child_stream, the
+        # one histogram form: the root's and a leaf segment's program,
+        # the sliced one, the megakernel's second stream
         "hist_child_stream": delta["kernels.hist_child_stream"],
         "fused_block_hits": delta["fused.block_hits"],
         "trees": len(leaves),
@@ -275,11 +276,11 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     # every compiled partition, the megakernel's phase 0 included, is
     # the pipelined stream (the megakernel's interpret twin has none)
     assert report["partition_pipelined"] > 0 or interpret, report
-    # the compiled megakernel histograms through the child stream (the
-    # per-phase body and the twin call ``histogram_segment``, which
-    # takes the same stream a column slice at a time on a wide table)
-    assert report["hist_child_stream"] > 0 or not megakernel \
-        or interpret, report
+    # every histogram of every compiled route is the one-hot stream:
+    # the root's and the per-phase body's ``histogram_segment``, the
+    # megakernel's phase 0 (in interpret mode the count is 0 where the
+    # process had traced the same shapes before)
+    assert report["hist_child_stream"] > 0 or interpret, report
     # a TPU's plan alone refuses for width: off one, ``auto`` never
     # picks the megakernel
     assert report["wide_table"] == (

@@ -49,8 +49,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.hist_pallas import MAX_FUSED_F
 from ..ops.split import MAX_CAT_WORDS
-from ..ops.split_step_pallas import MAX_FUSED_F
 from ..utils import LightGBMError
 from ..utils.device import on_tpu
 
@@ -147,7 +147,8 @@ def plan_split_step(*, mode: str, params, bundled: bool,
     elif mode == "auto":
         # the compiled body's static scope; its per-feature loops are
         # unrolled, so a wide table keeps the per-phase kernels, which
-        # cut their work by columns (ops/hist_pallas.py SLICE_F,
+        # cut their work by columns past the same bound
+        # (ops/hist_pallas.py MAX_FUSED_F and SLICE_F,
         # ops/split_scan_pallas.py SCAN_BLOCK_F)
         in_scope = (eligible and tpu and not forced_plan
                     and not has_cat and not bundled

@@ -4,14 +4,16 @@ Reference analog: the OpenCL histogram kernels
 (``src/treelearner/ocl/histogram256.cl``) + ``DenseBin::
 ConstructHistogramInner`` (dense_bin.hpp:76-105). The GPU reference
 scatter-adds into workgroup-local memory with float atomics; TPUs have
-no scatter-add, so the kernel is reformulated for the MXU: per bin b,
+no scatter-add, so the kernel is reformulated for the MXU: per block
+of rows and per feature f,
 
-    hist[b] += lhs[win, 8]^T @ (mat == b)[win, C]
+    hist[f] += pay[win, 8]^T @ (mat[:, f] == bins)[win, B128]
 
-one bf16 matmul whose one-hot factor is exact and whose gh operand is a
-bf16 hi/lo pair summing to the f32 value — full f32 fidelity on the
-bf16 datapath (the reference's ``gpu_use_dp`` story one level up,
-gpu_tree_learner.cpp:299).
+one bf16 matmul whose one-hot factor (built on the VPU from the bin
+byte) is exact and whose payload operand holds grad and hess as bf16
+hi/lo pairs summing to the f32 value, accumulated in f32: full f32
+fidelity on the bf16 datapath (the reference's ``gpu_use_dp`` story
+one level up, gpu_tree_learner.cpp:299).
 
 **Single training-matrix layout.** Everything a tree build touches
 rides in ONE row-major uint8 matrix (the TPU analog of the reference
@@ -34,16 +36,16 @@ The segment [begin, begin+count) is DYNAMIC — per-leaf cost is
 O(leaf rows), not O(N) (the point of partitioned layout; LightGBM
 scans only the leaf's rows via DataPartition, data_partition.hpp:161).
 DMA windows start at the 8-aligned floor of `begin` (Mosaic granule
-for u8 rows); the in-window shift is masked via the gh operand, so no
-dynamic VMEM slicing is needed anywhere.
+for u8 rows); the in-window shift is masked via the payload operand,
+so no dynamic VMEM slicing is needed anywhere.
 
-**Two forms, picked by the table's width alone** (``histogram_segment``).
-Up to ``MAX_NIBBLE_F`` columns the grouped nibble kernel above streams
-whole rows. Past it the kernel cannot be compiled for the chip, and
-the plain one-hot stream (``hist_child_stream``: per feature a
-[rows, 256] one-hot and one matmul, the form the split-step
-megakernel runs over the smaller child) takes the table ``SLICE_F``
-columns at a time: one ``pallas_call`` whose grid axis is the slice.
+**One form** (``hist_child_stream``), whoever asks: the root's
+histogram, a leaf segment's (``histogram_segment``) and the smaller
+child's inside the split-step megakernel are the same block stream.
+The table's width only says whether it runs over whole rows (up to
+``MAX_FUSED_F`` columns: the body unrolls over exactly ``F`` features)
+or a ``SLICE_F``-column slice at a time (past it: one ``pallas_call``
+whose grid axis is the slice, one body for every slice).
 """
 
 from __future__ import annotations
@@ -62,15 +64,6 @@ from ..utils.jit_registry import register_jit
 ALIGN = 8          # Mosaic offset granule for u8 2-D row slices
 GH_COLS = 13       # payload columns appended after the features
 RID_OFF = 9        # row-id bytes start at column F + RID_OFF
-
-# Mosaic's default scoped-VMEM budget is 16 MB; the nibble kernel's
-# statically-unrolled group loop stacks ~34 MB of block intermediates
-# at blk=2048 (measured on v5e: "scoped allocation with size 33.93M").
-# v5e has 128 MB of VMEM — raise the ceiling rather than shrink the
-# block (smaller blocks double the DMA count per row).
-VMEM_LIMIT = 100 * 1024 * 1024
-_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
-
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
@@ -132,34 +125,18 @@ def extract_row_ids(mat, num_features: int, n: int) -> jnp.ndarray:
         jnp.int32)
 
 
-LO = 8             # low-nibble size (bin = hi * LO + lo)
-PAY = 5            # payload planes: g_hi, g_lo, h_hi, h_lo, cnt
-GRP = 3            # features per MXU tile of the nibble kernel
-# widest table the nibble kernel takes: its group loop is unrolled
-# and the chip's compiler keeps every group's block intermediates on
-# the VMEM stack. At blk=2048, compiled for the v5e: 67 and 68 columns
-# pass, 69 need 115.72 MB of the 100 MB scoped limit, 96 need 137 MB
-# of the chip's 128 (PERF.md section 6, PR 31). Wider tables take
-# ``hist_child_stream`` a column slice at a time.
-MAX_NIBBLE_F = 68
+# the widest table whose whole rows go through ONE per-feature
+# unrolled body: the histogram stream below and the split-step
+# megakernel that holds it (ops/split_step_pallas.py refuses a wider
+# table, ``plan_split_step`` calls it ``wide``). Past it the histogram
+# is cut into column slices.
+MAX_FUSED_F = 192
 
-# a wide table's histogram is cut into slices of this many columns:
+# a wider table's histogram is cut into slices of this many columns:
 # one lane tile of the u8 matrix, so a slice's DMA starts on a tile
 # boundary whatever its index
 SLICE_F = 128
-SLICE_BLK = 512    # row block of the sliced stream (the megakernel's)
-
-
-def _payload_lanes(g_hi, g_lo, h_hi, h_lo, cnt, lhs_p):
-    """Route the 5 payload planes into their (.., p) lane pattern —
-    the pattern repeats per lo/feature, so one build serves every mask
-    tile of the block."""
-    pay = [g_hi.astype(jnp.float32), g_lo.astype(jnp.float32),
-           h_hi.astype(jnp.float32), h_lo.astype(jnp.float32), cnt]
-    pay_b = pay[PAY - 1]
-    for p in range(PAY - 2, -1, -1):
-        pay_b = jnp.where(lhs_p == p, pay[p], pay_b)
-    return pay_b
+SLICE_BLK = 512    # row block of the stream (the megakernel's)
 
 
 def _decode_block(mat_i32, feat0: int, shift, rem, win: int):
@@ -192,140 +169,6 @@ def _decode_block(mat_i32, feat0: int, shift, rem, win: int):
     return valid, g_hi, g_lo, h_hi, h_lo, cnt
 
 
-def _hist_nibble_kernel_grouped(scal_ref,  # SMEM [2] (begin, count)
-                                mat_hbm,   # ANY [N_pad, C] u8
-                                out_ref,   # VMEM [NG, 120, GRP*H] f32
-                                buf, sems,
-                                *, blk: int, feat0: int,
-                                ngroups: int, hi_n: int):
-    """Hierarchical (hi/lo nibble) histogram build: ``bin = hi*LO +
-    lo``, and per group of GRP features,
-
-        out[(f, lo, p), (f', hi)] += lhs[win, GRP*LO*PAY]^T
-                                     @ rhs[win, GRP*H]
-
-    diagonal f == f' blocks are the histogram; cross-feature products
-    land in otherwise-idle MXU lanes and are discarded. lo/hi are
-    precomputed FULL-WIDTH once per block (3 VPU ops for all features)
-    and routed into mask lanes with two selects per group: VPU op cost
-    scales with op COUNT x sublanes, not lanes, so packing 3 features'
-    masks into one ~full-width tile amortizes each compare/select
-    across 3 features (~10 ops per group per block). Payload stays
-    exact: lhs entries are the bf16 hi/lo halves of the f32 grad/hess,
-    accumulated in f32.
-    """
-    begin = scal_ref[0]
-    count = scal_ref[1]
-    nblk = pl.cdiv(count, blk)
-    base = (begin // ALIGN) * ALIGN
-    shift = begin - base
-    win = blk + ALIGN
-
-    m_lhs = GRP * LO * PAY                           # 120
-    n_rhs = GRP * hi_n
-
-    def read(slot, i):
-        s = pl.multiple_of(base + i * blk, ALIGN)
-        return pltpu.make_async_copy(mat_hbm.at[pl.ds(s, win), :],
-                                     buf.at[slot], sems.at[slot])
-
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    lane_l = jax.lax.broadcasted_iota(jnp.int32, (1, m_lhs), 1)
-    lhs_f = lane_l // (LO * PAY)                     # feature-in-group
-    lhs_lo = (lane_l % (LO * PAY)) // PAY            # lo value
-    lhs_p = lane_l % PAY                             # payload plane
-    lane_r = jax.lax.broadcasted_iota(jnp.int32, (1, n_rhs), 1)
-    rhs_f = lane_r // hi_n
-    rhs_hi = lane_r % hi_n
-
-    @pl.when(nblk > 0)
-    def _():
-        read(0, 0).start()
-
-    def block_body(i, _):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < nblk)
-        def _():
-            read(1 - slot, i + 1).start()
-
-        read(slot, i).wait()
-        mat_i32 = buf[slot].astype(jnp.int32)        # [win, C]
-        # full-width nibble split ONCE for every feature column
-        mat_hi = mat_i32 // LO                       # [win, C]
-        mat_lo = mat_i32 - mat_hi * LO
-
-        rem = jnp.minimum(count - i * blk, blk)
-        _, g_hi, g_lo, h_hi, h_lo, cnt = _decode_block(
-            mat_i32, feat0, shift, rem, win)
-        pay_b = _payload_lanes(g_hi, g_lo, h_hi, h_lo, cnt,
-                               lhs_p)                # [win, m_lhs]
-
-        for gidx in range(ngroups):
-            # tail group clamps past-the-end columns onto the last
-            # feature; garbage lanes are sliced off in the epilogue
-            def fcol(m, j):
-                c = min(gidx * GRP + j, feat0 - 1)
-                return m[:, c:c + 1]                 # [win, 1]
-
-            def pick3(m, fl):
-                x = jnp.where(fl == 1, fcol(m, 1), fcol(m, 0))
-                return jnp.where(fl == 2, fcol(m, 2), x)
-
-            binlo = pick3(mat_lo, lhs_f)             # [win, m_lhs]
-            lhs = jnp.where(binlo == lhs_lo, pay_b,
-                            0.0).astype(jnp.bfloat16)
-            binhi = pick3(mat_hi, rhs_f)             # [win, n_rhs]
-            rhs = jnp.where(binhi == rhs_hi, jnp.float32(1),
-                            jnp.float32(0)).astype(jnp.bfloat16)
-            out_ref[gidx] += jax.lax.dot_general(
-                lhs, rhs, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [m_lhs, n_rhs]
-        return 0
-
-    jax.lax.fori_loop(0, nblk, block_body, 0)
-
-
-@register_jit("hist_segment_nibble")
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_features", "num_bins", "blk", "interpret"))
-def _histogram_segment_nibble(mat, begin, count, *, num_features: int,
-                              num_bins: int, blk: int = 2048,
-                              interpret: bool = False):
-    """Nibble-kernel call -> [F, B, 3] histogram."""
-    if blk % ALIGN:
-        raise ValueError(f"blk must be a multiple of {ALIGN}, got {blk}")
-    _, cols = mat.shape
-    f = num_features
-    hi_n = -(-num_bins // LO)                        # ceil(B / LO)
-    ngroups = -(-f // GRP)
-    raw = pl.pallas_call(
-        functools.partial(_hist_nibble_kernel_grouped, blk=blk,
-                          feat0=f, ngroups=ngroups, hi_n=hi_n),
-        out_shape=jax.ShapeDtypeStruct(
-            (ngroups, GRP * LO * PAY, GRP * hi_n), jnp.float32),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, blk + ALIGN, cols), jnp.uint8),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-    )(_segment_scalars(begin, count), mat)
-    # [NG, (fl,lo,p), (fr,hi)] -> diagonal fl == fr -> [F, B, P]
-    raw = raw.reshape(ngroups, GRP, LO, PAY, GRP, hi_n)
-    diag = jnp.einsum("gjlpjh->gjhlp", raw)          # [NG,GRP,H,LO,P]
-    hist = diag.reshape(ngroups * GRP, hi_n * LO, PAY)[:f, :num_bins]
-    return _sum_planes(hist[..., 0], hist[..., 1], hist[..., 2],
-                       hist[..., 3], hist[..., 4])
-
-
 def _segment_scalars(begin, count):
     return jnp.stack([jnp.asarray(begin, jnp.int32),
                       jnp.asarray(count, jnp.int32)])
@@ -341,11 +184,11 @@ def hist_child_stream(mat_hbm, buf, sems, hpl, begin, count, *,
     """The plain one-hot histogram: a pipelined block stream over the
     rows ``mat_hbm[begin, begin+count)`` alone, accumulated into the
     five ``hpl`` planes ``[5, F8, B128]`` f32 (g hi, g lo, h hi, h lo,
-    count; zeroed here first). Phase 0 of the split-step megakernel
-    (ops/split_step_pallas.py) runs it after ``partition_stream`` has
-    returned, on the smaller child's compact segment, and a table too
-    wide for the nibble kernel runs it a column slice at a time
-    (``_histogram_segment_slices``).
+    count; zeroed here first). Every histogram of a partitioned
+    learner is this body: ``histogram_segment`` runs it over the root
+    and over a leaf's segment, and phase 0 of the split-step megakernel
+    (ops/split_step_pallas.py) after ``partition_stream`` has returned,
+    on the smaller child's compact segment.
 
     ``col0`` None: whole rows are streamed (``buf`` [2, blk+8, C] u8,
     ``sems`` two DMA semaphores or more) and columns ``[0, f)``
@@ -432,6 +275,38 @@ def hist_child_stream(mat_hbm, buf, sems, hpl, begin, count, *,
     jax.lax.fori_loop(0, nblk, block_body, 0)
 
 
+def _hist_rows_kernel(scal_ref, mat_hbm, hpl, buf, sems, *, f, blk):
+    hist_child_stream(mat_hbm, buf, sems, hpl, scal_ref[0], scal_ref[1],
+                      f=f, blk=blk)
+
+
+@register_jit("hist_child_stream")
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_features", "num_bins", "blk", "interpret"))
+def histogram_child_stream(mat, begin, count, *, num_features: int,
+                           num_bins: int, blk: int = SLICE_BLK,
+                           interpret: bool = False):
+    """``hist_child_stream`` over whole rows, as ``partition_segment``
+    wraps ``partition_stream`` -> [F, B, 3]: the histogram of a table
+    of at most ``MAX_FUSED_F`` columns, exactly ``F`` of them
+    histogrammed."""
+    f = num_features
+    fp, bp = _round_up(f, 8), _round_up(num_bins, 128)
+    planes = pl.pallas_call(
+        functools.partial(_hist_rows_kernel, f=f, blk=blk),
+        out_shape=jax.ShapeDtypeStruct((5, fp, bp), jnp.float32),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, blk + ALIGN, mat.shape[1]), jnp.uint8),
+            pltpu.SemaphoreType.DMA((2,))],
+        interpret=interpret,
+    )(_segment_scalars(begin, count), mat)[:, :f, :num_bins]
+    return _sum_planes(*planes)
+
+
 def _hist_slices_kernel(scal_ref, mat_hbm, hpl, buf, sems, *, f, blk):
     # one grid step a column slice; ``hpl`` is the slice's block of
     # the output planes
@@ -447,13 +322,13 @@ def _hist_slices_kernel(scal_ref, mat_hbm, hpl, buf, sems, *, f, blk):
 def _histogram_segment_slices(mat, begin, count, *, num_features: int,
                               num_bins: int, blk: int = SLICE_BLK,
                               interpret: bool = False):
-    """A wide table's histogram -> [F, B, 3]: ONE ``pallas_call`` whose
-    grid axis is the column slice (``SLICE_F`` columns each), one
-    Mosaic body for every slice. A slice streams its own columns and
-    the payload's lane tile, not the whole row; the last slice runs
-    past ``F`` into the payload and padding columns, which are cut off
-    here (``matrix_cols`` rounds the row up to whole tiles, so the
-    slice is always inside the matrix)."""
+    """The histogram of a table past ``MAX_FUSED_F`` columns ->
+    [F, B, 3]: ONE ``pallas_call`` whose grid axis is the column slice
+    (``SLICE_F`` columns each), one Mosaic body for every slice. A
+    slice streams its own columns and the payload's lane tile, not the
+    whole row; the last slice runs past ``F`` into the payload and
+    padding columns, which are cut off here (``matrix_cols`` rounds the
+    row up to whole tiles, so the slice is always inside the matrix)."""
     f, cols = num_features, mat.shape[1]
     n_slices = -(-f // SLICE_F)
     bp = _round_up(num_bins, 128)
@@ -469,7 +344,6 @@ def _histogram_segment_slices(mat, begin, count, *, num_features: int,
         scratch_shapes=[
             pltpu.VMEM((2, blk + ALIGN, SLICE_F + pay_cols), jnp.uint8),
             pltpu.SemaphoreType.DMA((4,))],
-        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(_segment_scalars(begin, count), mat)[:, :f, :num_bins]
     return _sum_planes(*planes)
@@ -478,27 +352,22 @@ def _histogram_segment_slices(mat, begin, count, *, num_features: int,
 def histogram_segment(mat, begin, count, num_bins: int, num_features: int,
                       blk: int = 2048,
                       interpret: bool = False) -> jnp.ndarray:
-    """Histogram of rows [begin, begin+count) -> [F, B, 3] f32: by the
-    nibble kernel up to ``MAX_NIBBLE_F`` columns, by the plain one-hot
-    stream a ``SLICE_F``-column slice at a time above. The table's
-    width alone decides. ``blk`` is the row block the matrix was padded
-    for (``matrix_rows``): the nibble kernel's block, and the most the
-    stream's may be (``SLICE_BLK`` where ``blk`` allows it, so that a
-    window never leaves the matrix). ``ops/histogram.py`` is the
-    reference the tests compare both with."""
+    """Histogram of rows [begin, begin+count) -> [F, B, 3] f32 by the
+    one-hot stream: over whole rows up to ``MAX_FUSED_F`` columns, a
+    ``SLICE_F``-column slice at a time past it. The table's width alone
+    decides. ``blk`` is the row block the matrix was padded for
+    (``matrix_rows``), the most the stream's may be (``SLICE_BLK``
+    where ``blk`` allows it, so that a window never leaves the matrix).
+    ``ops/histogram.py`` is the reference the tests compare it with."""
     f = num_features
-    wide = f > MAX_NIBBLE_F
+    sliced = f > MAX_FUSED_F
     # counted where a histogram call is traced: the column slices it
     # is cut into
     get_telemetry().count("kernels.hist_feature_slices",
-                          -(-f // SLICE_F) if wide else 1)
-    if wide:
-        return _histogram_segment_slices(
-            mat, begin, count, num_features=f, num_bins=num_bins,
-            blk=min(blk, SLICE_BLK), interpret=interpret)
-    return _histogram_segment_nibble(
-        mat, begin, count, num_features=f, num_bins=num_bins, blk=blk,
-        interpret=interpret)
+                          -(-f // SLICE_F) if sliced else 1)
+    stream = _histogram_segment_slices if sliced else histogram_child_stream
+    return stream(mat, begin, count, num_features=f, num_bins=num_bins,
+                  blk=min(blk, SLICE_BLK), interpret=interpret)
 
 
 def histogram_pallas(binned, ghc, num_bins: int, blk: int = 2048,

@@ -442,10 +442,9 @@ def partition_segment(mat, ws, begin, count, feat, thr, default_left,
         scratch_shapes=stream_scratch(blk, cols),
         input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
-        # raise the scoped-VMEM ceiling like the histogram kernels
-        # (hist_pallas.VMEM_LIMIT): block intermediates beyond the
-        # declared scratch live on the Mosaic stack, and the default
-        # 16 MB budget OOMed the hist kernel's first v5e compile
+        # raise the scoped-VMEM ceiling (v5e has 128 MB): block
+        # intermediates beyond the declared scratch live on the Mosaic
+        # stack, which the default 16 MB budget may not hold
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
             vmem_limit_bytes=100 * 1024 * 1024),

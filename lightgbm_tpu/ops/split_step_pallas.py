@@ -42,7 +42,8 @@ contiguous segment. Two bodies sit behind the one wrapper:
   ``ops/split.py`` semantics (categorical + monotone paths).
 
 This module holds kernels, their wrappers and the limits that are
-facts about the kernel (``MAX_FUSED_F``, ``FUSED_BLK``, ``SEG_BLK``).
+facts about the kernel (``FUSED_BLK``, ``SEG_BLK``; the width it takes
+is ``hist_pallas.MAX_FUSED_F``, the unrolled histogram stream's).
 It does not know its caller: the packed carry (``StatePack``) and the
 comm arrive as arguments, and whether the kernel runs at all is
 ``learner/split_step.py`` ``plan_split_step``'s decision.
@@ -60,8 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.jit_registry import register_jit
-from .hist_pallas import (_segment_scalars, _sum_planes,
-                          hist_child_stream)
+from .hist_pallas import MAX_FUSED_F, hist_child_stream
 from .split import (MISSING_NAN_CODE, MISSING_ZERO_CODE, FeatureMeta,
                     child_columns, child_constraints,
                     child_constraints_mono, kEpsilon, make_scan_leaf,
@@ -231,7 +231,7 @@ def _segment_kernel_ref(iscal, s_in, t_in, mat_in, ws_in, hist_in,
     ref-loaded values. The stable partition is computed as an exact
     prefix-sum permutation (bit-identical row content to
     ``partition_segment``); the smaller child's histogram reuses the
-    SAME interpret-mode nibble kernel the foil streams
+    SAME interpret-mode histogram stream the foil runs
     (``hist_pallas.histogram_segment``), so the float accumulation
     order — and therefore the model — is bit-identical."""
     del s_in, t_in, mat_in, ws_in, hist_in
@@ -288,7 +288,7 @@ def _segment_kernel_ref(iscal, s_in, t_in, mat_in, ws_in, hist_in,
         nr = cnt - nl
 
         # ---- smaller-child segment histogram + subtraction ----------
-        # the SAME interpret nibble kernel the foil streams — nested
+        # the SAME interpret histogram stream the foil runs — nested
         # pallas_call, bit-identical block accumulation order
         small_is_left = lc <= rc
         sb = jnp.where(small_is_left, begin, begin + nl)
@@ -509,9 +509,6 @@ def lower_for_tpu(pack, *, big_l: int) -> None:
 # per-leaf histogram cache rides CHANNELS-MAJOR [L, 3, F, B] on the
 # compiled path so every plane is a static-leading-index slice), bool
 # vectors only as compare->select intermediates.
-
-MAX_FUSED_F = 192      # static per-feature unroll cap (program size)
-
 
 def _iota_f32(shape, dim):
     """f32 index grid. Mosaic's ``tpu.iota`` yields integer vectors
@@ -820,37 +817,6 @@ def _leaf_site_scalars(pack, iscal, s_in, imeta_ref, big_l):
     nbins_f = _imeta_col_f(imeta_ref, IM_NBINS, fio, feat_f)
     return leaf, k, sm, feat_f, thr_f, dleft_f, miss_f, defbin_f, \
         nbins_f
-
-
-def _child_stream_kernel(scal_ref, mat_hbm, hpl, buf, sems, *, f, blk):
-    hist_child_stream(mat_hbm, buf, sems, hpl, scal_ref[0], scal_ref[1],
-                      f=f, blk=blk)
-
-
-def histogram_child_stream(mat, begin, count, *, num_bins: int,
-                           num_features: int, blk: int = SEG_BLK,
-                           interpret: bool = False):
-    """``hist_child_stream`` alone, as ``partition_segment`` wraps
-    ``partition_stream``: histogram of rows [begin, begin+count) ->
-    [F, B, 3] f32. What the CPU tests and the on-chip checks compare
-    with ``ops/histogram.py``; no learner calls it, so it is no
-    registered program."""
-    f, b = num_features, num_bins
-    fp, bp = -(-f // 8) * 8, -(-b // 128) * 128
-    planes = pl.pallas_call(  # graftlint: allow[GL506]
-        functools.partial(_child_stream_kernel, f=f, blk=blk),
-        out_shape=jax.ShapeDtypeStruct((5, fp, bp), jnp.float32),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, blk + ALIGN, mat.shape[1]), jnp.uint8),
-            pltpu.SemaphoreType.DMA((2,))],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT),
-        interpret=interpret,
-    )(_segment_scalars(begin, count), mat)[:, :f, :b]
-    return _sum_planes(*planes)
 
 
 def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,

@@ -39,9 +39,12 @@ def _lowers(fn, *args):
     jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
 
 
-def test_histogram_kernel_lowers_for_tpu():
+@pytest.mark.parametrize("f", [28, 69, 192])
+def test_histogram_kernel_lowers_for_tpu(f):
+    """The whole-row one-hot stream: the Higgs width, the narrowest
+    table that used to be sliced, the widest that is not."""
     from lightgbm_tpu.ops.hist_pallas import histogram_segment
-    f, b = 28, 256
+    b = 256
     mat = _mat(f=f, b=b)
     _lowers(functools.partial(histogram_segment, num_bins=b,
                               num_features=f, interpret=False),
@@ -314,12 +317,13 @@ def test_a_column_wider_than_a_byte_raises():
         == 256
 
 
-@pytest.mark.parametrize("f", [69, 250, 2000])
+@pytest.mark.parametrize("f", [193, 250, 2000])
 def test_histogram_wide_slices_lower_for_tpu(f):
     """The sliced one-hot stream (one ``pallas_call``, the column
     slice a grid axis, two-region DMA: the slice's columns and the
-    payload's lane tile) lowers for TPU: the narrowest table past the
-    nibble kernel, two slices, the Epsilon table's sixteen."""
+    payload's lane tile) lowers for TPU: the narrowest table past
+    ``MAX_FUSED_F`` and 250 columns (two slices each), the Epsilon
+    table's sixteen."""
     from lightgbm_tpu.ops.hist_pallas import histogram_segment
     b = 256
     mat = _mat(n=2048, f=f, b=b)
@@ -483,19 +487,32 @@ def test_pipelined_megakernel_compiles_for_v5e(one_chip, f, n):
         sds((f, 8), jnp.int32), sds((f, 2), jnp.float32)).compile()
 
 
-@pytest.mark.parametrize("f", [28, 67])
-def test_hist_child_stream_compiles_for_v5e(one_chip, f):
-    """The histogram stream alone (the checks' thin wrapper) at the
-    megakernel's block size over a 1 M-row matrix, both cells' widths:
-    what step 0 of PR 30 timed."""
-    from lightgbm_tpu.ops.split_step_pallas import (SEG_BLK,
-                                                    histogram_child_stream)
+@pytest.mark.parametrize("f,n", [
+    pytest.param(28, 10_500_000, id="higgs-10m"),
+    pytest.param(67, 7_000_000, id="criteo-7m"),
+    pytest.param(40, 10_000_000, id="expo-10m"),
+    pytest.param(69, 1_000_000, id="69-columns"),
+    pytest.param(128, 1_000_000, id="128-columns"),
+    pytest.param(192, 1_000_000, id="192-columns")])
+def test_whole_row_histogram_compiles_for_v5e(one_chip, f, n):
+    """``histogram_segment`` up to ``MAX_FUSED_F`` columns, the
+    whole-row one-hot stream (the root's histogram and a leaf
+    segment's), on the learner's matrix: the three narrow cells'
+    shapes; 69 columns, where the deleted nibble kernel ran out of
+    scoped VMEM; one whole lane tile of bins; and the bound itself, the
+    longest unrolled body."""
+    from lightgbm_tpu.learner.partitioned import HIST_BLK
+    from lightgbm_tpu.ops.hist_pallas import (MAX_FUSED_F,
+                                              histogram_segment,
+                                              matrix_cols, matrix_rows)
+    assert f <= MAX_FUSED_F
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     i32 = sds((), jnp.int32)
     jax.jit(functools.partial(
-        histogram_child_stream, num_bins=256, num_features=f,
-        blk=SEG_BLK)).lower(
-        sds((1_003_528, 128), jnp.uint8), i32, i32).compile()
+        histogram_segment, num_bins=256, num_features=f,
+        blk=HIST_BLK)).lower(
+        sds((matrix_rows(n, HIST_BLK), matrix_cols(f)), jnp.uint8),
+        i32, i32).compile()
 
 
 # ---- PR 31: the wide table's kernels at the Epsilon cell's shape ------
@@ -514,30 +531,6 @@ def test_sliced_histogram_compiles_for_v5e(one_chip):
         histogram_segment, num_bins=b, num_features=f)).lower(
         sds((matrix_rows(n), matrix_cols(f)), jnp.uint8), i32,
         i32).compile()
-
-
-@pytest.mark.parametrize("f,passes", [
-    pytest.param(68, True, marks=pytest.mark.slow),    # 48 s here
-    (69, False)])
-def test_the_nibble_kernels_widest_table_on_v5e(one_chip, f, passes):
-    """What ``MAX_NIBBLE_F`` states: at the learner's block of 2,048
-    rows the chip's compiler takes the nibble kernel at 68 columns and
-    refuses it at 69 (its unrolled groups' intermediates pass the
-    scoped VMEM limit), which is why a wider table is sliced."""
-    from lightgbm_tpu.ops import hist_pallas as hp
-    assert hp.MAX_NIBBLE_F == 68
-    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    i32 = sds((), jnp.int32)
-    lowered = jax.jit(functools.partial(
-        hp._histogram_segment_nibble, num_bins=256, num_features=f,
-        blk=2048)).lower(
-        sds((hp.matrix_rows(100_000), hp.matrix_cols(f)), jnp.uint8),
-        i32, i32)
-    if passes:
-        lowered.compile()
-    else:
-        with pytest.raises(Exception, match="vmem"):
-            lowered.compile()
 
 
 def test_wide_partition_compiles_for_v5e(one_chip):

@@ -37,36 +37,103 @@ def packed():
     return _packed()
 
 
-# the cells' widths: 12 x 64 is the small fixture; 28 (Higgs), 67
-# (Criteo) and 40 (Expo) leave a ragged last group of three features,
-# and 255 / 256 bins fill the high nibble
-@pytest.mark.parametrize("begin,count", [(0, 3000), (517, 1234),
-                                         (2999, 1), (100, 0)])
-@pytest.mark.parametrize("f,b", [(12, 64), (28, 255), (67, 255),
-                                 (40, 256)])
-def test_histogram_segment_matches_scatter(f, b, begin, count):
-    binned, ghc, mat, n, f, b = _packed(f, b)
-    seg = histogram_segment(mat, begin, count, b, f, interpret=True)
-    if count:
-        ref = np.asarray(histogram_scatter(
-            jnp.asarray(binned[begin:begin + count]),
-            ghc[begin:begin + count], b))
-    else:
-        ref = np.zeros((f, b, 3), np.float32)
-    assert np.abs(ref - np.asarray(seg)).max() < 2e-3
+_last_programs = [None]
 
 
-# ---- PR 30: the megakernel's histogram stream over the child --------
+@pytest.fixture(autouse=True)
+def _drop_compiled_programs_between_widths(request):
+    """An interpret-mode histogram program is large (its body unrolls
+    over the features) and this file compiles one a width: drop the
+    compiled ones when the test or its width changes, as conftest.py
+    does between modules and for the same reason (this sandbox's
+    XLA:CPU segfaults in a later compile once a process holds too
+    many)."""
+    import jax
+    spec = getattr(request.node, "callspec", None)
+    key = (request.function.__name__, spec and spec.params.get("f"))
+    if key != _last_programs[0]:
+        jax.clear_caches()
+    _last_programs[0] = key
+
+
+# ---- one histogram form at every width ------------------------------
+# ``histogram_segment`` is the one-hot stream whoever asks: whole rows
+# up to ``MAX_FUSED_F`` (192) columns, a ``SLICE_F``-column (128) slice
+# at a time past it. The widths: the small fixture; the cells' 28
+# (Higgs), 40 (Expo, 256 bins) and 67 (Criteo); 68 | 69, where the
+# deleted nibble kernel stopped compiling; 115 | 116, where the
+# payload's 13 columns leave the first lane tile; 128 | 129, one tile
+# of bins and one column more; 192 | 193, the two sides of the bound
+# (193: two slices, the second nearly empty, the payload in it); 300
+# (no multiple of the slice, the payload past a tile boundary) and
+# the Epsilon table's 2,000 (15 slices and 80 columns, the payload in
+# the last slice's own tile).
+SEGMENT_WIDTHS = [(12, 64), (28, 255), (40, 256), (67, 255), (68, 255),
+                  (69, 255), (115, 255), (116, 255), (128, 255),
+                  (129, 255), (192, 255), (193, 255), (300, 255),
+                  (2000, 255)]
+
 
 @functools.lru_cache(maxsize=None)
+def _packed_wide(f, b=255, n=1300):
+    rng = np.random.RandomState(f)
+    binned = rng.randint(0, b, (n, f)).astype(np.uint8)
+    ghc = make_ghc(
+        jnp.asarray(rng.randn(n).astype(np.float32)),
+        jnp.asarray(np.abs(rng.randn(n)).astype(np.float32) + 0.1),
+        jnp.asarray((rng.rand(n) < 0.8).astype(np.float32)))
+    mat = pack_gh(build_matrix(jnp.asarray(binned)), f,
+                  ghc[:, 0], ghc[:, 1], ghc[:, 2])
+    return binned, ghc, mat
+
+
+def _scatter(binned, ghc, begin, count, b):
+    if not count:
+        return np.zeros((binned.shape[1], b, 3), np.float32)
+    return np.asarray(histogram_scatter(
+        jnp.asarray(binned[begin:begin + count]),
+        ghc[begin:begin + count], b))
+
+
+@pytest.mark.parametrize("begin,count", [(0, 1300), (517, 700),
+                                         (1299, 1), (100, 0)],
+                         ids=["whole", "unaligned-ragged", "one-row",
+                              "no-row"])
+@pytest.mark.parametrize("f,b", SEGMENT_WIDTHS)
+def test_histogram_segment_matches_scatter(f, b, begin, count):
+    """``histogram_segment`` against ``ops/histogram.py`` at every
+    width: exactly the table's columns land in the result (a slice's
+    columns in their own rows, those past the width cut off), rows
+    before ``begin`` in its granule and past the count are masked,
+    neighbours' rows stay out; and the call is counted as one slice up
+    to the bound and ``ceil(F / SLICE_F)`` past it."""
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    from lightgbm_tpu.ops.hist_pallas import MAX_FUSED_F, SLICE_F
+    binned, ghc, mat = _packed_wide(f, b)
+    tel = get_telemetry()
+    tel.ensure_ring()
+    slices0 = tel.counters.get("kernels.hist_feature_slices", 0)
+    seg = np.asarray(histogram_segment(mat, begin, count, b, f,
+                                       interpret=True))
+    assert tel.counters["kernels.hist_feature_slices"] - slices0 \
+        == (1 if f <= MAX_FUSED_F else -(-f // SLICE_F))
+    assert seg.shape == (f, b, 3)
+    ref = _scatter(binned, ghc, begin, count, b)
+    assert np.abs(ref - seg).max() < 2e-3
+    # counts are sums of 0/1: exact, so no neighbour's row and no
+    # other slice's column leaked in
+    np.testing.assert_array_equal(seg[..., 2], ref[..., 2])
+
+
+# ---- the whole-row stream's program, as the megakernel holds it ------
+
 def _child_stream(f, b, blk):
-    """One compiled (interpreted) program a width and block size; the
-    segment stays dynamic, as it is inside the megakernel."""
-    import jax
-    from lightgbm_tpu.ops.split_step_pallas import histogram_child_stream
-    return jax.jit(functools.partial(
+    """The registered program a width and block size; the segment stays
+    dynamic, as it is inside the megakernel."""
+    from lightgbm_tpu.ops.hist_pallas import histogram_child_stream
+    return functools.partial(
         histogram_child_stream, num_bins=b, num_features=f, blk=blk,
-        interpret=True))
+        interpret=True)
 
 
 # a parent [40, 2040) split at NL = 700: its left child starts where
@@ -90,113 +157,32 @@ CHILD_SEGMENTS = [
 @pytest.mark.parametrize("f,b,blk", [(28, 255, 512), (67, 255, 512),
                                      (28, 256, 256)])
 def test_hist_child_stream_matches_scatter(f, b, blk, begin, count):
-    """``hist_pallas.hist_child_stream`` through its thin wrapper
-    against ``ops/histogram.py``: rows before ``begin`` in its granule
-    and rows past the count are masked through the payload, the tail
-    block is short, neighbours' rows stay out."""
+    """``hist_pallas.histogram_child_stream`` against
+    ``ops/histogram.py`` over segments that span several blocks: rows
+    before ``begin`` in its granule and rows past the count are masked
+    through the payload, the tail block is short, neighbours' rows stay
+    out."""
     binned, ghc, mat, n, f, b = _packed(f, b)
     seg = np.asarray(_child_stream(f, b, blk)(mat, begin, count))
     assert seg.shape == (f, b, 3)
-    if count:
-        ref = np.asarray(histogram_scatter(
-            jnp.asarray(binned[begin:begin + count]),
-            ghc[begin:begin + count], b))
-    else:
-        ref = np.zeros((f, b, 3), np.float32)
+    ref = _scatter(binned, ghc, begin, count, b)
     assert np.abs(ref - seg).max() < 2e-3
     # counts are sums of 0/1: exact, so no neighbour's row leaked in
     np.testing.assert_array_equal(seg[..., 2], ref[..., 2])
 
 
-def test_histogram_wide_feature_slices(monkeypatch):
-    """A table wider than the nibble kernel takes goes through the
-    sliced one-hot stream, whatever the cap (here 7, so a 19-column
-    table does): one slice, the payload in the slice's own lane tile."""
-    import lightgbm_tpu.ops.hist_pallas as hp
-    monkeypatch.setattr(hp, "MAX_NIBBLE_F", 7)
-    rng = np.random.RandomState(4)
-    n, f, b = 800, 19, 32
-    binned = rng.randint(0, b, (n, f)).astype(np.uint8)
-    ghc = make_ghc(jnp.asarray(rng.randn(n).astype(np.float32)),
-                   jnp.asarray(np.abs(rng.randn(n).astype(np.float32))
-                               + 0.1),
-                   jnp.asarray(np.ones(n, np.float32)))
-    mat = pack_gh(build_matrix(jnp.asarray(binned)), f,
-                  ghc[:, 0], ghc[:, 1], ghc[:, 2])
-    seg = hp.histogram_segment(mat, 13, 700, b, f, interpret=True)
-    ref = np.asarray(histogram_scatter(
-        jnp.asarray(binned[13:713]), ghc[13:713], b))
-    assert np.abs(ref - np.asarray(seg)).max() < 2e-3
-
-
-# ---- PR 31: a wide table's histogram, a column slice at a time -------
-# widths on and off the slice boundary (SLICE_F = 128): the narrowest
-# table past the nibble kernel (one slice), one whole slice, a slice
-# and one column, no multiple of the slice with the payload past a
-# tile boundary (300 + 13 columns end in the third tile), and the
-# Epsilon table's 2,000 (15 slices and 80 columns, the payload in the
-# last slice's own tile)
-WIDE_WIDTHS = [69, 128, 129, 300, 2000]
-
-
-@functools.lru_cache(maxsize=None)
-def _packed_wide(f, b=255, n=1300):
-    rng = np.random.RandomState(f)
-    binned = rng.randint(0, b, (n, f)).astype(np.uint8)
-    ghc = make_ghc(
-        jnp.asarray(rng.randn(n).astype(np.float32)),
-        jnp.asarray(np.abs(rng.randn(n)).astype(np.float32) + 0.1),
-        jnp.asarray((rng.rand(n) < 0.8).astype(np.float32)))
-    mat = pack_gh(build_matrix(jnp.asarray(binned)), f,
-                  ghc[:, 0], ghc[:, 1], ghc[:, 2])
-    return binned, ghc, mat
-
-
-@pytest.mark.parametrize("begin,count", [(0, 1300), (517, 700),
-                                         (1299, 1), (100, 0)],
-                         ids=["whole", "unaligned-ragged", "one-row",
-                              "no-row"])
-@pytest.mark.parametrize("f", WIDE_WIDTHS)
-def test_histogram_segment_slices_match_scatter(f, begin, count):
-    """``histogram_segment`` past ``MAX_NIBBLE_F`` against
-    ``ops/histogram.py``: every slice's columns land in their own rows
-    of the result, the columns past the table's width in the last
-    slice are cut off, neighbours' rows stay out."""
-    from lightgbm_tpu.observability.telemetry import get_telemetry
-    from lightgbm_tpu.ops.hist_pallas import MAX_NIBBLE_F, SLICE_F
-    assert f > MAX_NIBBLE_F
-    binned, ghc, mat = _packed_wide(f)
-    b = 255
-    tel = get_telemetry()
-    tel.ensure_ring()
-    slices0 = tel.counters.get("kernels.hist_feature_slices", 0)
-    seg = np.asarray(histogram_segment(mat, begin, count, b, f,
-                                       interpret=True))
-    assert tel.counters["kernels.hist_feature_slices"] - slices0 \
-        == -(-f // SLICE_F)
-    assert seg.shape == (f, b, 3)
-    if count:
-        ref = np.asarray(histogram_scatter(
-            jnp.asarray(binned[begin:begin + count]),
-            ghc[begin:begin + count], b))
-    else:
-        ref = np.zeros((f, b, 3), np.float32)
-    assert np.abs(ref - seg).max() < 2e-3
-    # counts are sums of 0/1: exact, so no neighbour's row and no
-    # other slice's column leaked in
-    np.testing.assert_array_equal(seg[..., 2], ref[..., 2])
-
-
 @pytest.mark.parametrize("blk,stream_blk", [(256, 256), (512, 512),
                                             (2048, 512)])
-def test_the_sliced_streams_row_block_follows_the_matrix(monkeypatch, blk,
-                                                         stream_blk):
-    """``blk`` is the row block the matrix was padded for: the sliced
-    stream takes ``SLICE_BLK`` rows a block where that fits and ``blk``
-    where the matrix has less slack, so no window leaves the matrix;
-    the histogram is the same."""
+@pytest.mark.parametrize("f,program", [
+    (12, "histogram_child_stream"), (193, "_histogram_segment_slices")])
+def test_the_streams_row_block_follows_the_matrix(monkeypatch, f, program,
+                                                  blk, stream_blk):
+    """``blk`` is the row block the matrix was padded for: the stream,
+    whole rows or slices, takes ``SLICE_BLK`` rows a block where that
+    fits and ``blk`` where the matrix has less slack, so no window
+    leaves the matrix; the histogram is the same."""
     import lightgbm_tpu.ops.hist_pallas as hp
-    f, b, n = 129, 255, 700
+    b, n = 255, 700
     rng = np.random.RandomState(blk)
     binned = rng.randint(0, b, (n, f)).astype(np.int32)
     ghc = jnp.asarray(np.stack(
@@ -204,32 +190,16 @@ def test_the_sliced_streams_row_block_follows_the_matrix(monkeypatch, blk,
     mat = pack_gh(build_matrix(jnp.asarray(binned), blk), f,
                   ghc[:, 0], ghc[:, 1], ghc[:, 2])
     seen = []
-    plain = hp._histogram_segment_slices
+    plain = getattr(hp, program)
     monkeypatch.setattr(
-        hp, "_histogram_segment_slices",
+        hp, program,
         lambda *a, **kw: seen.append(kw["blk"]) or plain(*a, **kw))
     seg = np.asarray(hp.histogram_segment(mat, 3, 600, b, f, blk=blk,
                                           interpret=True))
     assert seen == [stream_blk]
-    ref = np.asarray(histogram_scatter(jnp.asarray(binned[3:603]),
-                                       ghc[3:603], b))
+    ref = _scatter(binned, ghc, 3, 600, b)
     assert np.abs(ref - seg).max() < 2e-3
     np.testing.assert_array_equal(seg[..., 2], ref[..., 2])
-
-
-@pytest.mark.parametrize("f", [28, 67, 68])
-def test_a_narrow_table_is_one_slice_of_the_nibble_kernel(f):
-    from lightgbm_tpu.observability.telemetry import get_telemetry
-    binned, ghc, mat, n, f, b = _packed(f, 255)
-    tel = get_telemetry()
-    tel.ensure_ring()
-    before = dict(tel.counters)
-    seg = histogram_segment(mat, 5, 1000, b, f, interpret=True)
-    assert tel.counters["kernels.hist_feature_slices"] \
-        - before.get("kernels.hist_feature_slices", 0) == 1
-    ref = np.asarray(histogram_scatter(
-        jnp.asarray(binned[5:1005]), ghc[5:1005], b))
-    assert np.abs(ref - np.asarray(seg)).max() < 2e-3
 
 
 def test_partition_stable_and_payload(packed):

@@ -243,19 +243,23 @@ def test_one_block_opens_its_three_spans_in_order(tel, monkeypatch):
 # histogram cache's write, which ISSUE 31 made two in-place row updates
 # in place of one scatter of the stacked pair (a scatter and a
 # concatenate went, two dynamic-update-slices and their index clamps
-# came; as many fusions as before)
+# came; as many fusions as before), and for the interpret-mode
+# expansion of the two histogram calls, which ISSUE 32 made the
+# one-hot stream (a dot and five one-row accumulates a feature,
+# unrolled, where the nibble kernel's groups of three were; on a TPU
+# each is one Mosaic call either way)
 PARENT_OPCODES = {
-    "abs": 20, "add": 345, "and": 175, "bitcast": 608,
-    "bitcast-convert": 98, "broadcast": 659, "clamp": 2, "compare": 441,
-    "concatenate": 29, "conditional": 15, "constant": 859, "convert": 219,
-    "copy": 108, "divide": 17, "dot": 12, "dynamic-slice": 85,
-    "dynamic-update-slice": 69, "exponential": 1, "fusion": 335,
-    "gather": 9, "get-tuple-element": 338, "iota": 48, "is-finite": 4,
-    "maximum": 25, "minimum": 16, "multiply": 193, "negate": 141, "not": 4,
-    "or": 44, "pad": 20, "parameter": 889, "reduce": 14,
-    "reduce-window": 8, "remainder": 11, "reverse": 2, "scatter": 2,
-    "select": 454, "shift-left": 33, "shift-right-logical": 47, "sign": 60,
-    "slice": 444, "sort": 1, "subtract": 120, "transpose": 11, "tuple": 41}
+    "abs": 20, "add": 447, "and": 130, "bitcast": 824,
+    "bitcast-convert": 74, "broadcast": 456, "clamp": 2, "compare": 381,
+    "concatenate": 31, "conditional": 15, "constant": 1156, "convert": 222,
+    "copy": 180, "divide": 12, "dot": 22, "dynamic-slice": 90,
+    "dynamic-update-slice": 218, "exponential": 1, "fusion": 476,
+    "gather": 9, "get-tuple-element": 267, "iota": 35, "is-finite": 4,
+    "maximum": 25, "minimum": 14, "multiply": 149, "negate": 105, "not": 4,
+    "or": 44, "pad": 20, "parameter": 1209, "reduce": 12,
+    "reduce-window": 8, "reverse": 2, "scatter": 2, "select": 352,
+    "shift-left": 33, "shift-right-logical": 35, "sign": 43, "slice": 702,
+    "sort": 1, "subtract": 104, "transpose": 8, "tuple": 41}
 _OPCODE = re.compile(
     r"^\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(?:\([^=]*?\)|\S+)\s+([a-z\-]+)\(")
 
@@ -360,14 +364,14 @@ def test_the_split_phases_own_the_per_phase_body(tel, categorical):
     # phase; the root histogram stays the root's
     assert _owners(text, table, in_loop, "partition_segment") \
         == {scopes.SPLITS_PARTITION}
-    assert _owners(text, table, in_loop, "histogram_segment") \
+    assert _owners(text, table, in_loop, "histogram_child_stream") \
         == {scopes.SPLITS_HIST}
     # the children's histograms go into the per-leaf cache under a
     # scope of their own
     assert _owners(text, table, in_loop, scopes.SPLITS_CACHE + "/") \
         == {scopes.SPLITS_CACHE}
     assert _owners(text, table, scopes.GROW_ROOT + "/",
-                   "histogram_segment") == {scopes.GROW_ROOT}
+                   "histogram_child_stream") == {scopes.GROW_ROOT}
     phases = set(table.values()) & set(scopes.SPLIT_PHASE_SCOPES)
     want = set(scopes.SPLIT_PHASE_SCOPES)
     if not categorical:

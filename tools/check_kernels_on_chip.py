@@ -4,10 +4,11 @@ The reference's GPU_DEBUG_COMPARE (gpu_tree_learner.cpp) recomputes
 device histograms on the host and compares; CI runs our Pallas kernels
 only in interpret mode on CPU, which catches none of Mosaic's
 hardware-compile failures. Each stage here runs one kernel the chip
-path can select — nibble histogram (grouped), ``partition_segment``,
-split-scan, and the split-step megakernel in both layouts — COMPILED
-against a NumPy/XLA oracle. The first shape of every stage is the
-Higgs width (28 features, 256 bins).
+path can select — the one-hot histogram stream (``histogram_segment``:
+whole rows up to ``MAX_FUSED_F`` columns, column slices past it),
+``partition_segment``, split-scan, and the split-step megakernel —
+COMPILED against a NumPy/XLA oracle. The first shape of every stage is
+the Higgs width (28 features, 256 bins).
 
 ``chip_smoke.py`` calls the stage functions in-process; standalone:
 
@@ -35,6 +36,9 @@ TOL = dict(rtol=1e-4, atol=1e-3)
 
 # (rows, features, bins); Higgs width first
 MATRIX_SHAPES = ((20000, 28, 256), (5000, 12, 64), (7333, 5, 16))
+# the histogram's: both sides of MAX_FUSED_F as well (whole rows at
+# 192 columns, two column slices at 193)
+HIST_SHAPES = MATRIX_SHAPES + ((4000, 192, 256), (4000, 193, 256))
 
 
 def _segments(n, unaligned):
@@ -60,14 +64,20 @@ def _hist_inputs(rng, n, f, b):
     return binned, g, h, c, mat
 
 
-def stage_hist(interpret: bool = False, shapes=MATRIX_SHAPES) -> int:
+def stage_hist(interpret: bool = False, shapes=HIST_SHAPES) -> int:
+    """``histogram_segment`` against a NumPy oracle (the gate) and
+    against ``ops/histogram.py``'s scatter on the same device (the
+    difference is printed)."""
+    import jax.numpy as jnp
     import numpy as np
 
     from lightgbm_tpu.ops.hist_pallas import histogram_segment
+    from lightgbm_tpu.ops.histogram import histogram_scatter
     rng = np.random.RandomState(0)
     failures = 0
     for n, f, b in shapes:
         binned, g, h, c, mat = _hist_inputs(rng, n, f, b)
+        ghc = jnp.asarray(np.stack([g * c, h * c, c], 1))
         for begin, count in _segments(n, 8):
             hc = np.asarray(histogram_segment(
                 mat, begin, count, b, f, interpret=interpret))
@@ -77,11 +87,15 @@ def stage_hist(interpret: bool = False, shapes=MATRIX_SHAPES) -> int:
                 np.add.at(ho[j], (binned[sl, j], 0), (g * c)[sl])
                 np.add.at(ho[j], (binned[sl, j], 1), (h * c)[sl])
                 np.add.at(ho[j], (binned[sl, j], 2), c[sl])
-            ok = np.allclose(hc, ho, **TOL)
-            err = np.abs(hc - ho).max()
+            hx = np.asarray(histogram_scatter(
+                jnp.asarray(binned[sl]), ghc[sl], b))
+            ok = np.allclose(hc, ho, **TOL) \
+                and np.array_equal(hc[..., 2], ho[..., 2])
             print(f"hist [{n}x{f} b={b}] seg=({begin},{count}) "
                   f"kernel-vs-oracle: {'ok ' if ok else 'FAIL'} "
-                  f"max|d|={err:.2e}", flush=True)
+                  f"max|d|={np.abs(hc - ho).max():.2e} "
+                  f"vs ops/histogram.py max|d|="
+                  f"{np.abs(hc - hx).max():.2e}", flush=True)
             failures += 0 if ok else 1
     return failures
 

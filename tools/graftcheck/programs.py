@@ -470,10 +470,12 @@ def _b_partitioned_grow():
 
 
 # --- pallas kernel wrappers (interpret mode on CPU) ------------------
-@builder("hist_segment_nibble")
-def _b_hist_segment_nibble():
+@builder("hist_child_stream")
+def _b_hist_child_stream():
+    """The whole-row one-hot stream (the root's and a leaf segment's
+    histogram up to ``MAX_FUSED_F`` columns) on a learner's matrix."""
     import jax.numpy as jnp
-    from lightgbm_tpu.learner.partitioned import HIST_BLK
+    from lightgbm_tpu.ops.hist_pallas import SLICE_BLK
 
     def make():
         import numpy as np
@@ -490,11 +492,11 @@ def _b_hist_segment_nibble():
             "min_data_in_leaf": 5, "verbosity": -1})
         return PartitionedTreeLearner(
             Dataset.from_numpy(x, cfg, label=y), cfg)
-    lrn = _env("partitioned_learner_nibble", make)
-    return _spec_fn("hist_segment_nibble").lower(
+    lrn = _env("partitioned_learner_hist", make)
+    return _spec_fn("hist_child_stream").lower(
         lrn.mat, jnp.int32(0), jnp.int32(lrn.num_data),
         num_features=lrn.num_groups, num_bins=lrn.num_bins_max,
-        blk=HIST_BLK, interpret=True)
+        blk=SLICE_BLK, interpret=True)
 
 
 @builder("hist_segment_slices")
@@ -503,8 +505,8 @@ def _b_hist_segment_slices():
     axis) on the same learner's matrix: one slice there."""
     import jax.numpy as jnp
     from lightgbm_tpu.ops.hist_pallas import SLICE_BLK
-    _b_hist_segment_nibble()        # builds the shared learner
-    lrn = _ENV["partitioned_learner_nibble"]
+    _b_hist_child_stream()          # builds the shared learner
+    lrn = _ENV["partitioned_learner_hist"]
     return _spec_fn("hist_segment_slices").lower(
         lrn.mat, jnp.int32(0), jnp.int32(lrn.num_data),
         num_features=lrn.num_groups, num_bins=lrn.num_bins_max,
