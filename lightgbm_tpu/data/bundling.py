@@ -21,14 +21,25 @@ Eligibility: numerical features whose default AND most-frequent bin is
 0 (the sparse-feature shape). Others get singleton groups that keep
 raw bin values (offset 0), so dense datasets pass through unchanged.
 
-Conflict rules mirror the reference: a feature may join a group when
-the count of rows where both are non-default stays within
-``total_sample_cnt / 10000``, the group's bin budget stays <= 256, and
-the feature's own conflicts stay <= nnz/2; candidate groups are
-searched newest-first with a random sample capped at 100
-(dataset.cpp:97-185). Two greedy passes (natural order and
-by-descending-nonzero-count) run and the one with fewer groups wins
-(FastFeatureBundling, dataset.cpp:238-302).
+Conflict rule: the reference's at v2.3.2 with its default
+``max_conflict_rate = 0.0`` (config.h), as a constant: a feature joins
+a group only when it shares no non-default row with the group in the
+rows the plan saw, so a bundle is lossless on those rows, not an
+approximation. (v3 dropped the parameter for a fixed budget of
+``total_sample_cnt / 10000`` rows a group; nothing here grants one.)
+The group's bin budget stays <= 256; candidate groups are searched
+newest-first with a random sample capped at 100 (dataset.cpp:97-185).
+Two greedy passes (natural order and by-descending-nonzero-count) run
+and the one with fewer groups wins (FastFeatureBundling,
+dataset.cpp:238-302).
+
+Low-density groups (under 40 % of the rows non-default) stay physical
+byte columns while the training matrix's row does not grow by them
+(``utils/matrix_layout.py matrix_cols``: a row is padded to 128 bytes,
+so up to 112 columns cost what one costs). The reference's later CPU
+storage choice (v3: dissolve them into a row-wise multi-val bin) is
+kept only for what cannot be a column: a set of sparse groups that
+would widen the row.
 """
 
 from __future__ import annotations
@@ -36,6 +47,8 @@ from __future__ import annotations
 from typing import List, Optional, Tuple
 
 import numpy as np
+
+from ..utils.matrix_layout import matrix_cols
 
 MAX_BIN_PER_GROUP = 256
 MAX_SEARCH_GROUP = 100
@@ -57,12 +70,16 @@ def decode_feature_bin(col, off, nbf):
 
 
 def encode_feature_bin(out_col: np.ndarray, bins: np.ndarray,
-                       off: int) -> None:
+                       off: int) -> int:
     """Write a feature's non-default bins into its group column in
-    place (FeatureGroup::PushData semantics; host-side)."""
+    place (FeatureGroup::PushData semantics; host-side). Returns the
+    rows in which an earlier member of the group was non-default and
+    is overwritten: the values a bundle with conflicts loses."""
     nz = bins != 0
+    lost = int(np.count_nonzero(out_col[nz]))
     out_col[nz] = (bins[nz].astype(np.int64) + off - 1).astype(
         out_col.dtype)
+    return lost
 
 
 class BundlePlan:
@@ -88,6 +105,9 @@ class BundlePlan:
         # first mv pseudo-group id; == num_groups when no multi-val
         self.mv_group_start = (num_groups if mv_group_start is None
                                else mv_group_start)
+        # rows in which ``bundle_matrix`` found a second member of a
+        # group non-default and overwrote the first (its last call)
+        self.conflict_rows = 0
 
     @property
     def num_dense_groups(self) -> int:
@@ -104,19 +124,30 @@ class BundlePlan:
             and not self.has_multival
 
 
+# rows of a candidate looked at before the rest: the first shared row
+# already refuses the group, and two frequent columns share one within
+# a few entries
+_CONFLICT_PROBE = 4096
+
+
+def _shares_a_row(mark: np.ndarray, idx: np.ndarray) -> bool:
+    """Whether a row of ``idx`` is marked in the group, O(nnz)."""
+    return bool(mark[idx[:_CONFLICT_PROBE]].any()
+                or mark[idx[_CONFLICT_PROBE:]].any())
+
+
 def _find_groups(nz_idx: List[Optional[np.ndarray]], nbins: np.ndarray,
-                 order: np.ndarray, total: int, max_conflict: int,
-                 seed: int) -> List[List[int]]:
-    """One greedy pass (FindGroups, dataset.cpp:97-185). ``nz_idx[f]``
-    is the sorted array of non-default sample-row indices of eligible
-    feature f (None = ineligible -> singleton). Per-feature storage is
-    O(nnz) like the reference's index lists; only per-GROUP marks are
-    dense bool arrays."""
+                 order: np.ndarray, total: int,
+                 seed: int) -> Tuple[List[List[int]], List[int]]:
+    """One greedy pass (FindGroups, dataset.cpp:97-185, at a conflict
+    budget of 0). ``nz_idx[f]`` is the sorted array of non-default
+    sample-row indices of eligible feature f (None = ineligible ->
+    singleton). Per-feature storage is O(nnz) like the reference's
+    index lists; only per-GROUP marks are dense bool arrays."""
     rng = np.random.RandomState(seed)
     groups: List[List[int]] = []
     marks: List[np.ndarray] = []
-    used_cnt: List[int] = []
-    total_cnt: List[int] = []
+    used_cnt: List[int] = []        # non-default rows of the group
     nbin: List[int] = []
 
     singletons: List[List[int]] = []
@@ -129,7 +160,7 @@ def _find_groups(nz_idx: List[Optional[np.ndarray]], nbins: np.ndarray,
         nnz = len(idx)
         add_bins = int(nbins[f]) - 1
         available = [g for g in range(len(groups))
-                     if total_cnt[g] + nnz <= total + max_conflict
+                     if used_cnt[g] + nnz <= total
                      and nbin[g] + add_bins <= MAX_BIN_PER_GROUP]
         search: List[int] = []
         if available:
@@ -140,19 +171,11 @@ def _find_groups(nz_idx: List[Optional[np.ndarray]], nbins: np.ndarray,
                                   replace=False)
                 rest = [rest[i] for i in pick]
             search.extend(rest)
-        best = -1
-        best_cnt = -1
-        for g in search:
-            rest_max = max_conflict - total_cnt[g] + used_cnt[g]
-            cnt = int(marks[g][idx].sum())  # O(nnz) conflict count
-            if cnt <= rest_max and cnt <= nnz // 2:
-                best = g
-                best_cnt = cnt
-                break
+        best = next((g for g in search
+                     if not _shares_a_row(marks[g], idx)), -1)
         if best >= 0:
             groups[best].append(f)
-            total_cnt[best] += nnz
-            used_cnt[best] += nnz - best_cnt
+            used_cnt[best] += nnz
             marks[best][idx] = True
             nbin[best] += add_bins
         else:
@@ -160,42 +183,41 @@ def _find_groups(nz_idx: List[Optional[np.ndarray]], nbins: np.ndarray,
             mark = np.zeros(total, bool)
             mark[idx] = True
             marks.append(mark)
-            total_cnt.append(nnz)
             used_cnt.append(nnz)
             nbin.append(1 + add_bins)
     # SECOND round (dataset.cpp:186-231): dissolve groups whose used-
     # row density is below 0.4 — their features are candidates for the
-    # row-wise multi-val representation when their combined conflicts
-    # overflow the single-column budget
+    # row-wise multi-val representation when they share rows
     DENSE_THRESHOLD = 0.4
-    kept: List[List[int]] = []
-    second: List[int] = []
-    second_nnz = 0
-    for g, feats in enumerate(groups):
-        if used_cnt[g] >= DENSE_THRESHOLD * total:
-            kept.append(feats)
-        else:
-            second.extend(feats)
-            second_nnz += total_cnt[g]
+    dense = [used_cnt[g] >= DENSE_THRESHOLD * total
+             for g in range(len(groups))]
+    # ... unless they fit the row as they are: a byte column of a
+    # 128-byte row that is mostly zeros costs the chip nothing extra,
+    # and a physical column is what the segment kernels stream
+    if matrix_cols(len(groups) + len(singletons)) \
+            <= matrix_cols(max(sum(dense) + len(singletons), 1)):
+        return groups + singletons, []
+    kept = [feats for g, feats in enumerate(groups) if dense[g]]
+    second = [fidx for g, feats in enumerate(groups) if not dense[g]
+              for fidx in feats]
     multival: List[int] = []
     if second:
-        # conflicts of one shared column = sum(nnz) - distinct rows;
-        # within budget -> ONE shared column (the reference's second-
-        # round group); over budget -> the whole set goes multi-val
-        # (row-wise). Documented divergences from dataset.cpp:210-231:
-        # (a) the shared column must fit the u8 bin budget (the
-        # reference lets second-round groups grow wider bins), and
-        # (b) multi-val must actually SHRINK the matrix — our slot
-        # matrix pads to the max per-row count (i32), unlike the
-        # reference's CSR row_ptr, so mid-sparsity sets where
-        # 4*max_nnz_per_row >= n_features stay dense singletons
+        # no row holds two of them -> ONE shared column (the
+        # reference's second-round group); else the whole set goes
+        # multi-val (row-wise). Documented divergences from
+        # dataset.cpp:210-231: (a) the shared column must fit the u8
+        # bin budget (the reference lets second-round groups grow
+        # wider bins), and (b) multi-val must actually SHRINK the
+        # matrix — our slot matrix pads to the max per-row count
+        # (i32), unlike the reference's CSR row_ptr, so mid-sparsity
+        # sets where 4*max_nnz_per_row >= n_features stay dense
+        # singletons
         row_cnt = np.zeros(total, np.int64)
         for fidx in second:
             np.add.at(row_cnt, nz_idx[fidx], 1)
-        conflicts = second_nnz - int((row_cnt > 0).sum())
         bins2 = 1 + sum(int(nbins[fidx]) - 1 for fidx in second)
         k_est = int(row_cnt.max(initial=0))
-        if conflicts <= max_conflict and bins2 <= MAX_BIN_PER_GROUP:
+        if k_est <= 1 and bins2 <= MAX_BIN_PER_GROUP:
             kept.append(sorted(second))
         elif 4 * k_est < len(second):
             multival = sorted(second)
@@ -232,18 +254,16 @@ def plan_bundles_from_nonzeros(nz_idx: List[Optional[np.ndarray]],
                                seed: int = 0) -> BundlePlan:
     """Plan from per-feature non-default row-index lists directly —
     the sparse path feeds CSC column indices here so the full binned
-    sample matrix never materializes (memory O(sample nnz))."""
+    sample matrix never materializes (memory O(nnz)). Every bundle is
+    lossless on the ``total`` rows the plan saw."""
     f = len(nz_idx)
     nnz = np.asarray([0 if ix is None else len(ix) for ix in nz_idx],
                      np.int64)
-    max_conflict = total // 10000
 
     natural = np.arange(f)
     by_cnt = np.argsort(-nnz, kind="stable")
-    g1, mv1 = _find_groups(nz_idx, num_bins, natural, total, max_conflict,
-                           seed)
-    g2, mv2 = _find_groups(nz_idx, num_bins, by_cnt, total, max_conflict,
-                           seed)
+    g1, mv1 = _find_groups(nz_idx, num_bins, natural, total, seed)
+    g2, mv2 = _find_groups(nz_idx, num_bins, by_cnt, total, seed)
     if len(g2) + (1 if mv2 else 0) < len(g1) + (1 if mv1 else 0):
         groups, multival = g2, mv2
     else:
@@ -297,14 +317,17 @@ def plan_bundles_from_nonzeros(nz_idx: List[Optional[np.ndarray]],
 def bundle_matrix(binned: np.ndarray, plan: BundlePlan) -> np.ndarray:
     """[N, F] raw bins -> [N, G_dense] bundled columns
     (FeatureGroup::PushData semantics: non-default values land at their
-    offset; ties resolved by feature order, bounded by the conflict
-    budget). Multi-val pseudo-groups get no column — their values ride
-    the slot matrix (build_mv_slots)."""
+    offset; where a table the plan did not see puts two members of a
+    group in one row, the later feature wins and the row is counted in
+    ``plan.conflict_rows``). Multi-val
+    pseudo-groups get no column — their values ride the slot matrix
+    (build_mv_slots)."""
     n, f = binned.shape
     g_dense = plan.num_dense_groups
     max_b = int(plan.group_num_bins[:g_dense].max(initial=2))
     dtype = np.uint8 if max_b <= 256 else np.uint16
     out = np.zeros((n, max(g_dense, 1)), dtype)
+    plan.conflict_rows = 0
     for j in range(f):
         g = plan.feature_group[j]
         if g >= g_dense:
@@ -314,7 +337,8 @@ def bundle_matrix(binned: np.ndarray, plan: BundlePlan) -> np.ndarray:
         if off == 0:
             out[:, g] = col.astype(dtype)
         else:
-            encode_feature_bin(out[:, g], col, int(off))
+            plan.conflict_rows += encode_feature_bin(out[:, g], col,
+                                                     int(off))
     return out
 
 

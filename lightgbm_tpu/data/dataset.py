@@ -26,6 +26,14 @@ from .binning import (BIN_TYPE_CATEGORICAL, BIN_TYPE_NUMERICAL,
                       BinMapper, kZeroThreshold)
 
 
+def _bundle_eligible(m: BinMapper) -> bool:
+    """A column EFB may put in a shared byte column: numeric, its
+    default and most frequent bin both 0 (the sparse-feature shape),
+    bins in a byte."""
+    return (m.bin_type == BIN_TYPE_NUMERICAL and m.most_freq_bin == 0
+            and m.default_bin == 0 and m.num_bin <= 256)
+
+
 def load_forced_bins(path: str) -> Dict[int, List[float]]:
     """Parse a forced-bin-bounds JSON file
     (``forcedbins_filename``; DatasetLoader::GetForcedBins,
@@ -176,6 +184,11 @@ class Dataset:
         # mv_group_start have no physical column (data/bundling.py)
         self.mv_slots: Optional[np.ndarray] = None
         self.mv_group_start: Optional[int] = None
+        # rows of THIS table in which a second member of a bundle was
+        # non-default and overwrote the first: 0 where the bundling is
+        # lossless here, which every plan is on the rows it saw
+        # (data/bundling.py)
+        self.bundle_conflict_rows: int = 0
         # raw numeric feature values [N, F_used] f32 (NaN preserved),
         # kept only when linear_tree is on: the leaf-linear fits and
         # the linear prediction paths consume raw values, not bins
@@ -250,6 +263,21 @@ class Dataset:
                     np.zeros(f, np.int32), self.num_bins_array())
         return self.feature_group, self.feature_offset, self.group_num_bins
 
+    def count_bundle_telemetry(self) -> None:
+        """What this table's bundling came to, as telemetry counters
+        set once a table is constructed or loaded (the newest table's
+        values stand): logical features, physical matrix columns, rows
+        a conflict lost, features in multi-val pseudo-groups."""
+        from ..observability.telemetry import get_telemetry
+        tel = get_telemetry()
+        group, _, _ = self.bundle_maps()
+        tel.set_counter("data.bundle_features", self.num_features)
+        tel.set_counter("data.bundle_columns", self.num_dense_groups)
+        tel.set_counter("data.bundle_conflict_rows",
+                        self.bundle_conflict_rows)
+        tel.set_counter("data.multival_features", int(
+            (np.asarray(group) >= self.num_dense_groups).sum()))
+
     def bundle_plan(self):
         """The dataset's stored bundling as a BundlePlan (the ONE
         reconstruction shared by valid-set extraction and the
@@ -316,20 +344,14 @@ class Dataset:
         if reference is None:
             self._maybe_bundle(config)
         elif self.feature_group is not None:
-            from .bundling import build_mv_slots, bundle_matrix
-            plan = self.bundle_plan()
-            raw = self.binned
-            self.binned = bundle_matrix(raw, plan)
-            if plan.has_multival:
-                from .bundling import dense_feature_bins
-                self.mv_slots = build_mv_slots(plan, raw.shape[0],
-                                               dense_feature_bins(raw))
+            self._bundle_binned(self.bundle_plan())
         self.metadata.num_data = n
         if label is not None:
             self.metadata.set_label(label)
         self.metadata.set_weights(weight)
         self.metadata.set_query(group)
         self.metadata.set_init_score(init_score)
+        self.count_bundle_telemetry()
         return self
 
     def _find_bins(self, data: np.ndarray, config: Config,
@@ -420,16 +442,12 @@ class Dataset:
         """EFB (FindGroups/FastFeatureBundling, dataset.cpp:41-314):
         collapse nearly-exclusive features into shared columns. No-op
         for dense data (every group ends up a singleton)."""
-        from .binning import BIN_TYPE_NUMERICAL
         if not config.enable_bundle or self.num_features < 2:
             return
-        from .bundling import bundle_matrix, plan_bundles
+        from .bundling import plan_bundles
         nb = self.num_bins_array()
-        eligible = np.asarray([
-            m.bin_type == BIN_TYPE_NUMERICAL and m.most_freq_bin == 0
-            and m.default_bin == 0 and m.num_bin <= 256
-            for m in (self.feature_mapper(i)
-                      for i in range(self.num_features))])
+        eligible = np.asarray([_bundle_eligible(self.feature_mapper(i))
+                               for i in range(self.num_features)])
         if not eligible.any():
             return
         plan = plan_bundles(self.binned, nb, eligible,
@@ -443,16 +461,24 @@ class Dataset:
                  f"{plan.num_groups} columns"
                  + (f" ({plan.num_groups - plan.mv_group_start} "
                     "multi-val)" if plan.has_multival else ""))
-        raw = self.binned
-        self.binned = bundle_matrix(raw, plan)
-        if plan.has_multival:
-            from .bundling import build_mv_slots, dense_feature_bins
-            self.mv_slots = build_mv_slots(plan, raw.shape[0],
-                                           dense_feature_bins(raw))
-            self.mv_group_start = plan.mv_group_start
+        self._bundle_binned(plan)
+        self.mv_group_start = plan.mv_group_start \
+            if plan.has_multival else None
         self.feature_group = plan.feature_group
         self.feature_offset = plan.feature_offset
         self.group_num_bins = plan.group_num_bins
+
+    def _bundle_binned(self, plan) -> None:
+        """``self.binned`` from per-feature bins to the plan's group
+        columns (and slot matrix), counting the rows a conflict lost."""
+        from .bundling import (build_mv_slots, bundle_matrix,
+                               dense_feature_bins)
+        raw = self.binned
+        self.binned = bundle_matrix(raw, plan)
+        self.bundle_conflict_rows = plan.conflict_rows
+        if plan.has_multival:
+            self.mv_slots = build_mv_slots(plan, raw.shape[0],
+                                           dense_feature_bins(raw))
 
     def _resolve_monotone_and_penalty(self, config: Config) -> None:
         mt = list(config.monotone_constraints)
@@ -567,14 +593,7 @@ class Dataset:
         if reference is None:
             self._maybe_bundle(config)
         elif self.feature_group is not None:
-            from .bundling import build_mv_slots, bundle_matrix
-            plan = self.bundle_plan()
-            raw = self.binned
-            self.binned = bundle_matrix(raw, plan)
-            if plan.has_multival:
-                from .bundling import dense_feature_bins
-                self.mv_slots = build_mv_slots(plan, raw.shape[0],
-                                               dense_feature_bins(raw))
+            self._bundle_binned(self.bundle_plan())
 
         # ---- metadata: file columns, sidecars, explicit overrides
         f_weight, f_group, f_init = loader.load_sidecars()
@@ -600,6 +619,7 @@ class Dataset:
         self.metadata.set_init_score(init_score)
         log_info(f"Loaded {n} rows x {num_features} features from "
                  f"{path} in two passes ({loader.fmt})")
+        self.count_bundle_telemetry()
         return self
 
     # ------------------------------------------------------------------
@@ -661,6 +681,7 @@ class Dataset:
         self.metadata.set_weights(weight)
         self.metadata.set_query(group)
         self.metadata.set_init_score(init_score)
+        self.count_bundle_telemetry()
         return self
 
     @classmethod
@@ -728,10 +749,7 @@ class Dataset:
             nz_idx: List[Optional[np.ndarray]] = []
             for inner, orig in enumerate(self.real_feature_idx):
                 m = self.bin_mappers[orig]
-                ok = (m.bin_type == BIN_TYPE_NUMERICAL
-                      and m.most_freq_bin == 0 and m.default_bin == 0
-                      and m.num_bin <= 256)
-                if not ok:
+                if not _bundle_eligible(m):
                     nz_idx.append(None)
                     continue
                 vals = np.asarray(col_values[orig], np.float64)
@@ -780,6 +798,7 @@ class Dataset:
         if self._push_plan is not None:
             from .bundling import bundle_matrix
             raw = bundle_matrix(raw, self._push_plan)
+            self.bundle_conflict_rows += self._push_plan.conflict_rows
         self.binned[start_row:start_row + m] = raw
         self._push_filled += m
 
@@ -839,9 +858,12 @@ class Dataset:
 
     def _extract_sparse(self, csc, config: Config, reference) -> None:
         """CSC nonzeros -> (bundled) binned matrix, no [N, F]
-        intermediate: the EFB plan comes from a row SAMPLE; the full
-        matrix is written group-column by group-column."""
-        from .bundling import plan_bundles_from_nonzeros
+        intermediate of any type: the EFB plan comes from the columns'
+        non-default row lists, which the CSC structure holds already,
+        and the matrix is written group column by group column."""
+        from ..observability import scopes
+        from ..observability.telemetry import get_telemetry
+        tel = get_telemetry()
         n = csc.shape[0]
         f_used = self.num_features
         indptr, indices = csc.indptr, csc.indices
@@ -853,88 +875,68 @@ class Dataset:
 
         zero_bin = np.zeros(max(f_used, 1), np.int64)
         bins_nz: List[np.ndarray] = []
-        for inner, orig in enumerate(self.real_feature_idx):
-            m = self.bin_mappers[orig]
-            zero_bin[inner] = int(m.values_to_bins(np.zeros(1))[0])
-            bins_nz.append(m.values_to_bins(np.asarray(
-                vals[indptr[orig]:indptr[orig + 1]],
-                np.float64)).astype(dtype))
+        nz_rows: List[np.ndarray] = []      # the rows bins_nz is of
+        with tel.span(scopes.DATA_EXTRACT, trace=scopes.DATA_EXTRACT):
+            for inner, orig in enumerate(self.real_feature_idx):
+                m = self.bin_mappers[orig]
+                zero_bin[inner] = int(m.values_to_bins(np.zeros(1))[0])
+                bj = m.values_to_bins(np.asarray(
+                    vals[indptr[orig]:indptr[orig + 1]],
+                    np.float64)).astype(dtype)
+                rows_j = indices[indptr[orig]:indptr[orig + 1]]
+                if not zero_bin[inner]:
+                    # stored values that fall in bin 0, where the
+                    # implicit zeros are, need no write and are no
+                    # non-default rows to the planner
+                    nz = bj != 0
+                    if not nz.all():
+                        bj, rows_j = bj[nz], rows_j[nz]
+                bins_nz.append(bj)
+                nz_rows.append(rows_j)
 
         plan = None
         if reference is not None:
             plan = self.bundle_plan()
         elif config.enable_bundle and f_used >= 2:
-            # the planner only needs per-feature NON-DEFAULT row sets
-            # within a row sample — taken straight from the CSC
-            # structure, O(sample nnz), no binned sample matrix
-            take = min(n, self.bin_construct_sample_cnt)
-            if take < n:
-                rows = np.sort(np.random.RandomState(
-                    config.data_random_seed).choice(n, take,
-                                                    replace=False))
-                pos_of_row = np.full(n, -1, np.int32)
-                pos_of_row[rows] = np.arange(take, dtype=np.int32)
-            else:
-                pos_of_row = None
-            nz_idx: List[Optional[np.ndarray]] = []
-            for inner, orig in enumerate(self.real_feature_idx):
-                m = self.bin_mappers[orig]
-                ok = (m.bin_type == BIN_TYPE_NUMERICAL
-                      and m.most_freq_bin == 0 and m.default_bin == 0
-                      and m.num_bin <= 256)
-                if not ok:
-                    nz_idx.append(None)
-                    continue
-                rows_j = indices[indptr[orig]:indptr[orig + 1]]
-                nz = bins_nz[inner] != 0    # stored but bin-0 excluded
-                if pos_of_row is None:
-                    nz_idx.append(rows_j[nz].astype(np.int32))
-                else:
-                    pos = pos_of_row[rows_j[nz]]
-                    nz_idx.append(pos[pos >= 0])
-            if any(ix is not None for ix in nz_idx):
-                cand = plan_bundles_from_nonzeros(
-                    nz_idx, nbins, take, seed=config.data_random_seed)
-                if cand.num_groups < f_used or cand.has_multival:
-                    from ..utils.log import log_info
-                    log_info(
-                        f"EFB: bundled {f_used} sparse features into "
-                        f"{cand.num_groups} columns"
-                        + (f" ({cand.num_groups - cand.mv_group_start}"
-                           " multi-val)" if cand.has_multival else ""))
-                    plan = cand
+            with tel.span(scopes.DATA_BUNDLE_PLAN,
+                          trace=scopes.DATA_BUNDLE_PLAN):
+                plan = self._plan_sparse_bundles(nz_rows, nbins, n, config)
 
         g_dense = plan.num_dense_groups if plan is not None \
             else max(f_used, 1)
-        out = np.zeros((n, max(g_dense, 1)), dtype)
-        for inner in range(f_used):
-            orig = self.real_feature_idx[inner]
-            if plan is not None \
-                    and plan.feature_group[inner] >= g_dense:
-                continue  # multi-val: rides the slot matrix below
-            rows_j = indices[indptr[orig]:indptr[orig + 1]]
-            bj = bins_nz[inner]
-            if plan is None or plan.feature_offset[inner] == 0:
-                g = inner if plan is None else plan.feature_group[inner]
-                if zero_bin[inner]:
-                    out[:, g] = dtype(zero_bin[inner])
-                out[rows_j, g] = bj.astype(dtype)
-            else:
-                g = plan.feature_group[inner]
-                off = int(plan.feature_offset[inner])
-                nz = bj != 0
-                out[rows_j[nz], g] = (bj[nz].astype(np.int64) + off
-                                      - 1).astype(dtype)
+        with tel.span(scopes.DATA_EXTRACT, trace=scopes.DATA_EXTRACT):
+            # written a group column at a time into a column-major
+            # scratch (a column's rows ascend, so each write walks one
+            # contiguous array), then turned into rows
+            cols = np.zeros((max(g_dense, 1), n), dtype)
+            lost = 0
+            for inner in range(f_used):
+                if plan is not None \
+                        and plan.feature_group[inner] >= g_dense:
+                    continue  # multi-val: rides the slot matrix below
+                rows_j, bj = nz_rows[inner], bins_nz[inner]
+                if plan is None or plan.feature_offset[inner] == 0:
+                    col = cols[inner if plan is None
+                               else plan.feature_group[inner]]
+                    if zero_bin[inner]:
+                        col[:] = dtype(zero_bin[inner])
+                    col[rows_j] = bj
+                else:
+                    col = cols[plan.feature_group[inner]]
+                    off = int(plan.feature_offset[inner])
+                    lost += int(np.count_nonzero(col[rows_j]))
+                    col[rows_j] = bj + dtype(off - 1)
+            out = np.empty((n, cols.shape[0]), dtype)
+            for r0 in range(0, n, 1 << 16):
+                out[r0:r0 + (1 << 16)] = cols[:, r0:r0 + (1 << 16)].T
+            del cols
         self.binned = out
+        self.bundle_conflict_rows = lost
         if plan is not None and plan.has_multival:
             from .bundling import build_mv_slots
 
             def feature_bins(inner):
-                orig = self.real_feature_idx[inner]
-                rows_j = indices[indptr[orig]:indptr[orig + 1]]
-                bj = bins_nz[inner]
-                nz = bj != 0
-                return rows_j[nz], bj[nz].astype(np.int64)
+                return nz_rows[inner], bins_nz[inner].astype(np.int64)
 
             self.mv_slots = build_mv_slots(plan, n, feature_bins)
             self.mv_group_start = plan.mv_group_start
@@ -942,6 +944,33 @@ class Dataset:
             self.feature_group = plan.feature_group
             self.feature_offset = plan.feature_offset
             self.group_num_bins = plan.group_num_bins
+
+    def _plan_sparse_bundles(self, nz_rows: List[np.ndarray],
+                             nbins: np.ndarray, n: int, config: Config):
+        """The EFB plan of a sparse table, or None where nothing
+        bundles. The planner needs per-feature NON-DEFAULT row sets,
+        taken straight from the CSC structure, and it gets them for
+        ALL rows, not for a sample, so that no two members of a group
+        share a non-default row anywhere in the table: what a plan
+        made from a sample cannot promise of rare columns (seven rows
+        in the sample miss a group that holds a tenth of the table
+        every other time). The planner keeps one bool a row for each
+        group it opens, at most what the matrix it plans will take."""
+        from .bundling import plan_bundles_from_nonzeros
+        eligible = [_bundle_eligible(self.feature_mapper(inner))
+                    for inner in range(len(nz_rows))]
+        if not any(eligible):
+            return None
+        cand = plan_bundles_from_nonzeros(
+            [r if ok else None for r, ok in zip(nz_rows, eligible)],
+            nbins, n, seed=config.data_random_seed)
+        if cand.num_groups >= len(nz_rows) and not cand.has_multival:
+            return None
+        log_info(f"EFB: bundled {len(nz_rows)} sparse features into "
+                 f"{cand.num_groups} columns"
+                 + (f" ({cand.num_groups - cand.mv_group_start}"
+                    " multi-val)" if cand.has_multival else ""))
+        return cand
 
     def create_valid(self, data: np.ndarray,
                      label: Optional[Sequence[float]] = None,
@@ -1057,6 +1086,7 @@ class Dataset:
             "group_num_bins": None if self.group_num_bins is None
             else [int(v) for v in self.group_num_bins],
             "mv_group_start": self.mv_group_start,
+            "bundle_conflict_rows": int(self.bundle_conflict_rows),
         }
         # write to the EXACT path the caller gave (reference .bin
         # convention) — a bare np.savez would silently append .npz
@@ -1149,6 +1179,8 @@ class Dataset:
             if meta.get("mv_group_start") is not None:
                 self.mv_group_start = meta["mv_group_start"]
                 self.mv_slots = z["mv_slots"]
+            self.bundle_conflict_rows = int(
+                meta.get("bundle_conflict_rows", 0))
             self.num_data = len(self.binned)
             md = Metadata(self.num_data)
             if len(z["label"]):
@@ -1161,6 +1193,7 @@ class Dataset:
             if len(z["init_score"]):
                 md.init_score = z["init_score"]
             self.metadata = md
+        self.count_bundle_telemetry()
         return self
 
 

@@ -424,6 +424,9 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         get_telemetry().count("learner.cat_scan_traces")
     if plan.wide:
         get_telemetry().count("learner.wide_table_traces")
+    if bundled:
+        # the EFB route: group histograms debundled before every scan
+        get_telemetry().count("learner.bundled_traces")
 
     # shared scan-leaf composition (ops/split.py — the fused
     # megakernel twin calls the SAME maker, keeping both paths
@@ -433,10 +436,12 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
     reduce_root, select_root, to_scan = comm_root_hooks(comm)
     scan_root = make_scan_leaf(comm, meta, params, feature_mask,
                                node_rand, bundled, max_depth,
-                               select=select_root)
+                               select=select_root,
+                               debundle_scope=scopes.ROOT_DEBUNDLE)
     if body_scan is None:
         scan_body = make_scan_leaf(comm, meta, params, feature_mask,
-                                   node_rand, bundled, max_depth)
+                                   node_rand, bundled, max_depth,
+                                   debundle_scope=scopes.SPLITS_DEBUNDLE)
     else:
         node_rand_body = make_node_rand(
             body_scan.rand_key, body_scan.fmask,
@@ -444,7 +449,8 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
             extra_trees, ff_bynode, bynode_cap=body_scan.bynode_cap)
         scan_body = make_scan_leaf(comm, body_scan.meta, params,
                                    body_scan.fmask, node_rand_body,
-                                   bundled, max_depth)
+                                   bundled, max_depth,
+                                   debundle_scope=scopes.SPLITS_DEBUNDLE)
 
     def scan_leaf_pf(hist, g, h, c, depth, cmin, cmax, salt, cegb_used):
         # CEGB candidate-cache scan (see learner/serial.py): best from

@@ -53,6 +53,15 @@ SPLITS_CACHE = "lgbm.grow.splits.cache"
 CAT_SCAN = "lgbm.cat_scan"
 SPLIT_PHASE_SCOPES = (SPLITS_PARTITION, SPLITS_HIST, SPLITS_SCAN,
                       SPLITS_CACHE, CAT_SCAN)
+# a bundled table's own (EFB; ops/histogram.py debundle_leaf_hist): the
+# group histograms of a leaf expanded to one histogram a logical
+# feature, before the scan. In the split body it is a child of
+# GROW_SPLITS beside the four above, at the root a child of GROW_ROOT,
+# so that the scan's scope holds the scan alone and the root's the
+# root histogram. An unbundled table's program traces neither.
+SPLITS_DEBUNDLE = "lgbm.grow.splits.debundle"
+ROOT_DEBUNDLE = "lgbm.grow.root.debundle"
+BUNDLE_SCOPES = (SPLITS_DEBUNDLE, ROOT_DEBUNDLE)
 
 # host spans of the fused driver, on the profiler's clock
 # (Telemetry.span(..., trace=<name>))
@@ -60,6 +69,12 @@ BLOCK_DISPATCH = "lgbm.block.dispatch"
 BLOCK_SYNC = "lgbm.block.sync"
 BLOCK_TREES = "lgbm.block.trees"
 EVAL = "lgbm.eval"
+# host spans of a sparse table's construction (Dataset.from_scipy): the
+# bundle plan, and the binned matrix written from the stored entries.
+# They run in set-up, before any profile is taken: read them from
+# ``Telemetry.spans``
+DATA_BUNDLE_PLAN = "lgbm.data.bundle_plan"
+DATA_EXTRACT = "lgbm.data.extract"
 
 PREFIX = "lgbm."
 

@@ -357,6 +357,14 @@ class Telemetry:
             with self._lock:
                 self.counters[name] = self.counters.get(name, 0.0) + v
 
+    def set_counter(self, name: str, value: float) -> None:
+        """A counter that states a fact of the newest object of its
+        kind (a constructed dataset's ``data.bundle_*``) and is
+        written whole, not added to."""
+        if self._enabled:
+            with self._lock:
+                self.counters[name] = float(value)
+
     def count_iter(self, name: str, value: float = 1.0) -> None:
         """Counter that ALSO accumulates into the current iteration's
         ``counts`` table (flushed into the ``iter`` record by

@@ -60,17 +60,13 @@ from jax.experimental.pallas import tpu as pltpu
 from ..observability.telemetry import get_telemetry
 from ..utils.device import on_tpu
 from ..utils.jit_registry import register_jit
+from ..utils.matrix_layout import matrix_cols
 
 ALIGN = 8          # Mosaic offset granule for u8 2-D row slices
-GH_COLS = 13       # payload columns appended after the features
 RID_OFF = 9        # row-id bytes start at column F + RID_OFF
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
-
-
-def matrix_cols(num_features: int) -> int:
-    return _round_up(num_features + GH_COLS, 128)
 
 
 def matrix_rows(n: int, blk: int = 2048) -> int:

@@ -180,10 +180,21 @@ def debundle_hist(hist_g: jnp.ndarray, group: jnp.ndarray,
     b = hist_g.shape[1]
     hf = hist_g[group]                               # [F, B, 3]
     bins = jnp.arange(b, dtype=jnp.int32)[None, :]   # [1, B]
-    src = offset[:, None] + bins - 1                 # [F, B]
     valid = (bins >= 1) & (bins < num_bins[:, None])
-    gathered = jnp.take_along_axis(
-        hf, jnp.clip(src, 0, b - 1)[:, :, None], axis=1)
+    # feature bin k is group bin o - 1 + k: the group's row moved left
+    # by o - 1, a row gather and then one static roll a bit of the
+    # shift (what wraps around lands in slots ``valid`` masks: the
+    # last bin read, o + nb - 2, is inside the group's budget). An
+    # index a bin (``take_along_axis``) is F x B element gathers, which
+    # the TPU runs one at a time: 10.5 ms for both children of a split
+    # at 4,228 features against 1.6 ms for this (chip, PERF.md PR 33;
+    # ``tools/check_kernels_on_chip.py debundle`` holds the two equal)
+    shift = jnp.where(offset > 0, offset - 1, 0)
+    gathered = hf
+    for bit in range(max(b - 1, 1).bit_length()):
+        take = ((shift >> bit) & 1).astype(bool)[:, None, None]
+        gathered = jnp.where(take, jnp.roll(gathered, -(1 << bit), axis=1),
+                             gathered)
     x = jnp.where(valid[:, :, None], gathered, 0.0)
     sums = x.sum(axis=1)                             # [F, 3]
     f = hf.shape[0]

@@ -27,6 +27,8 @@ by the learner.
 
 from __future__ import annotations
 
+import contextlib
+
 from typing import NamedTuple
 
 import jax
@@ -646,7 +648,8 @@ def child_columns(split, g, h, c, out, cmin, cmax, s, side, depth,
 
 
 def make_scan_leaf(comm, meta_scan, params, feature_mask, node_rand,
-                   bundled: bool, max_depth: int, select=None):
+                   bundled: bool, max_depth: int, select=None,
+                   debundle_scope: str | None = None):
     """One leaf's best-split scan (debundle -> per-node randomness ->
     comm.select_split -> max_depth blocking) — ONE definition shared by
     the serial and partitioned grow bodies AND the fused megakernel's
@@ -654,15 +657,19 @@ def make_scan_leaf(comm, meta_scan, params, feature_mask, node_rand,
     parity with the foil rests on this being the same function.
     ``select`` overrides ``comm.select_split`` where the root and
     per-split scan layouts differ (the data-parallel reduce-scatter
-    recipe scans the root replicated, learner/comm.py)."""
+    recipe scans the root replicated, learner/comm.py).
+    ``debundle_scope`` names the EFB debundle's device scope where the
+    caller's program has one (observability/scopes.py BUNDLE_SCOPES)."""
     if select is None:
         select = comm.select_split
 
     def scan_leaf(hist, g, h, c, depth, cmin, cmax, salt):
         if bundled:
             from .histogram import debundle_leaf_hist
-            hist = debundle_leaf_hist(hist, meta_scan, g, h, c,
-                                      comm.local_hist)
+            with jax.named_scope(debundle_scope) if debundle_scope \
+                    else contextlib.nullcontext():
+                hist = debundle_leaf_hist(hist, meta_scan, g, h, c,
+                                          comm.local_hist)
         rb, nm = node_rand(salt)
         fm = feature_mask if nm is None else nm  # nm already in-subset
         res = select(hist, g, h, c, meta_scan, params,

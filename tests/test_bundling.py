@@ -38,7 +38,7 @@ def test_plan_bundles_sparse_features_collapse():
     X, y = _sparse_problem()
     cfg = Config.from_params({"objective": "binary", "verbosity": -1})
     ds = Dataset.from_numpy(X, cfg, label=y)
-    # sparse features (3% density, conflict budget n/10000) must bundle
+    # sparse features (3% density, no shared row allowed) must bundle
     assert ds.feature_group is not None
     assert ds.num_groups < ds.num_features / 2
     assert ds.binned.shape[1] == ds.num_groups

@@ -116,6 +116,25 @@ def test_wide_stage_tiny(fuse_iters):
     assert abs(report["auc"] - foil["auc"]) <= cs.WIDE_FOIL_AUC_TOL
 
 
+def test_bundled_stage_tiny(fuse_iters):
+    """The stage of the sparse one-hot table (ISSUE 33): a scipy CSR
+    of the benchmark's 4,228 columns, bundled by the dataset into a few
+    byte columns, through the bundled per-phase body in fused blocks,
+    against the XLA foil on the same bundled table."""
+    x, y = cs.allstate_like(4000)
+    assert x.shape == (4000, 4228) and x.nnz == 4000 * 33
+    params = dict(TINY, tree_learner="partitioned")
+    _, report = cs.stage_train(x, y, params, cs.ONEHOT_ROUNDS,
+                               learner="PartitionedTreeLearner",
+                               interpret=True, megakernel=False,
+                               bundled=True, min_auc=cs.ONEHOT_MIN_AUC)
+    assert report["fused_block_hits"] == 1      # 1 sync + one block of 4
+    assert report["bundled"] == "on" and report["lut_partition"] == "on"
+    assert report["bundle_conflict_rows"] == 0
+    foil = cs.stage_foil(x, y, params, cs.ONEHOT_ROUNDS)
+    assert abs(report["auc"] - foil["auc"]) <= cs.ONEHOT_FOIL_AUC_TOL
+
+
 @pytest.mark.slow
 def test_kernel_and_foil_stages_tiny(fuse_iters):
     kernels = cs.stage_kernels(
@@ -123,9 +142,10 @@ def test_kernel_and_foil_stages_tiny(fuse_iters):
         hist=dict(shapes=((2100, 28, 256),)),
         partition_v1=dict(shapes=((2100, 28, 256),)),
         split_scan=dict(shapes=((28, 256, False),)),
-        fused_split=dict(rows=1500, features=28, leaves=7))
+        fused_split=dict(rows=1500, features=28, leaves=7),
+        debundle=dict(columns=9, numeric=3, indicators=300))
     assert set(kernels) == {"hist", "partition_v1", "split_scan",
-                            "fused_split"}
+                            "fused_split", "debundle"}
     x, y = cs.higgs_like(2000)
     params = dict(TINY, tree_learner="partitioned")
     _, report = cs.stage_train(x, y, params, cs.ROUNDS,

@@ -609,3 +609,64 @@ def test_histogram_cache_is_written_in_place_on_v5e(one_chip, monkeypatch,
     cache_bytes = 255 * f * ln.num_bins_max * 3 * 4
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 1.5 * cache_bytes + (64 << 20)
+
+
+def test_bundled_grow_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The fused block of a bundled one-hot table at the benchmark's
+    widths (``allstate-12m-train``: 4,228 logical features in 47 byte
+    columns, 255 leaves), compiled for the v5e under the chip's plan:
+    the table partition, the group histogram over 47 columns, the
+    debundle to 4,228 per-feature histograms under its two scopes, the
+    Pallas scan over 34 feature blocks. The per-leaf cache stays in
+    group layout (``[255, 47, 256, 3]``, 37 MB) and is written in
+    place, never copied; the ``[F, 256, 3]`` expansion is made a split
+    and is no part of the loop's carry."""
+    import re
+
+    import lightgbm_tpu.learner.split_step as split_step
+    from benchmarks.generators.allstate_like import CARDS, NUMERIC
+    from benchmarks.kinds.train_sparse import _probe_table
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data import Dataset
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
+    from lightgbm_tpu.models.gbdt import GBDT, _fused_iter_block
+    from lightgbm_tpu.observability import scopes
+
+    monkeypatch.setattr(split_step, "on_tpu", lambda: True)
+    x, y = _probe_table(NUMERIC + sum(CARDS), NUMERIC, list(CARDS))
+    cfg = Config.from_params({
+        "objective": "binary", "num_leaves": 255, "verbosity": -1,
+        "min_data_in_bin": 1, "feature_pre_filter": False})
+    ds = Dataset.from_scipy(x, cfg, label=y)
+    b = GBDT(cfg, ds)
+    ln = PartitionedTreeLearner(ds, cfg, interpret=False)
+    f, g, bins = ln.num_features, ln.num_groups, ln.num_bins_max
+    assert (f, g, bins) == (4228, 47, 256)
+    plan = ln.split_plan()
+    assert plan.body == "per_phase" and plan.scan_kernel \
+        and plan.lut_partition and not plan.cat_scan
+    sds = lambda a: jax.ShapeDtypeStruct(               # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    compiled = jax.jit(
+        functools.partial(_fused_iter_block, learner=ln,
+                          grad_fn=b._grad_fn, bag_fn=None,
+                          valid_data=(), k=1),
+        static_argnames=("m",)).lower(
+        sds(ln.mat), sds(ln.ws), sds(b.train_score), (),
+        sds(jnp.float32(0.1)), sds(jnp.int32(0)), m=1).compile()
+    text = compiled.as_text()
+    cache = r"f32\[255,%d,%d,3\]" % (g, bins)
+    writes = re.findall(r"= %s\S* dynamic-update-slice\(" % cache, text)
+    copies = re.findall(r"= %s\S* copy\(" % cache, text)
+    assert len(writes) >= 2 and not copies, (len(writes), copies)
+    # both debundle scopes own instructions; the scan is a Mosaic call
+    table = scopes.parse_hlo_scopes(text)
+    assert set(scopes.BUNDLE_SCOPES) <= set(table.values())
+    assert "tpu_custom_call" in text
+    # no while loop carries a buffer with a logical-feature axis
+    for carry in re.findall(r"= (\([^\n]*?\)) while\(", text):
+        assert not re.search(r"\[(?:2,)?%d,%d(?:,3)?\]" % (f, bins),
+                             carry), carry[:200]
+    cache_bytes = 255 * g * bins * 3 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.5 * cache_bytes + (64 << 20)

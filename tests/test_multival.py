@@ -12,8 +12,12 @@ from lightgbm_tpu.data import Dataset
 from lightgbm_tpu.learner.serial import SerialTreeLearner
 
 
-def _bosch_like(n=2500, f=150, density=0.04, seed=3):
-    """>=95% sparse, conflicting nonzeros -> no exclusive bundles."""
+def _bosch_like(n=2500, f=300, density=0.04, seed=3):
+    """>=95% sparse, conflicting nonzeros -> no exclusive bundles.
+    300 columns: sparse groups that fit the training matrix's 128-byte
+    row (up to 112 columns) stay physical byte columns, which cost the
+    chip nothing extra; multi-val is for a set that would widen the
+    row, as here."""
     rng = np.random.RandomState(seed)
     X = np.where(rng.rand(n, f) < density,
                  rng.randint(1, 9, size=(n, f)) * 0.5, 0.0)
@@ -111,7 +115,7 @@ def test_multival_dense_parity_auc():
 
 
 def test_multival_binary_cache_roundtrip(tmp_path):
-    X, y = _bosch_like(n=1200)
+    X, y = _bosch_like()
     cfg = Config.from_params({"objective": "binary", "verbosity": -1})
     ds = Dataset.from_numpy(X, cfg, label=y)
     assert ds.has_multival

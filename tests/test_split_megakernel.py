@@ -304,6 +304,12 @@ PLAN_ROWS = [
     ("wide-on-cpu-twin", dict(num_features=2000, mode="on", tpu=False,
                               interpret=True),
      (MEGA, False, False, False)),
+    # 4,228 one-hot columns bundled into 47 byte columns whose widest
+    # holds 256 values: the plan sees the physical width, so the
+    # width does not refuse; the bundles do
+    ("allstate-12m-train", dict(bundled=True, num_bins_max=256,
+                                num_features=47),
+     (PHASE, True, True, False)),
     ("bundled", dict(bundled=True), (PHASE, True, True, False)),
     ("257-bins", dict(num_bins_max=257), (PHASE, True, False, False)),
     ("forced-plan", dict(forced_plan=((0, 1, 3, False),)),
@@ -389,6 +395,23 @@ def test_cells_plans_from_real_learners(monkeypatch):
     assert wide.split_plan() == split_step.SplitStepPlan(
         PHASE, True, False, False, wide=True)
     assert wide.params.use_scan_kernel
+    # Allstate: a one-hot CSR of 4,228 columns that the dataset
+    # bundles into 47 (the benchmark's width probe: every column holds
+    # a value); the learner's width is the physical one
+    from benchmarks.generators.allstate_like import CARDS, NUMERIC
+    from benchmarks.kinds.train_sparse import _probe_table
+    x, y = _probe_table(NUMERIC + sum(CARDS), NUMERIC, list(CARDS))
+    sparse_cfg = Config.from_params({
+        "objective": "binary", "num_leaves": 255, "verbosity": -1,
+        "min_data_in_bin": 1, "feature_pre_filter": False})
+    onehot = PartitionedTreeLearner(
+        Dataset.from_scipy(x, sparse_cfg, label=y), sparse_cfg,
+        interpret=False)
+    assert (onehot.num_features, onehot.num_groups) == (4228, 47)
+    assert onehot.bundled and onehot.cache_hists
+    assert onehot.split_plan() == split_step.SplitStepPlan(
+        PHASE, True, True, False)
+    assert onehot.params.use_scan_kernel
 
 
 def test_forced_splits_keep_foil_for_forced_steps(tmp_path):

@@ -7,14 +7,16 @@ hardware-compile failures. Each stage here runs one kernel the chip
 path can select — the one-hot histogram stream (``histogram_segment``:
 whole rows up to ``MAX_FUSED_F`` columns, column slices past it),
 ``partition_segment``, split-scan, and the split-step megakernel —
-COMPILED against a NumPy/XLA oracle. The first shape of every stage is
+COMPILED against a NumPy/XLA oracle, and the one XLA function a bundled
+table's split walks at a one-hot table's real width (``debundle``). The first shape of every stage is
 the Higgs width (28 features, 256 bins).
 
 ``chip_smoke.py`` calls the stage functions in-process; standalone:
 
     python tools/check_kernels_on_chip.py [stage ...]
 
-Stages: hist partition_v1 split_scan fused_split (default: all). Every
+Stages: hist partition_v1 split_scan fused_split debundle (default:
+all). Every
 requested stage runs every time — no verdict is remembered between
 runs. A stage returns its number of failed comparisons; a kernel the
 compiler refuses raises. Exits non-zero unless every stage passed.
@@ -149,6 +151,78 @@ def stage_partition_v1(interpret: bool = False,
                       f"({int(nl_c[1]) / max(windows, 1):.1%}; "
                       f"host rule {merged})", flush=True)
                 failures += 0 if ok else 1
+    return failures + _partition_bundled_split(interpret)
+
+
+def _partition_bundled_split(interpret: bool) -> int:
+    """``partition_segment`` with the 256-entry table of a BUNDLED
+    numeric split (``partition_decision_lut``: group value -> feature
+    bin -> goes left), on a one-hot table as ``Dataset.from_scipy``
+    bundles it: an indicator in the middle of a bundle, and a raw
+    numeric column of the same matrix, which takes the threshold
+    compare through the same compiled kernel."""
+    import jax.numpy as jnp
+    import numpy as np
+    import scipy.sparse as sp
+
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data import Dataset
+    from lightgbm_tpu.data.bundling import decode_feature_bin
+    from lightgbm_tpu.learner.serial import feature_meta_from_dataset
+    from lightgbm_tpu.ops.hist_pallas import (build_matrix,
+                                              extract_row_ids)
+    from lightgbm_tpu.ops.partition_pallas import (partition_decision_lut,
+                                                   partition_segment)
+    rng = np.random.RandomState(7)
+    n, cards = 6000, (40, 300, 5)
+    dense = np.zeros((n, 2 + sum(cards)), np.float32)
+    dense[:, :2] = rng.randn(n, 2)
+    base = 2
+    for c in cards:
+        dense[np.arange(n), base + rng.randint(0, c, n)] = 1.0
+        base += c
+    cfg = Config.from_params({"objective": "binary", "verbosity": -1,
+                              "min_data_in_bin": 1,
+                              "feature_pre_filter": False})
+    ds = Dataset.from_scipy(sp.csr_matrix(dense), cfg,
+                            label=dense[:, 0] > 0)
+    assert ds.feature_group is not None and not ds.has_multival \
+        and ds.bundle_conflict_rows == 0, "the table did not bundle"
+    meta = feature_meta_from_dataset(ds, cfg)
+    g = ds.num_groups
+    mat = build_matrix(jnp.asarray(ds.binned), 2048)
+    offsets = np.asarray(ds.feature_offset)
+    inside = int(np.flatnonzero(offsets > 100)[0])  # deep in a bundle
+    raw = int(np.flatnonzero(offsets == 0)[0])
+    failures = 0
+    for feat, thr in ((inside, 0), (raw, 120)):
+        grp_col, use_lut, lut = partition_decision_lut(
+            meta, jnp.int32(feat), jnp.int32(thr), jnp.bool_(False),
+            jnp.bool_(False), jnp.zeros((8,), jnp.uint32), True)
+        for begin, count in _segments(n, 13):
+            m_c, _, nl_c = partition_segment(
+                mat, jnp.zeros_like(mat), jnp.int32(begin),
+                jnp.int32(count), grp_col, jnp.int32(thr), jnp.int32(0),
+                meta.missing[feat], meta.default_bin[feat],
+                meta.num_bins[feat], use_lut.astype(jnp.int32), lut,
+                blk=512, interpret=interpret, use_lut_path=True)
+            sl = slice(begin, begin + count)
+            fbin = decode_feature_bin(
+                ds.binned[sl, ds.feature_group[feat]].astype(np.int64),
+                int(offsets[feat]), int(ds.num_bin(feat)))
+            go_left = fbin <= thr
+            rid_seg = np.asarray(extract_row_ids(m_c, g, mat.shape[0]))[sl]
+            rid_orig = np.arange(n)[sl]
+            want = np.concatenate([rid_orig[go_left], rid_orig[~go_left]])
+            ok = (int(nl_c[0]) == int(go_left.sum())
+                  and np.array_equal(rid_seg[:count], want)
+                  and bool(use_lut) == (offsets[feat] > 0))
+            print(f"partition bundled [{n}x{ds.num_features} in {g}] "
+                  f"feature={feat} offset={int(offsets[feat])} "
+                  f"seg=({begin},{count}) table={bool(use_lut)}: "
+                  f"{'ok ' if ok else 'FAIL'} "
+                  f"left={int(nl_c[0])}/{int(go_left.sum())}", flush=True)
+            failures += 0 if ok else 1
     return failures
 
 
@@ -300,9 +374,66 @@ def stage_fused_split(interpret: bool = False, rows: int = 20000,
     return 0 if ok else 1
 
 
+def stage_debundle(interpret: bool = False, columns: int = 47,
+                   numeric: int = 16, indicators: int = 4212) -> int:
+    """``debundle_hist`` (a row gather and one static roll a bit of the
+    shift) against the form it replaced (PR 33), an index a bin
+    (``take_along_axis``), compiled for the same device: equal bit for
+    bit for both children of a split, at the one-hot table's width
+    (``allstate-onehot``: 16 raw numeric columns and 4,212 two-bin
+    indicators in 31 bundles, 4,228 features in 47 columns). Plain
+    XLA, so ``interpret`` changes nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from lightgbm_tpu.ops.histogram import debundle_hist
+    bins = 256
+    bundles = columns - numeric
+    sizes = [indicators // bundles + (k < indicators % bundles)
+             for k in range(bundles)]
+    assert max(sizes) < bins
+    group = np.r_[np.arange(numeric),
+                  np.repeat(numeric + np.arange(bundles), sizes)]
+    offset = np.r_[np.zeros(numeric, np.int64),
+                   np.concatenate([1 + np.arange(m) for m in sizes])]
+    num_bins = np.r_[np.full(numeric, bins), np.full(indicators, 2)]
+    group, offset, num_bins = (jnp.asarray(a, jnp.int32)
+                               for a in (group, offset, num_bins))
+
+    def index_a_bin(hist_g, leaf):
+        hf = hist_g[group]
+        at = jnp.arange(bins, dtype=jnp.int32)[None, :]
+        picked = jnp.take_along_axis(
+            hf, jnp.clip(offset[:, None] + at - 1, 0, bins - 1)[:, :, None],
+            axis=1)
+        x = jnp.where(((at >= 1) & (at < num_bins[:, None]))[:, :, None],
+                      picked, 0.0)
+        x = x.at[:, 0, :].set(leaf[None, :] - x.sum(axis=1))
+        return jnp.where((offset > 0)[:, None, None], x, hf)
+
+    def rolls(hist_g, leaf):
+        return debundle_hist(hist_g, group, offset, num_bins,
+                             leaf[0], leaf[1], leaf[2])
+
+    rng = np.random.default_rng(11)
+    hist_g = jnp.asarray(rng.random((2, columns, bins, 3),
+                                    dtype=np.float32))
+    leaf = hist_g[:, 0].sum(axis=1)                  # [2, 3]
+    got = jax.jit(jax.vmap(rolls))(hist_g, leaf)
+    want = jax.jit(jax.vmap(index_a_bin))(hist_g, leaf)
+    apart = int(np.count_nonzero(np.asarray(got) != np.asarray(want)))
+    ok = apart == 0 and got.shape == (2, len(group), bins, 3)
+    print(f"debundle [{columns} columns -> {len(group)} features x "
+          f"{bins} bins, both children]: {'ok ' if ok else 'FAIL'} "
+          f"{apart} values apart", flush=True)
+    return 0 if ok else 1
+
+
 STAGE_FNS = {"hist": stage_hist, "partition_v1": stage_partition_v1,
              "split_scan": stage_split_scan,
-             "fused_split": stage_fused_split}
+             "fused_split": stage_fused_split,
+             "debundle": stage_debundle}
 STAGES = tuple(STAGE_FNS)
 
 
