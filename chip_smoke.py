@@ -57,6 +57,7 @@ this file).
 """
 
 import argparse
+import hashlib
 import json
 import sys
 import time
@@ -250,6 +251,7 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
                "learner.wide_table_traces",
                "learner.bundled_traces",
                "kernels.partition_pipelined",
+               "kernels.partition_one_compaction",
                "kernels.hist_child_stream",
                "kernels.hist_feature_slices")}
     t0 = time.perf_counter()
@@ -283,6 +285,12 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "hist_feature_slices": delta["kernels.hist_feature_slices"],
         # kernel traces that took partition_pallas.partition_stream
         "partition_pipelined": delta["kernels.partition_pipelined"],
+        # compactions (a one-hot and its permutation product) those
+        # traces hold, counted where one enters a trace: one a stream
+        # (PR 34; the block step before it held two and the back-copy
+        # a third)
+        "partition_one_compaction":
+        delta["kernels.partition_one_compaction"],
         # kernel traces through hist_pallas.hist_child_stream, the
         # one histogram form: the root's and a leaf segment's program,
         # the sliced one, the megakernel's second stream
@@ -294,6 +302,12 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "num_shards": int(getattr(ln, "num_shards", 1)),
         "auc": round(train_auc(bst, x, y), 6),
         "train_seconds": round(seconds, 1),
+        # the trees themselves: the tables are seeded, so a change
+        # that moves the same bytes in the same order (a partition
+        # kernel's) leaves every stage's hash as the last PR wrote it
+        # down (PERF.md section 6)
+        "model_sha256": hashlib.sha256(
+            bst.model_to_string().encode()).hexdigest()[:16],
     }
     print(f"path[{learner}]: {json.dumps(report)}", flush=True)
     assert report["learner"] == learner, report
@@ -318,6 +332,10 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     # every compiled partition, the megakernel's phase 0 included, is
     # the pipelined stream (the megakernel's interpret twin has none)
     assert report["partition_pipelined"] > 0 or interpret, report
+    # ... and holds one compaction: a second one in the block step or
+    # one in the back-copy would count 2 or 3 a stream
+    assert report["partition_one_compaction"] \
+        == report["partition_pipelined"], report
     # every histogram of every compiled route is the one-hot stream:
     # the root's and the per-phase body's ``histogram_segment``, the
     # megakernel's phase 0 (in interpret mode the count is 0 where the

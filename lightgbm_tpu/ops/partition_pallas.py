@@ -14,15 +14,27 @@ of the split-step megakernel, which imports it):
      lane reduction) and decide left/right (numerical threshold with
      missing handling, or categorical bitset via a 256-entry LUT
      matmul) — the caller's ``decide``;
-  2. stable-compact the block's left rows via a permutation matmul
-     (PT[src, dst] one-hot x row block on the MXU — bin/payload bytes
-     are exact in bf16) and write them at the left write head IN
-     PLACE; rights go to a workspace buffer the same way;
-  3. after the stream, copy the workspace back behind the lefts.
+  2. stable-compact the block ONCE (PR 34): both masks become rows
+     and are prefix-summed in one product; one destination-major
+     one-hot ``PT[dst, src]`` sends the lefts to the left write
+     head's place in their window and the rights to the right write
+     head's place in a window of their own, which begins at the first
+     8-row boundary past the lefts; one permutation product (one-hot x
+     row block on the MXU -- bin/payload bytes are exact in bf16)
+     fills both windows. The lefts' window is written at the left
+     write head IN PLACE, the rights' to a workspace buffer;
+  3. after the stream, copy the workspace back behind the lefts. Its
+     rows are compact already and the block is a multiple of 8 rows,
+     so every block of a call goes the same ``(begin + NL) % 8`` rows
+     down its window: a sublane roll, no compaction.
 
 Writes go through windows aligned to Mosaic's 8-row u8 granule, so
 segment boundaries can sit anywhere and neighbours' rows survive.
-Prefix sums are triangular matmuls (no native cumsum).
+Prefix sums are triangular matmuls (no native cumsum). No operand of
+a product is one the VPU has to transpose, and the slot arithmetic
+runs on [8, win] rows, not on [win, 1] columns of one lane a vreg: a
+column mask becomes a row through the MXU's own transposed-operand
+form (``pick`` x ``sel_cols``^T).
 
 The pipeline (PR 28): no DMA wait on the stream's critical path.
   * Input: two ``inbuf`` slots; block k+1 (and workspace window j+1
@@ -30,20 +42,24 @@ The pipeline (PR 28): no DMA wait on the stream's critical path.
   * Write heads carried in VMEM: of a destination window only the
     up-to-7 rows before ``dest`` in its granule must survive where
     everything else it covers is dead — consumed rows not yet
-    rewritten, workspace scratch — and those rows are the side's
-    previous window's own (``head``). Such a window takes the FAST
-    path and reads nothing back: every workspace window; a forward
-    left window that ends at or before the last row its block
-    consumed (``forward_fast``); a back-copy window after the first
-    that lies inside the segment (``back_fast``). Any other window —
-    block 0, a left window before ~8 rows have gone right, a
-    segment's last windows — takes the old read-merge-write.
+    rewritten, workspace scratch; dead whatever is written there: the
+    tail of the same product, which holds the block's rights, or rows
+    of ``staged`` no product of the call has reached — and those rows
+    are the side's previous window's own
+    (``head``). Such a window takes the FAST path and reads nothing
+    back: every workspace window; a forward left window that ends at
+    or before the last row its block consumed (``forward_fast``); a
+    back-copy window after the first that lies inside the segment
+    (``back_fast``). Any other window — block 0, a left window
+    before ~8 rows have gone right, a segment's last windows — takes
+    the old read-merge-write.
   * Writes behind the computation: a window's write is started and
     waited for only when its side's next window is ready to go (one
-    compaction later), before a merge reads that side, before the
-    back-copy reads the workspace, and at the end. One write a side
-    in flight, not two: consecutive windows of a side overlap, and
-    two overlapping writes in flight could land in either order.
+    block -- one compaction -- later), before a merge reads that
+    side, before the back-copy reads the workspace, and at the end.
+    One write a side in flight, not two: consecutive windows of a
+    side overlap, and two overlapping writes in flight could land in
+    either order.
   * The prefetched window of block k+1 begins ``shift`` (< 8) rows
     inside block k's rows, which a fast left write may be touching;
     ``valid`` masks them out of the decision and the carried head.
@@ -52,7 +68,11 @@ trees are byte-identical to the unpipelined kernel's.
 
 Returns the left-row count NL and the number of windows that took the
 merge path (``merge_windows`` is the host twin); children are
-[begin, begin+NL) and [begin+NL, begin+count).
+[begin, begin+NL) and [begin+NL, begin+count). The compactions a call
+runs are a property of the program, not of the data:
+``stream_compactions`` is the host rule, and the products over whole
+rows in each loop's traced body are the count it is held to
+(tests/test_partition_v2.py, ``tools/check_kernels_on_chip.py``).
 """
 
 from __future__ import annotations
@@ -68,6 +88,10 @@ from ..observability.telemetry import get_telemetry
 from ..utils.jit_registry import register_jit
 
 ALIGN = 8
+# rows of ``staged`` past a window that one product fills: the lefts'
+# window and, from the first granule boundary past the lefts, the
+# rights' (head rows included) end at most 8 + 7 + 7 rows past ``blk``
+PAD = 2 * ALIGN
 
 # scalar input slots
 S_BEGIN, S_COUNT, S_FEAT, S_THR, S_DLEFT, S_MISS, S_DEFBIN, S_NBINS, \
@@ -88,11 +112,16 @@ _SEM_IN, _SEM_RBUF, _SEM_W = 0, 2, 3
 
 def stream_scratch(blk: int, cols: int):
     """``scratch_shapes`` of ``partition_stream``, in the order it takes
-    them (``inbuf, staged, flush, rbuf, head, sems``)."""
+    them (``inbuf, staged, flush, rbuf, head, sems``). ``staged`` holds
+    two windows: the one product of a block fills its first
+    ``win + PAD`` rows, and the rights' window, a static ``win`` rows
+    from wherever the lefts end, starts as late as row ``win`` (a
+    block whose rows all go left). The rows past ``win + PAD`` are
+    never written: they reach a workspace window's dead tail alone."""
     win = blk + ALIGN
     return [
         pltpu.VMEM((2, win, cols), jnp.uint8),       # inbuf: 2 slots
-        pltpu.VMEM((win, cols), jnp.float32),        # staged window
+        pltpu.VMEM((2 * win, cols), jnp.float32),    # staged windows
         pltpu.VMEM((2, win, cols), jnp.uint8),       # flush: per side
         pltpu.VMEM((win, cols), jnp.uint8),          # rbuf: merge path
         pltpu.VMEM((2, ALIGN, cols), jnp.float32),   # head: per side
@@ -104,24 +133,32 @@ def partition_stream(mat_hbm, ws_hbm, scratch, begin, count, decide,
                      *, blk: int):
     """The pipelined block stream both partition kernels run: stable
     partition of ``mat_hbm[begin, begin+count)`` in place, rights via
-    ``ws_hbm``. ``decide(mat_i32, mat_f, valid, shift, rem)`` returns
-    the block's ``(go_left, go_right)`` [win, 1] i32 0/1 masks (already
-    masked by ``valid``): the decision alone, in both kernels (the
-    megakernel histograms the smaller child in a stream of its own,
-    after this one has returned). Returns ``(NL, merge-path windows)``.
-    Every write has landed by then, the back-copy's included, and the
+    ``ws_hbm``. ``decide(mat_i32, mat_f, valid)`` returns the block's
+    ``(go_left, go_right)`` [win, 1] i32 0/1 masks (already masked by
+    ``valid``): the decision alone, in both kernels (the megakernel
+    histograms the smaller child in a stream of its own, after this
+    one has returned). Returns ``(NL, merge-path windows)``. Every
+    write has landed by then, the back-copy's included, and the
     ``inbuf`` slots and their semaphores are free.
 
-    No DMA wait sits on the critical path (module docstring): block
-    k+1 is read while block k computes; each window write is waited
-    only when its side's next window is ready to go, a whole
-    compaction later; and a window is read back (``rbuf``) only where
-    it holds rows the stream does not own.
+    ONE compaction a forward block (module docstring): both masks
+    turned into rows and prefix-summed in one product, one
+    destination-major one-hot, one permutation product that fills the
+    lefts' window and, behind it, the rights'. The back-copy runs
+    none: its rows are compact already, so a block is rolled by the
+    one shift every block of a call shares.
+
+    No DMA wait sits on the critical path: block k+1 is read while
+    block k computes; each window write is waited only when its
+    side's next window is ready to go, a whole block later; and a
+    window is read back (``rbuf``) only where it holds rows the stream
+    does not own.
     """
     inbuf, staged, flush, rbuf, head, sems = scratch
     # counted where the helper enters a kernel's trace, like
     # ``learner.megakernel_traces``
-    get_telemetry().count("kernels.partition_pipelined")
+    tel = get_telemetry()
+    tel.count("kernels.partition_pipelined")
     win = blk + ALIGN
     nblk = pl.cdiv(count, blk)
     base = (begin // ALIGN) * ALIGN
@@ -131,12 +168,22 @@ def partition_stream(mat_hbm, ws_hbm, scratch, begin, count, decide,
 
     row_w = jax.lax.broadcasted_iota(jnp.int32, (win, 1), 0)
     row8 = jax.lax.broadcasted_iota(jnp.int32, (ALIGN, 1), 0)
-    dst_w = jax.lax.broadcasted_iota(jnp.int32, (win, win), 1)
-    # inclusive prefix-sum operator: tri[s, d] = s <= d
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (win, win), 0)
-           <= jax.lax.broadcasted_iota(jnp.int32, (win, win), 1))
-    tri_bf = jnp.where(tri, jnp.float32(1), jnp.float32(0)).astype(
-        jnp.bfloat16)
+    one, zero = jnp.float32(1), jnp.float32(0)
+    # inclusive prefix-sum operator on ROWS: tri[s, d] = s <= d
+    tri_bf = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (win, win), 0)
+        <= jax.lax.broadcasted_iota(jnp.int32, (win, win), 1),
+        one, zero).astype(jnp.bfloat16)
+    # masks as columns -> masks as rows: lane 0 of ``sel_cols`` is the
+    # lefts' mask, lane 1 the rights'; ``pick`` @ ``sel_cols``^T (the
+    # MXU's own transposed-operand form) has them as rows 0 and 1
+    lane_w = jax.lax.broadcasted_iota(jnp.int32, (win, 128), 1)
+    pick = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (ALIGN, 128), 0)
+        == jax.lax.broadcasted_iota(jnp.int32, (ALIGN, 128), 1),
+        one, zero).astype(jnp.bfloat16)
+    # destination row of the one-hot, destination-major
+    dst_w = jax.lax.broadcasted_iota(jnp.int32, (win + PAD, win), 0)
 
     def window(ref, start):
         return ref.at[pl.ds(pl.multiple_of(start, ALIGN), win), :]
@@ -161,43 +208,37 @@ def partition_stream(mat_hbm, ws_hbm, scratch, begin, count, decide,
     def load_block(slot):
         mat_i32 = inbuf[slot].astype(jnp.int32)          # [win, C]
         mat_f = mat_i32.astype(jnp.float32)
-        return mat_i32, mat_f, mat_f.astype(jnp.bfloat16)
+        return mat_i32, mat_f
 
-    def compact_and_write(mat_bf, sel, dest, side, fast, inflight):
-        """Stable-compact rows with sel==1 to ``outs[side][dest, ...)``
+    def write_window(side, r0, dest, n, fast, inflight):
+        """``staged[r0, r0+win)``, whose rows from ``dest % 8`` on are
+        the ``n`` rows for ``outs[side][dest, dest+n)``, goes out
         through the 8-aligned window that holds ``dest``; the write is
-        left in flight. Returns the number of rows written and the
-        window's first row (the side's next ``inflight``).
+        left in flight. Returns the window's first row (the side's
+        next ``inflight``).
 
         ``fast``: every window row outside [dest, dest+n) is either
         one of the up-to-7 rows before ``dest`` in its granule, which
         this side's previous window wrote and ``head`` carries, or
-        dead (consumed and not yet rewritten, or workspace scratch):
-        nothing is read back. Otherwise a read-merge-write keeps
-        the neighbours' and the unconsumed rows.
+        dead (consumed and not yet rewritten, or workspace scratch)
+        whatever it holds -- the other side's rows of the same
+        product, rows no product has written: nothing is read back.
+        Otherwise a read-merge-write keeps the neighbours' and the
+        unconsumed rows.
         """
-        sel_bf = sel.astype(jnp.float32).astype(
-            jnp.bfloat16)                               # [win, 1] 0/1
-        cs = jax.lax.dot_general(
-            tri_bf, sel_bf, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [win, 1] incl
-        n = cs[win - 1, 0].astype(jnp.int32)
         wstart = (dest // ALIGN) * ALIGN
         dshift = dest - wstart
-        slot = jnp.where(sel > 0, dshift + cs.astype(jnp.int32) - 1, -1)
-        pt = jnp.where(slot == dst_w, jnp.float32(1),
-                       jnp.float32(0)).astype(jnp.bfloat16)  # [win, win]
-        staged[...] = jax.lax.dot_general(
-            pt, mat_bf, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [win, C]
+        r0 = r0 if isinstance(r0, int) else pl.multiple_of(r0, ALIGN)
+        w_rows = pl.ds(r0, win)
         # windows of one side overlap, so its previous write must have
         # landed before this one starts (and before a merge reads the
         # window, and before ``flush[side]`` is refilled)
         drain(side, inflight)
 
         def carry_head():
-            staged[0:ALIGN, :] = jnp.where(
-                row8 < dshift, head[side], staged[0:ALIGN, :])
+            h_rows = pl.ds(r0, ALIGN)
+            staged[h_rows, :] = jnp.where(
+                row8 < dshift, head[side], staged[h_rows, :])
 
         def merge():
             cp = pltpu.make_async_copy(window(outs[side], wstart), rbuf,
@@ -205,8 +246,8 @@ def partition_stream(mat_hbm, ws_hbm, scratch, begin, count, decide,
             cp.start()
             cp.wait()
             keep = (row_w >= dshift) & (row_w < dshift + n)
-            staged[...] = jnp.where(
-                keep, staged[...],
+            staged[w_rows, :] = jnp.where(
+                keep, staged[w_rows, :],
                 rbuf[...].astype(jnp.int32).astype(jnp.float32))
 
         if fast is True:
@@ -214,12 +255,13 @@ def partition_stream(mat_hbm, ws_hbm, scratch, begin, count, decide,
         else:
             pl.when(fast)(carry_head)
             pl.when(jnp.logical_not(fast))(merge)
-        flush[side] = staged[...].astype(jnp.int32).astype(jnp.uint8)
+        flush[side] = staged[w_rows, :].astype(jnp.int32).astype(
+            jnp.uint8)
         write(side, wstart).start()
         # the rows before the side's next ``dest`` in its granule
-        nxt = ((dshift + n) // ALIGN) * ALIGN
+        nxt = r0 + ((dshift + n) // ALIGN) * ALIGN
         head[side] = staged[pl.ds(pl.multiple_of(nxt, ALIGN), ALIGN), :]
-        return n, wstart
+        return wstart
 
     # ---- forward: lefts in place, rights to the workspace ------------
     @pl.when(nblk > 0)
@@ -238,21 +280,54 @@ def partition_stream(mat_hbm, ws_hbm, scratch, begin, count, decide,
             read(mat_hbm, base + (k + 1) * blk, 1 - slot).start()
 
         read(mat_hbm, base + k * blk, slot).wait()
-        mat_i32, mat_f, mat_bf = load_block(slot)
+        mat_i32, mat_f = load_block(slot)
         rem = jnp.minimum(count - k * blk, blk)
         # all masks kept as i32 0/1: Mosaic cannot narrow i8 vectors to
         # i1, which jnp bool intermediates would require
         valid = jnp.where((row_w >= shift) & (row_w < shift + rem),
                           1, 0)                         # [win, 1] i32
-        gl, gr = decide(mat_i32, mat_f, valid, shift, rem)
+        gl, gr = decide(mat_i32, mat_f, valid)
+        # the compaction, counted where it enters a kernel's trace: a
+        # second one in this block, or one in the back-copy, reads 2
+        # or 3 a stream. Every operand as the MXU takes it (none
+        # transposed by the VPU), and all the slot arithmetic on
+        # [8, win] rows, not on [win, 1] columns of one lane a vreg
+        tel.count("kernels.partition_one_compaction")
+        sel_cols = jnp.where(
+            lane_w == 0, gl, jnp.where(lane_w == 1, gr, 0)).astype(
+            jnp.float32).astype(jnp.bfloat16)           # [win, 128]
+        sel = jax.lax.dot_general(
+            pick, sel_cols, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [8, win] 0/1
+        cs = jax.lax.dot_general(
+            sel.astype(jnp.bfloat16), tri_bf, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(
+            jnp.int32)                                  # [8, win] incl
+        nl = cs[_L, win - 1]
+        nr = rem - nl
+        # lefts to ``dest_l``'s place in its window; rights to
+        # ``dest_r``'s place in a window of their own, which begins at
+        # the first granule boundary past the lefts
+        dshift_l = dest_l % ALIGN
+        r0 = ((dshift_l + nl + ALIGN - 1) // ALIGN) * ALIGN
+        slot_of = jnp.where(
+            sel[_L:_L + 1, :] > 0.5, dshift_l + cs[_L:_L + 1, :] - 1,
+            jnp.where(sel[_R:_R + 1, :] > 0.5,
+                      r0 + dest_r % ALIGN + cs[_R:_R + 1, :] - 1,
+                      -1))                              # [1, win]
+        pt = jnp.where(dst_w == slot_of, one, zero).astype(
+            jnp.bfloat16)                               # [win+PAD, win]
+        staged[0:win + PAD, :] = jax.lax.dot_general(
+            pt, mat_f.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [win+PAD, C]
         # the left window ends at or before the last row this block
         # consumed: false while fewer than ~8 rows have gone right
         # (block 0 always) and at the segment's end
         fast_l = forward_fast(begin, k, rem, dest_l, blk)
-        nl, fly_l = compact_and_write(mat_bf, gl, dest_l, _L, fast_l,
-                                      fly_l)
-        nr, fly_r = compact_and_write(mat_bf, gr, dest_r, _R, True,
-                                      fly_r)
+        # the rights first: a merge of the left window rewrites every
+        # row of it in ``staged``, the rights in its tail too
+        fly_r = write_window(_R, r0, dest_r, nr, True, fly_r)
+        fly_l = write_window(_L, 0, dest_l, nl, fast_l, fly_l)
         return (dest_l + nl, dest_r + nr, fly_l, fly_r,
                 merges + jnp.where(fast_l, 0, 1))
 
@@ -283,12 +358,17 @@ def partition_stream(mat_hbm, ws_hbm, scratch, begin, count, decide,
             read(ws_hbm, (j + 1) * blk, 1 - slot).start()
 
         read(ws_hbm, j * blk, slot).wait()
-        _, _, mat_bf = load_block(slot)
+        _, mat_f = load_block(slot)
         cnt_j = jnp.minimum(nr_total - j * blk, blk)
-        sel = jnp.where(row_w < cnt_j, 1, 0)
+        # the rows are compact already and ``blk`` is a multiple of 8,
+        # so every block goes ``dest_l % 8`` rows down its window: a
+        # roll. What wraps round lands in the head rows, which
+        # ``head`` or the merge replaces; rows past ``cnt_j`` exist in
+        # the last block alone, which always merges
+        dest = dest_l + j * blk
+        staged[0:win, :] = pltpu.roll(mat_f, dest % ALIGN, 0)
         fast = back_fast(seg_end, j, dest_l, blk)
-        _, fly_l = compact_and_write(mat_bf, sel, dest_l + j * blk, _L,
-                                     fast, fly_l)
+        fly_l = write_window(_L, 0, dest, cnt_j, fast, fly_l)
         return fly_l, merges + jnp.where(fast, 0, 1)
 
     fly_l, merges = jax.lax.fori_loop(
@@ -337,6 +417,16 @@ def stream_windows(count: int, nl: int, blk: int = 512) -> int:
     return 2 * -(-count // blk) + -(-(count - nl) // blk)
 
 
+def stream_compactions(count: int, blk: int = 512) -> int:
+    """Compactions (a one-hot and its permutation product over whole
+    rows) one call runs: one a forward block, none in the back-copy
+    (the parent of PR 34 ran one a window, ``stream_windows``). The
+    program's side of the rule is read off its trace: the products
+    over whole rows in the forward and the back-copy loop's body
+    (``tools/check_kernels_on_chip.py`` ``traced_products``)."""
+    return -(-count // blk)
+
+
 def _partition_kernel(scal_ref, lut_ref, mat_in, ws_in,
                       mat_hbm, ws_hbm, nl_ref, *scratch,
                       blk: int, cols: int, use_lut_path: bool):
@@ -353,8 +443,8 @@ def _partition_kernel(scal_ref, lut_ref, mat_in, ws_in,
     win = blk + ALIGN
     lane_w = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
 
-    def decide(mat_i32, mat_f, valid, shift, rem):
-        del mat_f, shift, rem
+    def decide(mat_i32, mat_f, valid):
+        del mat_f
         # split feature's bin value per row (one-hot lane reduction)
         fsel = jnp.where(lane_w == feat, 1, 0)          # [1, C]
         bv = jnp.sum(mat_i32 * fsel, axis=1, keepdims=True)  # [win, 1]

@@ -866,8 +866,8 @@ def _segment_kernel_tpu(iscal, s_in, t_in, mat_in, ws_in, hist_in,
         lane_w = _iota_f32((1, cols), 1)
         fsel = jnp.where(lane_w == feat_f, jnp.float32(1), 0.0)
 
-        def decide(mat_i32, mat_f, valid, shift, rem):
-            del mat_i32, shift, rem
+        def decide(mat_i32, mat_f, valid):
+            del mat_i32
             # split feature's bin per row: f32 one-hot lane reduce
             bv = jnp.sum(mat_f * fsel, axis=1,
                          keepdims=True)                  # [win, 1]

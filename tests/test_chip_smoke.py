@@ -63,6 +63,7 @@ def test_train_and_serve_stages_tiny(fuse_iters):
     # the megakernel's interpret twin partitions by a prefix-sum
     # permutation: only a compiled phase 0 traces the pipelined stream
     assert report["partition_pipelined"] == 0
+    assert report["partition_one_compaction"] == 0
     # the twin's histograms are ``histogram_segment``'s, the one-hot
     # stream in interpret mode: counted per kernel trace, like the
     # categorical stage's ``partition_pipelined`` below
@@ -90,6 +91,11 @@ def test_categorical_stage_tiny(fuse_iters):
     # platform: counted per kernel trace, so 0 only where an earlier
     # test of this process already traced the same shapes
     assert isinstance(report["partition_pipelined"], int)
+    # PR 34: one compaction a stream traced (counted where the
+    # compaction enters the trace, not where the stream does)
+    assert report["partition_one_compaction"] \
+        == report["partition_pipelined"]
+    assert len(report["model_sha256"]) == 16
     assert report["cat_splits"] > 0
     foil = cs.stage_foil(x, y, params, cs.CAT_ROUNDS)
     assert abs(report["auc"] - foil["auc"]) <= cs.CAT_FOIL_AUC_TOL

@@ -390,8 +390,10 @@ def test_both_partition_kernels_lower_the_pipelined_stream():
     was_on = tel.enabled
     tel.ensure_ring()
     name = "kernels.partition_pipelined"
+    one = "kernels.partition_one_compaction"
     child = "kernels.hist_child_stream"
     before = tel.counters.get(name, 0)
+    one_before = tel.counters.get(one, 0)
     child_before = tel.counters.get(child, 0)
     mat = _mat(n=6000)          # a shape no other test traced
     lut = jnp.zeros((1, 256), jnp.float32)
@@ -408,11 +410,16 @@ def test_both_partition_kernels_lower_the_pipelined_stream():
     jax.clear_caches()
     split_step_pallas.lower_for_tpu(segment_grow_pack(15), big_l=15)
     after_mega = tel.counters.get(name, 0)
+    one_after_mega = tel.counters.get(one, 0)
     child_after_mega = tel.counters.get(child, 0)
     if not was_on:
         tel.reset()
     assert after_partition - before == 2
     assert after_mega - after_partition >= 1
+    # PR 34: one compaction (counted where its one-hot and product
+    # enter the trace) a stream traced, through both kernels' lowering;
+    # the block step before it would count 3 a stream
+    assert one_after_mega - one_before == after_mega - before
     # PR 30: only the megakernel's phase 0 holds the histogram stream
     # over the smaller child's segment, behind its partition stream
     assert child_after_partition == child_before
@@ -452,13 +459,16 @@ def test_pipelined_partition_compiles_for_v5e(one_chip, use_lut):
         mat, mat, *([i32] * 9), sds((1, 256), jnp.float32)).compile()
 
 
-@pytest.mark.parametrize("f,n", [(28, 10_500_000), (67, 7_000_000)],
-                         ids=["higgs-10m", "criteo-7m"])
+@pytest.mark.parametrize("f,n", [(28, 10_500_000), (67, 7_000_000),
+                                 (192, 1_000_000)],
+                         ids=["higgs-10m", "criteo-7m", "192-columns"])
 def test_pipelined_megakernel_compiles_for_v5e(one_chip, f, n):
     """The megakernel at the two megakernel cells' shapes (10.5 M x 28
     and 7 M x 67, 255 leaves, 256 bins): the shared partition stream
     and, behind it, the histogram stream over the smaller child's
-    segment (PR 30) in phase 0."""
+    segment (PR 30) in phase 0. And at ``MAX_FUSED_F`` = 192 columns
+    (PR 34), its widest row: the stream's scratch, two staged windows
+    since the one-compaction block step, beside the largest ``hpl``."""
     from lightgbm_tpu.learner.partitioned import segment_grow_pack
     from lightgbm_tpu.ops import split_step_pallas as ssp
     from lightgbm_tpu.ops.hist_pallas import matrix_cols, matrix_rows
@@ -485,6 +495,43 @@ def test_pipelined_megakernel_compiles_for_v5e(one_chip, f, n):
         interpret=False)).lower(
         sds((), jnp.int32), S, T, mat, mat, hist,
         sds((f, 8), jnp.int32), sds((f, 2), jnp.float32)).compile()
+
+
+def test_partition_kernels_ask_for_no_more_scoped_vmem(one_chip,
+                                                        monkeypatch):
+    """The one-compaction block step (PR 34) holds two staged windows
+    where the parent held one. Both kernels ask for the scoped-VMEM
+    limit they asked for before, and at the 128-byte rows of four
+    cells ``partition_segment`` needs no raised limit at all: with the
+    request taken out of its compiler parameters it compiles for the
+    described v5e under the default one. (At 2,048-byte rows the
+    parent's kernel needed the raise too.)"""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from lightgbm_tpu.ops import partition_pallas, split_step_pallas
+
+    asked = []
+
+    class Params:
+        @staticmethod
+        def CompilerParams(**kw):
+            asked.append(kw.pop("vmem_limit_bytes", None))
+            return pltpu.CompilerParams(**kw)
+
+        def __getattr__(self, name):
+            return getattr(pltpu, name)
+
+    assert split_step_pallas.VMEM_LIMIT == 100 * 1024 * 1024
+    monkeypatch.setattr(partition_pallas, "pltpu", Params())
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    mat = sds((1_003_520, 128), jnp.uint8)   # a shape no test traced
+    i32 = sds((), jnp.int32)
+    for use_lut in (True, False):
+        jax.jit(functools.partial(
+            partition_pallas.partition_segment, blk=512,
+            use_lut_path=use_lut)).lower(
+            mat, mat, *([i32] * 9), sds((1, 256), jnp.float32)).compile()
+    assert asked == [100 * 1024 * 1024] * 2
 
 
 @pytest.mark.parametrize("f,n", [

@@ -113,7 +113,10 @@ def test_partition_all_one_side():
 # carried in VMEM, window writes left in flight) -----------------------
 
 from lightgbm_tpu.ops import partition_pallas
-from lightgbm_tpu.ops.partition_pallas import ALIGN, merge_windows
+from lightgbm_tpu.ops.partition_pallas import (ALIGN, forward_fast,
+                                               merge_windows,
+                                               stream_compactions,
+                                               stream_windows)
 
 P_BLK, P_F, P_B, P_COL, P_THR = 512, 5, 64, 2, 30
 P_ROWS = 7 + 5 * P_BLK + 3 + 40          # largest begin + count, + slack
@@ -149,7 +152,7 @@ def _stream_inputs(split):
 
 
 def _run_stream(split, begin, count, use_lut, mat=None):
-    left, mat0, _, lut = _stream_inputs(split)
+    _, mat0, _, lut = _stream_inputs(split)
     mat = mat0 if mat is None else mat
     ws = jnp.full(mat.shape, P_SENTINEL, jnp.uint8)
     # the category table decides where the LUT path is compiled in;
@@ -162,10 +165,11 @@ def _run_stream(split, begin, count, use_lut, mat=None):
         interpret=True, use_lut_path=use_lut)
 
 
-def _check_stream(split, begin, count, res, mat_np=None):
+def _check_stream(split, begin, count, res, mat_np=None, left=None):
     """Stable order, NL, the merge-window count, and every row the
     call does not own untouched."""
-    left, _, mat0_np, _ = _stream_inputs(split)
+    left0, _, mat0_np, _ = _stream_inputs(split)
+    left = left0 if left is None else left
     mat_np = mat0_np if mat_np is None else mat_np
     m2, w2, nl = (np.asarray(a) for a in res)
     sl = slice(begin, begin + count)
@@ -303,3 +307,115 @@ def test_masked_rows_reach_nothing():
     res = _run_stream(split, begin, count, False,
                       mat=jnp.asarray(poisoned))
     _check_stream(split, begin, count, res, mat_np=poisoned)
+
+
+# ---- PR 34: one compaction a block (both sides in one one-hot and one
+# product; the back-copy a roll) ----------------------------------------
+
+def _run_and_check(left, begin, count):
+    """The stream over the "half" matrix with column P_COL rewritten so
+    that row i goes left as ``left[i]`` says; returns its result."""
+    _, _, mat_np, _ = _stream_inputs("half")
+    mat_np = mat_np.copy()
+    mat_np[:P_ROWS, P_COL] = np.where(left, 0, P_B - 1)
+    res = _run_stream("half", begin, count, False,
+                      mat=jnp.asarray(mat_np))
+    _check_stream("half", begin, count, res, mat_np=mat_np, left=left)
+    return res
+
+
+BLOCK_SPLITS = {
+    "all_left": lambda n: np.ones(n, bool),
+    "all_right": lambda n: np.zeros(n, bool),
+    "one_left": lambda n: np.arange(n) == 100,
+    "one_right": lambda n: np.arange(n) != 100,
+}
+
+
+@pytest.mark.parametrize("path", ["fast", "merge"])
+@pytest.mark.parametrize("block", list(BLOCK_SPLITS))
+@pytest.mark.parametrize("dshift_r", range(ALIGN))
+@pytest.mark.parametrize("dshift_l", range(ALIGN))
+def test_one_compaction_seams(dshift_l, dshift_r, block, path):
+    """Block 1 meets every pair of write-head offsets: its lefts start
+    ``dshift_l`` rows into their window, its rights ``dshift_r`` rows
+    into theirs, which begins at the first granule boundary past the
+    lefts in the SAME staged product. All rows left (the rights'
+    window holds a head alone), all right (it begins at the lefts' own
+    granule), one row each way. ``fast`` (a whole block, a third
+    behind it): the left window's dead tail now holds rights and is
+    written as it is; ``merge`` (the segment's last 200 rows): the
+    window reaches past the segment, is read back, and ``keep`` masks
+    the rights out of it. Sentinels before, after and in the workspace
+    (``_check_stream``)."""
+    # lefts of block 0: its rights leave ``dshift_r``, and ``begin``
+    # then puts the left head at ``dshift_l``
+    l0 = P_BLK // 2 - dshift_r
+    begin = (dshift_l - l0) % ALIGN
+    n1, tail = (P_BLK, 77) if path == "fast" else (200, 0)
+    count = P_BLK + n1 + tail
+    left = np.zeros(P_ROWS, bool)
+    rng = np.random.RandomState(dshift_l * 8 + dshift_r)
+    left[begin:begin + P_BLK] = rng.permutation(np.arange(P_BLK) < l0)
+    left[begin + P_BLK:begin + P_BLK + n1] = BLOCK_SPLITS[block](n1)
+    left[begin + P_BLK + n1:begin + count] = rng.rand(tail) < 0.5
+    assert (begin + l0) % ALIGN == dshift_l
+    assert (P_BLK - l0) % ALIGN == dshift_r
+    assert bool(forward_fast(begin, 1, n1, begin + l0, P_BLK)) \
+        == (path == "fast")
+    _run_and_check(left, begin, count)
+
+
+@pytest.mark.parametrize("last", [1, 7, 8, 511])
+@pytest.mark.parametrize("dshift", range(ALIGN))
+def test_back_copy_rolls_by_one_shift(dshift, last):
+    """The back-copy compacts nothing: every block of a call goes the
+    same ``(begin + NL) % 8`` rows down its window (a roll, what wraps
+    round replaced by the carried head or the merge). Two back blocks,
+    the last of 1, 7, 8 and 511 rows, at every shift."""
+    nl_total, nr_total = 700, P_BLK + last
+    begin = (dshift - nl_total) % ALIGN
+    count = nl_total + nr_total
+    left = np.zeros(P_ROWS, bool)
+    left[begin:begin + count] = np.random.RandomState(
+        dshift * 1000 + last).permutation(np.arange(count) < nl_total)
+    assert int(_run_and_check(left, begin, count)[2][0]) == nl_total
+
+
+@pytest.mark.parametrize("count,nl,compactions,before", [
+    (0, 0, 0, 0), (1, 1, 1, 2), (512, 0, 1, 3), (513, 256, 2, 5),
+    (5 * 512 + 3, 1300, 6, 15), (10_500_000, 5_000_000, 20508, 51759)])
+def test_stream_compactions_is_the_rule(count, nl, compactions, before):
+    """One compaction a forward block, against one a window (two a
+    forward block and one a back-copy block), ``before``."""
+    assert stream_compactions(count, 512) == compactions
+    assert stream_windows(count, nl, 512) == before
+
+
+@pytest.mark.parametrize("use_lut", [False, True])
+def test_traced_program_runs_the_rule(use_lut):
+    """The count ``stream_compactions`` is held to, taken from the
+    program itself in interpret mode: the forward loop's body holds
+    ONE product over whole rows (the permutation, both sides' windows
+    in one ``[win + PAD, cols]`` output) beside the two small ones
+    that turn the masks into rows and prefix-sum them (and the table's
+    lookup), the back-copy loop's body none. A second compaction in
+    the block, or one in the back-copy, is a second whole-row product:
+    the parent's bodies read 2 and 1."""
+    from tools.check_kernels_on_chip import traced_products
+    _, mat, _, lut = _stream_inputs("half")
+    cols = mat.shape[1]
+    win = P_BLK + ALIGN
+    forward, back = traced_products(
+        functools.partial(partition_pallas.partition_segment, blk=P_BLK,
+                          interpret=True, use_lut_path=use_lut),
+        mat, mat, *([jnp.int32(0)] * 9), lut)
+    small = [(ALIGN, win), (ALIGN, win)]
+    assert sorted(forward) == sorted(
+        small + [(win + partition_pallas.PAD, cols)]
+        + ([(win, 1)] if use_lut else []))
+    assert back == []
+    for count, nr in ((1, 0), (P_BLK, P_BLK), (5 * P_BLK + 3, 1300)):
+        ran = sum(s[-1] == cols for s in forward) * -(-count // P_BLK) \
+            + sum(s[-1] == cols for s in back) * -(-nr // P_BLK)
+        assert ran == stream_compactions(count, P_BLK)

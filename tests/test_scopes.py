@@ -247,19 +247,24 @@ def test_one_block_opens_its_three_spans_in_order(tel, monkeypatch):
 # expansion of the two histogram calls, which ISSUE 32 made the
 # one-hot stream (a dot and five one-row accumulates a feature,
 # unrolled, where the nibble kernel's groups of three were; on a TPU
-# each is one Mosaic call either way)
+# each is one Mosaic call either way), and for the interpret-mode
+# expansion of the partition kernel's block step, which ISSUE 34 made
+# one compaction a block (three dots fewer: one prefix product, one
+# permutation product a forward block, none in the back-copy, whose
+# roll the interpreter writes as slices and a concatenate; on a TPU
+# the kernel is one Mosaic call either way)
 PARENT_OPCODES = {
-    "abs": 20, "add": 447, "and": 130, "bitcast": 824,
-    "bitcast-convert": 74, "broadcast": 456, "clamp": 2, "compare": 381,
-    "concatenate": 31, "conditional": 15, "constant": 1156, "convert": 222,
-    "copy": 180, "divide": 12, "dot": 22, "dynamic-slice": 90,
-    "dynamic-update-slice": 218, "exponential": 1, "fusion": 476,
-    "gather": 9, "get-tuple-element": 267, "iota": 35, "is-finite": 4,
-    "maximum": 25, "minimum": 14, "multiply": 149, "negate": 105, "not": 4,
-    "or": 44, "pad": 20, "parameter": 1209, "reduce": 12,
-    "reduce-window": 8, "reverse": 2, "scatter": 2, "select": 352,
-    "shift-left": 33, "shift-right-logical": 35, "sign": 43, "slice": 702,
-    "sort": 1, "subtract": 104, "transpose": 8, "tuple": 41}
+    "abs": 20, "add": 457, "and": 142, "bitcast": 808,
+    "bitcast-convert": 74, "broadcast": 417, "clamp": 2, "compare": 392,
+    "concatenate": 32, "conditional": 15, "constant": 1158, "convert": 205,
+    "copy": 183, "divide": 12, "dot": 19, "dynamic-slice": 93,
+    "dynamic-update-slice": 222, "exponential": 1, "fusion": 478,
+    "gather": 9, "get-tuple-element": 273, "iota": 39, "is-finite": 4,
+    "maximum": 25, "minimum": 13, "multiply": 143, "negate": 121, "not": 4,
+    "or": 44, "pad": 20, "parameter": 1181, "reduce": 12,
+    "reduce-window": 8, "remainder": 1, "reverse": 2, "scatter": 2,
+    "select": 358, "shift-left": 33, "shift-right-logical": 37, "sign": 45,
+    "slice": 709, "sort": 1, "subtract": 102, "transpose": 8, "tuple": 42}
 _OPCODE = re.compile(
     r"^\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(?:\([^=]*?\)|\S+)\s+([a-z\-]+)\(")
 

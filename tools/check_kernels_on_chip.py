@@ -102,14 +102,50 @@ def stage_hist(interpret: bool = False, shapes=HIST_SHAPES) -> int:
     return failures
 
 
+def traced_products(fn, *args) -> list:
+    """The matrix products in the body of each loop of the one Pallas
+    kernel ``fn(*args)`` traces: a list a loop, in program order
+    (``partition_segment``: the forward stream, then the back-copy),
+    of the products' output shapes. Read off the jaxpr the compiler is
+    handed, so nothing runs; what a call executes is a loop's list
+    times its trip count."""
+    import jax
+
+    def inner(eqn):
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (list, tuple)) \
+                    else (value,):
+                item = getattr(item, "jaxpr", item)
+                if hasattr(item, "eqns"):
+                    yield item
+
+    def find(jaxpr, name):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == name:
+                yield eqn
+            else:
+                for sub in inner(eqn):
+                    yield from find(sub, name)
+
+    (call,) = find(jax.make_jaxpr(fn)(*args).jaxpr, "pallas_call")
+    return [[tuple(dot.outvars[0].aval.shape)
+             for dot in find(loop.params["body_jaxpr"].jaxpr,
+                             "dot_general")]
+            for loop in call.params["jaxpr"].eqns
+            if loop.primitive.name == "while"]
+
+
 def stage_partition_v1(interpret: bool = False,
                        shapes=MATRIX_SHAPES) -> int:
+    import functools
+
     import jax.numpy as jnp
     import numpy as np
 
     from lightgbm_tpu.ops.hist_pallas import extract_row_ids
     from lightgbm_tpu.ops.partition_pallas import (merge_windows,
                                                    partition_segment,
+                                                   stream_compactions,
                                                    stream_windows)
     rng = np.random.RandomState(1)
     failures = 0
@@ -117,14 +153,23 @@ def stage_partition_v1(interpret: bool = False,
         binned, _, _, _, mat = _hist_inputs(rng, n, f, b)
         col, thr = f // 2, b // 2
         lut = jnp.zeros((1, 256), jnp.float32)
+        calls = {use_lut: functools.partial(
+            partition_segment, blk=512, interpret=interpret,
+            use_lut_path=use_lut) for use_lut in (True, False)}
+        # compactions a block, as each program is traced: the products
+        # over whole rows in the forward and the back-copy loop's body
+        row_products = {use_lut: [
+            sum(shape[-1] == mat.shape[1] for shape in loop)
+            for loop in traced_products(
+                call, mat, mat, *([jnp.int32(0)] * 9), lut)]
+            for use_lut, call in calls.items()}
         for begin, count in _segments(n, 13):
             for use_lut in (True, False):
                 args = (jnp.int32(begin), jnp.int32(count), col,
                         jnp.int32(thr), jnp.int32(0), jnp.int32(0),
                         jnp.int32(0), jnp.int32(b), jnp.int32(0), lut)
-                m_c, _, nl_c = partition_segment(
-                    mat, jnp.zeros_like(mat), *args, blk=512,
-                    interpret=interpret, use_lut_path=use_lut)
+                m_c, _, nl_c = calls[use_lut](
+                    mat, jnp.zeros_like(mat), *args)
                 sl = slice(begin, begin + count)
                 go_left = binned[sl, col] <= thr
                 nl_o = int(go_left.sum())
@@ -140,16 +185,27 @@ def stage_partition_v1(interpret: bool = False,
                     begin, count, [int(go_left[k:k + 512].sum())
                                    for k in range(0, count, 512)], 512)
                 windows = stream_windows(count, nl_o, 512)
+                # compactions the call ran: the traced products a
+                # block times each loop's trips, against the host
+                # rule (one a forward block, not one a window)
+                forward, back = row_products[use_lut]
+                ran = forward * -(-count // 512) \
+                    + back * -(-(count - nl_o) // 512)
+                compactions = stream_compactions(count, 512)
                 ok = (int(nl_c[0]) == nl_o
                       and np.array_equal(rid_seg[:count], want)
-                      and int(nl_c[1]) == merged)
+                      and int(nl_c[1]) == merged
+                      and ran == compactions)
                 print(f"partition [{n}x{f}] "
                       f"seg=({begin},{count}) lut={use_lut}: "
                       f"{'ok ' if ok else 'FAIL'} "
                       f"left={int(nl_c[0])}/{nl_o} "
                       f"merged={int(nl_c[1])}/{windows} windows "
                       f"({int(nl_c[1]) / max(windows, 1):.1%}; "
-                      f"host rule {merged})", flush=True)
+                      f"host rule {merged}) "
+                      f"compactions={ran} (host rule {compactions}; "
+                      f"{forward} a forward block and {back} a "
+                      f"back-copy block, as traced)", flush=True)
                 failures += 0 if ok else 1
     return failures + _partition_bundled_split(interpret)
 
