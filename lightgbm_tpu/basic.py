@@ -152,6 +152,11 @@ class Dataset:
             return self
 
         cfg = Config.from_params(self._merged_params())
+        # before the table is built, so that a JSONL asked for through
+        # the environment or the parameters holds its construction
+        from .observability.telemetry import get_telemetry
+        tel = get_telemetry()
+        tel.ensure_started(cfg)
         data = self.data
         feature_name = self.feature_name
         categorical = _categorical_from_params(self.categorical_feature,
@@ -284,8 +289,6 @@ class Dataset:
             else None,
             categorical_features=cat_idx, reference=ref_inner,
             forced_bins=forced)
-        from .observability.telemetry import get_telemetry
-        tel = get_telemetry()
         tel.count("data.rows_binned", self._inner.num_data)
         tel.count("data.cells_binned",
                   self._inner.num_data * self._inner.num_features)

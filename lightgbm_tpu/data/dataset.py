@@ -21,6 +21,8 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..config import Config
+from ..observability import scopes
+from ..observability.telemetry import get_telemetry
 from ..utils.log import log_fatal, log_info, log_warning
 from .binning import (BIN_TYPE_CATEGORICAL, BIN_TYPE_NUMERICAL,
                       BinMapper, kZeroThreshold)
@@ -319,39 +321,43 @@ class Dataset:
         if data.ndim != 2:
             log_fatal("Dataset data must be 2-dimensional")
         n, num_features = data.shape
-        self = cls()
-        self.num_data = n
-        self.num_total_features = num_features
-        self.max_bin = config.max_bin
-        self.bin_construct_sample_cnt = config.bin_construct_sample_cnt
-        self.min_data_in_bin = config.min_data_in_bin
-        self.use_missing = config.use_missing
-        self.zero_as_missing = config.zero_as_missing
-        self.feature_names = feature_names or [
-            f"Column_{i}" for i in range(num_features)]
+        with get_telemetry().setup_span(
+                scopes.DATA_CONSTRUCT, rows=n, columns=num_features,
+                source="numpy"):
+            self = cls()
+            self.num_data = n
+            self.num_total_features = num_features
+            self.max_bin = config.max_bin
+            self.bin_construct_sample_cnt = config.bin_construct_sample_cnt
+            self.min_data_in_bin = config.min_data_in_bin
+            self.use_missing = config.use_missing
+            self.zero_as_missing = config.zero_as_missing
+            self.feature_names = feature_names or [
+                f"Column_{i}" for i in range(num_features)]
 
-        if reference is not None:
-            # valid set aligned with train (CreateValid, dataset.cpp:703)
-            self._copy_layout_from(reference)
-        else:
-            self._find_bins(data, config, categorical_features, forced_bins)
-            self._resolve_monotone_and_penalty(config)
+            if reference is not None:
+                # valid set aligned with train (CreateValid, dataset.cpp:703)
+                self._copy_layout_from(reference)
+            else:
+                self._find_bins(data, config, categorical_features,
+                                forced_bins)
+                self._resolve_monotone_and_penalty(config)
 
-        self._extract_features(data)
-        if config.linear_tree or (reference is not None
-                                  and reference.raw_numeric is not None):
-            self._store_raw(data)
-        if reference is None:
-            self._maybe_bundle(config)
-        elif self.feature_group is not None:
-            self._bundle_binned(self.bundle_plan())
-        self.metadata.num_data = n
-        if label is not None:
-            self.metadata.set_label(label)
-        self.metadata.set_weights(weight)
-        self.metadata.set_query(group)
-        self.metadata.set_init_score(init_score)
-        self.count_bundle_telemetry()
+            self._extract_features(data)
+            if config.linear_tree or (reference is not None
+                                      and reference.raw_numeric is not None):
+                self._store_raw(data)
+            if reference is None:
+                self._maybe_bundle(config)
+            elif self.feature_group is not None:
+                self._bundle_reference_layout()
+            self.metadata.num_data = n
+            if label is not None:
+                self.metadata.set_label(label)
+            self.metadata.set_weights(weight)
+            self.metadata.set_query(group)
+            self.metadata.set_init_score(init_score)
+            self.count_bundle_telemetry()
         return self
 
     def _find_bins(self, data: np.ndarray, config: Config,
@@ -359,14 +365,18 @@ class Dataset:
                    forced_bins: Optional[Dict[int, List[float]]]) -> None:
         n = data.shape[0]
         sample_cnt = min(n, self.bin_construct_sample_cnt)
-        rng = np.random.RandomState(config.data_random_seed)
-        if sample_cnt < n:
-            sample_idx = np.sort(rng.choice(n, sample_cnt, replace=False))
-        else:
-            sample_idx = np.arange(n)
-        self._find_bins_from_sample(
-            np.asarray(data[sample_idx], np.float64), n, config,
-            categorical_features, forced_bins)
+        with get_telemetry().setup_span(
+                scopes.DATA_FIND_BINS, sample_rows=sample_cnt,
+                columns=data.shape[1]):
+            rng = np.random.RandomState(config.data_random_seed)
+            if sample_cnt < n:
+                sample_idx = np.sort(rng.choice(n, sample_cnt,
+                                                replace=False))
+            else:
+                sample_idx = np.arange(n)
+            self._find_bins_from_sample(
+                np.asarray(data[sample_idx], np.float64), n, config,
+                categorical_features, forced_bins)
 
     def _find_bins_from_sample(
             self, sample: np.ndarray, n: int, config: Config,
@@ -450,23 +460,31 @@ class Dataset:
                                for i in range(self.num_features)])
         if not eligible.any():
             return
-        plan = plan_bundles(self.binned, nb, eligible,
-                            sample_cnt=self.bin_construct_sample_cnt,
-                            seed=config.data_random_seed)
-        if plan.num_groups >= self.num_features \
-                and not plan.has_multival:
-            return
-        from ..utils.log import log_info
-        log_info(f"EFB: bundled {self.num_features} features into "
-                 f"{plan.num_groups} columns"
-                 + (f" ({plan.num_groups - plan.mv_group_start} "
-                    "multi-val)" if plan.has_multival else ""))
-        self._bundle_binned(plan)
+        with get_telemetry().setup_span(scopes.DATA_BUNDLE) as sp:
+            plan = plan_bundles(self.binned, nb, eligible,
+                                sample_cnt=self.bin_construct_sample_cnt,
+                                seed=config.data_random_seed)
+            sp.set(groups=plan.num_groups)
+            if plan.num_groups >= self.num_features \
+                    and not plan.has_multival:
+                return
+            log_info(f"EFB: bundled {self.num_features} features into "
+                     f"{plan.num_groups} columns"
+                     + (f" ({plan.num_groups - plan.mv_group_start} "
+                        "multi-val)" if plan.has_multival else ""))
+            self._bundle_binned(plan)
         self.mv_group_start = plan.mv_group_start \
             if plan.has_multival else None
         self.feature_group = plan.feature_group
         self.feature_offset = plan.feature_offset
         self.group_num_bins = plan.group_num_bins
+
+    def _bundle_reference_layout(self) -> None:
+        """A valid set's columns into its reference's bundles."""
+        plan = self.bundle_plan()
+        with get_telemetry().setup_span(scopes.DATA_BUNDLE,
+                                        groups=plan.num_groups):
+            self._bundle_binned(plan)
 
     def _bundle_binned(self, plan) -> None:
         """``self.binned`` from per-feature bins to the plan's group
@@ -496,11 +514,14 @@ class Dataset:
         max_b = max([self.num_bin(f) for f in range(self.num_features)],
                     default=2)
         dtype = np.uint8 if max_b <= 256 else np.uint16
-        out = np.zeros((n, width), dtype=dtype)
-        for inner, orig in enumerate(self.real_feature_idx):
-            mapper = self.bin_mappers[orig]
-            out[:, inner] = mapper.values_to_bins(
-                np.asarray(data[:, orig], dtype=np.float64)).astype(dtype)
+        with get_telemetry().setup_span(
+                scopes.DATA_BIN_ROWS,
+                bytes=n * width * np.dtype(dtype).itemsize):
+            out = np.zeros((n, width), dtype=dtype)
+            for inner, orig in enumerate(self.real_feature_idx):
+                mapper = self.bin_mappers[orig]
+                out[:, inner] = mapper.values_to_bins(np.asarray(
+                    data[:, orig], dtype=np.float64)).astype(dtype)
         self.binned = out
 
     # ------------------------------------------------------------------
@@ -519,107 +540,110 @@ class Dataset:
         in pass 2. Explicit label/weight/group/init_score arguments
         override the file's columns, like the in-memory path."""
         from .file_loader import TwoRoundLoader
-        loader = TwoRoundLoader(path, config)
-        n = loader.count_rows()
-        self = cls()
-        self.num_data = n
-        self.max_bin = config.max_bin
-        self.bin_construct_sample_cnt = config.bin_construct_sample_cnt
-        self.min_data_in_bin = config.min_data_in_bin
-        self.use_missing = config.use_missing
-        self.zero_as_missing = config.zero_as_missing
+        with get_telemetry().setup_span(scopes.DATA_CONSTRUCT,
+                                        source="file") as sp:
+            loader = TwoRoundLoader(path, config)
+            n = loader.count_rows()
+            self = cls()
+            self.num_data = n
+            self.max_bin = config.max_bin
+            self.bin_construct_sample_cnt = config.bin_construct_sample_cnt
+            self.min_data_in_bin = config.min_data_in_bin
+            self.use_missing = config.use_missing
+            self.zero_as_missing = config.zero_as_missing
 
-        # ---- pass 1: sample rows (same sorted-choice stream as the
-        # in-memory path -> bit-identical BinMappers) + label columns
-        sample_cnt = min(n, self.bin_construct_sample_cnt)
-        rng = np.random.RandomState(config.data_random_seed)
-        if sample_cnt < n:
-            sample_idx = np.sort(rng.choice(n, sample_cnt,
-                                            replace=False))
-        else:
-            sample_idx = np.arange(n)
-        sample_parts: List[np.ndarray] = []
-        labels: List[np.ndarray] = []
-        weights: List[np.ndarray] = []
-        qids: List[np.ndarray] = []
-        r = 0
-        num_features = 0
-        for X, lab, wt, qid in loader.iter_chunks():
-            m = X.shape[0]
-            num_features = X.shape[1]
-            lo = np.searchsorted(sample_idx, r)
-            hi = np.searchsorted(sample_idx, r + m)
-            if hi > lo:
-                sample_parts.append(X[sample_idx[lo:hi] - r])
-            labels.append(np.asarray(lab, np.float64))
-            if wt is not None:
-                weights.append(np.asarray(wt, np.float64))
-            if qid is not None:
-                qids.append(np.asarray(qid, np.float64))
-            r += m
-        if r != n:
-            log_fatal(f"two_round load of {path}: pass 1 saw {r} rows "
-                      f"but the file has {n}")
-        self.num_total_features = num_features
-        self.feature_names = feature_names or loader.feature_names \
-            or [f"Column_{i}" for i in range(num_features)]
-        sample = (np.concatenate(sample_parts) if sample_parts
-                  else np.zeros((0, num_features)))
+            # ---- pass 1: sample rows (same sorted-choice stream as the
+            # in-memory path -> bit-identical BinMappers) + label columns
+            sample_cnt = min(n, self.bin_construct_sample_cnt)
+            rng = np.random.RandomState(config.data_random_seed)
+            if sample_cnt < n:
+                sample_idx = np.sort(rng.choice(n, sample_cnt,
+                                                replace=False))
+            else:
+                sample_idx = np.arange(n)
+            sample_parts: List[np.ndarray] = []
+            labels: List[np.ndarray] = []
+            weights: List[np.ndarray] = []
+            qids: List[np.ndarray] = []
+            r = 0
+            num_features = 0
+            for X, lab, wt, qid in loader.iter_chunks():
+                m = X.shape[0]
+                num_features = X.shape[1]
+                lo = np.searchsorted(sample_idx, r)
+                hi = np.searchsorted(sample_idx, r + m)
+                if hi > lo:
+                    sample_parts.append(X[sample_idx[lo:hi] - r])
+                labels.append(np.asarray(lab, np.float64))
+                if wt is not None:
+                    weights.append(np.asarray(wt, np.float64))
+                if qid is not None:
+                    qids.append(np.asarray(qid, np.float64))
+                r += m
+            if r != n:
+                log_fatal(f"two_round load of {path}: pass 1 saw {r} rows "
+                          f"but the file has {n}")
+            self.num_total_features = num_features
+            sp.set(rows=n, columns=num_features)
+            self.feature_names = feature_names or loader.feature_names \
+                or [f"Column_{i}" for i in range(num_features)]
+            sample = (np.concatenate(sample_parts) if sample_parts
+                      else np.zeros((0, num_features)))
 
-        if reference is not None:
-            self._copy_layout_from(reference)
-        else:
-            self._find_bins_from_sample(sample, n, config,
-                                        categorical_features,
-                                        forced_bins)
-            self._resolve_monotone_and_penalty(config)
+            if reference is not None:
+                self._copy_layout_from(reference)
+            else:
+                self._find_bins_from_sample(sample, n, config,
+                                            categorical_features,
+                                            forced_bins)
+                self._resolve_monotone_and_penalty(config)
 
-        # ---- pass 2: chunked extraction into the packed matrix
-        width = max(self.num_features, 1)
-        max_b = max([self.num_bin(f)
-                     for f in range(self.num_features)], default=2)
-        dtype = np.uint8 if max_b <= 256 else np.uint16
-        out = np.zeros((n, width), dtype=dtype)
-        r = 0
-        for X, _, _, _ in loader.iter_chunks():
-            m = X.shape[0]
-            for inner, orig in enumerate(self.real_feature_idx):
-                mapper = self.bin_mappers[orig]
-                out[r:r + m, inner] = mapper.values_to_bins(
-                    np.asarray(X[:, orig], np.float64)).astype(dtype)
-            r += m
-        self.binned = out
+            # ---- pass 2: chunked extraction into the packed matrix
+            width = max(self.num_features, 1)
+            max_b = max([self.num_bin(f)
+                         for f in range(self.num_features)], default=2)
+            dtype = np.uint8 if max_b <= 256 else np.uint16
+            out = np.zeros((n, width), dtype=dtype)
+            r = 0
+            for X, _, _, _ in loader.iter_chunks():
+                m = X.shape[0]
+                for inner, orig in enumerate(self.real_feature_idx):
+                    mapper = self.bin_mappers[orig]
+                    out[r:r + m, inner] = mapper.values_to_bins(
+                        np.asarray(X[:, orig], np.float64)).astype(dtype)
+                r += m
+            self.binned = out
 
-        if reference is None:
-            self._maybe_bundle(config)
-        elif self.feature_group is not None:
-            self._bundle_binned(self.bundle_plan())
+            if reference is None:
+                self._maybe_bundle(config)
+            elif self.feature_group is not None:
+                self._bundle_reference_layout()
 
-        # ---- metadata: file columns, sidecars, explicit overrides
-        f_weight, f_group, f_init = loader.load_sidecars()
-        if label is None and labels:
-            label = np.concatenate(labels)
-        if weight is None:
-            weight = f_weight if f_weight is not None else (
-                np.concatenate(weights) if weights else None)
-        if group is None:
-            if f_group is not None:
-                group = f_group
-            elif qids:
-                from .file_loader import _qid_to_group_sizes
-                group = _qid_to_group_sizes(np.concatenate(qids))
-        if init_score is None:
-            init_score = f_init
-        self.metadata.num_data = n
-        if label is not None:
-            self.metadata.set_label(label)
-        self.metadata.set_weights(weight)
-        self.metadata.set_query(
-            None if group is None else np.asarray(group, np.int64))
-        self.metadata.set_init_score(init_score)
-        log_info(f"Loaded {n} rows x {num_features} features from "
-                 f"{path} in two passes ({loader.fmt})")
-        self.count_bundle_telemetry()
+            # ---- metadata: file columns, sidecars, explicit overrides
+            f_weight, f_group, f_init = loader.load_sidecars()
+            if label is None and labels:
+                label = np.concatenate(labels)
+            if weight is None:
+                weight = f_weight if f_weight is not None else (
+                    np.concatenate(weights) if weights else None)
+            if group is None:
+                if f_group is not None:
+                    group = f_group
+                elif qids:
+                    from .file_loader import _qid_to_group_sizes
+                    group = _qid_to_group_sizes(np.concatenate(qids))
+            if init_score is None:
+                init_score = f_init
+            self.metadata.num_data = n
+            if label is not None:
+                self.metadata.set_label(label)
+            self.metadata.set_weights(weight)
+            self.metadata.set_query(
+                None if group is None else np.asarray(group, np.int64))
+            self.metadata.set_init_score(init_score)
+            log_info(f"Loaded {n} rows x {num_features} features from "
+                     f"{path} in two passes ({loader.fmt})")
+            self.count_bundle_telemetry()
         return self
 
     # ------------------------------------------------------------------
@@ -648,40 +672,43 @@ class Dataset:
         import scipy.sparse as sp
         if not sp.issparse(data):
             log_fatal("Dataset.from_scipy requires a scipy.sparse matrix")
-        csc = data.tocsc()
-        if not csc.has_canonical_format:
-            # scipy semantics: duplicate entries SUM. Canonicalize on a
-            # copy when tocsc() aliased the caller's arrays — the
-            # user's matrix must never be mutated behind their back.
-            if csc is data:
-                csc = csc.copy()
-            csc.sum_duplicates()
-        n, num_features = csc.shape
-        self = cls()
-        self.num_data = n
-        self.num_total_features = num_features
-        self.max_bin = config.max_bin
-        self.bin_construct_sample_cnt = config.bin_construct_sample_cnt
-        self.min_data_in_bin = config.min_data_in_bin
-        self.use_missing = config.use_missing
-        self.zero_as_missing = config.zero_as_missing
-        self.feature_names = feature_names or [
-            f"Column_{i}" for i in range(num_features)]
+        with get_telemetry().setup_span(
+                scopes.DATA_CONSTRUCT, rows=data.shape[0],
+                columns=data.shape[1], source="scipy"):
+            csc = data.tocsc()
+            if not csc.has_canonical_format:
+                # scipy semantics: duplicate entries SUM. Canonicalize on a
+                # copy when tocsc() aliased the caller's arrays — the
+                # user's matrix must never be mutated behind their back.
+                if csc is data:
+                    csc = csc.copy()
+                csc.sum_duplicates()
+            n, num_features = csc.shape
+            self = cls()
+            self.num_data = n
+            self.num_total_features = num_features
+            self.max_bin = config.max_bin
+            self.bin_construct_sample_cnt = config.bin_construct_sample_cnt
+            self.min_data_in_bin = config.min_data_in_bin
+            self.use_missing = config.use_missing
+            self.zero_as_missing = config.zero_as_missing
+            self.feature_names = feature_names or [
+                f"Column_{i}" for i in range(num_features)]
 
-        if reference is not None:
-            self._copy_layout_from(reference)
-        else:
-            self._find_bins_sparse(csc, config, categorical_features,
-                                   forced_bins)
-            self._resolve_monotone_and_penalty(config)
-        self._extract_sparse(csc, config, reference)
-        self.metadata.num_data = n
-        if label is not None:
-            self.metadata.set_label(label)
-        self.metadata.set_weights(weight)
-        self.metadata.set_query(group)
-        self.metadata.set_init_score(init_score)
-        self.count_bundle_telemetry()
+            if reference is not None:
+                self._copy_layout_from(reference)
+            else:
+                self._find_bins_sparse(csc, config, categorical_features,
+                                       forced_bins)
+                self._resolve_monotone_and_penalty(config)
+            self._extract_sparse(csc, config, reference)
+            self.metadata.num_data = n
+            if label is not None:
+                self.metadata.set_label(label)
+            self.metadata.set_weights(weight)
+            self.metadata.set_query(group)
+            self.metadata.set_init_score(init_score)
+            self.count_bundle_telemetry()
         return self
 
     @classmethod
@@ -810,59 +837,60 @@ class Dataset:
         values are pushed, zeros ride total_sample_cnt)."""
         n, num_features = csc.shape
         sample_cnt = min(n, self.bin_construct_sample_cnt)
-        rng = np.random.RandomState(config.data_random_seed)
-        in_sample = None
-        if sample_cnt < n:
-            sample_idx = rng.choice(n, sample_cnt, replace=False)
-            in_sample = np.zeros(n, bool)
-            in_sample[sample_idx] = True
-        cat_set = set(int(c) for c in categorical_features)
+        with get_telemetry().setup_span(
+                scopes.DATA_FIND_BINS, sample_rows=sample_cnt,
+                columns=num_features):
+            rng = np.random.RandomState(config.data_random_seed)
+            in_sample = None
+            if sample_cnt < n:
+                sample_idx = rng.choice(n, sample_cnt, replace=False)
+                in_sample = np.zeros(n, bool)
+                in_sample[sample_idx] = True
+            cat_set = set(int(c) for c in categorical_features)
 
-        indptr, indices, vals = csc.indptr, csc.indices, csc.data
-        col_samples: List[np.ndarray] = []
-        for j in range(num_features):
-            colv = vals[indptr[j]:indptr[j + 1]]
-            if in_sample is not None:
-                rows_j = indices[indptr[j]:indptr[j + 1]]
-                colv = colv[in_sample[rows_j]]
-            colv = np.asarray(colv, np.float64)
-            col_samples.append(colv[(np.abs(colv) > kZeroThreshold)
-                                    | np.isnan(colv)])
-        # distributed bin finding (dataset_loader.cpp:824-1001, sparse
-        # branch): pre-partitioned hosts merge their per-feature
-        # nonzero samples so every host derives IDENTICAL BinMappers
-        from ..parallel.distributed import maybe_gather_sparse_bin_sample
-        col_samples, sample_cnt, n_global = maybe_gather_sparse_bin_sample(
-            col_samples, sample_cnt, config, n)
-        filter_cnt = int(max(
-            config.min_data_in_leaf * sample_cnt / max(n_global, 1), 1)) \
-            if config.feature_pre_filter else 0
+            indptr, indices, vals = csc.indptr, csc.indices, csc.data
+            col_samples: List[np.ndarray] = []
+            for j in range(num_features):
+                colv = vals[indptr[j]:indptr[j + 1]]
+                if in_sample is not None:
+                    rows_j = indices[indptr[j]:indptr[j + 1]]
+                    colv = colv[in_sample[rows_j]]
+                colv = np.asarray(colv, np.float64)
+                col_samples.append(colv[(np.abs(colv) > kZeroThreshold)
+                                        | np.isnan(colv)])
+            # distributed bin finding (dataset_loader.cpp:824-1001, sparse
+            # branch): pre-partitioned hosts merge their per-feature
+            # nonzero samples so every host derives IDENTICAL BinMappers
+            from ..parallel.distributed import maybe_gather_sparse_bin_sample
+            col_samples, sample_cnt, n_global = maybe_gather_sparse_bin_sample(
+                col_samples, sample_cnt, config, n)
+            filter_cnt = int(max(
+                config.min_data_in_leaf * sample_cnt / max(n_global, 1), 1)) \
+                if config.feature_pre_filter else 0
 
-        self.bin_mappers = []
-        for j in range(num_features):
-            mapper = BinMapper()
-            bt = BIN_TYPE_CATEGORICAL if j in cat_set \
-                else BIN_TYPE_NUMERICAL
-            fb = (forced_bins or {}).get(j, ())
-            mapper.find_bin(
-                col_samples[j], total_sample_cnt=sample_cnt,
-                max_bin=_max_bin_for(config, j),
-                min_data_in_bin=self.min_data_in_bin,
-                min_split_data=filter_cnt,
-                pre_filter=config.feature_pre_filter,
-                bin_type=bt, use_missing=self.use_missing,
-                zero_as_missing=self.zero_as_missing,
-                forced_upper_bounds=fb)
-            self.bin_mappers.append(mapper)
-        self._finalize_used_features()
+            self.bin_mappers = []
+            for j in range(num_features):
+                mapper = BinMapper()
+                bt = BIN_TYPE_CATEGORICAL if j in cat_set \
+                    else BIN_TYPE_NUMERICAL
+                fb = (forced_bins or {}).get(j, ())
+                mapper.find_bin(
+                    col_samples[j], total_sample_cnt=sample_cnt,
+                    max_bin=_max_bin_for(config, j),
+                    min_data_in_bin=self.min_data_in_bin,
+                    min_split_data=filter_cnt,
+                    pre_filter=config.feature_pre_filter,
+                    bin_type=bt, use_missing=self.use_missing,
+                    zero_as_missing=self.zero_as_missing,
+                    forced_upper_bounds=fb)
+                self.bin_mappers.append(mapper)
+            self._finalize_used_features()
 
     def _extract_sparse(self, csc, config: Config, reference) -> None:
         """CSC nonzeros -> (bundled) binned matrix, no [N, F]
         intermediate of any type: the EFB plan comes from the columns'
         non-default row lists, which the CSC structure holds already,
         and the matrix is written group column by group column."""
-        from ..observability import scopes
-        from ..observability.telemetry import get_telemetry
         tel = get_telemetry()
         n = csc.shape[0]
         f_used = self.num_features
@@ -876,7 +904,7 @@ class Dataset:
         zero_bin = np.zeros(max(f_used, 1), np.int64)
         bins_nz: List[np.ndarray] = []
         nz_rows: List[np.ndarray] = []      # the rows bins_nz is of
-        with tel.span(scopes.DATA_EXTRACT, trace=scopes.DATA_EXTRACT):
+        with tel.setup_span(scopes.DATA_EXTRACT):
             for inner, orig in enumerate(self.real_feature_idx):
                 m = self.bin_mappers[orig]
                 zero_bin[inner] = int(m.values_to_bins(np.zeros(1))[0])
@@ -898,13 +926,12 @@ class Dataset:
         if reference is not None:
             plan = self.bundle_plan()
         elif config.enable_bundle and f_used >= 2:
-            with tel.span(scopes.DATA_BUNDLE_PLAN,
-                          trace=scopes.DATA_BUNDLE_PLAN):
+            with tel.setup_span(scopes.DATA_BUNDLE_PLAN):
                 plan = self._plan_sparse_bundles(nz_rows, nbins, n, config)
 
         g_dense = plan.num_dense_groups if plan is not None \
             else max(f_used, 1)
-        with tel.span(scopes.DATA_EXTRACT, trace=scopes.DATA_EXTRACT):
+        with tel.setup_span(scopes.DATA_EXTRACT):
             # written a group column at a time into a column-major
             # scratch (a column's rows ascend, so each write walks one
             # contiguous array), then turned into rows
@@ -1088,27 +1115,29 @@ class Dataset:
             "mv_group_start": self.mv_group_start,
             "bundle_conflict_rows": int(self.bundle_conflict_rows),
         }
-        # write to the EXACT path the caller gave (reference .bin
-        # convention) — a bare np.savez would silently append .npz
-        with open(path, "wb") as fh:
-            np.savez_compressed(
-                fh, binned=self.binned,
-                mv_slots=self.mv_slots if self.mv_slots is not None
-                else np.zeros((0, 0), np.int32),
-                label=self.metadata.label
-                if self.metadata.label is not None
-                else np.zeros(0, np.float32),
-                weights=self.metadata.weights
-                if self.metadata.weights is not None
-                else np.zeros(0, np.float32),
-                query_boundaries=self.metadata.query_boundaries
-                if self.metadata.query_boundaries is not None
-                else np.zeros(0, np.int32),
-                init_score=self.metadata.init_score
-                if self.metadata.init_score is not None
-                else np.zeros(0, np.float64),
-                meta=np.frombuffer(json.dumps(meta).encode(),
-                                   dtype=np.uint8))
+        with get_telemetry().setup_span(scopes.DATA_SAVE_BINARY) as sp:
+            # write to the EXACT path the caller gave (reference .bin
+            # convention) — a bare np.savez would silently append .npz
+            with open(path, "wb") as fh:
+                np.savez_compressed(
+                    fh, binned=self.binned,
+                    mv_slots=self.mv_slots if self.mv_slots is not None
+                    else np.zeros((0, 0), np.int32),
+                    label=self.metadata.label
+                    if self.metadata.label is not None
+                    else np.zeros(0, np.float32),
+                    weights=self.metadata.weights
+                    if self.metadata.weights is not None
+                    else np.zeros(0, np.float32),
+                    query_boundaries=self.metadata.query_boundaries
+                    if self.metadata.query_boundaries is not None
+                    else np.zeros(0, np.int32),
+                    init_score=self.metadata.init_score
+                    if self.metadata.init_score is not None
+                    else np.zeros(0, np.float64),
+                    meta=np.frombuffer(json.dumps(meta).encode(),
+                                       dtype=np.uint8))
+            sp.set(bytes=os.path.getsize(path))
         log_info(f"Saved binary dataset to {path}")
 
     @staticmethod
@@ -1155,45 +1184,52 @@ class Dataset:
     @classmethod
     def load_binary(cls, path: str) -> "Dataset":
         import json
-        with np.load(path) as z:
-            meta = json.loads(bytes(z["meta"]).decode())
-            self = cls()
-            self.bin_mappers = [BinMapper.from_dict(d)
-                                for d in meta["mappers"]]
-            self.used_feature_map = meta["used_feature_map"]
-            self.real_feature_idx = meta["real_feature_idx"]
-            self.feature_names = meta["feature_names"]
-            self.num_total_features = meta["num_total_features"]
-            self.max_bin = meta["max_bin"]
-            self.min_data_in_bin = meta["min_data_in_bin"]
-            self.use_missing = meta["use_missing"]
-            self.zero_as_missing = meta["zero_as_missing"]
-            if meta.get("feature_group") is not None:
-                self.feature_group = np.asarray(meta["feature_group"],
-                                                np.int32)
-                self.feature_offset = np.asarray(meta["feature_offset"],
-                                                 np.int32)
-                self.group_num_bins = np.asarray(meta["group_num_bins"],
-                                                 np.int32)
-            self.binned = z["binned"]
-            if meta.get("mv_group_start") is not None:
-                self.mv_group_start = meta["mv_group_start"]
-                self.mv_slots = z["mv_slots"]
-            self.bundle_conflict_rows = int(
-                meta.get("bundle_conflict_rows", 0))
-            self.num_data = len(self.binned)
-            md = Metadata(self.num_data)
-            if len(z["label"]):
-                md.set_label(z["label"])
-            if len(z["weights"]):
-                md.set_weights(z["weights"])
-            if len(z["query_boundaries"]):
-                md.query_boundaries = z["query_boundaries"]
-                md._update_query_weights()
-            if len(z["init_score"]):
-                md.init_score = z["init_score"]
-            self.metadata = md
-        self.count_bundle_telemetry()
+        tel = get_telemetry()
+        with tel.setup_span(scopes.DATA_CONSTRUCT,
+                            source="binary") as root:
+            with tel.setup_span(scopes.DATA_LOAD_BINARY,
+                                bytes=os.path.getsize(path)), \
+                    np.load(path) as z:
+                meta = json.loads(bytes(z["meta"]).decode())
+                self = cls()
+                self.bin_mappers = [BinMapper.from_dict(d)
+                                    for d in meta["mappers"]]
+                self.used_feature_map = meta["used_feature_map"]
+                self.real_feature_idx = meta["real_feature_idx"]
+                self.feature_names = meta["feature_names"]
+                self.num_total_features = meta["num_total_features"]
+                self.max_bin = meta["max_bin"]
+                self.min_data_in_bin = meta["min_data_in_bin"]
+                self.use_missing = meta["use_missing"]
+                self.zero_as_missing = meta["zero_as_missing"]
+                if meta.get("feature_group") is not None:
+                    self.feature_group = np.asarray(meta["feature_group"],
+                                                    np.int32)
+                    self.feature_offset = np.asarray(meta["feature_offset"],
+                                                     np.int32)
+                    self.group_num_bins = np.asarray(meta["group_num_bins"],
+                                                     np.int32)
+                self.binned = z["binned"]
+                if meta.get("mv_group_start") is not None:
+                    self.mv_group_start = meta["mv_group_start"]
+                    self.mv_slots = z["mv_slots"]
+                self.bundle_conflict_rows = int(
+                    meta.get("bundle_conflict_rows", 0))
+                self.num_data = len(self.binned)
+                md = Metadata(self.num_data)
+                if len(z["label"]):
+                    md.set_label(z["label"])
+                if len(z["weights"]):
+                    md.set_weights(z["weights"])
+                if len(z["query_boundaries"]):
+                    md.query_boundaries = z["query_boundaries"]
+                    md._update_query_weights()
+                if len(z["init_score"]):
+                    md.init_score = z["init_score"]
+                self.metadata = md
+            self.count_bundle_telemetry()
+            root.set(rows=self.num_data,
+                     columns=self.num_total_features)
         return self
 
 

@@ -185,8 +185,10 @@ class PartitionedTreeLearner(PartitionedLearnerBase):
     def __init__(self, dataset: Dataset, config: Config,
                  hist_method: str = "auto", interpret: Optional[bool] = None):
         self._setup_partitioned(dataset, config, interpret)
-        self.mat = build_matrix(jnp.asarray(dataset.binned), HIST_BLK)
-        self.ws = jnp.zeros_like(self.mat)
+        with get_telemetry().setup_span(scopes.SETUP_DEVICE_TABLE) as sp:
+            self.mat = build_matrix(jnp.asarray(dataset.binned), HIST_BLK)
+            self.ws = jnp.zeros_like(self.mat)
+            sp.set(bytes=2 * self.mat.nbytes)
         # no-sampling defaults, built ONCE: a fresh ones_like per
         # train() call is a per-iteration device allocation + dispatch
         self._ones_rows = jnp.ones((self.num_data,), jnp.float32)

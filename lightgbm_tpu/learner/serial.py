@@ -36,6 +36,8 @@ from ..data.binning import (BIN_TYPE_CATEGORICAL, MISSING_NAN, MISSING_NONE,
 from ..data.dataset import Dataset
 from ..models.linear import LinearLeafFitMixin
 from ..models.tree import Tree, TreeArrays
+from ..observability import scopes
+from ..observability.telemetry import get_telemetry
 from ..utils.jit_registry import register_jit
 from ..ops.histogram import build_histogram, make_ghc
 from ..ops.partition import split_leaf
@@ -565,7 +567,6 @@ def count_tree_telemetry(learner) -> None:
     subtraction, 2 per split in pool-bounded mode; an early stop can
     only make the true count lower). Shared by every learner's
     ``train`` entry point; free when telemetry is disabled."""
-    from ..observability.telemetry import get_telemetry
     tel = get_telemetry()
     if not tel.enabled:
         return
@@ -613,8 +614,13 @@ class SerialTreeLearner(NodeRandMixin, CegbStateMixin,
         # would stage the FULL matrix on the default device before the
         # re-shard — exactly the replicated host-0 copy the ingest
         # layer exists to avoid
-        self.binned = jnp.asarray(dataset.binned) \
-            if self._stage_binned_on_device else dataset.binned
+        if self._stage_binned_on_device:
+            with get_telemetry().setup_span(
+                    scopes.SETUP_DEVICE_TABLE,
+                    bytes=dataset.binned.nbytes):
+                self.binned = jnp.asarray(dataset.binned)
+        else:
+            self.binned = dataset.binned
         # multi-val pseudo-groups (no physical column; bundling.py)
         self.mv_slots = dataset.mv_slots_device
         self.mv_groups = dataset.num_groups - dataset.num_dense_groups

@@ -302,9 +302,23 @@ class GBDT:
         from ..utils.compile_cache import maybe_enable_compile_cache
         maybe_enable_compile_cache()
         from ..parallel import create_tree_learner
-        self.learner = create_tree_learner(
-            cfg.tree_learner, train_data, cfg, hist_method=hist_method)
-        self.num_data = train_data.num_data
+        with tel.setup_span(scopes.SETUP,
+                            rows=train_data.num_data) as root:
+            with tel.setup_span(scopes.SETUP_LEARNER) as sp:
+                self.learner = create_tree_learner(
+                    cfg.tree_learner, train_data, cfg,
+                    hist_method=hist_method)
+                plan = getattr(self.learner, "split_plan", None)
+                sp.set(plan=str(plan()) if plan is not None else None)
+            root.set(learner=type(self.learner).__name__)
+            self.num_data = train_data.num_data
+            with tel.setup_span(scopes.SETUP_OBJECTIVE):
+                self._setup_objective(train_data)
+            with tel.setup_span(scopes.SETUP_SCORES):
+                self._setup_scores(train_data)
+            self._setup_guards()
+
+    def _setup_objective(self, train_data: Dataset) -> None:
         if self.objective is not None:
             self.objective.init(train_data.metadata, self.num_data)
             # objectives with per-call host randomness (rank_xendcg)
@@ -314,6 +328,11 @@ class GBDT:
                     "gbdt_grad", self.objective.gradients))) \
                 if getattr(self.objective, "jittable", True) \
                 else self.objective.gradients
+
+    def _setup_scores(self, train_data: Dataset) -> None:
+        """The training score, the training metrics and the bagging
+        state."""
+        cfg = self.config
         k = self.num_tree_per_iteration
         init = train_data.metadata.init_score
         if init is not None:
@@ -342,6 +361,9 @@ class GBDT:
         self._bag_label = None  # device label, built lazily (balanced)
         self.bag_weight: Optional[jnp.ndarray] = None
         self._feature_rng = np.random.RandomState(cfg.feature_fraction_seed)
+
+    def _setup_guards(self) -> None:
+        cfg = self.config
         # non-finite guard (robustness/guards.py): policy + the finite
         # flag folded into the combined gradient program when active
         self._guard_policy = str(getattr(cfg, "guard_policy", "off")
@@ -1298,7 +1320,7 @@ class GBDT:
         it0 = self.iter
         t0 = time.perf_counter()
         try:
-            with tel.span("train"):
+            with tel.span(scopes.TRAIN, ledger=True, rows=self.num_data):
                 self._train_impl(num_iterations)
         finally:
             # close a profiler capture still in flight (run shorter

@@ -69,12 +69,34 @@ BLOCK_DISPATCH = "lgbm.block.dispatch"
 BLOCK_SYNC = "lgbm.block.sync"
 BLOCK_TREES = "lgbm.block.trees"
 EVAL = "lgbm.eval"
-# host spans of a sparse table's construction (Dataset.from_scipy): the
-# bundle plan, and the binned matrix written from the stored entries.
-# They run in set-up, before any profile is taken: read them from
-# ``Telemetry.spans``
+# host spans of set-up (Telemetry.setup_span): each leaves one ``span``
+# record with its start and end on ``time.perf_counter()`` and is a
+# named profiler region as well (docs/Observability.md, "Set-up from
+# the inside"). One table's construction; the root is opened by
+# Dataset.from_numpy, from_scipy, from_file_two_round and load_binary
+DATA_CONSTRUCT = "lgbm.data.construct"
+DATA_FIND_BINS = "lgbm.data.find_bins"      # row sample + find_bin
+DATA_BIN_ROWS = "lgbm.data.bin_rows"        # dense values -> bin bytes
+DATA_BUNDLE = "lgbm.data.bundle"            # EFB of a dense table
+# a sparse table's own two (PR 33): the bundle plan, and the binned
+# matrix written from the stored entries
 DATA_BUNDLE_PLAN = "lgbm.data.bundle_plan"
 DATA_EXTRACT = "lgbm.data.extract"
+DATA_LOAD_BINARY = "lgbm.data.load_binary"  # the read and the inflate
+DATA_SAVE_BINARY = "lgbm.data.save_binary"  # the deflate and the write
+# one booster's set-up; the root is opened by GBDT._setup_train
+SETUP = "lgbm.setup"
+SETUP_LEARNER = "lgbm.setup.learner"        # create_tree_learner
+SETUP_DEVICE_TABLE = "lgbm.setup.device_table"  # closes on a dispatch
+SETUP_OBJECTIVE = "lgbm.setup.objective"
+SETUP_SCORES = "lgbm.setup.scores"
+# GBDT.train's own span leaves a record too: one a call, not a block
+TRAIN = "train"
+# every ``span`` record's name is one of these
+LEDGER_SPANS = (DATA_CONSTRUCT, DATA_FIND_BINS, DATA_BIN_ROWS, DATA_BUNDLE,
+                DATA_BUNDLE_PLAN, DATA_EXTRACT, DATA_LOAD_BINARY,
+                DATA_SAVE_BINARY, SETUP, SETUP_LEARNER, SETUP_DEVICE_TABLE,
+                SETUP_OBJECTIVE, SETUP_SCORES, TRAIN)
 
 PREFIX = "lgbm."
 

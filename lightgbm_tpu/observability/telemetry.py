@@ -4,11 +4,11 @@ One process-wide :class:`Telemetry` instance (``get_telemetry()``)
 collects
 
   * hierarchical **spans** — named wall-clock regions that nest
-    (``with tel.span("train"): ...``) and accumulate per dotted path.
-    The span context also drives ``utils/log.py``'s ``global_timer``
-    (the reference's -DTIMETAG analog) and can open a named
-    ``jax.profiler`` trace region, so it absorbs the previous
-    ``global_timer.scope(...) + annotate(...)`` pairs;
+    (``with tel.span("train"): ...``) and accumulate per dotted path;
+    a span can open a named ``jax.profiler`` trace region. The
+    set-up spans (``setup_span``; names in ``scopes.py``) and
+    ``train`` also leave one ``span`` record each, with when they
+    started and ended, so that set-up reads as a timeline;
   * typed **counters / gauges / distributions** — plain host floats
     (rows binned, histogram builds, collective payload bytes, ...);
   * **per-iteration records** — phase wall times (grad/grow/tree/
@@ -16,7 +16,10 @@ collects
     boundaries, flushed by ``end_iteration``;
   * **compile accounting** — a ``jax.monitoring`` duration listener
     feeds ``jit.compiles`` / ``jit.compile_s`` (and trace/lowering
-    seconds), separating compile time from steady-state throughput.
+    seconds), separating compile time from steady-state throughput,
+    and leaves one ``compile`` record a stage of every registered or
+    long compile: program, stage, start and end, persistent-cache hit
+    or miss, and the span it happened under.
 
 Records flow to pluggable sinks: an in-memory ring buffer, a JSONL
 file (``LGBM_TPU_TELEMETRY=/path`` env or the ``telemetry_out`` config
@@ -39,18 +42,26 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
-from ..utils.log import Timer, get_verbosity, global_timer, log_info, \
-    log_warning
+from ..utils import jit_registry
+from ..utils.log import get_verbosity, log_info, log_warning
 from .tracing import get_tracer
 
-# jax.monitoring event suffixes -> (count counter, seconds counter).
-# backend_compile is THE compile; trace/lowering are recorded too so a
-# trace-dominated workload is visible as such.
+# jax.monitoring event suffixes -> (count counter, seconds counter,
+# the ``compile`` record's stage). backend_compile is THE compile;
+# trace/lowering are recorded too so a trace-dominated workload is
+# visible as such.
 _COMPILE_EVENTS = {
-    "backend_compile_duration": ("jit.compiles", "jit.compile_s"),
-    "jaxpr_trace_duration": ("jit.traces", "jit.trace_s"),
-    "jaxpr_to_mlir_module_duration": ("jit.lowerings", "jit.lowering_s"),
+    "backend_compile_duration": ("jit.compiles", "jit.compile_s",
+                                 "backend"),
+    "jaxpr_trace_duration": ("jit.traces", "jit.trace_s", "trace"),
+    "jaxpr_to_mlir_module_duration": ("jit.lowerings", "jit.lowering_s",
+                                      "lower"),
 }
+# a stage of a program the registry does not know gets a ``compile``
+# record from this many seconds on: jax's own primitives
+# (``jit(convert_element_type)``) compile by the hundred in a few
+# milliseconds each, and the ring is bounded
+COMPILE_RECORD_MIN_S = 0.05
 
 # plain (no-duration) jax.monitoring events worth counting: persistent
 # compilation-cache traffic, so a warmed cache is visible as hits
@@ -179,40 +190,50 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **fields) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Active span: telemetry accumulation + global_timer bridge +
-    optional jax profiler trace region + the trace-correlation bridge
-    (every telemetry span lands on the tracing.py timeline with ids
-    when the tracer is enabled — the training side of the end-to-end
-    trace plane rides this, no second instrumentation pass)."""
+    """Active span: telemetry accumulation + optional jax profiler
+    trace region + the trace-correlation bridge (every telemetry span
+    lands on the tracing.py timeline with ids when the tracer is
+    enabled — the training side of the end-to-end trace plane rides
+    this, no second instrumentation pass). A ``ledger`` span also
+    leaves a ``span`` record when it closes."""
 
-    __slots__ = ("tel", "name", "phase", "trace", "timer_on", "_t0",
-                 "_path", "_ann", "_tspan")
+    __slots__ = ("tel", "name", "phase", "trace", "ledger", "fields",
+                 "_t0", "_path", "_ann", "_tspan")
 
     def __init__(self, tel: "Telemetry", name: str, phase: bool,
-                 trace: Optional[str], timer_on: bool, tracer):
+                 trace: Optional[str], ledger: bool,
+                 fields: Dict[str, Any], tracer):
         self.tel = tel
         self.name = name
         self.phase = phase
         self.trace = trace
-        self.timer_on = timer_on
+        self.ledger = ledger
+        self.fields = fields
         self._ann = None
         self._tspan = None if tracer is None \
             else tracer._begin(name, "train", None, None, scoped=True)
 
+    def set(self, **fields) -> None:
+        """Fields of the ``span`` record known only once the work is
+        under way (the plan a learner settled on)."""
+        self.fields.update(fields)
+
     def __enter__(self):
         tel = self.tel
         if tel._enabled:
-            tel._stack.append(self.name)
-            self._path = "/".join(tel._stack)
+            stack = tel._stack()
+            stack.append(self.name)
+            self._path = "/".join(stack)
         else:
             self._path = None
-        if self.timer_on:
-            global_timer.begin(self.name)
         if self.trace is not None:
             from ..utils.log import annotate
             self._ann = annotate(self.trace)
@@ -221,17 +242,17 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
+        t1 = time.perf_counter()
+        dur = t1 - self._t0
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        if self.timer_on:
-            global_timer.end(self.name)
         if self._tspan is not None:
-            self._tspan.finish()
+            self._tspan.finish(**self.fields)
         tel = self.tel
         if self._path is not None and tel._enabled:
-            if tel._stack and tel._stack[-1] == self.name:
-                tel._stack.pop()
+            stack = tel._stack()
+            if stack and stack[-1] == self.name:
+                stack.pop()
             with tel._lock:
                 acc = tel.spans.setdefault(self._path, [0.0, 0])
                 acc[0] += dur
@@ -239,6 +260,11 @@ class _Span:
                 if self.phase:
                     tel._iter_phases[self.name] = \
                         tel._iter_phases.get(self.name, 0.0) + dur
+            if self.ledger:
+                tel.record("span", name=self.name, path=self._path,
+                           parent=self._path.rpartition("/")[0] or None,
+                           t0=self._t0, t1=t1, dur_s=round(dur, 6),
+                           **self.fields)
         return False
 
 
@@ -254,7 +280,10 @@ class Telemetry:
         self._lock = threading.Lock()
         self._sinks: list = []
         self._ring: Optional[RingSink] = None
-        self._stack: List[str] = []
+        # open span names and the compile listener's cache slot, a
+        # thread its own: a span opened on the serving flusher's
+        # thread is no child of the training thread's
+        self._tls = threading.local()
         self.spans: Dict[str, list] = {}      # path -> [total_s, count]
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, Any] = {}
@@ -337,18 +366,39 @@ class Telemetry:
         self.__init__()
 
     # -- spans ---------------------------------------------------------
+    def _stack(self) -> List[str]:
+        st = getattr(self._tls, "stack", None)
+        if st is None:
+            st = self._tls.stack = []
+        return st
+
+    def current_path(self) -> Optional[str]:
+        """Path of the innermost span open on this thread."""
+        return "/".join(self._stack()) or None
+
     def span(self, name: str, phase: bool = False,
-             trace: Optional[str] = None):
+             trace: Optional[str] = None, ledger: bool = False,
+             **fields):
         """Timed region. ``phase=True`` also accumulates the duration
         into the current iteration's phase table; ``trace=<name>`` opens
-        a named jax profiler region (the old ``annotate``)."""
-        timer_on = Timer._enabled
+        a named jax profiler region. ``ledger=True`` also emits one
+        ``span`` record when the region closes (``name``, ``path``,
+        ``parent``, ``t0`` and ``t1`` as ``time.perf_counter()`` read
+        them, ``dur_s`` and ``fields``); such a span is the shared
+        no-op whenever telemetry and the tracer are both off, named
+        profiler region or not."""
         tracer = get_tracer()
-        if not self._enabled and not timer_on and trace is None \
-                and not tracer._enabled:
+        if not self._enabled and not tracer._enabled \
+                and (trace is None or ledger):
             return _NULL_SPAN
-        return _Span(self, name, phase, trace, timer_on,
+        return _Span(self, name, phase, trace, ledger, fields,
                      tracer if tracer._enabled else None)
+
+    def setup_span(self, name: str, **fields):
+        """One phase of table construction or booster set-up (the
+        names are ``observability/scopes.py``'s): a ledger span that
+        is also a named profiler region."""
+        return self.span(name, trace=name, ledger=True, **fields)
 
     # -- metrics -------------------------------------------------------
     def count(self, name: str, value: float = 1.0) -> None:
@@ -572,10 +622,28 @@ def _install_atexit_flush() -> None:
         atexit.register(_atexit_flush)
 
 
+def _program_of(fun_name: Optional[str]) -> Tuple[str, bool]:
+    """``(program, registered)`` of a compile event's ``fun_name``:
+    jax's name without its ``jit(...)``, or the name the program is
+    registered under (``utils/jit_registry.py``) where the registry
+    holds a callable of jax's name (``_grow_partitioned`` is
+    ``partitioned_grow``)."""
+    fun = str(fun_name or "")
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]
+    if jit_registry.get(fun) is not None:
+        return fun, True
+    for name, prog in jit_registry.programs().items():
+        if getattr(prog.fn, "__name__", None) == fun:
+            return name, True
+    return fun, False
+
+
 def _install_compile_listener() -> None:
     """Register ONE process-wide jax.monitoring duration listener that
-    feeds the singleton's compile counters (jax has no unregister, so
-    installation must survive Telemetry.reset without stacking)."""
+    feeds the singleton's compile counters and ``compile`` records
+    (jax has no unregister, so installation must survive
+    Telemetry.reset without stacking)."""
     if _LISTENER_INSTALLED[0]:
         return
     _LISTENER_INSTALLED[0] = True
@@ -590,11 +658,23 @@ def _install_compile_listener() -> None:
             names = _COMPILE_EVENTS.get(tail)
             if names is None:
                 return
+            now = time.perf_counter()   # jax calls as the stage ends
             tel.count(names[0], 1)
             tel.count(names[1], duration)
-            if tail == "backend_compile_duration":
-                tel.record("compile", event=tail,
-                           dur_s=round(duration, 6))
+            fields: Dict[str, Any] = {}
+            if names[2] == "backend":
+                # the persistent cache says hit or miss on this thread
+                # inside the compile it belongs to; neither where
+                # there is no cache or the entry is too small to keep
+                fields["cache"] = getattr(tel._tls, "cache", None) \
+                    or "none"
+                tel._tls.cache = None
+            program, registered = _program_of(kw.get("fun_name"))
+            if registered or duration >= COMPILE_RECORD_MIN_S:
+                tel.record("compile", program=program, stage=names[2],
+                           t0=now - duration, t1=now,
+                           dur_s=round(duration, 6),
+                           parent=tel.current_path(), **fields)
 
         monitoring.register_event_duration_secs_listener(_listener)
 
@@ -602,9 +682,11 @@ def _install_compile_listener() -> None:
             tel = _TELEMETRY
             if not tel._enabled:
                 return
-            name = _PLAIN_EVENTS.get(event.rsplit("/", 1)[-1])
+            tail = event.rsplit("/", 1)[-1]
+            name = _PLAIN_EVENTS.get(tail)
             if name is not None:
                 tel.count(name, 1)
+                tel._tls.cache = "hit" if tail == "cache_hits" else "miss"
 
         monitoring.register_event_listener(_plain_listener)
     except Exception as e:  # pragma: no cover - jax API drift

@@ -581,7 +581,6 @@ class ProfileWindow:
         self.spans = 4
         self.state = "off"          # off | armed | capturing | done
         self._boundaries = 0
-        self._timer_prev = False
         self._lock = threading.Lock()
 
     def arm(self, dirname: str) -> None:
@@ -623,13 +622,6 @@ class ProfileWindow:
             import jax
             jax.profiler.start_trace(self.dir)
             self.state = "capturing"
-            # host-side phase timers cover the same window (the
-            # reference's -DTIMETAG analog): cleared + enabled for the
-            # capture, dumped + restored at stop
-            from ..utils.log import Timer, global_timer
-            self._timer_prev = Timer._enabled
-            Timer.enable(True)
-            global_timer.acc.clear()
             get_tracer().instant("profile.start", cat="profile",
                                  args={"dir": self.dir, "at": label})
             log_info(f"profiler capture started ({label}) -> "
@@ -648,13 +640,6 @@ class ProfileWindow:
                      f"{self.dir}")
         except Exception as e:  # pragma: no cover - backend-dependent
             log_warning(f"profiler stop failed: {e}")
-        try:
-            from ..utils.log import Timer, global_timer
-            if global_timer.acc:
-                global_timer.print_all()
-            Timer.enable(getattr(self, "_timer_prev", False))
-        except Exception:  # pragma: no cover - teardown safety
-            pass
         self.state = "done"
 
 

@@ -45,6 +45,8 @@ from ..learner.comm import (ShardScanCtx, make_data_parallel_comm,
                             make_voting_parallel_comm)
 from ..learner.serial import (GrowResult, SerialTreeLearner, grow_tree,
                               split_params_from_config)
+from ..observability import scopes
+from ..observability.telemetry import get_telemetry
 from ..utils.device import on_tpu
 from ..utils.jit_registry import register_dynamic
 from . import ingest
@@ -178,9 +180,11 @@ class DataParallelTreeLearner(_MeshLearnerBase):
         self._n_pad = _round_up(n, d)
         # sharded ingest: host rows -> per-shard transfers, no
         # replicated staging copy (parallel/ingest.py)
-        self.binned = ingest.shard_rows(
-            ingest.pad_rows(np.asarray(self.binned), self._n_pad),
-            self.mesh)
+        with get_telemetry().setup_span(scopes.SETUP_DEVICE_TABLE) as sp:
+            self.binned = ingest.shard_rows(
+                ingest.pad_rows(np.asarray(self.binned), self._n_pad),
+                self.mesh)
+            sp.set(bytes=self.binned.nbytes)
         meta = self.meta
         mv_groups = self._mv_groups
         # reduce-scatter recipe unless the config's bookkeeping needs
@@ -319,11 +323,14 @@ class FeatureParallelTreeLearner(_MeshLearnerBase):
         # place once with the mode's rule table (replicated rows for
         # the partition path, column-sharded permuted copy + permuted
         # meta for the histogram build/scan)
-        placed = shard_arrays(self.mesh, self._mode, {
-            "binned": binned_np,
-            "binned_hist": plan.permute_binned(binned_np),
-            "meta_local": plan.meta_local})
-        self.binned = placed["binned"]
+        with get_telemetry().setup_span(scopes.SETUP_DEVICE_TABLE) as sp:
+            placed = shard_arrays(self.mesh, self._mode, {
+                "binned": binned_np,
+                "binned_hist": plan.permute_binned(binned_np),
+                "meta_local": plan.meta_local})
+            self.binned = placed["binned"]
+            sp.set(bytes=self.binned.nbytes
+                   + placed["binned_hist"].nbytes)
         self._fn = functools.partial(sharded, self.binned,
                                      placed["binned_hist"],
                                      placed["meta_local"])
@@ -344,9 +351,11 @@ class VotingParallelTreeLearner(_MeshLearnerBase):
         d = self.num_shards
         n = self.dataset.num_data
         self._n_pad = _round_up(n, d)
-        self.binned = ingest.shard_rows(
-            ingest.pad_rows(np.asarray(self.binned), self._n_pad),
-            self.mesh)
+        with get_telemetry().setup_span(scopes.SETUP_DEVICE_TABLE) as sp:
+            self.binned = ingest.shard_rows(
+                ingest.pad_rows(np.asarray(self.binned), self._n_pad),
+                self.mesh)
+            sp.set(bytes=self.binned.nbytes)
         # local constraints relaxed by the machine count
         # (voting_parallel_tree_learner.cpp:57-59)
         params_local = self.params._replace(
@@ -445,25 +454,27 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
             self.comm = make_data_parallel_comm(AXIS, plan=self._plan)
         self.mode = mode
 
-        # one training matrix per shard, rows carrying GLOBAL ids
-        rows_local = matrix_rows(self.n_local, HIST_BLK)
-        cols = matrix_cols(self.num_groups)
-        mats = np.zeros((d, rows_local, cols), np.uint8)
-        binned = np.asarray(dataset.binned, np.uint8)
-        g0 = self.num_groups
-        for s in range(d):
-            lo = s * self.n_local
-            hi = min(lo + self.n_local, n)
-            if hi > lo:
-                mats[s, :hi - lo, :g0] = binned[lo:hi]
-            rid = (lo + np.arange(self.n_local)).astype(np.uint32)
-            for kk in range(4):
-                mats[s, :self.n_local, g0 + RID_OFF + kk] = \
-                    ((rid >> np.uint32(8 * kk)) & 0xFF).astype(np.uint8)
-        # sharded ingest: shards transfer host->device individually,
-        # never materializing the full matrix in one HBM
-        self.mat = ingest.shard_rows(mats, self.mesh)
-        self.ws = ingest.shard_rows(np.zeros_like(mats), self.mesh)
+        with get_telemetry().setup_span(scopes.SETUP_DEVICE_TABLE) as sp:
+            # one training matrix per shard, rows carrying GLOBAL ids
+            rows_local = matrix_rows(self.n_local, HIST_BLK)
+            cols = matrix_cols(self.num_groups)
+            mats = np.zeros((d, rows_local, cols), np.uint8)
+            binned = np.asarray(dataset.binned, np.uint8)
+            g0 = self.num_groups
+            for s in range(d):
+                lo = s * self.n_local
+                hi = min(lo + self.n_local, n)
+                if hi > lo:
+                    mats[s, :hi - lo, :g0] = binned[lo:hi]
+                rid = (lo + np.arange(self.n_local)).astype(np.uint32)
+                for kk in range(4):
+                    mats[s, :self.n_local, g0 + RID_OFF + kk] = \
+                        ((rid >> np.uint32(8 * kk)) & 0xFF).astype(np.uint8)
+            # sharded ingest: shards transfer host->device individually,
+            # never materializing the full matrix in one HBM
+            self.mat = ingest.shard_rows(mats, self.mesh)
+            self.ws = ingest.shard_rows(np.zeros_like(mats), self.mesh)
+            sp.set(bytes=2 * self.mat.nbytes)
         self._build()
 
     def _build(self):

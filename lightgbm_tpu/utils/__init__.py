@@ -1,7 +1,7 @@
-from .log import (LightGBMError, Timer, get_verbosity, global_timer,
-                  log_debug, log_fatal, log_info, log_warning, set_verbosity)
+from .log import (LightGBMError, get_verbosity, log_debug, log_fatal,
+                  log_info, log_warning, set_verbosity)
 
 __all__ = [
-    "LightGBMError", "Timer", "get_verbosity", "global_timer", "log_debug",
-    "log_fatal", "log_info", "log_warning", "set_verbosity",
+    "LightGBMError", "get_verbosity", "log_debug", "log_fatal", "log_info",
+    "log_warning", "set_verbosity",
 ]

@@ -8,7 +8,6 @@ the same way (<0 fatal only, 0 +warning, 1 +info, >1 +debug).
 from __future__ import annotations
 
 import sys
-import time
 
 _LEVEL = 1  # matches default verbosity=1
 
@@ -48,56 +47,6 @@ def log_warning(msg: str) -> None:
 
 def log_fatal(msg: str) -> None:
     raise LightGBMError(msg)
-
-
-class Timer:
-    """Named accumulating timers (Common::Timer, utils/common.h:1026-1108).
-
-    Opt-in like the reference's -DTIMETAG: enable with ``Timer.enable()``;
-    ``print_all`` mirrors the global_timer atexit dump.
-    """
-
-    _enabled = False
-
-    def __init__(self):
-        self.acc: dict[str, float] = {}
-        self.start: dict[str, float] = {}
-
-    @classmethod
-    def enable(cls, on: bool = True) -> None:
-        cls._enabled = on
-
-    def begin(self, name: str) -> None:
-        if Timer._enabled:
-            self.start[name] = time.perf_counter()
-
-    def end(self, name: str) -> None:
-        if Timer._enabled and name in self.start:
-            self.acc[name] = self.acc.get(name, 0.0) + (
-                time.perf_counter() - self.start.pop(name))
-
-    def scope(self, name: str):
-        return _TimerScope(self, name)
-
-    def print_all(self) -> None:
-        for name, dur in sorted(self.acc.items(), key=lambda kv: -kv[1]):
-            _emit("Info", f"{name} costs {dur:.6f}s")
-
-
-class _TimerScope:
-    def __init__(self, timer: Timer, name: str):
-        self.timer, self.name = timer, name
-
-    def __enter__(self):
-        self.timer.begin(self.name)
-        return self
-
-    def __exit__(self, *exc):
-        self.timer.end(self.name)
-        return False
-
-
-global_timer = Timer()
 
 
 def annotate(name: str):

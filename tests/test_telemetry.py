@@ -318,3 +318,219 @@ def test_telemetry_out_param_enables_file(tel, tmp_path, monkeypatch):
     recs = [json.loads(ln) for ln in open(path)]
     assert any(r["kind"] == "run_start" for r in recs)
     assert any(r["kind"] == "train_end" for r in recs)
+
+
+# ---------------------------------------------------------------------
+# set-up from the inside: span and compile records (ISSUE 35)
+from lightgbm_tpu.observability import scopes as vocabulary  # noqa: E402
+from lightgbm_tpu.observability.telemetry import _NULL_SPAN  # noqa: E402
+
+_FUSED_PARAMS = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+                 "tree_learner": "partitioned"}
+
+
+def _fused_job(on: bool):
+    """A small ``Dataset`` + ``Booster`` + the first iteration + two
+    fused blocks of 2 trees with telemetry on (ring only) or off:
+    ``(records, lowered text of the block, the model's text)``."""
+    import jax.numpy as jnp
+    tel = get_telemetry()
+    tel.reset()
+    if on:
+        tel.ensure_ring()
+    try:
+        X, y = _toy(n=700)
+        ds = lgb.Dataset(X, label=y, params=dict(_FUSED_PARAMS)).construct()
+        bst = lgb.Booster(dict(_FUSED_PARAMS), ds)
+        g = bst._gbdt
+        g.train(1)
+        g.train(3)
+        g.train(5)
+        records = tel.records       # the job's, not the lowering below
+        text = g._fused_jit.lower(
+            g.learner.mat, g.learner.ws, g.train_score,
+            tuple(g.valid_scores), jnp.float32(g.shrinkage_rate),
+            jnp.int32(g.iter), m=2).as_text()
+        return records, text, bst.model_to_string()
+    finally:
+        tel.reset()
+
+
+@pytest.fixture(scope="module")
+def fused_jobs():
+    mp = pytest.MonkeyPatch()
+    mp.setenv("LGBM_TPU_FUSE_ITERS", "1")
+    try:
+        yield {"on": _fused_job(True), "off": _fused_job(False)}
+    finally:
+        mp.undo()
+
+
+def _spans_of(records):
+    return [r for r in records if r["kind"] == "span"]
+
+
+@pytest.mark.parametrize("what", ["roots", "nesting", "vocabulary",
+                                  "fields", "compiles", "compile_parents"])
+def test_setup_ledger_of_a_small_job(fused_jobs, what):
+    records = fused_jobs["on"][0]
+    spans = _spans_of(records)
+    compiles = [r for r in records if r["kind"] == "compile"]
+    by_path = {}
+    for r in spans:
+        by_path.setdefault(r["path"], []).append(r)
+    if what == "roots":
+        assert len(by_path[vocabulary.DATA_CONSTRUCT]) == 1
+        assert len(by_path[vocabulary.SETUP]) == 1
+        # the first iteration's call and two blocks
+        assert len(by_path[vocabulary.TRAIN]) == 3
+        assert {r["path"].split("/")[0] for r in spans} == {
+            vocabulary.DATA_CONSTRUCT, vocabulary.SETUP, vocabulary.TRAIN}
+    elif what == "nesting":
+        children = [r for r in spans if r["parent"] is not None]
+        assert {r["name"] for r in children} >= {
+            vocabulary.DATA_FIND_BINS, vocabulary.DATA_BIN_ROWS,
+            vocabulary.SETUP_LEARNER, vocabulary.SETUP_DEVICE_TABLE,
+            vocabulary.SETUP_OBJECTIVE, vocabulary.SETUP_SCORES}
+        for r in children:
+            assert r["path"] == r["parent"] + "/" + r["name"]
+            (parent,) = by_path[r["parent"]]
+            assert parent["t0"] <= r["t0"] <= r["t1"] <= parent["t1"]
+    elif what == "vocabulary":
+        assert {r["name"] for r in spans} <= set(vocabulary.LEDGER_SPANS)
+        for r in spans:
+            assert r["dur_s"] == pytest.approx(r["t1"] - r["t0"], abs=1e-5)
+    elif what == "fields":
+        (root,) = by_path[vocabulary.DATA_CONSTRUCT]
+        assert (root["rows"], root["columns"], root["source"]) \
+            == (700, 6, "numpy")
+        (setup,) = by_path[vocabulary.SETUP]
+        assert setup["rows"] == 700
+        assert setup["learner"] == "PartitionedTreeLearner"
+        (learner,) = [r for r in spans
+                      if r["name"] == vocabulary.SETUP_LEARNER]
+        assert learner["plan"].startswith("SplitStepPlan(")
+        (table,) = [r for r in spans
+                    if r["name"] == vocabulary.SETUP_DEVICE_TABLE]
+        assert table["bytes"] > 0
+    elif what == "compiles":
+        block = [r for r in compiles if r["program"] == "gbdt_fused_block"]
+        assert {r["stage"] for r in block} == {"trace", "lower", "backend"}
+        for r in compiles:
+            assert r["stage"] in ("trace", "lower", "backend")
+            assert r["t1"] - r["t0"] == pytest.approx(r["dur_s"], abs=1e-5)
+            if r["stage"] == "backend":
+                assert r["cache"] in ("hit", "miss", "none")
+            else:
+                assert "cache" not in r
+    else:
+        # a compile is a child of whatever caused it
+        for r in compiles:
+            if r["program"] == "gbdt_fused_block":
+                assert r["parent"] == "train/boosting"
+            if r["program"] == "gbdt_grad":
+                assert r["parent"].startswith("train/")
+
+
+def test_the_program_is_the_same_with_telemetry_on_and_off(fused_jobs):
+    _, text_on, model_on = fused_jobs["on"]
+    records_off, text_off, model_off = fused_jobs["off"]
+    assert records_off == []
+    assert text_on == text_off and "while" in text_on
+    assert model_on == model_off
+    assert model_on.count("Tree=") == 5
+
+
+def test_load_after_save_leaves_the_two_io_spans(tel, tmp_path):
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data import Dataset
+    tel.configure(summary=False)
+    X, y = _toy(n=400)
+    cfg = Config.from_params({"objective": "binary", "verbosity": -1})
+    path = str(tmp_path / "t.bin")
+    Dataset.from_numpy(X, cfg, label=y).save_binary(path)
+    before = len(tel.records)
+    loaded = Dataset.load_binary(path)
+    assert loaded.num_data == 400
+    saved = [r for r in _spans_of(tel.records[:before])
+             if r["name"] == vocabulary.DATA_SAVE_BINARY]
+    assert len(saved) == 1 and saved[0]["parent"] is None
+    assert saved[0]["bytes"] == os.path.getsize(path)
+    after = _spans_of(tel.records[before:])
+    assert [r["name"] for r in after] == [vocabulary.DATA_LOAD_BINARY,
+                                          vocabulary.DATA_CONSTRUCT]
+    io, root = after
+    assert io["parent"] == vocabulary.DATA_CONSTRUCT
+    assert io["bytes"] == os.path.getsize(path)
+    assert root["source"] == "binary" and root["rows"] == 400
+    assert root["t0"] <= io["t0"] <= io["t1"] <= root["t1"]
+
+
+@pytest.mark.parametrize("name", vocabulary.LEDGER_SPANS)
+def test_a_ledger_span_is_the_null_span_when_off(tel, name):
+    assert not tel.enabled
+    assert tel.setup_span(name, rows=1) is _NULL_SPAN
+    assert tel.span(name, ledger=True) is _NULL_SPAN
+    with tel.setup_span(name) as sp:
+        sp.set(bytes=1)
+    assert tel.records == [] and tel.spans == {}
+
+
+def test_the_span_stack_is_a_thread_its_own(tel):
+    import threading
+    tel.configure(summary=False)
+    seen = {}
+
+    def other():
+        with tel.span("flusher", ledger=True):
+            seen["path"] = tel.current_path()
+
+    with tel.span("train"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+        assert tel.current_path() == "train"
+    assert seen["path"] == "flusher"
+    (rec,) = _spans_of(tel.records)
+    assert rec["path"] == "flusher" and rec["parent"] is None
+    assert set(tel.spans) == {"train", "flusher"}
+
+
+@pytest.mark.parametrize("case", ["hit", "miss", "none", "short",
+                                  "registered", "renamed"])
+def test_compile_records_from_jax_monitoring_events(tel, case):
+    """The listener on made-up events, as jax 0.9.0 sends them: the
+    plain cache event on the compiling thread just before the backend
+    duration it belongs to."""
+    import jax.monitoring as monitoring
+
+    import lightgbm_tpu.models.gbdt  # noqa: F401  registers programs
+    tel.configure(summary=False)
+    backend = "/jax/core/compile/backend_compile_duration"
+    if case in ("hit", "miss"):
+        monitoring.record_event(
+            f"/jax/compilation_cache/cache_{'hits' if case == 'hit' else 'misses'}")
+    name, dur = {"short": ("jit(convert_element_type)", 0.004),
+                 "registered": ("jit(gbdt_fused_block)", 0.004),
+                 "renamed": ("jit(_bag_mask_jit)", 0.004)}.get(
+                     case, ("jit(some_program)", 0.25))
+    with tel.span("train"):
+        monitoring.record_event_duration_secs(backend, dur, fun_name=name)
+        # the slot is emptied: the next compile is nobody's hit
+        monitoring.record_event_duration_secs(
+            "/jax/core/compile/jaxpr_trace_duration", 0.2,
+            fun_name="jit(next)")
+    recs = [r for r in tel.records if r["kind"] == "compile"]
+    assert tel.counters["jit.compiles"] == 1
+    assert tel.counters["jit.compile_s"] == pytest.approx(dur)
+    if case == "short":
+        assert [r["program"] for r in recs] == ["next"]
+        return
+    first, second = recs
+    assert first["stage"] == "backend" and first["parent"] == "train"
+    assert first["program"] == {"registered": "gbdt_fused_block",
+                                "renamed": "bag_mask"}.get(
+                                    case, "some_program")
+    assert first["cache"] == (case if case in ("hit", "miss") else "none")
+    assert first["t1"] - first["t0"] == pytest.approx(dur)
+    assert second["stage"] == "trace" and "cache" not in second

@@ -325,12 +325,13 @@ def test_histogram_pool_bounded_matches_cached():
 def test_profile_capture(tmp_path, monkeypatch):
     """LGBM_TPU_PROFILE_DIR arms the ONE-SHOT span-aligned capture
     window (observability/tracing.py ProfileWindow): the xprof trace
-    covers a few steady-state iteration boundaries and the host-side
-    phase timers accumulate over the same window."""
+    covers a few steady-state iteration boundaries, and the host-side
+    spans of the same run are in ``Telemetry.spans``."""
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.data import Dataset
     from lightgbm_tpu.models.gbdt import GBDT
     from lightgbm_tpu.observability import tracing
+    from lightgbm_tpu.observability.telemetry import get_telemetry
     # fresh window: the singleton is one-shot per process and another
     # test may have consumed it
     monkeypatch.setattr(tracing, "_PROFILE", tracing.ProfileWindow())
@@ -341,19 +342,24 @@ def test_profile_capture(tmp_path, monkeypatch):
     y = (X[:, 0] > 0).astype(np.float32)
     cfg = Config.from_params({"objective": "binary", "num_leaves": 5,
                               "num_iterations": 6, "verbosity": -1})
-    booster = GBDT(cfg, Dataset.from_numpy(X, cfg, label=y))
-    booster.train()
+    tel = get_telemetry()
+    tel.reset()
+    tel.ensure_ring()
+    try:
+        booster = GBDT(cfg, Dataset.from_numpy(X, cfg, label=y))
+        booster.train()
+        spans = dict(tel.spans)
+    finally:
+        tel.reset()
     assert tracing.profile_window().state == "done"
-    from lightgbm_tpu.utils.log import Timer
-    assert not Timer._enabled  # enable state restored after the trace
-    # a trace was written and the boosting timer accumulated inside
-    # the capture window
+    # a trace was written and the boosting span accumulated over the
+    # same run
     import os
     found = [f for _, _, fs in os.walk(tmp_path) for f in fs]
     assert any(f.endswith((".pb", ".json.gz", ".xplane.pb"))
                for f in found), found
-    from lightgbm_tpu.utils.log import global_timer
-    assert global_timer.acc.get("boosting", 0) > 0
+    assert spans["train/boosting"][1] > 0
+    assert spans["train/boosting"][0] > 0
 
 
 def test_histogram_pool_lru_matches_cached():

@@ -64,6 +64,71 @@ def _last(records, kind):
     return out
 
 
+def _union_len(intervals) -> float:
+    """Total length the ``(start, end)`` intervals cover."""
+    total, reach = 0.0, None
+    for a, b in sorted(intervals):
+        if reach is None or a > reach:
+            total += b - a
+            reach = b
+        elif b > reach:
+            total += b - reach
+            reach = b
+    return total
+
+
+_SPAN_FIELDS = ("rows", "columns", "source", "sample_rows", "bytes",
+                "groups", "learner", "plan")
+
+
+def setup_digest(records: List[Dict[str, Any]]):
+    """The set-up timeline: every ``span`` and ``compile`` record that
+    ended by the end of the first ``train`` span (the first tree), on
+    seconds since telemetry started. ``None`` where the trace holds no
+    timed record (telemetry older than the span ledger)."""
+    timed = [r for r in records
+             if r.get("kind") in ("span", "compile")
+             and r.get("t0") is not None and r.get("t1") is not None]
+    if not timed:
+        return None
+    # a record is emitted as its region ends: t is t1 on the file's
+    # clock, so their difference is when telemetry started
+    origin = min(r["t1"] - r.get("t", 0.0) for r in timed)
+    first = next((r for r in timed if r["kind"] == "span"
+                  and r.get("name") == "train"), None)
+    end = first["t1"] if first else max(r["t1"] for r in timed)
+    kept = [r for r in timed if r["t1"] <= end]
+    compiles = [r for r in kept if r["kind"] == "compile"]
+    spans = []
+    for r in sorted((r for r in kept if r["kind"] == "span"),
+                    key=lambda r: (r["t0"], -r["t1"])):
+        inside = [(max(c["t0"], r["t0"]), min(c["t1"], r["t1"]))
+                  for c in compiles
+                  if c["t0"] < r["t1"] and c["t1"] > r["t0"]]
+        spans.append({
+            "name": r["name"], "depth": str(r.get("path", "")).count("/"),
+            "start_s": r["t0"] - origin, "dur_s": r["t1"] - r["t0"],
+            "compile_s": _union_len(inside),
+            "fields": {k: r[k] for k in _SPAN_FIELDS
+                       if r.get(k) is not None}})
+    programs: Dict[str, Dict[str, Any]] = {}
+    for c in compiles:
+        p = programs.setdefault(c.get("program") or "?", {
+            "trace": 0.0, "lower": 0.0, "backend": 0.0, "cache": "",
+            "parent": c.get("parent")})
+        p[c.get("stage", "backend")] += c["t1"] - c["t0"]
+        if c.get("cache"):
+            p["cache"] = c["cache"]
+    roots = [(r["t0"], r["t1"]) for r in kept
+             if r["kind"] == "span" and not r.get("parent")]
+    everything = roots + [(c["t0"], c["t1"]) for c in compiles]
+    return {"first_tree_s": end - origin,
+            "covered_s": _union_len(everything),
+            "compile_s": _union_len([(c["t0"], c["t1"])
+                                     for c in compiles]),
+            "spans": spans, "programs": programs}
+
+
 def digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Aggregate a record list into the report's data model."""
     run = _last(records, "run_start") or {}
@@ -265,6 +330,7 @@ def digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         "rows_per_s": end.get("rows_per_s"),
         "block_rows_per_s": block_rows_per_s,
         "compile": end.get("compile") or {},
+        "setup": setup_digest(records),
         "phases": phases,
         "iter_counts": iter_counts,
         "fused_block_hits": int((end.get("counters") or {}).get(
@@ -275,6 +341,31 @@ def digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         "eval": evals,
         "eval_iter": ev.get("iter") if ev else None,
     }
+
+
+def _render_setup(su: Dict[str, Any]) -> List[str]:
+    """Why the job took N seconds before its first tree."""
+    L = ["", "== set-up (to the end of the first train call) =="]
+    L.append(f"first tree after {su['first_tree_s']:.3f}s of telemetry: "
+             f"{su['covered_s']:.3f}s under a span or a compile, "
+             f"{su['compile_s']:.3f}s of it compiling")
+    L.append(f"{'span':<34}{'start_s':>9}{'dur_s':>9}{'compile_s':>10}"
+             "  fields")
+    for sp in su["spans"]:
+        name = "  " * sp["depth"] + sp["name"]
+        fields = " ".join(f"{k}={v}" for k, v in sp["fields"].items())
+        L.append(f"{name:<34}{sp['start_s']:>9.3f}{sp['dur_s']:>9.3f}"
+                 f"{sp['compile_s']:>10.3f}  {fields}"[:160])
+    if su["programs"]:
+        L.append(f"{'compiled program':<28}{'trace_s':>9}{'lower_s':>9}"
+                 f"{'backend_s':>10}  cache  under")
+        ranked = sorted(su["programs"].items(), key=lambda kv: -(
+            kv[1]["trace"] + kv[1]["lower"] + kv[1]["backend"]))
+        for name, p in ranked[:12]:
+            L.append(f"{name:<28}{p['trace']:>9.3f}{p['lower']:>9.3f}"
+                     f"{p['backend']:>10.3f}  {p['cache'] or '-':<5}  "
+                     f"{p['parent'] or '-'}")
+    return L
 
 
 def render(records: List[Dict[str, Any]]) -> str:
@@ -303,6 +394,9 @@ def render(records: List[Dict[str, Any]]) -> str:
         best = max(d["block_rows_per_s"])
         L.append(f"fused blocks: {len(d['block_rows_per_s'])}, best "
                  f"{best / 1e6:.4f} Mrow-iters/s (steady state)")
+
+    if d["setup"]:
+        L.extend(_render_setup(d["setup"]))
 
     L.append("")
     L.append("== phases (host wall, per-iteration records) ==")
@@ -972,7 +1066,10 @@ def render_crash(d: Dict[str, Any]) -> str:
         elif kind == "eval":
             extra = f" iter={r.get('iter')} {r.get('results')}"
         elif kind == "compile":
-            extra = f" dur_s={r.get('dur_s')}"
+            extra = (f" {r.get('program')} {r.get('stage')} "
+                     f"dur_s={r.get('dur_s')}")
+        elif kind == "span":
+            extra = f" {r.get('path')} dur_s={r.get('dur_s')}"
         L.append(f"  t={r.get('t')} {kind}{extra}"[:100])
     return "\n".join(L) + "\n"
 
