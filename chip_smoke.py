@@ -226,7 +226,8 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     """``lgb.train`` + the path report. Asserts the run took the path
     it was meant to take; ``megakernel`` is what the caller expects of
     the config, the report's value is what the trace counted, as is
-    ``leaf_of_pos`` (the block pass or the search, by num_leaves).
+    ``leaf_of_pos`` (the block pass or the search, by num_leaves) and
+    ``leaf_value_pass_traces`` (the pass painted the leaf's value).
     ``categorical``: ``params`` names categorical columns, so the
     bitset partition and the categorical scan must have been traced,
     the Pallas scan kernel not, and the trees must hold category
@@ -246,6 +247,7 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     before = {k: tel.counters.get(k, 0) for k in
               ("fused.block_hits", "learner.megakernel_traces",
                "learner.leaf_of_pos_dense_traces",
+               "learner.leaf_value_pass_traces",
                "learner.lut_partition_traces",
                "learner.cat_scan_traces",
                "learner.wide_table_traces",
@@ -272,6 +274,10 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "megakernel_reason": _megakernel_reason(ln),
         "leaf_of_pos": "dense"
         if delta["learner.leaf_of_pos_dense_traces"] else "search",
+        # grow programs whose pass over the positions paints the
+        # leaf's VALUE for the fused driver's score update (PR 36)
+        "leaf_value_pass_traces":
+        delta["learner.leaf_value_pass_traces"],
         "lut_partition": "on" if delta["learner.lut_partition_traces"]
         else "off",
         "cat_scan": "on" if delta["learner.cat_scan_traces"] else "off",
@@ -318,6 +324,10 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         report
     assert report["leaf_of_pos"] == (
         "dense" if uses_block_pass(ln.num_leaves) else "search"), report
+    # the fused driver's score update reads no table by position: its
+    # grow call painted the leaf values (a process that had traced the
+    # same shapes before counts nothing, which interpret mode allows)
+    assert report["leaf_value_pass_traces"] > 0 or interpret, report
     assert report["fused_block_hits"] > 0, \
         "_train_fused_blocks did not run"
     assert report["trees"] == rounds, report

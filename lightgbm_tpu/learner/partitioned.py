@@ -234,7 +234,7 @@ class PartitionedTreeLearner(PartitionedLearnerBase):
     def traceable_grow(self, mat, ws, grad, hess, bag=None):
         """One tree grown inside an enclosing trace (no jit boundary,
         no host state updates). Caller owns the mat/ws carry. Returns
-        ``(mat, ws, tree, (row_ids, pos_leaf))`` — leaf parts, not a
+        ``(mat, ws, tree, (row_ids, pos_value))`` — leaf parts, not a
         materialized leaf_id (see return_leaf_parts)."""
         if bag is None:
             bag = jnp.ones_like(grad)
@@ -315,13 +315,14 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
 
     Returns ``(mat, ws, tree, leaf_id)``, ``leaf_id`` int32 ``[n]`` by
     LOCAL row. ``return_leaf_parts=True`` returns in its place the pair
-    ``(row_ids, pos_leaf)``, both by POSITION of the partitioned matrix:
-    the local row id at each position and the leaf whose segment
-    ``[leaf_begin, leaf_begin + leaf_cnt)`` holds it, so the caller
-    updates its score with one scatter-add. ``pos_leaf`` is written by
-    one block pass of compares over the positions (ops/leaf_of_pos.py),
-    not searched for; a used leaf without a local row (a mesh shard)
-    owns no position.
+    ``(row_ids, pos_value)``, both by POSITION of the partitioned matrix:
+    the local row id at each position and the f32 ``leaf_value`` of the
+    leaf whose segment ``[leaf_begin, leaf_begin + leaf_cnt)`` holds it,
+    so the caller updates its score with one scatter-add and reads no
+    table by position. Either is written by one block pass of compares
+    over the positions (ops/leaf_of_pos.py), not searched for, one pass
+    a tree; a used leaf without a local row (a mesh shard) owns no
+    position.
     """
     if comm is None:
         from .comm import SERIAL_COMM
@@ -870,25 +871,29 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
     )
 
     with jax.named_scope(scopes.GROW_LEAF_OF_POS):
-        # ---- leaf_id reconstruction: segments -> positions -> row ids ----
-        # the leaf of each position is piecewise constant over the live
-        # segments: one block pass of compares, no search and no gather
-        # over the positions (ops/leaf_of_pos.py); counted where it
-        # enters the trace, like the megakernel
-        if uses_block_pass(big_l):
+        # ---- segments -> positions -> row ids ----
+        # what a position reads of its leaf is piecewise constant over
+        # the live segments: one block pass of compares, no search and
+        # no gather over the positions (ops/leaf_of_pos.py); counted
+        # where it enters the trace, like the megakernel
+        dense = uses_block_pass(big_l)
+        if dense:
             get_telemetry().count("learner.leaf_of_pos_dense_traces")
-        pos_leaf = leaf_of_pos(vf["leaf_begin"], vf["leaf_cnt"], st["k"],
-                               n=n, interpret=interpret)
         # rows never leave their shard, so local ids = global - row_id_base
-        rids_final = extract_row_ids(st["mat"], f, mat.shape[0])[:n] \
-            - row_id_base
+        rids_final = jnp.clip(
+            extract_row_ids(st["mat"], f, mat.shape[0])[:n] - row_id_base,
+            0, n - 1)
+        # fused path: the pass paints the leaf's VALUE, so the caller's
+        # score update is ONE scatter-add that reads no table by
+        # position; the un-fused return wants the leaf itself
+        painted = leaf_of_pos(
+            vf["leaf_begin"], vf["leaf_cnt"], st["k"],
+            vf["leaf_value"] if return_leaf_parts else None, n=n,
+            interpret=interpret)
         if return_leaf_parts:
-            # fused path: (row ids, per-POSITION leaf) lets the caller do
-            # its score update with ONE scatter-add instead of this
-            # scatter + a leaf_value gather (two random [N] passes)
-            return st["mat"], st["ws"], tree, (
-                jnp.clip(rids_final, 0, n - 1), pos_leaf)
-        leaf_id = jnp.zeros((n,), jnp.int32).at[
-            jnp.clip(rids_final, 0, n - 1)].set(pos_leaf)
+            if dense:   # the search side reads its entries by position
+                get_telemetry().count("learner.leaf_value_pass_traces")
+            return st["mat"], st["ws"], tree, (rids_final, painted)
+        leaf_id = jnp.zeros((n,), jnp.int32).at[rids_final].set(painted)
 
     return st["mat"], st["ws"], tree, leaf_id

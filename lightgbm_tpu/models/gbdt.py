@@ -234,16 +234,16 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, *, learner,
         ok = None
         for tid in range(k):
             with jax.named_scope(scopes.GROW):
-                mat, ws, tree, (row_ids, pos_leaf) = \
+                mat, ws, tree, (row_ids, pos_value) = \
                     learner.traceable_grow(mat, ws, grad[:, tid],
                                            hess[:, tid], bag=bag)
             ok_t = tree.num_leaves > 1
             scale = jnp.where(ok_t, lr, jnp.float32(0.0))
             # one scatter-add in segment order: row_ids is a
-            # permutation of [0, N), pos_leaf the leaf per POSITION
+            # permutation of [0, N), pos_value the leaf's value per
+            # POSITION, so no table is read by position
             with jax.named_scope(scopes.SCORE_UPDATE):
-                score = score.at[row_ids, tid].add(
-                    (tree.leaf_value * scale)[pos_leaf])
+                score = score.at[row_ids, tid].add(pos_value * scale)
             vscores = tuple(
                 vs.at[:, tid].add(traverse_tree_arrays(
                     tree, vb, learner.meta, scale, vmv))

@@ -529,12 +529,12 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
                 has_monotone=self.has_monotone, plan=plan,
                 return_leaf_parts=leaf_parts, body_scan=ctx)
             if leaf_parts:
-                mat_l, ws_l, tree, (rid_l, pos_leaf) = out
+                mat_l, ws_l, tree, (rid_l, pos_value) = out
                 # GLOBAL ids: unique across shards; the caller's
                 # scatter-add drops pad ids >= num_data (JAX OOB-write
                 # semantics), so padding never aliases a real row
                 return (mat_l[None], ws_l[None], tree,
-                        rid_l + base, pos_leaf)
+                        rid_l + base, pos_value)
             mat_l, ws_l, tree, leaf_id = out
             return mat_l[None], ws_l[None], tree, leaf_id
 
@@ -598,7 +598,7 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
 
     def traceable_grow(self, mat, ws, grad, hess, bag=None):
         """One mesh-parallel tree inside an enclosing trace. Returns
-        ``(mat, ws, tree, (global_row_ids, pos_leaf))`` with padded
+        ``(mat, ws, tree, (global_row_ids, pos_value))`` with padded
         entries carrying ids >= num_data (dropped by the caller's
         scatter-add)."""
         n = self.dataset.num_data
@@ -612,10 +612,10 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
         fmask = jnp.ones((self.num_features,), bool)
         rkey = jnp.zeros((2, 2), jnp.uint32)
         cegb0 = jnp.zeros((self.num_features,), bool)
-        mat, ws, tree, rids, pos_leaf = self._mapped_parts(
+        mat, ws, tree, rids, pos_value = self._mapped_parts(
             mat, ws, *self._grow_extra, grad, hess, bag, fmask, rkey,
             cegb0)
-        return mat, ws, tree, (rids, pos_leaf)
+        return mat, ws, tree, (rids, pos_value)
 
 
 def TreeArrays_spec():

@@ -252,16 +252,20 @@ def test_one_block_opens_its_three_spans_in_order(tel, monkeypatch):
 # one compaction a block (three dots fewer: one prefix product, one
 # permutation product a forward block, none in the back-copy, whose
 # roll the interpreter writes as slices and a concatenate; on a TPU
-# the kernel is one Mosaic call either way)
+# the kernel is one Mosaic call either way), and for the block pass
+# painting the leaf's value, ISSUE 36 (the leaf-value gather over the
+# positions went and one over the num_leaves sorted segments came; the
+# f32 table goes through the pass as int32 words: three
+# bitcast-converts, two small fusions)
 PARENT_OPCODES = {
     "abs": 20, "add": 457, "and": 142, "bitcast": 808,
-    "bitcast-convert": 74, "broadcast": 417, "clamp": 2, "compare": 392,
+    "bitcast-convert": 77, "broadcast": 417, "clamp": 2, "compare": 392,
     "concatenate": 32, "conditional": 15, "constant": 1158, "convert": 205,
     "copy": 183, "divide": 12, "dot": 19, "dynamic-slice": 93,
-    "dynamic-update-slice": 222, "exponential": 1, "fusion": 478,
+    "dynamic-update-slice": 222, "exponential": 1, "fusion": 480,
     "gather": 9, "get-tuple-element": 273, "iota": 39, "is-finite": 4,
     "maximum": 25, "minimum": 13, "multiply": 143, "negate": 121, "not": 4,
-    "or": 44, "pad": 20, "parameter": 1181, "reduce": 12,
+    "or": 44, "pad": 20, "parameter": 1183, "reduce": 12,
     "reduce-window": 8, "remainder": 1, "reverse": 2, "scatter": 2,
     "select": 358, "shift-left": 33, "shift-right-logical": 37, "sign": 45,
     "slice": 709, "sort": 1, "subtract": 102, "transpose": 8, "tuple": 42}
@@ -332,6 +336,113 @@ def test_the_block_pass_runs_under_the_leaf_of_pos_scope(tel):
         == {scopes.GROW_LEAF_OF_POS}
     # and the search it replaced is gone from the program
     assert "searchsorted" not in text
+
+
+# ---------------------------------------------------------------------
+# the score update reads no table by position (ISSUE 36)
+def _eqns_under(jaxpr, scope):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations
+    hold (scan and while bodies, closed calls) whose name stack has
+    ``scope``."""
+    import jax
+    for eqn in jaxpr.eqns:
+        if scope in str(eqn.source_info.name_stack).split("/"):
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns_under(sub, scope)
+
+
+def _fused_block_of(g, m):
+    """``_fused_iter_block`` over ``m`` trees of ``g``'s table, as the
+    booster wraps it, and its arguments (nothing donated)."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.models.gbdt import _fused_iter_block
+    ln = g.learner
+    fn = functools.partial(
+        _fused_iter_block, learner=ln, grad_fn=g._grad_fn,
+        bag_fn=g._traceable_bag_fn(), valid_data=(), m=m, k=1)
+    return fn, (ln.mat, ln.ws, g.train_score, (), jnp.float32(0.1),
+                jnp.int32(0))
+
+
+def test_the_score_update_is_one_scatter_add_and_no_gather(tel):
+    import jax
+    tel.ensure_ring()
+    fn, args = _fused_block_of(_gbdt(), 3)
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    assert tel.counters["learner.leaf_value_pass_traces"] >= 1
+    # every dense pass is still counted, the value pass among them
+    assert tel.counters["learner.leaf_of_pos_dense_traces"] \
+        >= tel.counters["learner.leaf_value_pass_traces"]
+    got = collections.Counter(
+        e.primitive.name for e in _eqns_under(jaxpr.jaxpr,
+                                              scopes.SCORE_UPDATE))
+    assert got["scatter-add"] == 1, got
+    assert not [p for p in got if "gather" in p], got
+    # what is left beside it: the product with the shrinkage
+    assert got["mul"] == 1, got
+
+
+@pytest.mark.parametrize("bagging", [False, True],
+                         ids=["plain", "bagging"])
+def test_scores_bit_equal_to_the_parents_statement(tel, bagging):
+    """Three trees through ``_fused_iter_block`` as it stands against
+    three through the parent's statement,
+    ``score.at[row_ids, tid].add((leaf_value * scale)[pos_leaf])``, on
+    the same leaf parts: ``pos_leaf = leaf_of_pos(...)`` is what the
+    un-fused grow call scatters into ``leaf_id`` by ``row_ids``, so it
+    is read back from there. And against the un-fused route's own
+    update within a rounding (its grow program is another compiled
+    program; the fused-scan tests hold the two routes' models equal)."""
+    import jax
+    import jax.numpy as jnp
+    tel.ensure_ring()
+    g = _gbdt(bagging, rows=700)
+    fn, args = _fused_block_of(g, 3)
+    _, _, got, _, trees, oks = jax.jit(fn)(*args)
+    assert bool(np.asarray(oks).all())
+    assert int(np.asarray(trees.num_leaves).min()) > 1
+
+    ln, lr, score = g.learner, args[4], args[2]
+    unfused = score
+    bag_fn = g._traceable_bag_fn()
+    assert (bag_fn is not None) == bagging
+    parts = jax.jit(ln.traceable_grow)
+    for it in range(3):
+        grad, hess = g._grad_fn(score[:, 0])
+        bag = None if bag_fn is None else bag_fn(jnp.int32(it), grad, hess)
+        _, _, tree, (row_ids, pos_value) = parts(ln.mat, ln.ws, grad,
+                                                 hess, bag)
+        # the same tree through the un-fused return: the index pass
+        res = ln.train(grad, hess, bag)
+        for field in ("num_leaves", "split_feature", "threshold_bin",
+                      "leaf_count"):
+            assert np.array_equal(np.asarray(getattr(tree, field)),
+                                  np.asarray(getattr(res.tree, field)))
+        # a permutation of the rows, with bagging too (what
+        # unique_indices would promise of the scatter)
+        assert np.array_equal(np.sort(np.asarray(row_ids)),
+                              np.arange(700))
+        pos_leaf = res.leaf_id[row_ids]
+        assert np.array_equal(
+            np.asarray(pos_value).view(np.uint32),
+            np.asarray(tree.leaf_value[pos_leaf]).view(np.uint32))
+        scale = jnp.where(tree.num_leaves > 1, lr, jnp.float32(0.0))
+        score = score.at[row_ids, 0].add(
+            (tree.leaf_value * scale)[pos_leaf])
+        unfused = unfused.at[:, 0].add(
+            (res.tree.leaf_value * lr)[res.leaf_id])
+    assert np.array_equal(np.asarray(got).view(np.uint32),
+                          np.asarray(score).view(np.uint32))
+    assert not np.array_equal(np.asarray(got), np.asarray(args[2]))
+
+    # the un-fused route's own statement on its own return
+    # (``_score_add_leaf``: leaf values read by ``leaf_id`` by row)
+    np.testing.assert_allclose(np.asarray(unfused), np.asarray(got),
+                               rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------

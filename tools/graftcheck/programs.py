@@ -536,8 +536,11 @@ def _b_leaf_of_pos():
     import jax.numpy as jnp
     lrn = _partitioned_learner()
     table = jnp.zeros((lrn.num_leaves,), jnp.int32)
+    # the fused driver's form: the pass paints the f32 leaf values
     return _spec_fn("leaf_of_pos").lower(
-        table, table, jnp.int32(1), n=lrn.num_data, interpret=True)
+        table, table, jnp.int32(1),
+        jnp.zeros((lrn.num_leaves,), jnp.float32), n=lrn.num_data,
+        interpret=True)
 
 
 def _fused_step_state(lrn):
