@@ -37,7 +37,14 @@ binary objective, 255 leaves, 255 bins), on rows generated from a seed:
    feature, and every split runs the bundled per-phase body (the
    256-entry table partition, the debundle before each scan, the
    Pallas scan over the logical features), still in fused blocks; its
-   route counters and its AUC against the XLA foil on the same table.
+   route counters and its AUC against the XLA foil on the same table;
+8. rank: 200,000 rows of the benchmark's learning-to-rank table (MS
+   LTR: 137 columns in 256-byte rows, about 1,700 ragged query groups
+   of 10 to 1,251 documents, grades 0-4) with ``objective=lambdarank``
+   for 5 rounds: the megakernel at its third width, the pairwise
+   gradients on the query layout that follows the queries' lengths
+   (``objective.rank_slots`` at most 1.6 x ``objective.rank_docs``),
+   still in fused blocks; its route counters and its NDCG@10.
 
 ``--devices 4`` instead trains the same shape data-parallel over four
 chips and checks the sharding and the AUC against the one-chip model.
@@ -108,6 +115,17 @@ ONEHOT_MIN_AUC = 0.6
 # the plain reference, agrees to 8e-8 in the first tree's gains. A
 # learner that reads a bundle wrong parts by a tenth and more
 ONEHOT_FOIL_AUC_TOL = 2e-2
+# the benchmark's learning-to-rank table (MS LTR): 137 columns in
+# 256-byte rows, about 1,700 ragged query groups of 10 to 1,251
+# documents, relevance grades 0-4, lambdarank. The third width through
+# the megakernel and the one objective whose gradients are no
+# elementwise pass (PR 37)
+RANK_ROWS = 200_000
+RANK_ROUNDS = 5         # the sync first iteration plus a block of 4
+# in-sample NDCG@10 after five trees, from 0.36-0.38 at the seeded
+# start (PERF.md, PR 37); and the AUC of "relevant at all" by the score
+RANK_MIN_NDCG = 0.45
+RANK_MIN_AUC = 0.6
 
 
 def device_report() -> dict:
@@ -156,8 +174,9 @@ def higgs_like(n: int, f: int = FEATURES, seed: int = 42):
 
 
 def _benchmark_rows(config: str, generator: str, n: int, seed: int):
-    """``(x, y, the configuration)``: ``n`` rows of a benchmark table
-    from its own generator and configuration file (``benchmarks/``)."""
+    """``(x, y, [query sizes,] the configuration)``: ``n`` rows of a
+    benchmark table from its own generator and configuration file
+    (``benchmarks/``)."""
     import importlib
     import os
     here = os.path.dirname(os.path.abspath(__file__))
@@ -165,9 +184,8 @@ def _benchmark_rows(config: str, generator: str, n: int, seed: int):
                            config + ".json")) as fh:
         cfg = json.load(fh)
     gen = importlib.import_module("benchmarks.generators." + generator)
-    x, y = gen.make(seed, n, cfg["features"],
-                    **cfg["generator"]["params"])
-    return x, y, cfg
+    return (*gen.make(seed, n, cfg["features"],
+                      **cfg["generator"]["params"]), cfg)
 
 
 def expo_like(n: int, seed: int = 42):
@@ -186,6 +204,14 @@ def allstate_like(n: int, seed: int = 42):
     """``(x, y)``: rows of the benchmark's sparse one-hot table, ``x``
     a scipy CSR."""
     return _benchmark_rows("allstate-onehot", "allstate_like", n, seed)[:2]
+
+
+def msltr_like(n: int, seed: int = 42):
+    """``(x, grades, query sizes, params)``: rows of the benchmark's
+    learning-to-rank table, and ``PARAMS`` with that file's objective."""
+    x, y, sizes, cfg = _benchmark_rows("msltr-rank", "msltr_like", n, seed)
+    return x, y, sizes, dict(PARAMS,
+                             objective=cfg["params"]["objective"])
 
 
 def train_auc(bst, x, y) -> float:
@@ -222,7 +248,8 @@ def stage_kernels(interpret: bool = False, **shapes) -> dict:
 def stage_train(x, y, params, rounds: int, *, learner: str,
                 interpret: bool, megakernel: bool, shards: int = 1,
                 categorical: bool = False, wide: bool = False,
-                bundled: bool = False, min_auc: float = MIN_AUC):
+                bundled: bool = False, min_auc: float = MIN_AUC,
+                group=None):
     """``lgb.train`` + the path report. Asserts the run took the path
     it was meant to take; ``megakernel`` is what the caller expects of
     the config, the report's value is what the trace counted, as is
@@ -238,7 +265,11 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     sparse one-hot table, so the dataset must have bundled it (EFB)
     into fewer physical columns without a conflict row or a multi-val
     feature, and the bundled split body (table partition, debundle
-    before every scan) must have been traced."""
+    before every scan) must have been traced. ``group``: the rows come
+    in query groups of these sizes and ``y`` holds relevance grades, so
+    the objective's counters must say that its query layout follows
+    the documents, and the model is judged by NDCG@10 (the AUC is that
+    of "relevant at all")."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.observability.telemetry import get_telemetry
     from lightgbm_tpu.ops.leaf_of_pos import uses_block_pass
@@ -257,7 +288,7 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
                "kernels.hist_child_stream",
                "kernels.hist_feature_slices")}
     t0 = time.perf_counter()
-    bst = lgb.train(dict(params), lgb.Dataset(x, label=y),
+    bst = lgb.train(dict(params), lgb.Dataset(x, label=y, group=group),
                     num_boost_round=rounds)
     seconds = time.perf_counter() - t0
     delta = {k: int(tel.counters.get(k, 0) - v)
@@ -306,7 +337,7 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         "min_leaves": min(leaves),
         "max_leaves": max(leaves),
         "num_shards": int(getattr(ln, "num_shards", 1)),
-        "auc": round(train_auc(bst, x, y), 6),
+        "auc": round(train_auc(bst, x, y if group is None else y > 0), 6),
         "train_seconds": round(seconds, 1),
         # the trees themselves: the tables are seeded, so a change
         # that moves the same bytes in the same order (a partition
@@ -360,6 +391,19 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         # the root's call and the split body's, each cut into slices
         assert report["hist_feature_slices"] \
             >= 2 * -(-ln.num_groups // SLICE_F) > 2, report
+    if group is not None:
+        import numpy as np
+
+        from benchmarks.reference.gbdt_rank_numpy import ndcg_at
+        seen = {"rank_" + k: int(tel.counters["objective.rank_" + k])
+                for k in ("queries", "docs", "slots", "classes")}
+        seen["ndcg10"] = round(ndcg_at(np.asarray(
+            gbdt.train_score[:, 0], np.float64), y, group, 10), 6)
+        print(f"path[{learner}]: rank {json.dumps(seen)}", flush=True)
+        report.update(seen)
+        assert seen["rank_docs"] == len(y), report
+        assert seen["rank_slots"] <= 1.6 * seen["rank_docs"], report
+        assert seen["ndcg10"] >= RANK_MIN_NDCG, report
     if bundled:
         inner = gbdt.train_data
         seen = {"logical_features": inner.num_features,
@@ -626,6 +670,14 @@ def main(argv=None) -> int:
         gap = abs(report["bundled"]["auc"] - report["bundled_foil"]["auc"])
         assert gap <= ONEHOT_FOIL_AUC_TOL, ("bundled chip path vs foil",
                                             gap)
+        # the learning-to-rank table: ragged query groups, lambdarank's
+        # pairwise gradients on the query layout, the megakernel at 137
+        # columns and 256-byte rows, still fused
+        rx, ry, sizes, rank_params = msltr_like(RANK_ROWS)
+        _, report["rank"] = stage_train(
+            rx, ry, rank_params, RANK_ROUNDS,
+            learner="PartitionedTreeLearner", interpret=False,
+            megakernel=True, min_auc=RANK_MIN_AUC, group=sizes)
     else:
         mesh_params = dict(PARAMS, tree_learner="data",
                            num_machines=args.devices)

@@ -205,8 +205,8 @@ def _named(name, fn):
     return named
 
 
-def _fused_iter_block(mat, ws, score, vscores, lr, it0, *, learner,
-                      grad_fn, bag_fn, valid_data, m, k):
+def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(), *,
+                      learner, grad_fn, bag_fn, valid_data, m, k):
     """``m`` boosting iterations as one device program (lax.scan over
     gradients -> [sampling] -> grow -> score update; ``k`` trees per
     iteration for multiclass; ``bag_fn(it, grad, hess)`` supplies
@@ -214,6 +214,9 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, *, learner,
     sampling). ``vscores``/``valid_data`` carry the valid-set scores
     through the scan: each tree is traversed on device against every
     valid set's binned matrix, so eval-bearing configs fuse too.
+    ``gops`` are the objective's ``grad_operands()``: arrays the size
+    of the table that ``grad_fn`` takes after the score (a ranking
+    objective's query layout), arguments of the program, not constants.
     NOT module-jitted: the learner and grad_fn capture device state
     (training matrix layout, objective label arrays), so each booster
     wraps this in its OWN jax.jit (``GBDT._train_fused_blocks``) — the
@@ -222,7 +225,7 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, *, learner,
     def body(carry, it):
         mat, ws, score, vscores = carry
         with jax.named_scope(scopes.GRADIENTS):
-            grad, hess = grad_fn(score if k > 1 else score[:, 0])
+            grad, hess = grad_fn(score if k > 1 else score[:, 0], *gops)
             if k == 1:
                 grad = grad[:, None]
                 hess = hess[:, None]
@@ -285,6 +288,7 @@ class GBDT:
         self.valid_scores: List[jnp.ndarray] = []
         self.training_metrics: list = []
         self._grad_fn = None
+        self._grad_operands: tuple = ()
         self.evals_result: Dict[str, Dict[str, list]] = {}
 
         if train_data is not None:
@@ -312,8 +316,10 @@ class GBDT:
                 sp.set(plan=str(plan()) if plan is not None else None)
             root.set(learner=type(self.learner).__name__)
             self.num_data = train_data.num_data
-            with tel.setup_span(scopes.SETUP_OBJECTIVE):
+            with tel.setup_span(scopes.SETUP_OBJECTIVE) as sp:
                 self._setup_objective(train_data)
+                if self.objective is not None:
+                    sp.set(**self.objective.setup_facts())
             with tel.setup_span(scopes.SETUP_SCORES):
                 self._setup_scores(train_data)
             self._setup_guards()
@@ -321,6 +327,7 @@ class GBDT:
     def _setup_objective(self, train_data: Dataset) -> None:
         if self.objective is not None:
             self.objective.init(train_data.metadata, self.num_data)
+            self._grad_operands = self.objective.grad_operands()
             # objectives with per-call host randomness (rank_xendcg)
             # jit internally instead
             self._grad_fn = register_dynamic(
@@ -478,7 +485,7 @@ class GBDT:
                     and getattr(self.objective, "jittable", True))
         if not combined:
             tel.count_iter("host.dispatches")
-            grad, hess = self._grad_fn(score)
+            grad, hess = self._grad_fn(score, *self._grad_operands)
             self._last_grad_ok = None
             return grad, hess, None
         fn = getattr(self, "_grad_bag_jit", None)
@@ -487,8 +494,8 @@ class GBDT:
             grad_fn = self._grad_fn
             guard_on = self._guard_policy != "off"
 
-            def _fused(s, i):
-                g, h = grad_fn(s)
+            def _fused(s, i, *gops):
+                g, h = grad_fn(s, *gops)
                 if guard_on:
                     # guard reduction folded into the SAME program:
                     # the finite flag costs no extra dispatch
@@ -501,7 +508,7 @@ class GBDT:
                 "gbdt_grad_bag", jax.jit(_named("gbdt_grad_bag", _fused)))
             self._grad_bag_jit = fn
         tel.count_iter("host.dispatches")
-        out = fn(score, jnp.int32(it))
+        out = fn(score, jnp.int32(it), *self._grad_operands)
         if len(out) == 4:
             grad, hess, bag, self._last_grad_ok = out
         else:
@@ -848,7 +855,7 @@ class GBDT:
         pending = []  # (tree, device refit output, linear feats|None)
         for it in range(n_iters):
             sc = self.train_score if k > 1 else self.train_score[:, 0]
-            grad, hess = self._grad_fn(sc)
+            grad, hess = self._grad_fn(sc, *self._grad_operands)
             if grad.ndim == 1:
                 grad = grad[:, None]
                 hess = hess[:, None]
@@ -1249,7 +1256,7 @@ class GBDT:
                 tel.count("fused.block_hits")
                 vs = tuple(self.valid_scores)
                 args = (ln.mat, ln.ws, self.train_score, vs, lr,
-                        jnp.int32(self.iter))
+                        jnp.int32(self.iter), self._grad_operands)
                 # avals before the call: the arguments are donated
                 new_prog = tel.enabled and scopes.remember(
                     "gbdt_fused_block", fused, args, m=m)

@@ -245,7 +245,7 @@ class RF(GBDT):
         tmp = jnp.tile(jnp.asarray(self._init_scores, jnp.float32)[None, :],
                        (self.num_data, 1))
         score = tmp if k > 1 else tmp[:, 0]
-        g, h = self._grad_fn(score)
+        g, h = self._grad_fn(score, *self._grad_operands)
         if k == 1:
             g, h = g[:, None], h[:, None]
         self._rf_grad, self._rf_hess = g, h

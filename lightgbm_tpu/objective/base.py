@@ -60,6 +60,18 @@ class ObjectiveFunction:
     def gradients(self, score: jnp.ndarray):
         raise NotImplementedError
 
+    def grad_operands(self) -> tuple:
+        """Device arrays ``gradients`` takes after the score, which a
+        compiled program that holds it is handed as ARGUMENTS (the
+        fused block, ``gbdt_grad``): what would otherwise be baked
+        into the program as constants the size of the table
+        (objective/rank.py's query layout)."""
+        return ()
+
+    def setup_facts(self) -> dict:
+        """Attributes of the ``lgbm.setup.objective`` span."""
+        return {}
+
     # -- BoostFromScore(class_id) -> initial score (double)
     def boost_from_score(self, class_id: int = 0) -> float:
         return 0.0

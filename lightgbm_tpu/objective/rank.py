@@ -2,12 +2,31 @@
 
 Reference analog: ``src/objective/rank_objective.hpp:98-330``. The
 reference loops per query with OpenMP and walks all document pairs
-serially; here queries are PADDED to a common length Q and processed as
-dense ``[nq, Q]`` tensors — per-query sorts become batched ``argsort``,
-the pairwise lambda accumulation becomes a ``[C, Q, Q]`` tensor
-contraction evaluated in bounded-memory query chunks via ``lax.map``
-(SURVEY §7 M2: "per-query variable-length pairwise loops need
-bucketing/padding by query size").
+serially. Here the queries are grouped into a handful of LENGTH
+CLASSES (``QueryLayout``): a class of length ``L`` holds every query
+of more than the class below's and at most ``L`` documents as one
+dense ``[nq_c, L]`` block with static shapes, so the slots follow the
+documents and the pair slots the pairs the queries hold, not the
+longest query (docs/ARCHITECTURE.md, "The query layout").
+
+* Into the layout: a query's documents are contiguous rows, so a
+  query's scores (and its labels, which ride in the same operand) are
+  ONE window of ``L`` rows from the query's first row
+  (``dynamic_slice`` under ``vmap``: a gather of whole windows, one
+  index a query); the window's tail past the query's own documents is
+  masked.
+* Order within a query: one stable multi-operand ``lax.sort`` a class
+  with the label and the position as payload, undone by a second sort
+  on the position. No ``argsort`` + ``take_along_axis``.
+* Pairs: the ``[C, L, L]`` block of ``_lambdarank_pairs``, evaluated in
+  bounded-memory chunks of ``C`` queries by ``lax.map``, ``C`` set by
+  the class's own ``L``.
+* Back to row order: one gather over the documents
+  (``slot_of_row``), the only operation with an index a document.
+
+The layout's arrays reach the compiled programs as ARGUMENTS
+(``grad_operands``), never as constants: a program's text does not
+grow with the table.
 
 Semantic deviations (documented):
   * the reference quantizes the sigmoid into a 2^20-entry lookup table
@@ -21,6 +40,7 @@ Semantic deviations (documented):
 from __future__ import annotations
 
 import functools
+from typing import List
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +48,9 @@ import numpy as np
 
 from ..config import Config
 from ..data.dataset import Metadata
-from ..utils.jit_registry import register_jit
+from ..observability import scopes
+from ..observability.telemetry import get_telemetry
+from ..utils.jit_registry import register_dynamic
 from ..utils.log import log_fatal
 from .base import ObjectiveFunction
 
@@ -69,8 +91,146 @@ def max_dcg_at_k(k: int, labels: np.ndarray, gain: np.ndarray,
     return float((gain[top] * discount[:k]).sum())
 
 
+# a class's pair block [C, L, L] holds at most this many pair slots
+PAIR_BLOCK = 1 << 22
+# at most this many length classes: each one is two sorts and a pair
+# block of its own in the compiled program
+MAX_CLASSES = 8
+# what a slot costs beside a pair slot when the class lengths are
+# chosen: two sorts at 0.8-2 ns an element against 0.02-0.06 ns a pair
+# slot, as timed alone on a v5e (PERF.md section 6, PR 37, step 0)
+SLOT_COST = 64
+
+
+def class_lengths(counts: np.ndarray) -> List[int]:
+    """The lengths of the classes for queries of ``counts`` documents:
+    at most ``MAX_CLASSES`` of the candidates 8, 16, 32, 64, 96, 128,
+    192, 256, ... (powers of two and, from 64 up, their halves between)
+    with the longest query's own length rounded up to a tile as the
+    last, chosen so that ``sum(nq_c * (L_c^2 + SLOT_COST * L_c))`` is
+    least (a dynamic programme over the candidates; a query goes to the
+    shortest class that holds it)."""
+    longest = int(counts.max())
+    tile = 128 if longest > 128 else 8
+    top = -(-longest // tile) * tile
+    cand = sorted({c for k in range(3, 31) for c in
+                   ((1 << k), 3 << (k - 1) if k >= 6 else 1 << k)
+                   if c < top} | {top})
+    # queries of at most cand[j] documents
+    ordered = np.sort(counts)
+    upto = np.searchsorted(ordered, cand, side="right")
+    cost = [c * c + SLOT_COST * c for c in cand]
+    n = len(cand)
+    inf = float("inf")
+    # best[k][j]: least cost of the queries up to cand[j] in k + 1
+    # classes of which cand[j] is the longest
+    best = [[inf] * n for _ in range(MAX_CLASSES)]
+    back = [[-1] * n for _ in range(MAX_CLASSES)]
+    for j in range(n):
+        best[0][j] = int(upto[j]) * cost[j]
+    for k in range(1, MAX_CLASSES):
+        for j in range(n):
+            for i in range(j):
+                c = best[k - 1][i] + int(upto[j] - upto[i]) * cost[j]
+                if c < best[k][j]:
+                    best[k][j], back[k][j] = c, i
+    k = min(range(MAX_CLASSES), key=lambda k: best[k][n - 1])
+    j, chosen = n - 1, []
+    while j >= 0:
+        chosen.append(cand[j])
+        j, k = back[k][j], k - 1
+    chosen = chosen[::-1]
+    # a class of equal cost that holds no query is no class
+    held = np.diff(np.searchsorted(ordered, chosen, side="right"),
+                   prepend=0)
+    return [c for c, h in zip(chosen, held) if h]
+
+
+class QueryLayout:
+    """Ragged query groups as a few dense ``[nq_c, L_c]`` blocks.
+
+    Static (Python) facts: ``lengths`` (``L_c``), ``sizes`` (``nq_c``,
+    padded to a whole number of pair chunks with empty queries),
+    ``chunks`` (queries a pair block). Device operands (``operands``, a
+    pytree the gradient programs take as an argument): per class the
+    queries' first rows and document counts, and ``slot_of_row``, the
+    slot every document's result is read back from."""
+
+    def __init__(self, query_boundaries: np.ndarray, num_data: int):
+        qb = np.asarray(query_boundaries, np.int64)
+        counts = np.diff(qb)
+        self.num_queries = len(counts)
+        self.lengths = class_lengths(counts)
+        self.pad = self.lengths[-1]
+        cls = np.searchsorted(self.lengths, counts, side="left")
+        self.sizes: List[int] = []
+        self.chunks: List[int] = []
+        self.members: List[np.ndarray] = []   # query ids, data order
+        first_slot = np.zeros(self.num_queries, np.int64)
+        starts, cnts = [], []
+        base = 0
+        for c, length in enumerate(self.lengths):
+            q = np.flatnonzero(cls == c)
+            chunk = max(1, min(len(q), PAIR_BLOCK // (length * length)))
+            size = -(-len(q) // chunk) * chunk
+            fill = np.zeros(size - len(q), np.int64)
+            starts.append(np.concatenate([qb[q], fill]).astype(np.int32))
+            cnts.append(np.concatenate([counts[q], fill]).astype(np.int32))
+            first_slot[q] = base + np.arange(len(q)) * length
+            base += size * length
+            self.sizes.append(size)
+            self.chunks.append(chunk)
+            self.members.append(q)
+        self.slots = base
+        self.pair_slots = sum(s * l * l for s, l in
+                              zip(self.sizes, self.lengths))
+        self.doc_pairs = int((counts * counts).sum())
+        slot_of_row = np.repeat(first_slot - qb[:-1], counts) \
+            + np.arange(num_data)
+        self.operands = {
+            "starts": tuple(jnp.asarray(s) for s in starts),
+            "counts": tuple(jnp.asarray(c) for c in cnts),
+            "slot_of_row": jnp.asarray(slot_of_row.astype(np.int32)),
+        }
+
+    def per_query(self, values: np.ndarray) -> tuple:
+        """A per-query host vector as one device vector a class, zero
+        for the empty queries that fill a class up."""
+        return tuple(
+            jnp.asarray(np.concatenate(
+                [values[q], np.zeros(size - len(q), values.dtype)]))
+            for q, size in zip(self.members, self.sizes))
+
+    def windows(self, rows: jnp.ndarray, ops) -> list:
+        """``rows`` ``[K, num_data]`` (one value a document) as one
+        ``[K, nq_c, L_c]`` block a class, with the mask of the slots
+        that hold a document: a window of ``L_c`` rows from each
+        query's first row."""
+        k = rows.shape[0]
+        ext = jnp.concatenate(
+            [rows, jnp.zeros((k, self.pad), rows.dtype)], axis=1)
+        out = []
+        for length, starts, counts in zip(self.lengths, ops["starts"],
+                                          ops["counts"]):
+            win = jax.vmap(lambda s: jax.lax.dynamic_slice(
+                ext, (0, s), (k, length)))(starts)       # [nq_c, K, L]
+            valid = jnp.arange(length, dtype=jnp.int32)[None, :] \
+                < counts[:, None]
+            out.append((jnp.moveaxis(win, 1, 0), valid))
+        return out
+
+    def to_rows(self, blocks: list, ops) -> jnp.ndarray:
+        """One ``[nq_c, L_c, K]`` block a class back to ``[num_data,
+        K]`` in row order: the one pass with an index a document (a
+        gather of rows of ``K``: 12.6 ms at 2.27 M documents where two
+        element gathers take 35.6; PERF.md section 6, PR 37)."""
+        k = blocks[0].shape[-1]
+        flat = jnp.concatenate([b.reshape(-1, k) for b in blocks])
+        return jnp.take(flat, ops["slot_of_row"], axis=0)
+
+
 class RankingObjective(ObjectiveFunction):
-    """RankingObjective (rank_objective.hpp:25-96): padded query layout."""
+    """RankingObjective (rank_objective.hpp:25-96) on ``QueryLayout``."""
 
     need_accuracte_prediction = False
 
@@ -79,37 +239,33 @@ class RankingObjective(ObjectiveFunction):
         qb = metadata.query_boundaries
         if qb is None:
             log_fatal("Ranking tasks require query information")
-        qb = np.asarray(qb, np.int64)
-        self.num_queries = len(qb) - 1
-        counts = np.diff(qb)
-        self.max_query = int(counts.max())
-        q = self.max_query
-        idx = np.full((self.num_queries, q), num_data, np.int32)
-        for i in range(self.num_queries):
-            idx[i, :counts[i]] = np.arange(qb[i], qb[i + 1])
-        self._pad_idx = jnp.asarray(idx)
-        self._pad_mask = jnp.asarray(idx < num_data)
-        lab = np.asarray(metadata.label, np.float64)
-        lab_pad = np.zeros((self.num_queries, q))
-        for i in range(self.num_queries):
-            lab_pad[i, :counts[i]] = lab[qb[i]:qb[i + 1]]
-        self._labels_pad = jnp.asarray(lab_pad.astype(np.int32))
-        self._counts = jnp.asarray(counts.astype(np.int32))
-        # chunk queries so the [C, Q, Q] pairwise block stays bounded
-        self._chunk = max(1, (1 << 22) // max(q * q, 1))
+        lay = self.layout = QueryLayout(qb, num_data)
+        self.num_queries = lay.num_queries
+        # the labels ride into the layout beside the scores
+        self._operands = dict(lay.operands, label=jnp.asarray(
+            np.asarray(metadata.label, np.float32)))
+        tel = get_telemetry()
+        for name, value in (("queries", lay.num_queries),
+                            ("docs", num_data), ("slots", lay.slots),
+                            ("pair_slots", lay.pair_slots),
+                            ("doc_pairs", lay.doc_pairs),
+                            ("classes", len(lay.lengths))):
+            tel.set_counter("objective.rank_" + name, value)
 
-    def _pad_scores(self, score: jnp.ndarray) -> jnp.ndarray:
-        ext = jnp.concatenate([score.astype(jnp.float32),
-                               jnp.asarray([0.0], jnp.float32)])
-        return jnp.where(self._pad_mask, ext[self._pad_idx], kMinScore)
+    def setup_facts(self) -> dict:
+        return {"queries": self.num_queries,
+                "classes": len(self.layout.lengths)}
 
-    def _scatter_back(self, lam_pad, hess_pad):
-        flat = self._pad_idx.reshape(-1)
-        lam = jnp.zeros((self.num_data + 1,), jnp.float32).at[flat].add(
-            lam_pad.reshape(-1))[:self.num_data]
-        hess = jnp.zeros((self.num_data + 1,), jnp.float32).at[flat].add(
-            hess_pad.reshape(-1))[:self.num_data]
-        return self._weighted(lam, hess)
+    def _windows(self, score: jnp.ndarray, ops, *more) -> list:
+        with jax.named_scope(scopes.RANK_LAYOUT):
+            rows = jnp.stack([score.astype(jnp.float32), ops["label"],
+                              *more])
+            return self.layout.windows(rows, ops)
+
+    def _to_rows(self, blocks: list, ops):
+        with jax.named_scope(scopes.RANK_LAYOUT):
+            rows = self.layout.to_rows(blocks, ops)
+        return self._weighted(rows[:, 0], rows[:, 1])
 
 
 class LambdarankNDCG(RankingObjective):
@@ -129,74 +285,90 @@ class LambdarankNDCG(RankingObjective):
         super().init(metadata, num_data)
         lab = np.asarray(metadata.label, np.float64)
         check_rank_labels(lab, len(self.label_gain))
-        q = self.max_query
-        discount = 1.0 / np.log2(2.0 + np.arange(q))
+        lab = lab.astype(np.int64)
         qb = np.asarray(metadata.query_boundaries, np.int64)
-        inv = np.zeros(self.num_queries)
-        for i in range(self.num_queries):
-            m = max_dcg_at_k(self.truncation_level, lab[qb[i]:qb[i + 1]],
-                             self.label_gain, discount)
-            inv[i] = 1.0 / m if m > 0 else 0.0
-        self._inv_max_dcg = jnp.asarray(inv, jnp.float32)
-        self._discount = jnp.asarray(discount, jnp.float32)
-        self._gain_tbl = jnp.asarray(self.label_gain, jnp.float32)
+        counts = np.diff(qb)
+        discount = 1.0 / np.log2(2.0 + np.arange(self.layout.pad))
+        # CalMaxDCGAtK of every query at once: the labels sorted
+        # descending within their query, the first truncation_level of
+        # each dotted with the discounts
+        qid = np.repeat(np.arange(self.num_queries), counts)
+        order = np.lexsort((-lab, qid))
+        rank = np.arange(num_data) - qb[qid]
+        top = rank < self.truncation_level
+        dcg = np.bincount(
+            qid[top], minlength=self.num_queries,
+            weights=self.label_gain[lab[order][top]] * discount[rank[top]])
+        inv = np.where(dcg > 0, 1.0 / np.maximum(dcg, kEpsilon), 0.0)
+        self._operands["inv_max_dcg"] = self.layout.per_query(
+            inv.astype(np.float32))
+        self._discount = discount.astype(np.float32)
+        # the gains of the labels the table holds
+        self._gains = [float(g) for g in
+                       self.label_gain[:int(lab.max(initial=0)) + 1]]
 
-    def gradients(self, score: jnp.ndarray):
-        s_pad = self._pad_scores(score)
-        nq, q = s_pad.shape
-        c = min(self._chunk, nq)
-        nchunk = (nq + c - 1) // c
-        pad_q = nchunk * c - nq
+    def grad_operands(self) -> tuple:
+        return (self._operands,)
 
-        def padq(a, fill):
-            return jnp.concatenate(
-                [a, jnp.full((pad_q,) + a.shape[1:], fill, a.dtype)]) \
-                if pad_q else a
-
-        s_c = padq(s_pad, kMinScore).reshape(nchunk, c, q)
-        lab_c = padq(self._labels_pad, 0).reshape(nchunk, c, q)
-        msk_c = padq(self._pad_mask, False).reshape(nchunk, c, q)
-        inv_c = padq(self._inv_max_dcg, 0.0).reshape(nchunk, c)
-        cnt_c = padq(self._counts, 1).reshape(nchunk, c)
-
-        body = functools.partial(
-            _lambdarank_chunk, discount=self._discount,
-            gain_tbl=self._gain_tbl, sigmoid=self.sigmoid, norm=self.norm)
-        lam_c, hess_c = jax.lax.map(
-            lambda t: body(*t), (s_c, lab_c, msk_c, inv_c, cnt_c))
-        lam_pad = lam_c.reshape(nchunk * c, q)[:nq]
-        hess_pad = hess_c.reshape(nchunk * c, q)[:nq]
-        return self._scatter_back(lam_pad, hess_pad)
+    def gradients(self, score: jnp.ndarray, ops=None):
+        ops = self._operands if ops is None else ops
+        lay = self.layout
+        blocks = []
+        for c, ((rows, valid), inv) in enumerate(zip(
+                self._windows(score, ops), ops["inv_max_dcg"])):
+            length, chunk = lay.lengths[c], lay.chunks[c]
+            with jax.named_scope(scopes.RANK_SORT):
+                pos = jnp.broadcast_to(
+                    jnp.arange(length, dtype=jnp.int32), valid.shape)
+                # descending by score, ties in row order, empty slots
+                # last: what a stable argsort of -score gives
+                key, lab_s, pos_s = jax.lax.sort(
+                    (jnp.where(valid, -rows[0], jnp.inf), rows[1], pos),
+                    dimension=1, num_keys=1, is_stable=True)
+            with jax.named_scope(scopes.RANK_PAIRS):
+                body = functools.partial(
+                    _lambdarank_pairs, gains=self._gains,
+                    discount=jnp.asarray(self._discount[:length]),
+                    sigmoid=self.sigmoid, norm=self.norm)
+                lam_s, hess_s = jax.lax.map(
+                    lambda t: body(*t),
+                    tuple(a.reshape((-1, chunk) + a.shape[1:]) for a in
+                          (-key, lab_s, ops["counts"][c], inv)))
+            with jax.named_scope(scopes.RANK_SORT):
+                _, lam, hess = jax.lax.sort(
+                    (pos_s, lam_s.reshape(valid.shape),
+                     hess_s.reshape(valid.shape)),
+                    dimension=1, num_keys=1)
+            blocks.append(jnp.stack([lam, hess], axis=-1))
+        return self._to_rows(blocks, ops)
 
     def name(self) -> str:
         return "lambdarank"
 
 
-def _lambdarank_chunk(sc, lab, msk, inv, cnt, *, discount, gain_tbl,
-                      sigmoid, norm):
-    """Pairwise lambdas for a [C, Q] query chunk
-    (GetGradientsForOneQuery, rank_objective.hpp:139-230)."""
-    c, q = sc.shape
-    order = jnp.argsort(-sc, axis=1, stable=True)       # pads sort last
-    sc_s = jnp.take_along_axis(sc, order, axis=1)
-    lab_s = jnp.take_along_axis(lab, order, axis=1)
-    valid_s = jnp.take_along_axis(msk, order, axis=1) \
-        & (sc_s > kMinScore)
-
+def _lambdarank_pairs(sc_s, lab_s, cnt, inv, *, gains, discount, sigmoid,
+                      norm):
+    """Pairwise lambdas for a [C, L] chunk of queries sorted by score
+    (GetGradientsForOneQuery, rank_objective.hpp:139-230); an empty
+    slot's score is ``-inf``."""
+    length = sc_s.shape[1]
+    slot = jnp.arange(length, dtype=jnp.int32)[None, :]
+    valid_s = (slot < cnt[:, None]) & (sc_s > kMinScore)
     best = sc_s[:, 0]
-    worst = jnp.take_along_axis(
-        sc_s, jnp.maximum(cnt - 1, 0)[:, None], axis=1)[:, 0]
+    worst = jnp.max(jnp.where(slot == cnt[:, None] - 1, sc_s, kMinScore),
+                    axis=1)
+    # the gain of a label, by a select a label the table holds
+    gain_s = jnp.zeros_like(sc_s)
+    for label, gain in enumerate(gains):
+        gain_s = jnp.where(lab_s == label, jnp.float32(gain), gain_s)
 
     lab_a = lab_s[:, :, None]
     lab_b = lab_s[:, None, :]
-    sc_a = sc_s[:, :, None]
-    sc_b = sc_s[:, None, :]
     pair_ok = (lab_a > lab_b) & valid_s[:, :, None] & valid_s[:, None, :]
 
-    ds = sc_a - sc_b
-    gap = gain_tbl[lab_a] - gain_tbl[lab_b]
-    d = discount[:q]
-    pd = jnp.abs(d[None, :, None] - d[None, None, :])
+    ds = sc_s[:, :, None] - sc_s[:, None, :]
+    gap = gain_s[:, :, None] - gain_s[:, None, :]
+    pd = jnp.abs(discount[None, :, None] - discount[None, None, :])
     delta = gap * pd * inv[:, None, None]
     if norm:
         use_norm = (best != worst)[:, None, None]
@@ -215,11 +387,7 @@ def _lambdarank_chunk(sc, lab, msk, inv, cnt, *, discount, gain_tbl,
                        / jnp.maximum(sum_lambdas, kEpsilon), 1.0)
         lam_s = lam_s * nf[:, None]
         hess_s = hess_s * nf[:, None]
-
-    inv_order = jnp.argsort(order, axis=1, stable=True)
-    lam = jnp.take_along_axis(lam_s, inv_order, axis=1)
-    hess = jnp.take_along_axis(hess_s, inv_order, axis=1)
-    return lam, hess
+    return lam_s, hess_s
 
 
 class RankXENDCG(RankingObjective):
@@ -236,55 +404,46 @@ class RankXENDCG(RankingObjective):
         lab = np.asarray(metadata.label, np.float64)
         check_rank_labels(lab, 31)
 
+        def xendcg_grad(score, uniforms, ops):
+            blocks = [jnp.stack(_xendcg_block(rows[0], rows[2], rows[1],
+                                              valid, counts), axis=-1)
+                      for (rows, valid), counts in zip(
+                          self._windows(score, ops, uniforms),
+                          ops["counts"])]
+            return self._to_rows(blocks, ops)
+
+        self._grad = register_dynamic("xendcg_grad", jax.jit(xendcg_grad))
+
     def gradients(self, score: jnp.ndarray):
         u = self._rng.rand(self.num_data).astype(np.float32)
-        return _xendcg_grad(score, jnp.asarray(u), self._pad_idx,
-                            self._pad_mask, self._labels_pad, self._counts,
-                            self.num_data, self.weights)
+        return self._grad(score, jnp.asarray(u), self._operands)
 
     def name(self) -> str:
         return "rank_xendcg"
 
 
-@register_jit("xendcg_grad")
-@functools.partial(jax.jit, static_argnames=("num_data",))
-def _xendcg_grad(score, uniforms, pad_idx, pad_mask, labels_pad, counts,
-                 num_data, weights):
-    nq, q = pad_idx.shape
-    ext = jnp.concatenate([score.astype(jnp.float32),
-                           jnp.asarray([0.0], jnp.float32)])
-    s = jnp.where(pad_mask, ext[pad_idx], -jnp.inf)
-    u_ext = jnp.concatenate([uniforms, jnp.asarray([0.0], jnp.float32)])
-    u = jnp.where(pad_mask, u_ext[pad_idx], 0.0)
-
-    # softmax over valid docs
+def _xendcg_block(s, u, labels, mask, counts):
+    """One class's ``[nq_c, L]`` block: scores, uniforms, labels."""
+    s = jnp.where(mask, s, -jnp.inf)
+    # softmax over valid docs; an empty (padding) query's row is all
+    # -inf, whose maximum must not enter the difference
     m = jnp.max(s, axis=1, keepdims=True)
-    e = jnp.where(pad_mask, jnp.exp(s - m), 0.0)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    e = jnp.where(mask, jnp.exp(s - m), 0.0)
     rho = e / jnp.maximum(e.sum(axis=1, keepdims=True), kEpsilon)
 
-    phi = jnp.where(pad_mask,
-                    jnp.exp2(labels_pad.astype(jnp.float32)) - u, 0.0)
+    phi = jnp.where(mask, jnp.exp2(labels) - u, 0.0)
     sum_labels = jnp.maximum(phi.sum(axis=1, keepdims=True), kEpsilon)
-    l1 = jnp.where(pad_mask, -phi / sum_labels + rho, 0.0)
+    l1 = jnp.where(mask, -phi / sum_labels + rho, 0.0)
     sum_l1 = l1.sum(axis=1, keepdims=True)
 
     denom = jnp.maximum(1.0 - rho, kEpsilon)
-    l2 = jnp.where(pad_mask, (sum_l1 - l1) / denom, 0.0)
+    l2 = jnp.where(mask, (sum_l1 - l1) / denom, 0.0)
     sum_l2 = l2.sum(axis=1, keepdims=True)
-    l3 = jnp.where(pad_mask, (sum_l2 - l2) / denom, 0.0)
+    l3 = jnp.where(mask, (sum_l2 - l2) / denom, 0.0)
 
     lam_full = l1 + rho * l2 + rho * rho * l3
-    lam_simple = l1
     single = (counts <= 1)[:, None]
-    lam = jnp.where(pad_mask, jnp.where(single, lam_simple, lam_full), 0.0)
-    hess = jnp.where(pad_mask, rho * (1.0 - rho), 0.0)
-
-    flat = pad_idx.reshape(-1)
-    g = jnp.zeros((num_data + 1,), jnp.float32).at[flat].add(
-        lam.reshape(-1))[:num_data]
-    h = jnp.zeros((num_data + 1,), jnp.float32).at[flat].add(
-        hess.reshape(-1))[:num_data]
-    if weights is not None:
-        g = g * weights
-        h = h * weights
-    return g, h
+    lam = jnp.where(mask, jnp.where(single, l1, lam_full), 0.0)
+    hess = jnp.where(mask, rho * (1.0 - rho), 0.0)
+    return lam, hess

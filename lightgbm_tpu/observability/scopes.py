@@ -38,6 +38,16 @@ GROW_LEAF_OF_POS = "lgbm.grow.leaf_of_pos"
 SCORE_UPDATE = "lgbm.score_update"
 DEVICE_SCOPES = (GRADIENTS, SAMPLE, GROW, GROW_PACK, GROW_ROOT,
                  GROW_SPLITS, GROW_LEAF_OF_POS, SCORE_UPDATE)
+# the parts of a ranking objective's gradient program
+# (objective/rank.py), children of GRADIENTS, which keeps what is
+# outside them (the weights' multiply): scores and labels into the
+# query layout and gradients back to row order; both sorts a class;
+# the pair block, its sums and the normalisation. An elementwise
+# objective's program traces none of them.
+RANK_LAYOUT = "lgbm.gradients.rank.layout"
+RANK_SORT = "lgbm.gradients.rank.sort"
+RANK_PAIRS = "lgbm.gradients.rank.pairs"
+RANK_SCOPES = (RANK_LAYOUT, RANK_SORT, RANK_PAIRS)
 # the parts of the per-phase split body, the one a table the megakernel
 # refuses runs (categorical, bundled): the megakernel's body traces
 # none of them. Four are children of GROW_SPLITS, opened by the

@@ -141,6 +141,26 @@ def test_bundled_stage_tiny(fuse_iters):
     assert abs(report["auc"] - foil["auc"]) <= cs.ONEHOT_FOIL_AUC_TOL
 
 
+def test_rank_stage_tiny(fuse_iters):
+    """The stage of the learning-to-rank table (ISSUE 37): ragged query
+    groups, lambdarank on the query layout, the megakernel's interpret
+    twin at the benchmark's 137 columns, in fused blocks."""
+    x, y, sizes, params = cs.msltr_like(3000)
+    assert x.shape == (3000, 137) and sizes.sum() == 3000
+    assert sizes.max() == 1251 and params["objective"] == "lambdarank"
+    params = dict(params, num_leaves=7, tree_learner="partitioned",
+                  fused_split_kernel="on")
+    _, report = cs.stage_train(x, y, params, cs.RANK_ROUNDS,
+                               learner="PartitionedTreeLearner",
+                               interpret=True, megakernel=True,
+                               min_auc=cs.RANK_MIN_AUC, group=sizes)
+    assert report["fused_block_hits"] == 1      # 1 sync + one block of 4
+    assert report["rank_docs"] == 3000 and report["rank_queries"] == 25
+    assert report["rank_slots"] <= 1.6 * 3000
+    assert report["ndcg10"] >= cs.RANK_MIN_NDCG
+    assert len(report["model_sha256"]) == 16
+
+
 @pytest.mark.slow
 def test_kernel_and_foil_stages_tiny(fuse_iters):
     kernels = cs.stage_kernels(

@@ -717,3 +717,110 @@ def test_bundled_grow_program_compiles_for_v5e(one_chip, monkeypatch):
     cache_bytes = 255 * g * bins * 3 * 4
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 1.5 * cache_bytes + (64 << 20)
+
+
+def test_fused_lambdarank_block_compiles_for_v5e(one_chip, monkeypatch):
+    """The fused block of the learning-to-rank cell at its real size
+    (``msltr-2m-train``: 2,270,296 x 137 in 18,919 query groups of 1 to
+    1,251 documents, 255 leaves, steps of 2 trees), compiled for the
+    v5e under the chip's plan: the megakernel at 137 columns and
+    256-byte rows, and lambdarank's gradients on the query layout
+    (``objective/rank.py``). The layout reaches the program as
+    ARGUMENTS: the program's text does not grow with the table. No
+    gather or scatter of the gradient program has an index a slot: the
+    queries come in as windows (an index a query), the order by sorts
+    with payload, and the one pass with an index a document is the way
+    back. The learner is built on a 2,048-row table and told the row
+    count; the matrix is a shape."""
+    import re
+
+    import lightgbm_tpu.learner.split_step as split_step
+    from benchmarks.generators.msltr_like import query_sizes
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data import Dataset
+    from lightgbm_tpu.data.dataset import Metadata
+    from lightgbm_tpu.learner.partitioned import (HIST_BLK,
+                                                  PartitionedTreeLearner)
+    from lightgbm_tpu.models.gbdt import _fused_iter_block
+    from lightgbm_tpu.objective.rank import LambdarankNDCG
+    from lightgbm_tpu.observability import scopes
+    from lightgbm_tpu.ops.hist_pallas import matrix_cols, matrix_rows
+
+    n, f = 2_270_296, 137
+    monkeypatch.setattr(split_step, "on_tpu", lambda: True)
+    rng = np.random.RandomState(0)
+    cfg = Config.from_params({"objective": "lambdarank",
+                              "num_leaves": 255, "verbosity": -1})
+    ds = Dataset.from_numpy(rng.randn(2048, f).astype(np.float32), cfg,
+                            label=rng.randint(0, 5, 2048).astype(float),
+                            group=[2048])
+    ln = PartitionedTreeLearner(ds, cfg, interpret=False)
+    assert ln.split_plan() == split_step.SplitStepPlan(
+        "megakernel", True, False, False)
+    ln.num_data = n
+    ln._ones_rows = jnp.ones((n,), jnp.float32)
+    md = Metadata(n)
+    md.set_label(rng.randint(0, 5, n).astype(np.float32))
+    sizes = query_sizes(n)
+    md.set_query(sizes)
+    obj = LambdarankNDCG(cfg)
+    obj.init(md, n)
+    lay = obj.layout
+    assert lay.num_queries == 18_919 and len(lay.lengths) <= 8
+    assert lay.slots <= 1.6 * n
+    assert lay.pair_slots <= 4 * lay.doc_pairs
+    sds = lambda a: jax.ShapeDtypeStruct(               # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    ops = jax.tree.map(sds, obj.grad_operands())
+    # nothing the program is handed has an entry a slot or a row of
+    # nq x max_query
+    assert max(a.size for a in jax.tree.leaves(ops)) == n
+    mat = jax.ShapeDtypeStruct(
+        (matrix_rows(n, HIST_BLK), matrix_cols(f)), jnp.uint8,
+        sharding=one_chip)
+    assert mat.shape[1] == 256
+    lowered = jax.jit(
+        functools.partial(_fused_iter_block, learner=ln,
+                          grad_fn=obj.gradients, bag_fn=None,
+                          valid_data=(), k=1),
+        static_argnames=("m",)).lower(
+        mat, mat, jax.ShapeDtypeStruct((n, 1), jnp.float32,
+                                       sharding=one_chip), (),
+        sds(jnp.float32(0.1)), sds(jnp.int32(0)), ops, m=2)
+    # 0.8 MB as lowered (my reading, PR 37); a layout baked in as
+    # constants would be 8 hex characters an element: 18 MB a
+    # document-sized array, 190 MB for the padded layout's three
+    assert len(lowered.as_text()) < 4 << 20
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(text) < 8 << 20                     # 1.9 MB (PR 37)
+    mem = compiled.memory_analysis()
+    print(mem)
+    # two 581 MB matrices in and out, donated by the driver; the
+    # temporaries held 618 MB (PR 37): the packed gradients, the pair
+    # block's chunk, the query layout's blocks
+    assert mem.argument_size_in_bytes < 1.3e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    table = scopes.parse_hlo_scopes(text)
+    assert set(scopes.RANK_SCOPES) <= set(table.values())
+    assert "tpu_custom_call" in text
+    # every gather of the gradient program as compiled (the windows are
+    # no gather any more: the TPU's compiler makes them a loop of
+    # slices): the indices it reads, its output's elements over its
+    # slice's, are an entry a query or a document, never a slot; it
+    # holds no scatter at all
+    rank = {name for name, scope in table.items()
+            if scope in scopes.RANK_SCOPES or scope == scopes.GRADIENTS}
+    seen = 0
+    for line in text.splitlines():
+        found = re.match(r"\s+(?:ROOT\s+)?%?([\w.\-]+) = [a-z]\d+\[([\d,]*)\]"
+                         r"\S* (gather|scatter)\(", line)
+        if found is None or found.group(1) not in rank:
+            continue
+        assert found.group(3) == "gather", line[:300]
+        seen += 1
+        out = np.prod([int(d) for d in found.group(2).split(",")])
+        window = np.prod([int(d) for d in re.search(
+            r"slice_sizes=\{([\d,]*)\}", line).group(1).split(",")])
+        assert out // window <= n < lay.slots, line[:300]
+    assert seen >= 1

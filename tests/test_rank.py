@@ -3,6 +3,7 @@ transcribed from the reference loops, NDCG/MAP metric values, and
 end-to-end LTR training lift."""
 
 import numpy as np
+import pytest
 
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.data import Dataset
@@ -260,3 +261,223 @@ def test_ndcg_early_stopping_on_valid():
     booster.train(100)
     assert booster.num_iterations_trained < 100
     assert "ndcg@5" in booster.evals_result["valid_0"]
+
+
+# -- the query layout (ISSUE 37) -----------------------------------------
+def _padded_lambdarank(score, label, qb, weights=None, sigmoid=1.0,
+                       norm=True, truncation=20):
+    """The layout this file's objective had until PR 37, kept here as
+    the oracle of the new one: every query padded to the longest, an
+    index a slot in and a scatter-add out, ``argsort`` +
+    ``take_along_axis`` for the order, one ``[nq, Q, Q]`` pair block."""
+    import jax.numpy as jnp
+    n, counts = len(score), np.diff(qb)
+    nq, q = len(counts), int(counts.max())
+    gain = default_label_gain()
+    idx = np.full((nq, q), n, np.int32)
+    inv = np.zeros(nq, np.float32)
+    disc = 1.0 / np.log2(2.0 + np.arange(q))
+    for i in range(nq):
+        idx[i, :counts[i]] = np.arange(qb[i], qb[i + 1])
+        top = np.sort(label[qb[i]:qb[i + 1]].astype(int))[::-1][:truncation]
+        m = (gain[top] * disc[:len(top)]).sum()
+        inv[i] = 1.0 / m if m > 0 else 0.0
+    msk = jnp.asarray(idx < n)
+    ext = jnp.concatenate([jnp.asarray(score, jnp.float32), jnp.zeros(1)])
+    sc = jnp.where(msk, ext[idx], -jnp.inf)
+    lab = jnp.asarray(np.concatenate([label, [0]]).astype(np.int32))[idx]
+    order = jnp.argsort(-sc, axis=1, stable=True)
+    sc_s = jnp.take_along_axis(sc, order, axis=1)
+    lab_s = jnp.take_along_axis(lab, order, axis=1)
+    ok_s = jnp.take_along_axis(msk, order, axis=1)
+    worst = jnp.take_along_axis(
+        sc_s, jnp.asarray(counts - 1)[:, None], axis=1)[:, 0]
+    ds = sc_s[:, :, None] - sc_s[:, None, :]
+    g = jnp.asarray(gain, jnp.float32)
+    d = jnp.asarray(disc, jnp.float32)
+    delta = (g[lab_s][:, :, None] - g[lab_s][:, None, :]) \
+        * jnp.abs(d[None, :, None] - d[None, None, :]) \
+        * jnp.asarray(inv)[:, None, None]
+    if norm:
+        delta = jnp.where((sc_s[:, 0] != worst)[:, None, None],
+                          delta / (0.01 + jnp.abs(ds)), delta)
+    pair = (lab_s[:, :, None] > lab_s[:, None, :]) \
+        & ok_s[:, :, None] & ok_s[:, None, :]
+    sig = 1.0 / (1.0 + jnp.exp(sigmoid * ds))
+    pl = jnp.where(pair, -sigmoid * delta * sig, 0.0)
+    ph = jnp.where(pair, sigmoid * sigmoid * delta * sig * (1 - sig), 0.0)
+    lam_s = pl.sum(axis=2) - pl.sum(axis=1)
+    hess_s = ph.sum(axis=2) + ph.sum(axis=1)
+    if norm:
+        s = -2.0 * pl.sum(axis=(1, 2))
+        nf = jnp.where(s > 0, jnp.log2(1 + s) / jnp.maximum(s, 1e-15), 1.0)
+        lam_s, hess_s = lam_s * nf[:, None], hess_s * nf[:, None]
+    back = jnp.argsort(order, axis=1, stable=True)
+    flat = idx.reshape(-1)
+    out = [jnp.zeros(n + 1).at[flat].add(
+        jnp.take_along_axis(a, back, axis=1).reshape(-1))[:n]
+        for a in (lam_s, hess_s)]
+    w = 1.0 if weights is None else jnp.asarray(weights)
+    return np.asarray(out[0] * w), np.asarray(out[1] * w)
+
+
+def _ragged_queries(seed=0):
+    """Seeded ragged queries of 1 to 300 documents: one of a single
+    document, one whose labels are all equal, one longer than the
+    largest class but one."""
+    rng = np.random.RandomState(seed)
+    counts = np.concatenate([[1, 17, 300], rng.randint(1, 120, 60)])
+    rng.shuffle(counts)
+    n = int(counts.sum())
+    y = rng.choice(5, n, p=[0.5, 0.3, 0.14, 0.04, 0.02]).astype(np.float32)
+    qb = np.concatenate([[0], np.cumsum(counts)])
+    q17 = int(np.flatnonzero(counts == 17)[0])
+    y[qb[q17]:qb[q17 + 1]] = 2.0
+    score = rng.randn(n).astype(np.float32)
+    # ties within a query keep row order
+    score[qb[3]:qb[3] + 4] = 0.25
+    return counts, qb, y, score, rng.uniform(0.5, 2.0, n).astype(np.float32)
+
+
+def _rank_objective(cls, counts, y, weights=None, **params):
+    cfg = Config.from_params(dict(
+        {"objective": "lambdarank", "verbosity": -1}, **params))
+    ds = Dataset.from_numpy(np.zeros((len(y), 2)), cfg, label=y,
+                            group=counts, weight=weights)
+    obj = cls(cfg)
+    obj.init(ds.metadata, ds.num_data)
+    return obj
+
+
+@pytest.mark.parametrize("norm", [True, False])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_layout_gradients_equal_the_padded_layouts(norm, weighted):
+    import jax.numpy as jnp
+    counts, qb, y, score, w = _ragged_queries()
+    w = w if weighted else None
+    obj = _rank_objective(LambdarankNDCG, counts, y, w,
+                          lambdarank_norm=norm)
+    lengths = obj.layout.lengths
+    assert len(lengths) > 2 and lengths[-2] < 300 <= lengths[-1]
+    g, h = obj.gradients(jnp.asarray(score))
+    og, oh = _padded_lambdarank(score, y, qb, w, norm=norm)
+    # float32 sums in another order
+    np.testing.assert_allclose(np.asarray(g), og, rtol=1e-4, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(h), oh, rtol=1e-4, atol=2e-6)
+    # the jitted program with the layout as an argument, as the fused
+    # block calls it
+    import jax
+    gj, hj = jax.jit(obj.gradients)(jnp.asarray(score),
+                                    *obj.grad_operands())
+    np.testing.assert_allclose(np.asarray(gj), np.asarray(g), rtol=1e-5,
+                               atol=1e-7)
+    np.testing.assert_allclose(np.asarray(hj), np.asarray(h), rtol=1e-5,
+                               atol=1e-7)
+    one = int(qb[np.flatnonzero(counts == 1)[0]])
+    assert float(g[one]) == 0.0 and float(h[one]) == 0.0
+    q17 = int(np.flatnonzero(counts == 17)[0])
+    assert not np.asarray(g[qb[q17]:qb[q17 + 1]]).any()
+
+
+@pytest.mark.parametrize("norm", [True, False])
+def test_layout_gradients_equal_the_plain_references(norm):
+    import jax.numpy as jnp
+    from benchmarks.reference import gbdt_rank_numpy
+    counts, _, y, score, _ = _ragged_queries(seed=1)
+    obj = _rank_objective(LambdarankNDCG, counts, y, lambdarank_norm=norm)
+    g, h = obj.gradients(jnp.asarray(score))
+    og, oh = gbdt_rank_numpy.lambdarank_gradients(
+        score, y, counts, {"lambdarank_norm": norm})
+    np.testing.assert_allclose(np.asarray(g), og, rtol=2e-4, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(h), oh, rtol=2e-4, atol=2e-6)
+
+
+def test_layout_follows_the_documents_not_the_longest_query():
+    """One 1,251-document query among 500 short ones: no ``nq x
+    max_query`` array, the counters say what the layout came to."""
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    rng = np.random.RandomState(0)
+    counts = np.concatenate([rng.randint(1, 60, 250), [1251],
+                             rng.randint(1, 60, 250)])
+    y = rng.randint(0, 5, counts.sum()).astype(np.float32)
+    tel = get_telemetry()
+    tel.ensure_ring()
+    obj = _rank_objective(LambdarankNDCG, counts, y)
+    c = {k.split("rank_")[1]: int(v) for k, v in tel.counters.items()
+         if k.startswith("objective.rank_")}
+    assert c["queries"] == 501 and c["docs"] == counts.sum()
+    assert c["doc_pairs"] == (counts.astype(np.int64) ** 2).sum()
+    assert c["slots"] <= 1.6 * c["docs"]
+    assert c["pair_slots"] <= 4 * c["doc_pairs"]
+    assert 2 <= c["classes"] <= 8
+    assert c["slots"] < 501 * 1251 // 10
+    lay = obj.layout
+    assert c["slots"] == sum(s * l for s, l in zip(lay.sizes, lay.lengths))
+    import jax
+    held = [a for a in jax.tree.leaves(obj.grad_operands())]
+    assert max(a.size for a in held) == counts.sum()
+    assert obj.setup_facts() == {"queries": 501, "classes": c["classes"]}
+
+
+def test_xendcg_on_the_layout_matches_oracle_on_ragged_queries():
+    import jax.numpy as jnp
+    counts, qb, y, score, _ = _ragged_queries(seed=2)
+    obj = _rank_objective(RankXENDCG, counts, y, objective="rank_xendcg",
+                          objective_seed=5)
+    u = np.random.RandomState(5).rand(len(y)).astype(np.float32)
+    g, h = obj.gradients(jnp.asarray(score))
+    og, oh = _oracle_xendcg(score.astype(np.float64), y, qb, u)
+    np.testing.assert_allclose(np.asarray(g), og, rtol=2e-4, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(h), oh, rtol=2e-4, atol=2e-6)
+
+
+def test_fused_lambdarank_matches_per_iteration_and_names_its_parts(
+        monkeypatch):
+    """(Here and not in tests/test_fused_scan.py, which tier-1 leaves
+    out as slow.) Lambdarank on ragged query groups rides the fused block (its
+    query layout is an argument of the program, ISSUE 37): tree for
+    tree the per-iteration route's model, and the block's gradient
+    program carries the three ranking scopes."""
+    import jax
+    from lightgbm_tpu.models.tree import DeferredStackTree
+    from lightgbm_tpu.models.variants import create_boosting
+    from lightgbm_tpu.observability import scopes
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    rng = np.random.RandomState(5)
+    group = np.concatenate([[1, 150], rng.randint(2, 40, 40)])
+    n = int(group.sum())
+    X = rng.randn(n, 6).astype(np.float32)
+    y = np.clip(np.round(X[:, 0] + 0.5 * rng.randn(n) + 1), 0, 4) \
+        .astype(np.float32)
+    get_telemetry().ensure_ring()
+    scopes.forget()
+    models = []
+    for fused in (False, True):
+        monkeypatch.setenv("LGBM_TPU_FUSE_ITERS", "1" if fused else "0")
+        cfg = Config.from_params({
+            "objective": "lambdarank", "num_leaves": 7,
+            "learning_rate": 0.1, "tree_learner": "partitioned",
+            "verbosity": -1, "metric": ""})
+        b = create_boosting(cfg, Dataset.from_numpy(X, cfg, label=y,
+                                                    group=group))
+        assert b._fused_scan_supported() is fused
+        b.train(5)
+        b.finalize_trees()
+        models.append(b)
+    assert any(isinstance(m, DeferredStackTree) for m in models[1].models)
+    assert len(models[0].models) == len(models[1].models) == 5
+    for t0, t1 in zip(models[0].models, models[1].models):
+        assert int(t0.num_leaves) == int(t1.num_leaves)
+        np.testing.assert_array_equal(t0.split_feature, t1.split_feature)
+        np.testing.assert_array_equal(t0.threshold_bin,
+                                      t1.threshold_bin)
+    # the same splits; the pair sums are float32 in the order XLA
+    # fuses them, inside the scan and outside it
+    np.testing.assert_allclose(np.asarray(models[0].predict_raw(X)),
+                               np.asarray(models[1].predict_raw(X)),
+                               rtol=1e-4, atol=1e-6)
+    table = scopes.program_scopes("gbdt_fused_block")
+    assert set(scopes.RANK_SCOPES) <= set(table.values())
+    # the layout is an argument of the program, not a constant in it
+    fused = models[1]
+    assert fused._grad_operands and jax.tree.leaves(fused._grad_operands)

@@ -311,6 +311,11 @@ PLAN_ROWS = [
                                 num_features=47),
      (PHASE, True, True, False)),
     ("bundled", dict(bundled=True), (PHASE, True, True, False)),
+    # 137 dense numeric columns, 256-byte rows: the third width
+    # through the megakernel, the first between 69 and 192 to train on
+    # a chip (PR 37)
+    ("msltr-2m-train", dict(num_features=137, num_bins_max=256),
+     (MEGA, True, False, False)),
     ("257-bins", dict(num_bins_max=257), (PHASE, True, False, False)),
     ("forced-plan", dict(forced_plan=((0, 1, 3, False),)),
      (PHASE, True, False, False)),
@@ -412,6 +417,17 @@ def test_cells_plans_from_real_learners(monkeypatch):
     assert onehot.split_plan() == split_step.SplitStepPlan(
         PHASE, True, True, False)
     assert onehot.params.use_scan_kernel
+    # MS LTR: 137 numeric columns in query groups, lambdarank; the
+    # plan knows no objective
+    x, y = _data(n=600, f=137)
+    rank_cfg = Config.from_params({"objective": "lambdarank",
+                                   "num_leaves": 255, "verbosity": -1})
+    msltr = PartitionedTreeLearner(
+        Dataset.from_numpy(x, rank_cfg, label=y, group=[200, 1, 399]),
+        rank_cfg, interpret=False)
+    assert msltr.num_groups == 137 and msltr.mat.shape[1] == 256
+    assert msltr.split_plan() == split_step.SplitStepPlan(
+        MEGA, True, False, False)
 
 
 def test_forced_splits_keep_foil_for_forced_steps(tmp_path):

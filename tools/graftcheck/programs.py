@@ -328,13 +328,22 @@ def _b_predict_scan_trees_linear():
 @builder("xendcg_grad")
 def _b_xendcg_grad():
     import jax.numpy as jnp
-    nq, q, n = 4, 8, 32
-    idx = jnp.arange(nq * q, dtype=jnp.int32).reshape(nq, q)
+    import numpy as np
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data.dataset import Metadata
+    from lightgbm_tpu.objective.rank import RankXENDCG
+    # the program is the objective's own (it closes over the query
+    # layout's static shapes; the layout's arrays are arguments)
+    n = 32
+    md = Metadata(n)
+    md.set_label(np.zeros(n, np.float32))
+    md.set_query([8, 8, 8, 8])
+    obj = RankXENDCG(Config.from_params({"objective": "rank_xendcg",
+                                         "verbosity": -1}))
+    obj.init(md, n)
     return _spec_fn("xendcg_grad").lower(
         jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32),
-        jnp.where(idx < n, idx, n), idx < n,
-        jnp.zeros((nq, q), jnp.float32),
-        jnp.full((nq,), q, jnp.int32), num_data=n, weights=None)
+        obj._operands)
 
 
 @builder("goss_weights")
