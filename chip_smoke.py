@@ -15,6 +15,10 @@ binary objective, 255 leaves, 255 bins), on rows generated from a seed:
    against the same data and params on the XLA foil;
 4. serve: the trained booster behind ``ServingEngine`` on the device
    route with the host fallback off, against the host route;
+   then a second table of the same shape from another seed, trained
+   the same way: its labels reach ``gbdt_grad`` and ``gbdt_fused_block``
+   as arguments, so both programs come from the persistent compile
+   cache (their ``compile`` records say ``hit``);
 5. categorical: 200,000 rows shaped like the benchmark's Expo table
    (40 columns, twelve of them categories named in ``params``, four
    of those cut to 255 bins by the binning) for 9 rounds: the route
@@ -435,6 +439,47 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     return bst, report
 
 
+def stage_second_table(rounds: int, rows: int = ROWS) -> dict:
+    """A second Higgs-like table of the first's shape from another
+    seed, trained as the first was. A new booster traces its programs
+    anew, and since every array they read from the table is an
+    argument, the two that hold the labels lower to the first table's
+    programs and the persistent cache serves them: the ``cache`` field
+    of their backend ``compile`` records, each ``hit`` on a TPU. The
+    kernels' trace-time counters stay at 0 here (the process traced
+    them for the first table), so the path is the first stage's."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    tel = get_telemetry()
+    x, y = higgs_like(rows, seed=43)
+    blocks = tel.counters.get("fused.block_hits", 0)
+    t0 = time.perf_counter()
+    bst = lgb.train(dict(PARAMS), lgb.Dataset(x, label=y),
+                    num_boost_round=rounds)
+    seconds = time.perf_counter() - t0
+    leaves = [int(t.num_leaves) for t in bst._gbdt.models]
+    report = {
+        "cache": {p: [r["cache"] for r in tel.records
+                      if r["kind"] == "compile"
+                      and r["stage"] == "backend" and r["program"] == p
+                      and r["t0"] >= t0]
+                  for p in ("gbdt_grad", "gbdt_fused_block")},
+        "fused_block_hits": int(tel.counters.get("fused.block_hits", 0)
+                                - blocks),
+        "trees": len(leaves), "min_leaves": min(leaves),
+        "auc": round(train_auc(bst, x, y), 6),
+        "train_seconds": round(seconds, 1),
+        "model_sha256": hashlib.sha256(
+            bst.model_to_string().encode()).hexdigest()[:16]}
+    print(f"second_table: {json.dumps(report)}", flush=True)
+    assert report["fused_block_hits"] > 0, report
+    assert report["trees"] == rounds and report["min_leaves"] > 1, report
+    assert report["auc"] >= MIN_AUC, report
+    for p, seen in report["cache"].items():
+        assert seen and set(seen) == {"hit"}, (p, seen)
+    return report
+
+
 def _megakernel_reason(ln) -> str:
     """What the plan says, next to what the trace counted. The mesh
     learners have no megakernel: their collectives sit between the
@@ -632,6 +677,7 @@ def main(argv=None) -> int:
         gap = abs(report["train"]["auc"] - report["foil"]["auc"])
         assert gap <= FOIL_AUC_TOL, ("chip path vs XLA foil", gap)
         report["serve"] = stage_serve(bst, x)
+        report["second_table"] = stage_second_table(ROUNDS)
         # the table the megakernel refuses: per-phase kernels, bitset
         # partition, categorical scan; no scan kernel, still fused
         cx, cy, cat_params = expo_like(CAT_ROWS)

@@ -231,16 +231,25 @@ class PartitionedTreeLearner(PartitionedLearnerBase):
                 and self.ff_bynode >= 1.0
                 and getattr(self, "_cegb_used", None) is None)
 
-    def traceable_grow(self, mat, ws, grad, hess, bag=None):
+    def grow_operands(self):
+        """What ``traceable_grow`` reads whose values come from the
+        table: the per-feature metadata (each feature's bins, default
+        and most frequent bin), which an enclosing compiled program
+        takes as an ARGUMENT (``models/gbdt.py`` ``_fused_iter_block``)
+        and hands back as ``meta``."""
+        return self.meta
+
+    def traceable_grow(self, mat, ws, grad, hess, bag=None, *, meta):
         """One tree grown inside an enclosing trace (no jit boundary,
-        no host state updates). Caller owns the mat/ws carry. Returns
-        ``(mat, ws, tree, (row_ids, pos_value))`` — leaf parts, not a
-        materialized leaf_id (see return_leaf_parts)."""
+        no host state updates). Caller owns the mat/ws carry; ``meta``
+        is ``grow_operands()`` as the caller holds it. Returns ``(mat, ws, tree, (row_ids,
+        pos_value))`` — leaf parts, not a materialized leaf_id (see
+        return_leaf_parts)."""
         if bag is None:
             bag = jnp.ones_like(grad)
         fmask = jnp.ones((self.num_features,), bool)
         return grow_partitioned(
-            mat, ws, grad, hess, bag, fmask, self.meta,
+            mat, ws, grad, hess, bag, fmask, meta,
             rand_key=None, params=self.params,
             num_leaves=self.num_leaves, max_depth=self.max_depth,
             num_bins_max=self.num_bins_max,

@@ -205,23 +205,33 @@ def _named(name, fn):
     return named
 
 
-def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(), *,
-                      learner, grad_fn, bag_fn, valid_data, m, k):
+def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(),
+                      valid_data=(), bops=(), lops=None, *, learner,
+                      grad_fn, bag_fn, m, k):
     """``m`` boosting iterations as one device program (lax.scan over
     gradients -> [sampling] -> grow -> score update; ``k`` trees per
-    iteration for multiclass; ``bag_fn(it, grad, hess)`` supplies
-    device-computed row weights — bagging/GOSS — or None for no
-    sampling). ``vscores``/``valid_data`` carry the valid-set scores
+    iteration for multiclass; ``bag_fn(it, grad, hess, *bops)``
+    supplies device-computed row weights — bagging/GOSS — or None for
+    no sampling). ``vscores``/``valid_data`` carry the valid-set scores
     through the scan: each tree is traversed on device against every
     valid set's binned matrix, so eval-bearing configs fuse too.
-    ``gops`` are the objective's ``grad_operands()``: arrays the size
-    of the table that ``grad_fn`` takes after the score (a ranking
-    objective's query layout), arguments of the program, not constants.
-    NOT module-jitted: the learner and grad_fn capture device state
-    (training matrix layout, objective label arrays), so each booster
-    wraps this in its OWN jax.jit (``GBDT._train_fused_blocks``) — the
-    compiled-program cache then dies with the booster instead of
+
+    Every array whose values come from a table is an ARGUMENT, never a
+    constant, so two tables of one shape lower to one program and the
+    second hits the persistent compile cache: ``gops`` are the
+    objective's ``grad_operands()`` (labels, weights, a ranking
+    objective's query layout) that ``grad_fn`` takes after the score,
+    ``valid_data`` the valid sets' binned matrices, ``bops`` the
+    sampling's operands (``GBDT._bag_operands``), ``lops`` the
+    learner's (``grow_operands()``: the per-feature metadata; a mesh
+    learner hands over None and its metadata stays a constant, see
+    ``MeshPartitionedTreeLearner.grow_operands``). NOT
+    module-jitted: the learner captures the training matrix layout, so
+    each booster wraps this in its OWN jax.jit (``GBDT._fused_block``)
+    — the compiled-program cache then dies with the booster instead of
     pinning its device buffers in a process-lifetime module cache."""
+    meta = learner.meta if lops is None else lops
+
     def body(carry, it):
         mat, ws, score, vscores = carry
         with jax.named_scope(scopes.GRADIENTS):
@@ -232,14 +242,15 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(), *,
         bag = None
         if bag_fn is not None:
             with jax.named_scope(scopes.SAMPLE):
-                bag = bag_fn(it, grad, hess)
+                bag = bag_fn(it, grad, hess, *bops)
         trees_k = []
         ok = None
         for tid in range(k):
             with jax.named_scope(scopes.GROW):
                 mat, ws, tree, (row_ids, pos_value) = \
                     learner.traceable_grow(mat, ws, grad[:, tid],
-                                           hess[:, tid], bag=bag)
+                                           hess[:, tid], bag=bag,
+                                           meta=meta)
             ok_t = tree.num_leaves > 1
             scale = jnp.where(ok_t, lr, jnp.float32(0.0))
             # one scatter-add in segment order: row_ids is a
@@ -249,7 +260,7 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(), *,
                 score = score.at[row_ids, tid].add(pos_value * scale)
             vscores = tuple(
                 vs.at[:, tid].add(traverse_tree_arrays(
-                    tree, vb, learner.meta, scale, vmv))
+                    tree, vb, meta, scale, vmv))
                 for vs, (vb, vmv) in zip(vscores, valid_data))
             trees_k.append(tree)
             ok = ok_t if ok is None else (ok | ok_t)
@@ -460,12 +471,11 @@ class GBDT:
             return self._bagging_weight_host(it)
         if it % cfg.bagging_freq != 0 and self.bag_weight is not None:
             return self.bag_weight
-        balanced = cfg.pos_bagging_fraction < 1.0 \
-            or cfg.neg_bagging_fraction < 1.0
         get_telemetry().count_iter("host.dispatches")
         self.bag_weight = _bag_mask_jit(
             self._bag_key, jnp.int32(it),
-            self._bag_balanced_label() if balanced else None,
+            self._bag_balanced_label() if self._balanced_bagging()
+            else None,
             freq=int(cfg.bagging_freq), n=self.num_data,
             frac=float(cfg.bagging_fraction),
             pos_frac=float(cfg.pos_bagging_fraction),
@@ -494,21 +504,22 @@ class GBDT:
             grad_fn = self._grad_fn
             guard_on = self._guard_policy != "off"
 
-            def _fused(s, i, *gops):
+            def _fused(s, i, gops=(), bops=()):
                 g, h = grad_fn(s, *gops)
+                bag = bag_core(i, g, h, *bops)
                 if guard_on:
                     # guard reduction folded into the SAME program:
                     # the finite flag costs no extra dispatch
                     from ..robustness.guards import fold_finite_check
-                    return g, h, bag_core(i, g, h), \
-                        fold_finite_check(g, h)
-                return g, h, bag_core(i, g, h)
+                    return g, h, bag, fold_finite_check(g, h)
+                return g, h, bag
 
             fn = register_dynamic(
                 "gbdt_grad_bag", jax.jit(_named("gbdt_grad_bag", _fused)))
             self._grad_bag_jit = fn
         tel.count_iter("host.dispatches")
-        out = fn(score, jnp.int32(it), *self._grad_operands)
+        out = fn(score, jnp.int32(it), self._grad_operands,
+                 self._bag_operands())
         if len(out) == 4:
             grad, hess, bag, self._last_grad_ok = out
         else:
@@ -1138,18 +1149,30 @@ class GBDT:
     # on a local chip is not measured yet (ROADMAP S3).
     _FUSED_BLOCK = 64
 
+    def _balanced_bagging(self) -> bool:
+        cfg = self.config
+        return cfg.pos_bagging_fraction < 1.0 \
+            or cfg.neg_bagging_fraction < 1.0
+
+    def _bag_operands(self) -> tuple:
+        """What the sampling hook takes after ``(it, grad, hess)`` in a
+        compiled program: balanced bagging's labels, an array the size
+        of the table, as an ARGUMENT (``_fused_iter_block``)."""
+        if not (self._bagging_need() and self._device_bagging()
+                and self._balanced_bagging()):
+            return ()
+        return (self._bag_balanced_label(),)
+
     def _traceable_bag_fn(self):
         """Device-traceable per-iteration sampling hook for the fused
-        path: a function ``(it, grad, hess) -> [N] weights`` or None.
-        Base GBDT returns the device bagging draw (the SAME stream as
-        ``_bagging_weight`` for equal ``it``) when bagging is
-        configured and device-resident; GOSS overrides."""
+        path: a function ``(it, grad, hess, *bops) -> [N] weights`` or
+        None, ``bops`` = ``_bag_operands()``: balanced bagging's labels,
+        none otherwise. Base GBDT returns the device bagging draw (the
+        SAME stream as ``_bagging_weight`` for equal ``it``) when bagging
+        is configured and device-resident; GOSS overrides."""
         cfg = self.config
         if not self._bagging_need() or not self._device_bagging():
             return None
-        balanced = cfg.pos_bagging_fraction < 1.0 \
-            or cfg.neg_bagging_fraction < 1.0
-        label = self._bag_balanced_label() if balanced else None
         key0 = self._bag_key
         freq = int(cfg.bagging_freq)
         n = self.num_data
@@ -1157,7 +1180,7 @@ class GBDT:
         pos_frac = float(cfg.pos_bagging_fraction)
         neg_frac = float(cfg.neg_bagging_fraction)
 
-        def bag_fn(it, grad, hess):
+        def bag_fn(it, grad, hess, label=None):
             return _bag_mask_core(key0, it, label, freq=freq, n=n,
                                   frac=frac, pos_frac=pos_frac,
                                   neg_frac=neg_frac)
@@ -1207,6 +1230,36 @@ class GBDT:
         asks for."""
         return max(1, int(self.config.metric_freq))
 
+    def _fused_block(self):
+        """The booster's ``gbdt_fused_block`` program, built once."""
+        fused = getattr(self, "_fused_jit", None)
+        if fused is None:
+            fused = register_dynamic(
+                "gbdt_fused_block",
+                jax.jit(
+                    _named("gbdt_fused_block", functools.partial(
+                        _fused_iter_block, learner=self.learner,
+                        grad_fn=self._grad_fn,
+                        bag_fn=self._traceable_bag_fn(),
+                        k=self.num_tree_per_iteration)),
+                    static_argnames=("m",), donate_argnums=(0, 1, 2, 3)),
+                donate=(0, 1, 2))
+            self._fused_jit = fused
+        return fused
+
+    def _fused_block_args(self) -> tuple:
+        """The arguments of the next fused block: the carry (matrix,
+        twin, scores, valid scores), the shrinkage and first iteration,
+        then every array whose values come from a table
+        (``_fused_iter_block``)."""
+        ln = self.learner
+        valid_data = tuple((vd.binned_device, vd.mv_slots_device)
+                           for vd in self.valid_sets)
+        return (ln.mat, ln.ws, self.train_score, tuple(self.valid_scores),
+                jnp.float32(self.shrinkage_rate), jnp.int32(self.iter),
+                self._grad_operands, valid_data, self._bag_operands(),
+                ln.grow_operands())
+
     def _train_fused_blocks(self, iters: int,
                             eval_every: Optional[int] = None) -> bool:
         """Run [self.iter, iters) in <=_FUSED_BLOCK-iteration scanned
@@ -1217,23 +1270,8 @@ class GBDT:
         (valid scores advance INSIDE the scan). Returns True when
         training stopped early (no-split)."""
         ln = self.learner
-        lr = jnp.float32(self.shrinkage_rate)
         k = self.num_tree_per_iteration
-        fused = getattr(self, "_fused_jit", None)
-        if fused is None:
-            valid_data = tuple((vd.binned_device, vd.mv_slots_device)
-                               for vd in self.valid_sets)
-            fused = register_dynamic(
-                "gbdt_fused_block",
-                jax.jit(
-                    _named("gbdt_fused_block", functools.partial(
-                        _fused_iter_block, learner=ln,
-                        grad_fn=self._grad_fn,
-                        bag_fn=self._traceable_bag_fn(),
-                        valid_data=valid_data, k=k)),
-                    static_argnames=("m",), donate_argnums=(0, 1, 2, 3)),
-                donate=(0, 1, 2))
-            self._fused_jit = fused
+        fused = self._fused_block()
         while self.iter < iters:
             # largest power-of-2 block <= remaining (capped): the set of
             # compiled scan lengths stays O(log) regardless of how the
@@ -1254,9 +1292,7 @@ class GBDT:
             with tel.span("boosting", trace=scopes.BLOCK_DISPATCH):
                 tel.count_iter("host.dispatches")
                 tel.count("fused.block_hits")
-                vs = tuple(self.valid_scores)
-                args = (ln.mat, ln.ws, self.train_score, vs, lr,
-                        jnp.int32(self.iter), self._grad_operands)
+                args = self._fused_block_args()
                 # avals before the call: the arguments are donated
                 new_prog = tel.enabled and scopes.remember(
                     "gbdt_fused_block", fused, args, m=m)

@@ -74,6 +74,9 @@ class GOSS(GBDT):
             other_rate=float(self.config.other_rate))
         return self.bag_weight
 
+    def _bag_operands(self) -> tuple:
+        return ()   # the selection reads the gradients, not the labels
+
     def _traceable_bag_fn(self):
         """Fused-path hook: the same selection with a TRACED iteration
         index (fold_in accepts traced data; the warmup cutoff becomes a

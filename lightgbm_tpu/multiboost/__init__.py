@@ -18,11 +18,10 @@ from .batch import (BoosterBatch, ModelSpec, MultiboostError,
                     ELIGIBLE_OBJECTIVES, VMAPPED_PARAMS, bucket_key,
                     bucket_models, multiboost_ineligible_reason,
                     multiboost_mode)
-from .program import HyperBatch, TRACE_ATTRS, build_grow_program, \
-    mb_score_add
+from .program import HyperBatch, build_grow_program, mb_score_add
 
 __all__ = [
     "BoosterBatch", "ModelSpec", "MultiboostError", "HyperBatch",
-    "TRACE_ATTRS", "ELIGIBLE_OBJECTIVES", "VMAPPED_PARAMS",
+    "ELIGIBLE_OBJECTIVES", "VMAPPED_PARAMS",
     "bucket_key", "bucket_models", "build_grow_program",
     "mb_score_add", "multiboost_ineligible_reason", "multiboost_mode"]

@@ -5,7 +5,7 @@ one device binned matrix) and one SerialTreeLearner; per-model state
 is stacked along a leading model axis:
 
 * ``score``      [B, N] f32 — every model's train score column
-* ``attrs``      per-model objective slices (label / weights / ...)
+* ``attrs``      per-model objective operands (label / weights / ...)
 * ``masks``      [B, N] f32 row-inclusion weights (cv folds, tenant
                  row partitions) — zero rows contribute zeros to the
                  scatter-add histograms, exactly like an out-of-bag row
@@ -41,8 +41,7 @@ from ..models.tree import Tree, TreeArrays
 from ..objective.base import create_objective
 from ..observability.telemetry import get_telemetry
 from ..utils.log import log_info
-from .program import TRACE_ATTRS, HyperBatch, build_grow_program, \
-    mb_score_add
+from .program import HyperBatch, build_grow_program, mb_score_add
 
 #: hyperparameter axes vmapped along the model axis; every other param
 #: is static (shape- or code-affecting) and buckets instead
@@ -51,8 +50,8 @@ VMAPPED_PARAMS = (
     "min_data_in_leaf", "min_sum_hessian_in_leaf", "min_gain_to_split",
     "bagging_fraction", "bagging_seed")
 
-#: objectives whose gradients are elementwise in the swapped device
-#: attributes (program.TRACE_ATTRS) — the functionalization contract
+#: objectives whose gradients are elementwise in their operands
+#: (``grad_operands``), so a model's slice of them is its own labels
 ELIGIBLE_OBJECTIVES = (
     "regression", "huber", "fair", "poisson", "gamma", "tweedie",
     "binary", "cross_entropy", "cross_entropy_lambda")
@@ -312,18 +311,11 @@ class BoosterBatch:
                 [s.row_index is None for s in self.specs])
             masks[ones] = 1.0
 
-        names = tuple(a for a in TRACE_ATTRS
-                      if getattr(obj_grad[0], a, None) is not None)
-        for og in obj_grad:
-            mine = tuple(a for a in TRACE_ATTRS
-                         if getattr(og, a, None) is not None)
-            if mine != names:
-                raise MultiboostError(
-                    "models disagree on objective attribute presence")
-        self._attr_names = names
-        self._attrs = {a: jnp.stack([jnp.asarray(getattr(og, a))
-                                     for og in obj_grad])
-                       for a in names}
+        ops = [og.grad_operands()[0] for og in obj_grad]
+        if len({jax.tree.structure(o) for o in ops}) > 1:
+            raise MultiboostError(
+                "models disagree on objective operand presence")
+        self._attrs = jax.tree.map(lambda *xs: jnp.stack(xs), *ops)
 
         use_bagging = cfg0.bagging_freq > 0 and any(
             c.bagging_fraction < 1.0 for c in self.configs)
@@ -368,7 +360,7 @@ class BoosterBatch:
         self._program = build_grow_program(
             self.learner, obj_grad[0], use_bagging=use_bagging,
             bagging_freq=int(cfg0.bagging_freq), has_mask=has_mask,
-            attr_names=names, traced_fields=traced)
+            traced_fields=traced)
 
         self._score = jnp.zeros((self.B, self.N), jnp.float32)
         self._models: List[List[Any]] = [[] for _ in range(self.B)]

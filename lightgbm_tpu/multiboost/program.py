@@ -30,11 +30,10 @@ Two program boundaries, mirroring the booster's sync/async split:
   ``where(ok, lr, 0)`` masking no-split models, identical to
   ``_train_one_iter_async``.
 
-The objective's device attributes (label / weights / binary's
-label_val / label_weight) are swapped for traced per-model slices for
-the duration of the trace — ``gradients`` is elementwise in those
-attributes for every whitelisted objective, so the swap is exactly
-"functionalizing" the instance.
+The objective's operands (``grad_operands``: label / weights /
+binary's label_val / label_weight) are stacked along the model axis and
+handed to ``gradients`` as the model's slice — ``gradients`` is
+elementwise in them for every whitelisted objective.
 """
 
 from __future__ import annotations
@@ -46,11 +45,6 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.jit_registry import register_dynamic, register_jit
-
-#: objective device attributes that may carry per-model traced slices
-#: (only the ones present on the instance are swapped)
-TRACE_ATTRS = ("label", "weights", "label_val", "label_weight")
-
 
 class HyperBatch(NamedTuple):
     """Per-model hyperparameter axes that trace cleanly — one [B]
@@ -82,14 +76,13 @@ def mb_score_add(score, leaf_vals, leaf_id):
 
 def build_grow_program(learner, objective, *, use_bagging: bool,
                        bagging_freq: int, has_mask: bool,
-                       attr_names: tuple,
                        traced_fields: tuple = ()):
     """One jitted iteration over B models; see module docstring.
 
     ``learner`` is the bucket's SerialTreeLearner on the SHARED
     dataset; ``objective`` the template instance whose ``gradients``
-    is traced with per-model attribute slices; ``attr_names`` the
-    subset of :data:`TRACE_ATTRS` stacked into the ``attrs`` pytree.
+    is traced with the model's slice of the stacked operands
+    (``attrs``, the pytree of ``grad_operands()`` with a model axis).
 
     ``traced_fields`` names the SplitParams numerics that VARY across
     the bucket and therefore enter the grow graph as traced per-model
@@ -122,18 +115,8 @@ def build_grow_program(learner, objective, *, use_bagging: bool,
     all_features = learner._all_features
     freq = int(max(bagging_freq, 1))
 
-    def _grad_hess(score_b, attrs_b):
-        saved = {a: getattr(objective, a) for a in attr_names}
-        for a in attr_names:
-            setattr(objective, a, attrs_b[a])
-        try:
-            return objective.gradients(score_b)
-        finally:
-            for a, v in saved.items():
-                setattr(objective, a, v)
-
     def _per_model(score_b, attrs_b, mask_b, hyp_b, it):
-        grad, hess = _grad_hess(score_b, attrs_b)
+        grad, hess = objective.gradients(score_b, attrs_b)
         if use_bagging:
             bag = _bag_mask_core(hyp_b.bag_key, it, None, freq=freq,
                                  n=n, frac=hyp_b.bagging_fraction,
@@ -173,5 +156,4 @@ def build_grow_program(learner, objective, *, use_bagging: bool,
         donate=(0,))
 
 
-__all__ = ["HyperBatch", "TRACE_ATTRS", "build_grow_program",
-           "mb_score_add"]
+__all__ = ["HyperBatch", "build_grow_program", "mb_score_add"]

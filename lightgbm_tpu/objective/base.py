@@ -33,6 +33,10 @@ class ObjectiveFunction:
         self.num_data = 0
         self.label: Optional[jnp.ndarray] = None
         self.weights: Optional[jnp.ndarray] = None
+        #: every device array ``gradients`` reads that is sized by the
+        #: table, by name (``grad_operands``); a subclass that derives
+        #: its own puts them here in its ``init``
+        self._operands: dict = {}
 
     # -- ObjectiveFunction::Init (objective_function.h:29)
     def init(self, metadata: Metadata, num_data: int) -> None:
@@ -51,22 +55,30 @@ class ObjectiveFunction:
         self.label_np = jax.device_get(self.label)
         self.weights_np = None if self.weights is None \
             else jax.device_get(self.weights)
+        self._operands = {"label": self.label, "weights": self.weights}
         self.check_label()
 
     def check_label(self) -> None:
         pass
 
     # -- GetGradients: score [N] or [N, K] -> (grad, hess) same shape
-    def gradients(self, score: jnp.ndarray):
+    def gradients(self, score: jnp.ndarray, ops: Optional[dict] = None):
+        """``ops`` is what ``grad_operands()`` hands over; None reads
+        the objective's own."""
+        return self._gradients(score,
+                               self._operands if ops is None else ops)
+
+    def _gradients(self, score: jnp.ndarray, ops: dict):
         raise NotImplementedError
 
     def grad_operands(self) -> tuple:
-        """Device arrays ``gradients`` takes after the score, which a
-        compiled program that holds it is handed as ARGUMENTS (the
-        fused block, ``gbdt_grad``): what would otherwise be baked
-        into the program as constants the size of the table
-        (objective/rank.py's query layout)."""
-        return ()
+        """What ``gradients`` takes after the score: every device array
+        it reads that is sized by the table (labels, weights, a ranking
+        objective's query layout), which a compiled program that holds
+        it is handed as ARGUMENTS (the fused block, ``gbdt_grad``).
+        Baked in as constants, they would make the program's text, and
+        so its persistent-cache key, differ from table to table."""
+        return (self._operands,)
 
     def setup_facts(self) -> dict:
         """Attributes of the ``lgbm.setup.objective`` span."""
@@ -89,9 +101,10 @@ class ObjectiveFunction:
     def name(self) -> str:
         raise NotImplementedError
 
-    def _weighted(self, grad, hess):
-        if self.weights is not None:
-            w = self.weights
+    @staticmethod
+    def _weighted(grad, hess, ops: dict):
+        w = ops["weights"]
+        if w is not None:
             if grad.ndim == 2:
                 w = w[:, None]
             return grad * w, hess * w

@@ -53,19 +53,21 @@ class BinaryLogloss(ObjectiveFunction):
                 w_pos = cnt_negative / cnt_positive
         w_pos *= self.scale_pos_weight
         # per-row ±1 label value and class weight
-        self.label_val = jnp.where(jnp.asarray(pos_mask), 1.0, -1.0)
-        self.label_weight = jnp.where(jnp.asarray(pos_mask), w_pos, w_neg)
+        pos = jnp.asarray(pos_mask)
+        self._operands = {"label_val": jnp.where(pos, 1.0, -1.0),
+                          "label_weight": jnp.where(pos, w_pos, w_neg),
+                          "weights": self.weights}
 
-    def gradients(self, score):
+    def _gradients(self, score, ops):
         if not self.need_train:
             return jnp.zeros_like(score), jnp.zeros_like(score)
-        lv = self.label_val
+        lv = ops["label_val"]
         response = -lv * self.sigmoid \
             / (1.0 + jnp.exp(lv * self.sigmoid * score))
         abs_resp = jnp.abs(response)
-        grad = response * self.label_weight
-        hess = abs_resp * (self.sigmoid - abs_resp) * self.label_weight
-        return self._weighted(grad, hess)
+        grad = response * ops["label_weight"]
+        hess = abs_resp * (self.sigmoid - abs_resp) * ops["label_weight"]
+        return self._weighted(grad, hess, ops)
 
     def boost_from_score(self, class_id: int = 0) -> float:
         lbl = self.label_np

@@ -31,21 +31,22 @@ class MulticlassSoftmax(ObjectiveFunction):
         if (lbl < 0).any() or (lbl >= self.num_class).any():
             log_fatal("Label must be in [0, num_class) for multiclass "
                       "objective")
-        self.label_int = jnp.asarray(lbl)
+        self._operands = {"label_int": jnp.asarray(lbl),
+                          "weights": self.weights}
         w = np.ones(num_data) if self.weights is None \
             else np.asarray(self.weights_np, np.float64)
         probs = np.zeros(self.num_class)
         np.add.at(probs, lbl, w)
         self.class_init_probs = probs / w.sum()
 
-    def gradients(self, score):
+    def _gradients(self, score, ops):
         # score [N, K]
         p = jax.nn.softmax(score, axis=-1)
-        onehot = jax.nn.one_hot(self.label_int, self.num_class,
+        onehot = jax.nn.one_hot(ops["label_int"], self.num_class,
                                 dtype=score.dtype)
         grad = p - onehot
         hess = 2.0 * p * (1.0 - p)
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def boost_from_score(self, class_id: int = 0) -> float:
         return float(np.log(max(kEpsilon,
@@ -79,11 +80,14 @@ class MulticlassOVA(ObjectiveFunction):
         super().init(metadata, num_data)
         for b in self._binary:
             b.init(metadata, num_data)
+        self._operands = {"classes": tuple(b._operands
+                                           for b in self._binary)}
 
-    def gradients(self, score):
+    def _gradients(self, score, ops):
         grads, hesses = [], []
         for k in range(self.num_class):
-            g, h = self._binary[k].gradients(score[:, k])
+            g, h = self._binary[k].gradients(score[:, k],
+                                             ops["classes"][k])
             grads.append(g)
             hesses.append(h)
         return jnp.stack(grads, axis=1), jnp.stack(hesses, axis=1)

@@ -243,7 +243,7 @@ class RankingObjective(ObjectiveFunction):
         self.num_queries = lay.num_queries
         # the labels ride into the layout beside the scores
         self._operands = dict(lay.operands, label=jnp.asarray(
-            np.asarray(metadata.label, np.float32)))
+            np.asarray(metadata.label, np.float32)), weights=self.weights)
         tel = get_telemetry()
         for name, value in (("queries", lay.num_queries),
                             ("docs", num_data), ("slots", lay.slots),
@@ -265,7 +265,7 @@ class RankingObjective(ObjectiveFunction):
     def _to_rows(self, blocks: list, ops):
         with jax.named_scope(scopes.RANK_LAYOUT):
             rows = self.layout.to_rows(blocks, ops)
-        return self._weighted(rows[:, 0], rows[:, 1])
+        return self._weighted(rows[:, 0], rows[:, 1], ops)
 
 
 class LambdarankNDCG(RankingObjective):
@@ -307,11 +307,7 @@ class LambdarankNDCG(RankingObjective):
         self._gains = [float(g) for g in
                        self.label_gain[:int(lab.max(initial=0)) + 1]]
 
-    def grad_operands(self) -> tuple:
-        return (self._operands,)
-
-    def gradients(self, score: jnp.ndarray, ops=None):
-        ops = self._operands if ops is None else ops
+    def _gradients(self, score: jnp.ndarray, ops):
         lay = self.layout
         blocks = []
         for c, ((rows, valid), inv) in enumerate(zip(
@@ -414,9 +410,9 @@ class RankXENDCG(RankingObjective):
 
         self._grad = register_dynamic("xendcg_grad", jax.jit(xendcg_grad))
 
-    def gradients(self, score: jnp.ndarray):
+    def _gradients(self, score: jnp.ndarray, ops):
         u = self._rng.rand(self.num_data).astype(np.float32)
-        return self._grad(score, jnp.asarray(u), self._operands)
+        return self._grad(score, jnp.asarray(u), ops)
 
     def name(self) -> str:
         return "rank_xendcg"

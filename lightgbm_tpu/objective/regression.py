@@ -36,15 +36,16 @@ class RegressionL2Loss(ObjectiveFunction):
             self.label = jnp.asarray(np.sign(lbl) * np.sqrt(np.abs(lbl)))
             import jax
             self.label_np = jax.device_get(self.label)
+            self._operands["label"] = self.label
 
     @property
     def is_constant_hessian(self):
         return self.weights is None
 
-    def gradients(self, score):
-        grad = score - self.label
+    def _gradients(self, score, ops):
+        grad = score - ops["label"]
         hess = jnp.ones_like(score)
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def boost_from_score(self, class_id: int = 0) -> float:
         lbl = np.asarray(self.label_np, np.float64)
@@ -68,11 +69,11 @@ class RegressionL1Loss(RegressionL2Loss):
     renew_alpha = 0.5
     is_renew_tree_output = True
 
-    def gradients(self, score):
-        diff = score - self.label
+    def _gradients(self, score, ops):
+        diff = score - ops["label"]
         grad = _sign(diff)
         hess = jnp.ones_like(score)
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def boost_from_score(self, class_id: int = 0) -> float:
         from ..ops.percentile import percentile_host
@@ -105,12 +106,12 @@ class RegressionHuberLoss(RegressionL2Loss):
     def is_constant_hessian(self):
         return self.weights is None
 
-    def gradients(self, score):
-        diff = score - self.label
+    def _gradients(self, score, ops):
+        diff = score - ops["label"]
         grad = jnp.where(jnp.abs(diff) <= self.alpha, diff,
                          _sign(diff) * self.alpha)
         hess = jnp.ones_like(score)
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def name(self):
         return "huber"
@@ -128,12 +129,12 @@ class RegressionFairLoss(RegressionL2Loss):
     def is_constant_hessian(self):
         return False
 
-    def gradients(self, score):
-        x = score - self.label
+    def _gradients(self, score, ops):
+        x = score - ops["label"]
         c = self.c
         grad = c * x / (jnp.abs(x) + c)
         hess = c * c / (jnp.abs(x) + c) ** 2
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def name(self):
         return "fair"
@@ -162,10 +163,10 @@ class RegressionPoissonLoss(RegressionL2Loss):
     def is_constant_hessian(self):
         return False
 
-    def gradients(self, score):
-        grad = jnp.exp(score) - self.label
+    def _gradients(self, score, ops):
+        grad = jnp.exp(score) - ops["label"]
         hess = jnp.exp(score + self.max_delta_step)
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def boost_from_score(self, class_id: int = 0) -> float:
         return float(np.log(max(RegressionL2Loss.boost_from_score(self),
@@ -193,11 +194,11 @@ class RegressionQuantileLoss(RegressionL2Loss):
     def is_constant_hessian(self):
         return self.weights is None
 
-    def gradients(self, score):
-        delta = score - self.label
+    def _gradients(self, score, ops):
+        delta = score - ops["label"]
         grad = jnp.where(delta >= 0, 1.0 - self.alpha, -self.alpha)
         hess = jnp.ones_like(score)
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def boost_from_score(self, class_id: int = 0) -> float:
         from ..ops.percentile import percentile_host
@@ -232,17 +233,18 @@ class RegressionMAPELoss(RegressionL1Loss):
         # device array used to fetch (jnp downcasts f64 -> f32)
         self._label_weight_np = np.asarray(
             1.0 / np.maximum(1.0, np.abs(lbl)) * w, np.float32)
-        self.label_weight = jnp.asarray(self._label_weight_np)
+        self._operands["label_weight"] = jnp.asarray(self._label_weight_np)
 
     @property
     def is_constant_hessian(self):
         return True
 
-    def gradients(self, score):
-        diff = score - self.label
-        grad = _sign(diff) * self.label_weight
-        hess = jnp.ones_like(score) if self.weights is None \
-            else jnp.broadcast_to(self.weights, score.shape)
+    def _gradients(self, score, ops):
+        diff = score - ops["label"]
+        grad = _sign(diff) * ops["label_weight"]
+        w = ops["weights"]
+        hess = jnp.ones_like(score) if w is None \
+            else jnp.broadcast_to(w, score.shape)
         return grad, hess
 
     def boost_from_score(self, class_id: int = 0) -> float:
@@ -264,14 +266,15 @@ class RegressionMAPELoss(RegressionL1Loss):
 class RegressionGammaLoss(RegressionPoissonLoss):
     """Gamma regression (regression_objective.hpp:673-706)."""
 
-    def gradients(self, score):
-        grad = 1.0 - self.label * jnp.exp(-score)
-        hess = self.label * jnp.exp(-score)
-        if self.weights is not None:
+    def _gradients(self, score, ops):
+        y, w = ops["label"], ops["weights"]
+        grad = 1.0 - y * jnp.exp(-score)
+        hess = y * jnp.exp(-score)
+        if w is not None:
             # reference applies the weight inside the label term only
             # (regression_objective.hpp:695-697)
-            grad = 1.0 - self.label * jnp.exp(-score) * self.weights
-            hess = self.label * jnp.exp(-score) * self.weights
+            grad = 1.0 - y * jnp.exp(-score) * w
+            hess = y * jnp.exp(-score) * w
         return grad, hess
 
     def name(self):
@@ -285,13 +288,13 @@ class RegressionTweedieLoss(RegressionPoissonLoss):
         super().__init__(config)
         self.rho = float(config.tweedie_variance_power)
 
-    def gradients(self, score):
-        rho = self.rho
-        grad = -self.label * jnp.exp((1 - rho) * score) \
+    def _gradients(self, score, ops):
+        rho, y = self.rho, ops["label"]
+        grad = -y * jnp.exp((1 - rho) * score) \
             + jnp.exp((2 - rho) * score)
-        hess = -self.label * (1 - rho) * jnp.exp((1 - rho) * score) \
+        hess = -y * (1 - rho) * jnp.exp((1 - rho) * score) \
             + (2 - rho) * jnp.exp((2 - rho) * score)
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def name(self):
         return "tweedie"

@@ -32,11 +32,11 @@ class CrossEntropy(ObjectiveFunction):
                 log_fatal(f"[{self.name()}]: at least one weight is "
                           "non-positive")
 
-    def gradients(self, score):
+    def _gradients(self, score, ops):
         z = 1.0 / (1.0 + jnp.exp(-score))
-        grad = z - self.label
+        grad = z - ops["label"]
         hess = z * (1.0 - z)
-        return self._weighted(grad, hess)
+        return self._weighted(grad, hess, ops)
 
     def boost_from_score(self, class_id: int = 0) -> float:
         lbl = np.asarray(self.label_np, np.float64)
@@ -71,12 +71,11 @@ class CrossEntropyLambda(ObjectiveFunction):
                 log_fatal(f"[{self.name()}]: at least one weight is "
                           "non-positive")
 
-    def gradients(self, score):
-        if self.weights is None:
+    def _gradients(self, score, ops):
+        w, y = ops["weights"], ops["label"]
+        if w is None:
             z = 1.0 / (1.0 + jnp.exp(-score))
-            return z - self.label, z * (1.0 - z)
-        w = self.weights
-        y = self.label
+            return z - y, z * (1.0 - z)
         epf = jnp.exp(score)
         hhat = jnp.log1p(epf)
         z = 1.0 - jnp.exp(-w * hhat)

@@ -596,11 +596,18 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
                 and self.ff_bynode >= 1.0
                 and getattr(self, "_cegb_used", None) is None)
 
-    def traceable_grow(self, mat, ws, grad, hess, bag=None):
-        """One mesh-parallel tree inside an enclosing trace. Returns
-        ``(mat, ws, tree, (global_row_ids, pos_value))`` with padded
-        entries carrying ids >= num_data (dropped by the caller's
-        scatter-add)."""
+    def grow_operands(self):
+        """None: the metadata, and the feature plan drawn from it, are
+        read inside the mesh program's ``shard_map`` (``_build``), where
+        they stay constants. So a mesh learner's fused block is compiled
+        anew for every table; only the single-chip learner's is not."""
+        return None
+
+    def traceable_grow(self, mat, ws, grad, hess, bag=None, *, meta):
+        """One mesh-parallel tree inside an enclosing trace (``meta``
+        is not read: see ``grow_operands``). Returns ``(mat, ws, tree,
+        (global_row_ids, pos_value))`` with padded entries carrying ids
+        >= num_data (dropped by the caller's scatter-add)."""
         n = self.dataset.num_data
         if bag is None:
             bag = jnp.ones((n,), jnp.float32)

@@ -138,7 +138,8 @@ def test_refused_kernel_raises_not_falls_back(monkeypatch):
     assert ln.split_plan().body == "megakernel"
     g = jnp.zeros((512,), jnp.float32)
     with pytest.raises(Exception, match="tpu.iota"):
-        jax.jit(ln.traceable_grow).trace(
+        jax.jit(functools.partial(ln.traceable_grow,
+                                  meta=ln.grow_operands())).trace(
             ln.mat, ln.ws, g, g + 1.0).lower(
             lowering_platforms=("tpu",))
 

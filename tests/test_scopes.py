@@ -5,6 +5,7 @@ clock, and none of it changes the program (ISSUE 24)."""
 
 import collections
 import contextlib
+import functools
 import gc
 import re
 
@@ -355,8 +356,6 @@ def _eqns_under(jaxpr, scope):
 def _fused_block_of(g, m):
     """``_fused_iter_block`` over ``m`` trees of ``g``'s table, as the
     booster wraps it, and its arguments (nothing donated)."""
-    import functools
-
     import jax.numpy as jnp
 
     from lightgbm_tpu.models.gbdt import _fused_iter_block
@@ -410,7 +409,8 @@ def test_scores_bit_equal_to_the_parents_statement(tel, bagging):
     unfused = score
     bag_fn = g._traceable_bag_fn()
     assert (bag_fn is not None) == bagging
-    parts = jax.jit(ln.traceable_grow)
+    parts = jax.jit(functools.partial(ln.traceable_grow,
+                                      meta=ln.grow_operands()))
     for it in range(3):
         grad, hess = g._grad_fn(score[:, 0])
         bag = None if bag_fn is None else bag_fn(jnp.int32(it), grad, hess)
