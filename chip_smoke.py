@@ -48,7 +48,13 @@ binary objective, 255 leaves, 255 bins), on rows generated from a seed:
    for 5 rounds: the megakernel at its third width, the pairwise
    gradients on the query layout that follows the queries' lengths
    (``objective.rank_slots`` at most 1.6 x ``objective.rank_docs``),
-   still in fused blocks; its route counters and its NDCG@10.
+   still in fused blocks; its route counters and its NDCG@10;
+9. data parallel, where the host has four chips (skipped on fewer):
+   500,000 rows at the benchmark's Criteo width (67 columns) for 5
+   rounds with ``tree_learner=data`` over four chips, the table binned
+   a row shard a worker: the mesh learner's per-phase kernels with the
+   collectives between them, still in fused blocks; its path report
+   and tree hash, the sharding, and the collectives' calls and bytes.
 
 ``--devices 4`` instead trains the same shape data-parallel over four
 chips and checks the sharding and the AUC against the one-chip model.
@@ -130,6 +136,13 @@ RANK_ROUNDS = 5         # the sync first iteration plus a block of 4
 # start (PERF.md, PR 37); and the AUC of "relevant at all" by the score
 RANK_MIN_NDCG = 0.45
 RANK_MIN_AUC = 0.6
+# the benchmark's data-parallel deployment (criteo-dp4): 67 dense
+# columns, rows sharded over the four chips of one host, the mesh
+# learner's collectives between the phases of every split
+DP_ROWS = 500_000
+DP_FEATURES = 67
+DP_CHIPS = 4
+DP_ROUNDS = 5           # the sync first iteration plus a block of 4
 
 
 def device_report() -> dict:
@@ -575,6 +588,37 @@ def stage_serve(bst, x, sizes=SERVE_SIZES) -> dict:
     return out
 
 
+def stage_data_parallel(chips: int, rows: int = DP_ROWS,
+                        params: dict = PARAMS,
+                        interpret: bool = False) -> dict:
+    """The data-parallel learner over ``DP_CHIPS`` chips at the
+    benchmark's Criteo width, where the host has them (skipped, and
+    said so, where it has fewer): the path report with its tree hash,
+    the sharding, and the collectives' counters (calls, payload bytes
+    and the bytes a chip sends of them, counted where each enters a
+    grow program's trace)."""
+    if chips < DP_CHIPS:
+        return {"skipped": f"{chips} chip(s), the stage needs {DP_CHIPS}"}
+    from lightgbm_tpu.observability.telemetry import get_telemetry
+    tel = get_telemetry()
+    before = {k: v for k, v in tel.counters.items()
+              if k.startswith("comm.")}
+    x, y = higgs_like(rows, DP_FEATURES, seed=44)
+    params = dict(params, tree_learner="data", num_machines=DP_CHIPS)
+    bst, report = stage_train(
+        x, y, params, DP_ROUNDS, learner="MeshPartitionedTreeLearner",
+        interpret=interpret, megakernel=False, shards=DP_CHIPS)
+    report["shards"] = stage_shards(bst, rows, DP_CHIPS)
+    report["comm"] = {k: v - before.get(k, 0)
+                      for k, v in sorted(tel.counters.items())
+                      if k.startswith("comm.")
+                      and v != before.get(k, 0)}
+    print(f"data_parallel: {json.dumps(report['comm'])}", flush=True)
+    for op in ("psum", "psum_scatter", "all_gather"):
+        assert report["comm"].get(f"comm.{op}_sent_bytes", 0) > 0, report
+    return report
+
+
 def stage_shards(bst, rows: int, devices: int) -> dict:
     """The mesh learner's training matrix: one shard per device, about
     rows/devices each, none holding the whole; device memory in use
@@ -737,6 +781,7 @@ def main(argv=None) -> int:
             interpret=False, megakernel=True)
         gap = abs(report["train"]["auc"] - report["one_chip"]["auc"])
         assert gap <= FOIL_AUC_TOL, ("four chips vs one chip", gap)
+    report["data_parallel"] = stage_data_parallel(device["count"])
     report["compile_cache"] = cache_report(cache_events)
     report["seconds"] = round(time.perf_counter() - t_start, 1)
     report["claim"] = None

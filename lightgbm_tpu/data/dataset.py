@@ -343,7 +343,8 @@ class Dataset:
                                 forced_bins)
                 self._resolve_monotone_and_penalty(config)
 
-            self._extract_features(data)
+            from ..parallel.ingest import row_shards
+            self._extract_features(data, row_shards(config))
             if config.linear_tree or (reference is not None
                                       and reference.raw_numeric is not None):
                 self._store_raw(data)
@@ -508,20 +509,33 @@ class Dataset:
             (fp[j] if j < len(fp) else 1.0) for j in self.real_feature_idx] \
             if fp else []
 
-    def _extract_features(self, data: np.ndarray) -> None:
+    def _extract_features(self, data: np.ndarray, shards: int = 1) -> None:
+        """The bins of every row. With ``shards`` row shards (the mesh
+        learners' count, ``parallel/ingest.py`` ``row_shards``) each
+        shard's rows are binned by a worker of their own: the bins are
+        those of one worker, bit for bit."""
+        from ..parallel import ingest
         n = data.shape[0]
         width = max(self.num_features, 1)
         max_b = max([self.num_bin(f) for f in range(self.num_features)],
                     default=2)
         dtype = np.uint8 if max_b <= 256 else np.uint16
-        with get_telemetry().setup_span(
-                scopes.DATA_BIN_ROWS,
-                bytes=n * width * np.dtype(dtype).itemsize):
-            out = np.zeros((n, width), dtype=dtype)
-            for inner, orig in enumerate(self.real_feature_idx):
-                mapper = self.bin_mappers[orig]
-                out[:, inner] = mapper.values_to_bins(np.asarray(
-                    data[:, orig], dtype=np.float64)).astype(dtype)
+        out = np.zeros((n, width), dtype=dtype)
+        tel = get_telemetry()
+        parent = tel.current_path()
+
+        def bin_shard(s: int) -> None:
+            lo, hi = ingest.shard_bounds(n, shards, s)
+            fields = {"shard": s, "rows": hi - lo} if shards > 1 else {}
+            with tel.under(parent), tel.setup_span(
+                    scopes.DATA_BIN_ROWS,
+                    bytes=(hi - lo) * width * np.dtype(dtype).itemsize,
+                    **fields):
+                for inner, orig in enumerate(self.real_feature_idx):
+                    mapper = self.bin_mappers[orig]
+                    out[lo:hi, inner] = mapper.values_to_bins(np.asarray(
+                        data[lo:hi, orig], dtype=np.float64)).astype(dtype)
+        ingest.per_shard(shards, bin_shard)
         self.binned = out
 
     # ------------------------------------------------------------------
@@ -1119,7 +1133,7 @@ class Dataset:
             # write to the EXACT path the caller gave (reference .bin
             # convention) — a bare np.savez would silently append .npz
             with open(path, "wb") as fh:
-                np.savez_compressed(
+                np.savez(
                     fh, binned=self.binned,
                     mv_slots=self.mv_slots if self.mv_slots is not None
                     else np.zeros((0, 0), np.int32),

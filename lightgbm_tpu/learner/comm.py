@@ -44,31 +44,53 @@ and histogram work are sharded.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..observability import scopes
 from ..ops.split import (MAX_CAT_WORDS, FeatureMeta, SplitParams,
                          SplitResult, _argmax_first, assemble_split,
                          best_split, per_feature_splits)
 
 
-def _count_collective(name: str, tree):
+# what one chip sends of a collective's payload, as a multiple of it,
+# in a ring over d chips: a reduce-scatter passes on (d-1)/d of its
+# input, an all-reduce is a reduce-scatter and an all-gather of the
+# result, an all-gather passes its own piece d-1 times
+_RING_SENT = {"psum_scatter": lambda d: (d - 1) / d,
+              "psum": lambda d: 2 * (d - 1) / d,
+              "all_gather": lambda d: d - 1}
+
+
+def _count_collective(name: str, tree, axis: Optional[str] = None):
     """Telemetry seam: add the payload bytes of a collective to counter
-    ``comm.<name>_bytes`` (+ ``comm.<name>_calls``) and return the
-    payload unchanged. The comm hooks run inside jitted grow programs,
-    so this executes at TRACE time over abstract values — the counter
-    records bytes moved per compiled-program invocation (grow-loop
-    collectives execute once per while-loop step at runtime), with
-    zero cost inside the program. ``tools/run_report.py`` renders the
-    counters as the per-op comms table."""
+    ``comm.<name>_bytes``, what a chip sends of it in a ring over the
+    mesh ``axis`` to ``comm.<name>_sent_bytes`` (+ ``comm.<name>_calls``)
+    and return the payload unchanged. The comm hooks run inside jitted
+    grow programs, so this executes at TRACE time over abstract values
+    — the counters record bytes per compiled-program invocation
+    (grow-loop collectives execute once per while-loop step at
+    runtime), with zero cost inside the program.
+    ``tools/run_report.py`` renders the counters as the per-op comms
+    table."""
     from ..observability.telemetry import get_telemetry, traced_bytes
     tel = get_telemetry()
     if tel.enabled:
-        tel.count(f"comm.{name}_bytes", traced_bytes(tree))
+        nbytes = traced_bytes(tree)
+        tel.count(f"comm.{name}_bytes", nbytes)
+        if axis is not None:
+            tel.count(f"comm.{name}_sent_bytes",
+                      nbytes * _RING_SENT[name](jax.lax.axis_size(axis)))
         tel.count(f"comm.{name}_calls", 1)
     return tree
+
+
+def _psum(x, axis: str, scope: str):
+    with jax.named_scope(scope):
+        return jax.lax.psum(_count_collective("psum", x, axis), axis)
 
 
 def _bitcast_f32(x):
@@ -160,14 +182,18 @@ def unpack_split(row: jnp.ndarray) -> SplitResult:
         cat_bitset=jax.lax.bitcast_convert_type(row[10:], jnp.uint32))
 
 
-def gather_best_split(res: SplitResult, axis: str) -> SplitResult:
+def gather_best_split(res: SplitResult, axis: str,
+                      scope: str = scopes.SPLITS_COLLECTIVE
+                      ) -> SplitResult:
     """The SyncUpGlobalBestSplit exchange
     (parallel_tree_learner.h:190-213) as ONE packed all_gather:
     max gain wins, ties broken by LOWER global feature id so
     equal-gain splits match serial's first-index rule even when
     bundled group blocks scramble the shard<->feature-id order."""
-    rows = jax.lax.all_gather(
-        _count_collective("all_gather", pack_split(res)), axis)
+    packed = pack_split(res)
+    with jax.named_scope(scope):
+        rows = jax.lax.all_gather(
+            _count_collective("all_gather", packed, axis), axis)
     gains = _bitcast_f32(rows[:, 0])
     feats = rows[:, 1]
     best = jnp.max(gains)
@@ -175,13 +201,13 @@ def gather_best_split(res: SplitResult, axis: str) -> SplitResult:
     return unpack_split(rows[jnp.argmin(tied)])
 
 
-def make_sharded_select(axis: str):
+def make_sharded_select(axis: str, scope: str = scopes.SPLITS_COLLECTIVE):
     """Best-split select over a column-sharded scan axis: local scan
     of the shard's slice (``meta_local.global_id`` maps the local slot
-    back to the global feature) + the packed winner gather. Shared by
-    the feature-parallel learner (locally-built sharded histograms)
-    and the data-parallel reduce-scatter recipe (slices of the
-    globally-reduced histogram)."""
+    back to the global feature) + the packed winner gather (under
+    ``scope``). Shared by the feature-parallel learner (locally-built
+    sharded histograms) and the data-parallel reduce-scatter recipe
+    (slices of the globally-reduced histogram)."""
 
     def select(hist, g, h, c, meta_local, params, cmin, cmax, fmask,
                rand_bins=None):
@@ -190,7 +216,7 @@ def make_sharded_select(axis: str):
         lb = _argmax_first(pf.score).astype(jnp.int32)
         res = assemble_split(pf, lb,
                              feature_id=meta_local.global_id[lb])
-        return gather_best_split(res, axis)
+        return gather_best_split(res, axis, scope)
 
     return select
 
@@ -215,23 +241,23 @@ def make_data_parallel_comm(axis: str, plan=None) -> Comm:
     """
     if plan is None:
         return Comm(
-            reduce_hist=lambda x: jax.lax.psum(
-                _count_collective("psum", x), axis),
-            reduce_sums=lambda x: jax.lax.psum(
-                _count_collective("psum", x), axis),
+            reduce_hist=lambda x: _psum(x, axis,
+                                        scopes.SPLITS_COLLECTIVE),
+            reduce_sums=lambda x: _psum(x, axis, scopes.ROOT_COLLECTIVE),
             select_split=_serial_select, vmap_safe=True)
 
     g_local = plan.g_local
 
     def reduce_hist(hist):
         hp = plan.permute_hist(hist)
-        return jax.lax.psum_scatter(
-            _count_collective("psum_scatter", hp), axis,
-            scatter_dimension=0, tiled=True)
+        with jax.named_scope(scopes.SPLITS_COLLECTIVE):
+            return jax.lax.psum_scatter(
+                _count_collective("psum_scatter", hp, axis), axis,
+                scatter_dimension=0, tiled=True)
 
     def reduce_root(hist, sums):
         flat = jnp.concatenate([hist.reshape(-1), sums])
-        flat = jax.lax.psum(_count_collective("psum", flat), axis)
+        flat = _psum(flat, axis, scopes.ROOT_COLLECTIVE)
         return flat[:-3].reshape(hist.shape), flat[-3:]
 
     def to_scan(hist_full):
@@ -242,8 +268,7 @@ def make_data_parallel_comm(axis: str, plan=None) -> Comm:
 
     return Comm(
         reduce_hist=reduce_hist,
-        reduce_sums=lambda x: jax.lax.psum(
-            _count_collective("psum", x), axis),
+        reduce_sums=lambda x: _psum(x, axis, scopes.ROOT_COLLECTIVE),
         select_split=make_sharded_select(axis), vmap_safe=True,
         reduce_root=reduce_root, select_root=_serial_select,
         to_scan=to_scan)
@@ -257,7 +282,9 @@ def make_feature_parallel_comm(axis: str) -> Comm:
     parallel_tree_learner.h:190-213). 2 collectives per program: the
     root select's gather + the vmapped pair's batched gather."""
     return Comm(reduce_hist=lambda x: x, reduce_sums=lambda x: x,
-                select_split=make_sharded_select(axis), vmap_safe=True)
+                select_split=make_sharded_select(axis), vmap_safe=True,
+                select_root=make_sharded_select(
+                    axis, scopes.ROOT_COLLECTIVE))
 
 
 def make_voting_parallel_comm(axis: str, num_machines: int, top_k: int,
@@ -277,7 +304,7 @@ def make_voting_parallel_comm(axis: str, num_machines: int, top_k: int,
     pair."""
 
     def select(hist_local, g, h, c, meta, params, cmin, cmax, fmask,
-               rand_bins=None):
+               rand_bins=None, scope=scopes.SPLITS_COLLECTIVE):
         f = hist_local.shape[0]
         k = min(top_k, f)
         # local leaf totals (every feature's bins sum to the leaf)
@@ -294,8 +321,9 @@ def make_voting_parallel_comm(axis: str, num_machines: int, top_k: int,
         # ONE packed gather for the whole vote: [2k] = gains ++ ids
         buf = jnp.concatenate([_bitcast_i32(w_gain),
                                top_ids.astype(jnp.int32)])
-        rows = jax.lax.all_gather(
-            _count_collective("all_gather", buf), axis)
+        with jax.named_scope(scope):
+            rows = jax.lax.all_gather(
+                _count_collective("all_gather", buf, axis), axis)
         all_gain = _bitcast_f32(rows[:, :k]).reshape(-1)
         all_ids = rows[:, k:].reshape(-1)
         # per-feature max weighted gain over all candidates, then top-k
@@ -303,8 +331,7 @@ def make_voting_parallel_comm(axis: str, num_machines: int, top_k: int,
             jnp.where(jnp.isfinite(all_gain), all_gain, -jnp.inf))
         _, win_ids = jax.lax.top_k(feat_gain, k)
         # aggregate only the winning columns across the data shards
-        hist_sel = jax.lax.psum(
-            _count_collective("psum", hist_local[win_ids]), axis)
+        hist_sel = _psum(hist_local[win_ids], axis, scope)
         meta_sel = FeatureMeta(*[m[win_ids] for m in meta])
         fmask_sel = None if fmask is None else fmask[win_ids]
         rb_sel = None if rand_bins is None else rand_bins[win_ids]
@@ -315,9 +342,11 @@ def make_voting_parallel_comm(axis: str, num_machines: int, top_k: int,
         return assemble_split(pf_glob, b, feature_id=win_ids[b])
 
     return Comm(reduce_hist=lambda x: x,
-                reduce_sums=lambda x: jax.lax.psum(
-                    _count_collective("psum", x), axis),
-                select_split=select, vmap_safe=True, local_hist=True)
+                reduce_sums=lambda x: _psum(x, axis,
+                                            scopes.ROOT_COLLECTIVE),
+                select_split=select, vmap_safe=True, local_hist=True,
+                select_root=functools.partial(
+                    select, scope=scopes.ROOT_COLLECTIVE))
 
 
 # ---------------------------------------------------------------------

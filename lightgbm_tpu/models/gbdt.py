@@ -223,9 +223,8 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(),
     objective's query layout) that ``grad_fn`` takes after the score,
     ``valid_data`` the valid sets' binned matrices, ``bops`` the
     sampling's operands (``GBDT._bag_operands``), ``lops`` the
-    learner's (``grow_operands()``: the per-feature metadata; a mesh
-    learner hands over None and its metadata stays a constant, see
-    ``MeshPartitionedTreeLearner.grow_operands``). NOT
+    learner's (``grow_operands()``: the per-feature metadata, placed
+    replicated on a mesh learner's devices). NOT
     module-jitted: the learner captures the training matrix layout, so
     each booster wraps this in its OWN jax.jit (``GBDT._fused_block``)
     — the compiled-program cache then dies with the booster instead of

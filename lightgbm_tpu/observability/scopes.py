@@ -72,6 +72,14 @@ SPLIT_PHASE_SCOPES = (SPLITS_PARTITION, SPLITS_HIST, SPLITS_SCAN,
 SPLITS_DEBUNDLE = "lgbm.grow.splits.debundle"
 ROOT_DEBUNDLE = "lgbm.grow.root.debundle"
 BUNDLE_SCOPES = (SPLITS_DEBUNDLE, ROOT_DEBUNDLE)
+# a mesh learner's collectives (learner/comm.py), each scope around the
+# collective alone: per split the reduce-scatter of the smaller child's
+# histogram and the packed winner gather, children of GROW_SPLITS; the
+# root's packed psum of histogram and sums, a child of GROW_ROOT. A
+# one-chip program traces neither.
+SPLITS_COLLECTIVE = "lgbm.grow.splits.collective"
+ROOT_COLLECTIVE = "lgbm.grow.root.collective"
+COLLECTIVE_SCOPES = (SPLITS_COLLECTIVE, ROOT_COLLECTIVE)
 
 # host spans of the fused driver, on the profiler's clock
 # (Telemetry.span(..., trace=<name>))
@@ -93,7 +101,7 @@ DATA_BUNDLE = "lgbm.data.bundle"            # EFB of a dense table
 DATA_BUNDLE_PLAN = "lgbm.data.bundle_plan"
 DATA_EXTRACT = "lgbm.data.extract"
 DATA_LOAD_BINARY = "lgbm.data.load_binary"  # the read and the inflate
-DATA_SAVE_BINARY = "lgbm.data.save_binary"  # the deflate and the write
+DATA_SAVE_BINARY = "lgbm.data.save_binary"  # the write
 # one booster's set-up; the root is opened by GBDT._setup_train
 SETUP = "lgbm.setup"
 SETUP_LEARNER = "lgbm.setup.learner"        # create_tree_learner
@@ -114,7 +122,7 @@ PREFIX = "lgbm."
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_CALLS = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
 
 
 # a scope opened inside a transformed function is printed inside the
@@ -137,8 +145,10 @@ def parse_hlo_scopes(hlo_text: str) -> Dict[str, str]:
     ``lgbm.`` component of its ``op_name`` (the innermost
     ``named_scope`` it was traced under). One that has none of its own
     inherits the commonest scope among the instructions of the
-    computation it ``calls=`` (a fusion whose root lost its metadata);
-    one outside every scope is absent."""
+    computation it ``calls=`` (a fusion whose root lost its metadata)
+    or reduces with ``to_apply=`` (the TPU compiler rewrites a small
+    reduce-scatter as an all-reduce that keeps none, but its reduction
+    keeps the scope); one outside every scope is absent."""
     table: Dict[str, str] = {}
     in_computation: Dict[str, Counter] = {}
     callers: List[tuple] = []               # (instruction, callee)

@@ -35,6 +35,7 @@ iteration boundaries are already materialized by the caller.
 from __future__ import annotations
 
 import atexit
+import contextlib
 import json
 import os
 import threading
@@ -375,6 +376,19 @@ class Telemetry:
     def current_path(self) -> Optional[str]:
         """Path of the innermost span open on this thread."""
         return "/".join(self._stack()) or None
+
+    @contextlib.contextmanager
+    def under(self, path: Optional[str]):
+        """Spans opened on this thread inside the block are children of
+        ``path``, a span open on another thread: a worker's part of its
+        caller's phase (``current_path()`` as the caller read it)."""
+        stack = self._stack()
+        saved = stack[:]
+        stack[:] = path.split("/") if path else []
+        try:
+            yield
+        finally:
+            stack[:] = saved
 
     def span(self, name: str, phase: bool = False,
              trace: Optional[str] = None, ledger: bool = False,

@@ -99,26 +99,23 @@ def build_matrix(binned, blk: int = 2048) -> jnp.ndarray:
 
 
 def pack_gh(mat, num_features: int, grad, hess, cnt) -> jnp.ndarray:
-    """Write the gh payload columns for rows [0, len(grad))."""
-    f = num_features
-    planes = []
-    for v in (grad, hess):
-        u = jax.lax.bitcast_convert_type(v.astype(jnp.float32),
-                                         jnp.uint32)
-        planes += [((u >> jnp.uint32(8 * k)) & jnp.uint32(0xFF)).astype(
-            jnp.uint8) for k in range(4)]
-    planes.append((cnt > 0).astype(jnp.uint8))
-    payload = jnp.stack(planes, axis=1)            # [n, 9]
-    return jax.lax.dynamic_update_slice(mat, payload, (0, f))
+    """Write the gh payload columns for rows [0, len(grad)): each f32
+    as its four bytes, low byte first, then the 0/1 count. Bitcasts, not
+    shifted byte planes: XLA may lay each plane out as an [n, 1] column
+    padded to 128 lanes (9 GB at 6.5 M rows in the mesh learner's
+    program, compiled for the v5e)."""
+    payload = jnp.concatenate([
+        jax.lax.bitcast_convert_type(grad.astype(jnp.float32), jnp.uint8),
+        jax.lax.bitcast_convert_type(hess.astype(jnp.float32), jnp.uint8),
+        (cnt > 0).astype(jnp.uint8)[:, None]], axis=1)     # [n, 9]
+    return jax.lax.dynamic_update_slice(mat, payload, (0, num_features))
 
 
 def extract_row_ids(mat, num_features: int, n: int) -> jnp.ndarray:
-    """Recover i32 row ids from the payload columns (rows [0, n))."""
-    f = num_features
-    b = [mat[:n, f + RID_OFF + k].astype(jnp.uint32)
-         for k in range(4)]
-    return (b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)).astype(
-        jnp.int32)
+    """Recover i32 row ids from the payload columns (rows [0, n)): four
+    bytes, low byte first, read as one word."""
+    col = num_features + RID_OFF
+    return jax.lax.bitcast_convert_type(mat[:n, col:col + 4], jnp.int32)
 
 
 # the widest table whose whole rows go through ONE per-feature

@@ -25,13 +25,14 @@ per-host ingest, and the telemetry counters (``ingest.sharded_bytes``,
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import threading
+from typing import Callable, Optional, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .partition_rules import AXIS, mesh_shards
+from .partition_rules import AXIS, mesh_from_config, mesh_shards
 
 
 def host_row_range(num_rows: int, process_index: Optional[int] = None,
@@ -98,8 +99,91 @@ def pad_rows(arr: np.ndarray, n_pad: int) -> np.ndarray:
     return np.pad(arr, ((0, n_pad - n),) + ((0, 0),) * (arr.ndim - 1))
 
 
+def zeros_rows(shape, dtype, mesh: Mesh, *, axis: str = AXIS):
+    """A row-sharded array of zeros made on the devices themselves:
+    nothing crosses from the host (a scratch twin of a training
+    matrix)."""
+    import jax.numpy as jnp
+    sharding = NamedSharding(mesh, P(axis, *([None] * (len(shape) - 1))))
+    return jnp.zeros(shape, dtype, device=sharding)
+
+
 def replicate(arr, mesh: Mesh):
     """Replicated placement (feature-parallel's row matrix: the
     algorithm requires every shard to hold all rows)."""
     return jax.device_put(np.asarray(arr),
                           NamedSharding(mesh, P()))
+
+
+# -- a table built a row shard at a time ----------------------------------
+def row_shards(config) -> int:
+    """The row shards a booster on ``config`` trains over: the size of
+    the data- and voting-parallel learners' mesh (``mesh_from_config``),
+    1 for every other learner and where no such mesh can be made here.
+    Table construction bins one shard a worker by it, so a one-shard
+    table keeps one worker."""
+    if config.tree_learner not in ("data", "voting"):
+        return 1
+    from ..utils.log import LightGBMError
+    try:
+        return mesh_shards(mesh_from_config(config))
+    except LightGBMError:
+        return 1
+
+
+def shard_bounds(num_rows: int, d: int, s: int) -> Tuple[int, int]:
+    """``[lo, hi)`` of shard ``s``'s rows: ``ceil(num_rows / d)`` a
+    shard, the last ones short or empty (the mesh learners' padding,
+    ``parallel/learners.py``)."""
+    n_local = -(-int(num_rows) // d)
+    lo = min(s * n_local, num_rows)
+    return lo, min(lo + n_local, num_rows)
+
+
+def per_shard(d: int, work: Callable[[int], None]) -> None:
+    """``work(s)`` for every shard, one thread a shard (the caller's
+    thread alone where ``d`` is 1); the first shard's error, if any, is
+    raised once every thread has ended. Threads, not a pool: a pool
+    hands a second shard to a thread that finished its first."""
+    if d == 1:
+        work(0)
+        return
+    errors: list = [None] * d
+
+    def run(s: int) -> None:
+        try:
+            work(s)
+        except Exception as e:      # noqa: BLE001 - raised below
+            errors[s] = e
+    threads = [threading.Thread(target=run, args=(s,)) for s in range(d)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    for e in errors:
+        if e is not None:
+            raise e
+
+
+def row_blocks_of(binned: np.ndarray, d: int) -> np.ndarray:
+    """The segment learners' training matrices of ``d`` row shards,
+    ``[d, rows_local, cols]`` u8 (``ops/hist_pallas.py``'s layout),
+    filled a shard a worker: shard ``s`` holds the global rows
+    ``shard_bounds(n, d, s)`` in its first rows, their bin bytes first
+    and their GLOBAL row id in four bytes from ``width + RID_OFF``; its
+    padding rows up to ``ceil(n / d)`` carry ids >= n."""
+    from ..learner.partitioned import HIST_BLK
+    from ..ops.hist_pallas import RID_OFF, matrix_cols, matrix_rows
+    n, width = binned.shape
+    n_local = -(-int(n) // d)
+    mats = np.zeros((d, matrix_rows(n_local, HIST_BLK), matrix_cols(width)),
+                    np.uint8)
+    col = width + RID_OFF
+
+    def fill(s: int) -> None:
+        lo, hi = shard_bounds(n, d, s)
+        mats[s, :hi - lo, :width] = binned[lo:hi]
+        rid = (s * n_local + np.arange(n_local)).astype("<u4")
+        mats[s, :n_local, col:col + 4] = rid.view(np.uint8).reshape(-1, 4)
+    per_shard(d, fill)
+    return mats

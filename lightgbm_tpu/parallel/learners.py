@@ -394,10 +394,9 @@ class VotingParallelTreeLearner(_MeshLearnerBase):
                                      self._mv_sharded())
 
 
-from ..learner.partitioned import (HIST_BLK, PartitionedLearnerBase,
+from ..learner.partitioned import (PartitionedLearnerBase,
                                    PartitionedTreeLearner,
                                    grow_partitioned)
-from ..ops.hist_pallas import RID_OFF, matrix_cols, matrix_rows
 
 
 class MeshPartitionedTreeLearner(PartitionedLearnerBase):
@@ -443,6 +442,7 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
             self.comm = make_voting_parallel_comm(
                 AXIS, d, int(config.top_k), params_local)
             self._use_rs = False
+            self._plan = None
         else:
             # reduce-scatter recipe unless CEGB / forced splits need
             # the replicated global-feature histogram (learner/comm.py)
@@ -455,25 +455,14 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
         self.mode = mode
 
         with get_telemetry().setup_span(scopes.SETUP_DEVICE_TABLE) as sp:
-            # one training matrix per shard, rows carrying GLOBAL ids
-            rows_local = matrix_rows(self.n_local, HIST_BLK)
-            cols = matrix_cols(self.num_groups)
-            mats = np.zeros((d, rows_local, cols), np.uint8)
-            binned = np.asarray(dataset.binned, np.uint8)
-            g0 = self.num_groups
-            for s in range(d):
-                lo = s * self.n_local
-                hi = min(lo + self.n_local, n)
-                if hi > lo:
-                    mats[s, :hi - lo, :g0] = binned[lo:hi]
-                rid = (lo + np.arange(self.n_local)).astype(np.uint32)
-                for kk in range(4):
-                    mats[s, :self.n_local, g0 + RID_OFF + kk] = \
-                        ((rid >> np.uint32(8 * kk)) & 0xFF).astype(np.uint8)
+            # one training matrix per shard, rows carrying GLOBAL ids,
+            # filled a shard a worker
+            mats = ingest.row_blocks_of(np.asarray(dataset.binned, np.uint8),
+                                        d)
             # sharded ingest: shards transfer host->device individually,
             # never materializing the full matrix in one HBM
             self.mat = ingest.shard_rows(mats, self.mesh)
-            self.ws = ingest.shard_rows(np.zeros_like(mats), self.mesh)
+            self.ws = ingest.zeros_rows(mats.shape, mats.dtype, self.mesh)
             sp.set(bytes=2 * self.mat.nbytes)
         self._build()
 
@@ -486,21 +475,21 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
         # resolved once (learner/split_step.py): per-phase body (the
         # collectives sit between the phases), LUT partition by table
         plan = self.split_plan()
+        feat_plan = self._plan
         if use_rs:
-            meta_l = shard_arrays(self.mesh, self._mode,
-                                  {"meta_local": self._plan.meta_local}
-                                  )["meta_local"]
             bn_floor, bn_rem, bn_cap = split_bynode_budget(
                 self.bynode_count, self.num_shards)
-            self._grow_extra = (meta_l,)
-        else:
-            self._grow_extra = ()
+        # the metadata is an ARGUMENT of every mesh program, placed
+        # replicated once: the shard's permuted slice is computed from
+        # it inside the program (``FeatureShardPlan.shard_meta``)
+        self._meta_dev = shard_arrays(self.mesh, self._mode,
+                                      {"meta": self.meta})["meta"]
 
-        def grow_shard(*args, leaf_parts):
+        def grow_shard(mat3, ws3, meta, grad, hess, bag, fmask, rkey,
+                       cegb0, *, leaf_parts):
             if use_rs:
-                (mat3, ws3, meta_loc, grad, hess, bag, fmask, rkey,
-                 cegb0) = args
                 idx = jax.lax.axis_index(AXIS)
+                meta_loc = feat_plan.shard_meta(meta, idx)
                 ctx = ShardScanCtx(
                     meta=meta_loc,
                     fmask=local_feature_mask(meta_loc, fmask, f),
@@ -509,11 +498,10 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
                                   + (idx < bn_rem).astype(jnp.int32)),
                     bynode_cap=bn_cap)
             else:
-                mat3, ws3, grad, hess, bag, fmask, rkey, cegb0 = args
                 ctx = None
             base = jax.lax.axis_index(AXIS) * n_local
             out = grow_partitioned(
-                mat3[0], ws3[0], grad, hess, bag, fmask, self.meta,
+                mat3[0], ws3[0], grad, hess, bag, fmask, meta,
                 rand_key=rkey, params=self.params,
                 num_leaves=self.num_leaves, max_depth=self.max_depth,
                 num_bins_max=self.num_bins_max,
@@ -538,11 +526,8 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
             mat_l, ws_l, tree, leaf_id = out
             return mat_l[None], ws_l[None], tree, leaf_id
 
-        names = {"mat": 3, "ws": 3}
-        if use_rs:
-            names["meta_local"] = 1
-        names.update(grad=1, hess=1, bag_weight=1, feature_mask=1,
-                     rand_key=2, cegb_used=1)
+        names = dict(mat=3, ws=3, meta=1, grad=1, hess=1, bag_weight=1,
+                     feature_mask=1, rand_key=2, cegb_used=1)
 
         def mk_mapped(leaf_parts):
             lid_spec = spec_for(self._mode, "leaf_id")
@@ -582,7 +567,7 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
             if getattr(self, "_cegb_used", None) is not None \
             else jnp.zeros((self.num_features,), bool)
         self.mat, self.ws, tree, leaf_id = self._fn(
-            self.mat, self.ws, *self._grow_extra, grad, hess,
+            self.mat, self.ws, self._meta_dev, grad, hess,
             bag_weight, feature_mask, rkey, cegb0)
         res = GrowResult(tree=tree, leaf_id=leaf_id[:n])
         self._cegb_after_tree(res)
@@ -597,17 +582,20 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
                 and getattr(self, "_cegb_used", None) is None)
 
     def grow_operands(self):
-        """None: the metadata, and the feature plan drawn from it, are
-        read inside the mesh program's ``shard_map`` (``_build``), where
-        they stay constants. So a mesh learner's fused block is compiled
-        anew for every table; only the single-chip learner's is not."""
-        return None
+        """The per-feature metadata, placed replicated on the mesh: an
+        enclosing compiled program takes it as an ARGUMENT
+        (``models/gbdt.py`` ``_fused_iter_block``) and hands it back as
+        ``meta``. The reduce-scatter recipe's per-shard slice is drawn
+        from it inside the program, by the plan's static permutation,
+        so two tables of one shape compile to one mesh program."""
+        return self._meta_dev
 
     def traceable_grow(self, mat, ws, grad, hess, bag=None, *, meta):
-        """One mesh-parallel tree inside an enclosing trace (``meta``
-        is not read: see ``grow_operands``). Returns ``(mat, ws, tree,
-        (global_row_ids, pos_value))`` with padded entries carrying ids
-        >= num_data (dropped by the caller's scatter-add)."""
+        """One mesh-parallel tree inside an enclosing trace; ``meta``
+        is ``grow_operands()`` as the caller holds it. Returns ``(mat,
+        ws, tree, (global_row_ids, pos_value))`` with padded entries
+        carrying ids >= num_data (dropped by the caller's
+        scatter-add)."""
         n = self.dataset.num_data
         if bag is None:
             bag = jnp.ones((n,), jnp.float32)
@@ -620,8 +608,7 @@ class MeshPartitionedTreeLearner(PartitionedLearnerBase):
         rkey = jnp.zeros((2, 2), jnp.uint32)
         cegb0 = jnp.zeros((self.num_features,), bool)
         mat, ws, tree, rids, pos_value = self._mapped_parts(
-            mat, ws, *self._grow_extra, grad, hess, bag, fmask, rkey,
-            cegb0)
+            mat, ws, meta, grad, hess, bag, fmask, rkey, cegb0)
         return mat, ws, tree, (rids, pos_value)
 
 

@@ -184,6 +184,19 @@ class FeatureShardPlan(NamedTuple):
     col_perm: np.ndarray   # [g_pad] int64 global group of each slot
     col_live: np.ndarray   # [g_pad] bool live slots
     feat_perm: np.ndarray  # [f_pad] int64 global feature (-1 = pad)
+    feat_col: np.ndarray   # [f_pad] int32 LOCAL column of each slot
+
+    def shard_meta(self, meta: FeatureMeta, idx) -> FeatureMeta:
+        """Shard ``idx``'s ``[f_local]`` slice of ``meta_local``,
+        computed from a (possibly traced) global ``meta``: the
+        permutation is the plan's, the values are the table's, so a
+        compiled program that takes ``meta`` as an argument holds
+        nothing of the table (docs/ARCHITECTURE.md, "What a compiled
+        program may hold")."""
+        full = _permute_meta(meta, self.feat_perm, self.feat_col,
+                             meta.num_bins.shape[0])
+        return FeatureMeta(*(jax.lax.dynamic_slice_in_dim(
+            a, idx * self.f_local, self.f_local) for a in full))
 
     def permute_hist(self, hist: jnp.ndarray) -> jnp.ndarray:
         """[G, B, 3] group histogram -> [g_pad, B, 3] in shard-slice
@@ -202,16 +215,18 @@ class FeatureShardPlan(NamedTuple):
 
 
 def _permute_meta(meta: FeatureMeta, perm: np.ndarray,
-                  local_col_of_feat: np.ndarray, f: int) -> FeatureMeta:
+                  feat_col: np.ndarray, f: int) -> FeatureMeta:
     """Permuted/padded per-shard scan meta: ``perm`` lists the global
-    feature of each scan slot (-1 = never-splittable padding)."""
+    feature of each scan slot (-1 = never-splittable padding),
+    ``feat_col`` its LOCAL column. Traceable: ``meta`` may be a traced
+    argument; the permutation is static."""
     live = perm >= 0
     safe = np.where(live, perm, 0)
 
     def take(arr, pad_value, dtype=None):
-        a = np.asarray(arr)
-        out = np.where(live, a[safe], pad_value)
-        return jnp.asarray(out if dtype is None else out.astype(dtype))
+        a = jnp.asarray(arr)
+        out = jnp.where(live, a[safe], pad_value)
+        return out if dtype is None else out.astype(dtype)
 
     return FeatureMeta(
         num_bins=take(meta.num_bins, 2),
@@ -222,8 +237,7 @@ def _permute_meta(meta: FeatureMeta, perm: np.ndarray,
         penalty=take(meta.penalty, 1.0, np.float32),
         is_categorical=take(meta.is_categorical, False),
         # LOCAL column index inside the shard's histogram slice
-        group=jnp.asarray(np.where(
-            live, local_col_of_feat[safe], 0).astype(np.int32)),
+        group=jnp.asarray(feat_col),
         offset=take(meta.offset, 0),
         cegb_coupled_penalty=take(meta.cegb_coupled_penalty, 0.0,
                                   np.float32),
@@ -269,12 +283,14 @@ def plan_feature_shards(meta: FeatureMeta, num_features: int,
             [feat_of_group[g] for g in sg]).astype(np.int64)) \
             if sg else np.zeros(0, np.int64)
         perm[s * f_local:s * f_local + len(fl)] = fl
-    meta_local = _permute_meta(meta, perm, local_col_of_group[groups],
-                               num_features)
+    feat_col = np.where(perm >= 0, local_col_of_group[groups][
+        np.maximum(perm, 0)], 0).astype(np.int32)
+    meta_local = _permute_meta(meta, perm, feat_col, num_features)
     return FeatureShardPlan(d=d, f_local=f_local, f_pad=f_pad,
                             g_local=g_local, g_pad=g_pad,
                             meta_local=meta_local, col_perm=col_perm,
-                            col_live=col_live, feat_perm=perm)
+                            col_live=col_live, feat_perm=perm,
+                            feat_col=feat_col)
 
 
 def local_feature_mask(meta_local: FeatureMeta, feature_mask,

@@ -195,3 +195,21 @@ def test_four_shard_stage_tiny(fuse_iters, monkeypatch):
         interpret=True, megakernel=False, shards=4)
     shards = cs.stage_shards(bst, 4000, 4)
     assert shards["shard_devices"] == [0, 1, 2, 3]
+
+
+def test_data_parallel_stage_tiny(fuse_iters, monkeypatch):
+    """The four-chip stage at the Criteo width on the virtual CPU mesh
+    (the factory routed as on a TPU, kernels in interpret mode): the
+    table binned a shard a worker, trained over four shards, and the
+    three collectives counted with the bytes a chip sends; skipped on
+    a host of fewer chips."""
+    import lightgbm_tpu.parallel.learners as learners
+    assert "skipped" in cs.stage_data_parallel(1)
+    monkeypatch.setattr(learners, "on_tpu", lambda: True)
+    report = cs.stage_data_parallel(4, rows=3000, params=TINY,
+                                    interpret=True)
+    assert report["learner"] == "MeshPartitionedTreeLearner"
+    assert report["num_shards"] == 4 and report["trees"] == cs.DP_ROUNDS
+    assert report["shards"]["shard_devices"] == [0, 1, 2, 3]
+    assert report["comm"]["comm.psum_scatter_calls"] >= 1
+    assert len(report["model_sha256"]) == 16

@@ -26,6 +26,12 @@ HLO = '''HloModule jit_gbdt_fused_block, is_scheduled=true
   ROOT %copy.9 = f32[8]{0} copy(%mul.2)
 }
 
+%region_3.2 (reduce_scatter.1: f32[], reduce_scatter.2: f32[]) -> f32[] {
+  %reduce_scatter.2 = f32[] parameter(1)
+  %reduce_scatter.1 = f32[] parameter(0)
+  ROOT %add.7 = f32[] add(%reduce_scatter.1, %reduce_scatter.2), metadata={op_name="lgbm.grow.splits/while/body/lgbm.grow.splits.hist/lgbm.grow.splits.collective/add"}
+}
+
 %region_0.1 (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
   %arg = (s32[], f32[8]{0}) parameter(0)
   %fusion.87 = s32[8]{0} fusion(%arg), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(gbdt_fused_block)/while/body/closed_call/lgbm.grow/lgbm.grow.leaf_of_pos/jit(searchsorted)/while/body/select_n"}
@@ -33,6 +39,7 @@ HLO = '''HloModule jit_gbdt_fused_block, is_scheduled=true
   %custom-call.2 = f32[8]{0} custom-call(%fusion.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(gbdt_fused_block)/while/body/closed_call/lgbm.grow/lgbm.grow.splits/while/body/jit(fused_split_step_segment)/pallas_call"}
   %add.5 = s32[] add(%arg, %arg), metadata={op_name="jit(gbdt_fused_block)/while/body/add"}
   %copy.4 = f32[8]{0} copy(%fusion.7)
+  %all-reduce.3 = f32[8]{0} all-reduce(%copy.4), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%region_3.2
   ROOT %tuple.1 = (s32[], f32[8]{0}) tuple(%add.5, %copy.4)
 }
 
@@ -52,6 +59,9 @@ ENTRY %main.3 (x: f32[8]) -> f32[8] {
     ("grad.1", scopes.GRADIENTS),
     # a fusion without op_name inherits from the computation it calls
     ("fusion.7", scopes.GROW_PACK),
+    # a collective the compiler rewrote without op_name (a small
+    # reduce-scatter made an all-reduce) inherits from its reduction
+    ("all-reduce.3", scopes.SPLITS_COLLECTIVE),
     # outside every scope, with and without an op_name: absent
     ("add.5", None), ("copy.4", None), ("copy.9", None),
     ("tuple.1", None), ("x", None),
@@ -257,19 +267,21 @@ def test_one_block_opens_its_three_spans_in_order(tel, monkeypatch):
 # painting the leaf's value, ISSUE 36 (the leaf-value gather over the
 # positions went and one over the num_leaves sorted segments came; the
 # f32 table goes through the pass as int32 words: three
-# bitcast-converts, two small fusions)
+# bitcast-converts, two small fusions), and for the gh payload written
+# and the row ids read as bitcasts, not as shifted byte planes
+# (ops/hist_pallas.py pack_gh, extract_row_ids: seven fusions fewer)
 PARENT_OPCODES = {
-    "abs": 20, "add": 457, "and": 142, "bitcast": 808,
-    "bitcast-convert": 77, "broadcast": 417, "clamp": 2, "compare": 392,
-    "concatenate": 32, "conditional": 15, "constant": 1158, "convert": 205,
+    "abs": 20, "add": 457, "and": 112, "bitcast": 786,
+    "bitcast-convert": 78, "broadcast": 348, "clamp": 2, "compare": 376,
+    "concatenate": 32, "conditional": 15, "constant": 1097, "convert": 146,
     "copy": 183, "divide": 12, "dot": 19, "dynamic-slice": 93,
-    "dynamic-update-slice": 222, "exponential": 1, "fusion": 480,
-    "gather": 9, "get-tuple-element": 273, "iota": 39, "is-finite": 4,
-    "maximum": 25, "minimum": 13, "multiply": 143, "negate": 121, "not": 4,
-    "or": 44, "pad": 20, "parameter": 1183, "reduce": 12,
+    "dynamic-update-slice": 222, "exponential": 1, "fusion": 473,
+    "gather": 9, "get-tuple-element": 273, "iota": 42, "is-finite": 4,
+    "maximum": 25, "minimum": 13, "multiply": 140, "negate": 121, "not": 4,
+    "or": 13, "pad": 20, "parameter": 1165, "reduce": 14,
     "reduce-window": 8, "remainder": 1, "reverse": 2, "scatter": 2,
-    "select": 358, "shift-left": 33, "shift-right-logical": 37, "sign": 45,
-    "slice": 709, "sort": 1, "subtract": 102, "transpose": 8, "tuple": 42}
+    "select": 350, "shift-left": 2, "shift-right-logical": 31, "sign": 45,
+    "slice": 653, "sort": 1, "subtract": 102, "transpose": 8, "tuple": 42}
 _OPCODE = re.compile(
     r"^\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(?:\([^=]*?\)|\S+)\s+([a-z\-]+)\(")
 

@@ -664,7 +664,7 @@ def _b_mesh_partitioned_grow():
     rkey = jnp.zeros((2, 2), jnp.uint32)
     cegb0 = jnp.zeros((lrn.num_features,), bool)
     return _spec_fn("mesh_partitioned_grow").lower(
-        lrn.mat, lrn.ws, *lrn._grow_extra, grad, hess, bag, fmask,
+        lrn.mat, lrn.ws, lrn.grow_operands(), grad, hess, bag, fmask,
         rkey, cegb0)
 
 

@@ -297,10 +297,11 @@ def digest(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     for k, v in counters_all.items():
         if not k.startswith("comm."):
             continue
-        for suffix in ("_bytes", "_calls"):
+        for suffix in ("_sent_bytes", "_bytes", "_calls"):
             if k.endswith(suffix):
                 op = k[len("comm."):-len(suffix)]
                 comms.setdefault(op, {})[suffix[1:]] = float(v)
+                break
     ingest = {k.split(".", 1)[1]: v for k, v in counters_all.items()
               if k.startswith("ingest.")}
 
