@@ -143,6 +143,16 @@ DP_ROWS = 500_000
 DP_FEATURES = 67
 DP_CHIPS = 4
 DP_ROUNDS = 5           # the sync first iteration plus a block of 4
+# each training stage's trees as the parent of the rows' score printed
+# them (model_sha256; PERF.md section 6, jax 0.9.0, libtpu 0.0.34): the
+# rows make the same gradients and add the same leaf values, so a
+# change there that moves a hash has changed the arithmetic
+PARENT_SHA256 = {"train": "1a874763a150c64e",
+                 "second_table": "c1db37e77b67785a",
+                 "categorical": "533734517965fe0e",
+                 "wide": "25c6e7809d2f48b2",
+                 "bundled": "c672c6e1ffd4049e",
+                 "rank": "77e21a4f0d4321d2"}
 
 
 def device_report() -> dict:
@@ -286,7 +296,10 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     in query groups of these sizes and ``y`` holds relevance grades, so
     the objective's counters must say that its query layout follows
     the documents, and the model is judged by NDCG@10 (the AUC is that
-    of "relevant at all")."""
+    of "relevant at all"). Every stage's fused block keeps the score
+    and labels in the matrix's rows (``models/gbdt.py`` ``RowScore``)
+    but a ranking stage's and the mesh learner's, which must have been
+    traced on the gather path."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.observability.telemetry import get_telemetry
     from lightgbm_tpu.ops.leaf_of_pos import uses_block_pass
@@ -303,7 +316,9 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
                "kernels.partition_pipelined",
                "kernels.partition_one_compaction",
                "kernels.hist_child_stream",
-               "kernels.hist_feature_slices")}
+               "kernels.hist_feature_slices",
+               "learner.score_in_row_traces",
+               "learner.score_in_row_refused")}
     t0 = time.perf_counter()
     bst = lgb.train(dict(params), lgb.Dataset(x, label=y, group=group),
                     num_boost_round=rounds)
@@ -350,6 +365,10 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
         # the sliced one, the megakernel's second stream
         "hist_child_stream": delta["kernels.hist_child_stream"],
         "fused_block_hits": delta["fused.block_hits"],
+        # fused blocks traced with the score in the rows, and on the
+        # gather path
+        "score_in_row": delta["learner.score_in_row_traces"],
+        "score_in_row_refused": delta["learner.score_in_row_refused"],
         "trees": len(leaves),
         "min_leaves": min(leaves),
         "max_leaves": max(leaves),
@@ -378,6 +397,15 @@ def stage_train(x, y, params, rounds: int, *, learner: str,
     assert report["leaf_value_pass_traces"] > 0 or interpret, report
     assert report["fused_block_hits"] > 0, \
         "_train_fused_blocks did not run"
+    # counted where a fused block is traced (a process that had traced
+    # the same shapes before counts nothing, which interpret mode
+    # allows); the path a booster takes is also on the booster
+    score_in_row = learner == "PartitionedTreeLearner" and group is None
+    assert (gbdt._fused_rows is not None) is score_in_row, report
+    assert report["score_in_row" if score_in_row
+                  else "score_in_row_refused"] > 0 or interpret, report
+    assert report["score_in_row_refused" if score_in_row
+                  else "score_in_row"] == 0, report
     assert report["trees"] == rounds, report
     assert report["min_leaves"] > 1, report
     assert report["num_shards"] == shards, report
@@ -480,12 +508,14 @@ def stage_second_table(rounds: int, rows: int = ROWS) -> dict:
         "fused_block_hits": int(tel.counters.get("fused.block_hits", 0)
                                 - blocks),
         "trees": len(leaves), "min_leaves": min(leaves),
+        "score_in_row": bst._gbdt._fused_rows is not None,
         "auc": round(train_auc(bst, x, y), 6),
         "train_seconds": round(seconds, 1),
         "model_sha256": hashlib.sha256(
             bst.model_to_string().encode()).hexdigest()[:16]}
     print(f"second_table: {json.dumps(report)}", flush=True)
     assert report["fused_block_hits"] > 0, report
+    assert report["score_in_row"], report
     assert report["trees"] == rounds and report["min_leaves"] > 1, report
     assert report["auc"] >= MIN_AUC, report
     for p, seen in report["cache"].items():
@@ -674,6 +704,15 @@ def cache_report(seen: dict) -> dict:
             "persistent_cache_misses": seen["misses"]}
 
 
+def parent_sha256(report: dict) -> dict:
+    """Whether each training stage's ``model_sha256`` is the parent's
+    (``PARENT_SHA256``), by stage."""
+    seen = {stage: report[stage]["model_sha256"] == want
+            for stage, want in PARENT_SHA256.items()}
+    print(f"parent_sha256: {json.dumps(seen)}", flush=True)
+    return seen
+
+
 def result_line(device: dict) -> str:
     """The last line of stdout: exactly ``ok`` and the device as JAX
     reports it. Everything else observed is on the ``report:`` line."""
@@ -783,9 +822,15 @@ def main(argv=None) -> int:
         assert gap <= FOIL_AUC_TOL, ("four chips vs one chip", gap)
     report["data_parallel"] = stage_data_parallel(device["count"])
     report["compile_cache"] = cache_report(cache_events)
+    if args.devices == 1:
+        report["parent_sha256"] = parent_sha256(report)
     report["seconds"] = round(time.perf_counter() - t_start, 1)
     report["claim"] = None
     print(f"report: {json.dumps(report)}", flush=True)
+    # after the report, so that a moved hash leaves every stage's
+    # numbers printed
+    assert all(report.get("parent_sha256", {}).values()), \
+        report["parent_sha256"]
     print(result_line(device), flush=True)
     return 0
 

@@ -39,7 +39,7 @@ from ..observability.telemetry import get_telemetry
 from ..utils.device import on_tpu
 from ..utils.jit_registry import register_jit
 from ..ops.hist_pallas import (build_matrix, extract_row_ids,
-                               histogram_segment, pack_gh)
+                               histogram_segment, pack_gh, row_words)
 from ..ops.leaf_of_pos import leaf_of_pos, uses_block_pass
 from ..ops.partition_pallas import (partition_decision_lut,
                                     partition_segment)
@@ -239,13 +239,21 @@ class PartitionedTreeLearner(PartitionedLearnerBase):
         and hands back as ``meta``."""
         return self.meta
 
-    def traceable_grow(self, mat, ws, grad, hess, bag=None, *, meta):
+    def row_words(self) -> int:
+        """The 4-byte words each matrix row has spare for the fused
+        driver's in-row score and operands (``models/gbdt.py``
+        ``RowScore``)."""
+        return row_words(self.num_groups)
+
+    def traceable_grow(self, mat, ws, grad, hess, bag=None, *, meta,
+                       row_score=None, leaf_scale=None):
         """One tree grown inside an enclosing trace (no jit boundary,
         no host state updates). Caller owns the mat/ws carry; ``meta``
         is ``grow_operands()`` as the caller holds it. Returns ``(mat, ws, tree, (row_ids,
         pos_value))`` — leaf parts, not a materialized leaf_id (see
-        return_leaf_parts)."""
-        if bag is None:
+        return_leaf_parts). ``row_score``, ``leaf_scale``: see
+        ``grow_partitioned``."""
+        if bag is None and row_score is None:
             bag = jnp.ones_like(grad)
         fmask = jnp.ones((self.num_features,), bool)
         return grow_partitioned(
@@ -259,7 +267,8 @@ class PartitionedTreeLearner(PartitionedLearnerBase):
             cache_hists=self.cache_hists, hist_slots=self.hist_slots,
             has_monotone=self.has_monotone,
             split_fusion=split_fusion_default(),
-            plan=self.split_plan(), return_leaf_parts=True)
+            plan=self.split_plan(), return_leaf_parts=True,
+            row_score=row_score, leaf_scale=leaf_scale)
 
 
 @register_jit("partitioned_grow", donate=(0, 1))
@@ -301,7 +310,7 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
                      cegb_used0=None, hist_slots=None,
                      has_monotone=True, split_fusion=None,
                      plan: SplitStepPlan, return_leaf_parts=False,
-                     body_scan=None):
+                     body_scan=None, row_score=None, leaf_scale=None):
     """Traceable partitioned grow loop.
 
     ``plan`` (learner/split_step.py ``plan_split_step``, resolved by
@@ -320,7 +329,16 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
     nothing larger is ever needed). ``body_scan`` (ShardScanCtx)
     switches per-split scans onto the column-sharded local context of
     the data-parallel reduce-scatter recipe (learner/comm.py) while
-    the root scan stays replicated.
+    the root scan stays replicated. ``row_score`` (``[n]`` f32): the
+    rows carry their score (``models/gbdt.py`` ``RowScore``), and it,
+    ``grad`` and ``hess`` are by POSITION of ``mat``, every row in the
+    bag: the pack writes the three into the rows where they are, no
+    gather.
+    ``leaf_scale`` (with ``return_leaf_parts``): ``pos_value`` is the
+    leaf's value times it, 0 where the tree did not split: the products
+    are rounded on the ``num_leaves`` table, so the caller's add takes
+    the same f32 values as ``pos_value * scale`` would give it, with no
+    multiply beside the add for a compiler to contract to one rounding.
 
     Returns ``(mat, ws, tree, leaf_id)``, ``leaf_id`` int32 ``[n]`` by
     LOCAL row. ``return_leaf_parts=True`` returns in its place the pair
@@ -343,22 +361,29 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
     big_l = num_leaves
 
     with jax.named_scope(scopes.GROW_PACK):
-        # repack the gh payload in current row order (rows carry their id).
-        # ONE row gather of the stacked [N, 3] table instead of three
-        # element gathers: the random-access stream is the cost on TPU, so
-        # fetching 12 contiguous bytes per index beats three 4-byte passes
-        rids = extract_row_ids(mat, f, mat.shape[0])
-        local = jnp.arange(mat.shape[0]) < n        # padding rows: all-zero
-        lrid = rids - row_id_base
-        rid_ok = local & (lrid >= 0) & (lrid < grad.shape[0]) \
-            & (rids < n_total)
-        rc_idx = jnp.clip(lrid, 0, grad.shape[0] - 1)
-        ghb = jnp.stack([grad, hess, bag_weight], axis=1)     # [N, 3]
-        vals = jnp.where(rid_ok[:, None], ghb[rc_idx], 0.0)
-        cp = vals[:, 2]
-        gp = vals[:, 0] * cp
-        hp = vals[:, 1] * cp
-        mat = pack_gh(mat, f, gp, hp, cp)
+        if row_score is not None:
+            # rows [0, n) in place; the padding rows past them keep the
+            # all-zero payload they were built with
+            mat = pack_gh(mat, f, grad, hess, jnp.ones_like(grad),
+                          score=row_score)
+        else:
+            # repack the gh payload in current row order (rows carry
+            # their id). ONE row gather of the stacked [N, 3] table
+            # instead of three element gathers: the random-access stream
+            # is the cost on TPU, so fetching 12 contiguous bytes per
+            # index beats three 4-byte passes
+            rids = extract_row_ids(mat, f, mat.shape[0])
+            local = jnp.arange(mat.shape[0]) < n    # padding: all-zero
+            lrid = rids - row_id_base
+            rid_ok = local & (lrid >= 0) & (lrid < grad.shape[0]) \
+                & (rids < n_total)
+            rc_idx = jnp.clip(lrid, 0, grad.shape[0] - 1)
+            ghb = jnp.stack([grad, hess, bag_weight], axis=1)  # [N, 3]
+            vals = jnp.where(rid_ok[:, None], ghb[rc_idx], 0.0)
+            cp = vals[:, 2]
+            gp = vals[:, 0] * cp
+            hp = vals[:, 1] * cp
+            mat = pack_gh(mat, f, gp, hp, cp)
 
     def seg_hist(m, begin, count):
         return comm.reduce_hist(histogram_segment(
@@ -892,12 +917,17 @@ def grow_partitioned(mat, ws, grad, hess, bag_weight, feature_mask, meta,
         rids_final = jnp.clip(
             extract_row_ids(st["mat"], f, mat.shape[0])[:n] - row_id_base,
             0, n - 1)
-        # fused path: the pass paints the leaf's VALUE, so the caller's
-        # score update is ONE scatter-add that reads no table by
-        # position; the un-fused return wants the leaf itself
+        # fused path: the pass paints the leaf's VALUE (times
+        # ``leaf_scale`` where the rows carry the score), so the
+        # caller's score update reads no table by position; the
+        # un-fused return wants the leaf itself
+        value = vf["leaf_value"]
+        if leaf_scale is not None:
+            value = value * jnp.where(st["k"] > 1, leaf_scale,
+                                      jnp.float32(0.0))
         painted = leaf_of_pos(
             vf["leaf_begin"], vf["leaf_cnt"], st["k"],
-            vf["leaf_value"] if return_leaf_parts else None, n=n,
+            value if return_leaf_parts else None, n=n,
             interpret=interpret)
         if return_leaf_parts:
             if dense:   # the search side reads its entries by position

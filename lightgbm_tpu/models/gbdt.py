@@ -32,6 +32,8 @@ from ..observability import scopes
 from ..observability.telemetry import get_telemetry, memory_snapshot
 from ..observability.tracing import (get_tracer, profile_boundary,
                                      profile_close)
+from ..ops.hist_pallas import (extract_row_ids, read_row_words,
+                               write_row_words)
 from ..robustness.guards import NonFiniteGradientError
 from ..utils.device import on_tpu
 from ..utils.jit_registry import register_dynamic, register_jit
@@ -205,9 +207,90 @@ def _named(name, fn):
     return named
 
 
+class RowScore:
+    """The training score and the objective's per-row operands kept in
+    the training matrix's rows: a 4-byte word each from column
+    ``F + WORD_OFF`` (``ops/hist_pallas.py``), bytes the row has spare.
+    The one-chip partitioned learner's partition moves whole rows, so
+    the words travel with their row, and the fused driver makes the
+    gradients where the row sits and adds each tree's leaf values by
+    position (``_fused_iter_block``): no gather of the gradients into
+    the matrix's order, no scatter-add of the leaf values into the
+    rows'. ``GBDT.train_score`` stays the row-order array every caller
+    reads, rebuilt from the rows once a fused block.
+
+    The rows' score lags one tree: the last tree's leaf values wait, by
+    position, in ``pending`` ``[n]`` f32, and the next pass over the
+    rows adds them as it makes the gradients and writes score,
+    gradients and hessians back in one go (one pass over the rows a
+    tree, where an add of its own would be a second)."""
+
+    def __init__(self, num_features: int, n: int, gops):
+        leaves, self.treedef = jax.tree.flatten(gops)
+        self.dtypes = tuple(x.dtype for x in leaves)
+        self.f = num_features
+        self.n = n
+        self.words = 1 + len(leaves)
+
+    @staticmethod
+    def fits(learner, k: int, objective, gops, n: int, sampled) -> bool:
+        """Whether the rows can carry the score: the learner's matrix
+        has ``row_words`` to spare (the one-chip partitioned learner's
+        has; a mesh learner's score is replicated), one tree an
+        iteration, an objective whose gradient of a row reads that row
+        alone from operands that are ``[n]`` words, and no sampler
+        (its draws are by row, not by position)."""
+        leaves = jax.tree.leaves(gops)
+        words = getattr(learner, "row_words", None)
+        return (words is not None and k == 1 and not sampled
+                and getattr(objective, "rowwise_gradients", False)
+                and all(x.shape == (n,) and x.dtype.itemsize == 4
+                        for x in leaves)
+                and words() >= 1 + len(leaves))
+
+    def _score(self, words, pending):
+        return jax.lax.bitcast_convert_type(words[:, 0],
+                                            jnp.float32) + pending
+
+    def read(self, mat, pending):
+        """``(score, *gops)`` by POSITION: the rows' words, the pending
+        leaf values added to the score."""
+        w = read_row_words(mat, self.f, self.n, self.words)
+        leaves = [jax.lax.bitcast_convert_type(w[:, 1 + i], dt)
+                  for i, dt in enumerate(self.dtypes)]
+        return (self._score(w, pending),
+                *jax.tree.unflatten(self.treedef, leaves))
+
+    def rebuild(self, mat, score, pending):
+        """``score`` [n, 1] set from the rows' scores, the pending leaf
+        values added, by their row ids: one scatter-set a block."""
+        rids = extract_row_ids(mat, self.f, self.n)
+        s = self._score(read_row_words(mat, self.f, self.n, 1), pending)
+        return score.at[rids, 0].set(s)
+
+    def seed(self, mat, score, gops):
+        """The row-order ``score`` and ``gops`` written into the rows
+        (one gather by the row id each row carries) and nothing
+        pending: ``(mat, pending)``."""
+        return _seed_row_words(mat, score, jax.tree.leaves(gops),
+                               f=self.f, n=self.n)
+
+
+@register_jit("seed_row_words", donate=(0,))
+@functools.partial(jax.jit, static_argnames=("f", "n"),
+                   donate_argnums=(0,))
+def _seed_row_words(mat, score, leaves, *, f: int, n: int):
+    table = jnp.stack(
+        [jax.lax.bitcast_convert_type(x, jnp.uint32)
+         for x in [score[:, 0], *leaves]], axis=1)       # [n, words]
+    rids = jnp.clip(extract_row_ids(mat, f, n), 0, n - 1)
+    return (write_row_words(mat, f, table[rids]),
+            jnp.zeros((n,), jnp.float32))
+
+
 def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(),
                       valid_data=(), bops=(), lops=None, *, learner,
-                      grad_fn, bag_fn, m, k):
+                      grad_fn, bag_fn, m, k, rows=None):
     """``m`` boosting iterations as one device program (lax.scan over
     gradients -> [sampling] -> grow -> score update; ``k`` trees per
     iteration for multiclass; ``bag_fn(it, grad, hess, *bops)``
@@ -228,13 +311,36 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(),
     module-jitted: the learner captures the training matrix layout, so
     each booster wraps this in its OWN jax.jit (``GBDT._fused_block``)
     — the compiled-program cache then dies with the booster instead of
-    pinning its device buffers in a process-lifetime module cache."""
+    pinning its device buffers in a process-lifetime module cache.
+
+    ``rows`` (a ``RowScore``): the rows carry the score and ``gops``
+    (``GBDT._fused_block_args`` seeds them), so the gradients are
+    made by position from the rows, each tree's leaf values are added
+    to the rows' scores by position, and the row-order score is rebuilt
+    from the rows once, at the block's end. ``score`` is then the pair
+    ``(score, pending)`` (``RowScore``), and ``gops`` is not read."""
     meta = learner.meta if lops is None else lops
+    # counted where the fused block is traced: which way its score
+    # goes, what a run observes
+    get_telemetry().count("learner.score_in_row_traces" if rows
+                          else "learner.score_in_row_refused")
 
     def body(carry, it):
         mat, ws, score, vscores = carry
         with jax.named_scope(scopes.GRADIENTS):
-            grad, hess = grad_fn(score if k > 1 else score[:, 0], *gops)
+            if rows is None:
+                grad, hess = grad_fn(score if k > 1 else score[:, 0],
+                                     *gops)
+            else:
+                # the last tree's leaf values are added here, where the
+                # pass reads the rows' score anyway; the barrier keeps
+                # this arithmetic a fusion of its own, apart from the
+                # pack's write of the results into the rows, so that
+                # each is booked to its own scope
+                row_score, *row_ops = rows.read(mat, score[1])
+                grad, hess = grad_fn(row_score, *row_ops)
+                row_score, grad, hess = jax.lax.optimization_barrier(
+                    (row_score, grad, hess))
             if k == 1:
                 grad = grad[:, None]
                 hess = hess[:, None]
@@ -244,19 +350,30 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(),
                 bag = bag_fn(it, grad, hess, *bops)
         trees_k = []
         ok = None
+        # the rows' gradients are by position: the grow packs them
+        # and the score into the rows without a gather, and paints the
+        # leaf values times the shrinkage (a multiply beside the next
+        # pass's add could be contracted with it to one rounding, as
+        # XLA:CPU does, where the scatter-add adds the rounded product)
+        by_pos = {} if rows is None else {"row_score": row_score,
+                                          "leaf_scale": lr}
         for tid in range(k):
             with jax.named_scope(scopes.GROW):
                 mat, ws, tree, (row_ids, pos_value) = \
                     learner.traceable_grow(mat, ws, grad[:, tid],
                                            hess[:, tid], bag=bag,
-                                           meta=meta)
+                                           meta=meta, **by_pos)
             ok_t = tree.num_leaves > 1
             scale = jnp.where(ok_t, lr, jnp.float32(0.0))
-            # one scatter-add in segment order: row_ids is a
-            # permutation of [0, N), pos_value the leaf's value per
-            # POSITION, so no table is read by position
+            # pos_value is the leaf's value per POSITION, so no table is
+            # read by position: the rows' scores take it where they sit;
+            # else one scatter-add in segment order (row_ids is a
+            # permutation of [0, N))
             with jax.named_scope(scopes.SCORE_UPDATE):
-                score = score.at[row_ids, tid].add(pos_value * scale)
+                if rows is None:
+                    score = score.at[row_ids, tid].add(pos_value * scale)
+                else:
+                    score = (score[0], pos_value)   # the next pass adds
             vscores = tuple(
                 vs.at[:, tid].add(traverse_tree_arrays(
                     tree, vb, meta, scale, vmv))
@@ -269,6 +386,9 @@ def _fused_iter_block(mat, ws, score, vscores, lr, it0, gops=(),
     (mat, ws, score, vscores), (trees, oks) = jax.lax.scan(
         body, (mat, ws, score, vscores),
         it0 + jnp.arange(m, dtype=jnp.int32))
+    if rows is not None:
+        with jax.named_scope(scopes.SCORE_UPDATE):
+            score = (rows.rebuild(mat, *score), score[1])
     # trees: TreeArrays stacked [m, k, ...]
     return mat, ws, score, vscores, trees, oks
 
@@ -299,10 +419,25 @@ class GBDT:
         self.training_metrics: list = []
         self._grad_fn = None
         self._grad_operands: tuple = ()
+        # the learner matrix whose rows hold ``train_score`` (RowScore),
+        # None where the row-order array alone is the truth
+        self._score_rows_mat = None
         self.evals_result: Dict[str, Dict[str, list]] = {}
 
         if train_data is not None:
             self._setup_train(train_data, hist_method)
+
+    @property
+    def train_score(self) -> jnp.ndarray:
+        """The training score, ``[N, k]`` f32 by row."""
+        return self._train_score
+
+    @train_score.setter
+    def train_score(self, value) -> None:
+        # a write makes the row-order array the truth: the rows' copy
+        # (RowScore) is written again before the next fused block
+        self._train_score = value
+        self._score_rows_mat = None
 
     # ------------------------------------------------------------------
     def _setup_train(self, train_data: Dataset, hist_method: str) -> None:
@@ -1230,31 +1365,51 @@ class GBDT:
         return max(1, int(self.config.metric_freq))
 
     def _fused_block(self):
-        """The booster's ``gbdt_fused_block`` program, built once."""
+        """The booster's ``gbdt_fused_block`` program, built once, and
+        its ``RowScore`` (None: the score stays in row order)."""
         fused = getattr(self, "_fused_jit", None)
         if fused is None:
+            bag_fn = self._traceable_bag_fn()
+            k = self.num_tree_per_iteration
+            rows = RowScore(self.learner.num_groups, self.num_data,
+                            self._grad_operands) \
+                if RowScore.fits(self.learner, k, self.objective,
+                                 self._grad_operands, self.num_data,
+                                 sampled=bag_fn is not None) else None
             fused = register_dynamic(
                 "gbdt_fused_block",
                 jax.jit(
                     _named("gbdt_fused_block", functools.partial(
                         _fused_iter_block, learner=self.learner,
-                        grad_fn=self._grad_fn,
-                        bag_fn=self._traceable_bag_fn(),
-                        k=self.num_tree_per_iteration)),
+                        grad_fn=self._grad_fn, bag_fn=bag_fn, k=k,
+                        rows=rows)),
                     static_argnames=("m",), donate_argnums=(0, 1, 2, 3)),
                 donate=(0, 1, 2))
             self._fused_jit = fused
+            self._fused_rows = rows
         return fused
 
     def _fused_block_args(self) -> tuple:
         """The arguments of the next fused block: the carry (matrix,
         twin, scores, valid scores), the shrinkage and first iteration,
         then every array whose values come from a table
-        (``_fused_iter_block``)."""
+        (``_fused_iter_block``). On the ``RowScore`` path the rows take
+        the score and the operands first where they do not hold them:
+        the row-order score was written, or a tree was grown outside a
+        block."""
         ln = self.learner
         valid_data = tuple((vd.binned_device, vd.mv_slots_device)
                            for vd in self.valid_sets)
-        return (ln.mat, ln.ws, self.train_score, tuple(self.valid_scores),
+        self._fused_block()
+        score = self.train_score
+        if self._fused_rows is not None:
+            if self._score_rows_mat is not ln.mat:
+                get_telemetry().count_iter("host.dispatches")
+                ln.mat, self._score_pending = self._fused_rows.seed(
+                    ln.mat, score, self._grad_operands)
+                self._score_rows_mat = ln.mat
+            score = (score, self._score_pending)
+        return (ln.mat, ln.ws, score, tuple(self.valid_scores),
                 jnp.float32(self.shrinkage_rate), jnp.int32(self.iter),
                 self._grad_operands, valid_data, self._bag_operands(),
                 ln.grow_operands())
@@ -1271,6 +1426,7 @@ class GBDT:
         ln = self.learner
         k = self.num_tree_per_iteration
         fused = self._fused_block()
+        rows = self._fused_rows
         while self.iter < iters:
             # largest power-of-2 block <= remaining (capped): the set of
             # compiled scan lengths stays O(log) regardless of how the
@@ -1295,8 +1451,12 @@ class GBDT:
                 # avals before the call: the arguments are donated
                 new_prog = tel.enabled and scopes.remember(
                     "gbdt_fused_block", fused, args, m=m)
-                (ln.mat, ln.ws, self.train_score, vs, trees,
-                 oks) = fused(*args, m=m)
+                (ln.mat, ln.ws, score, vs, trees, oks) = fused(*args, m=m)
+                if rows is None:
+                    self.train_score = score
+                else:
+                    self.train_score, self._score_pending = score
+                    self._score_rows_mat = ln.mat
                 if new_prog:
                     # first dispatch of this block length: the scope
                     # table now, while the compiled module is a cache

@@ -27,6 +27,10 @@ class ObjectiveFunction:
     is_constant_hessian = False
     is_renew_tree_output = False
     need_accuracte_prediction = True
+    #: a row's gradient reads that row's score and operands alone, so
+    #: it can be made wherever the row sits (``models/gbdt.py``
+    #: ``RowScore``); a ranking objective reads its query's rows
+    rowwise_gradients = False
 
     def __init__(self, config: Config):
         self.config = config

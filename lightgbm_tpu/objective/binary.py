@@ -17,6 +17,7 @@ kEpsilon = 1e-15
 
 class BinaryLogloss(ObjectiveFunction):
     need_accuracte_prediction = False
+    rowwise_gradients = True
 
     def __init__(self, config: Config, is_pos=None):
         super().__init__(config)

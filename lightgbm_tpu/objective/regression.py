@@ -25,6 +25,8 @@ def _sign(x):
 class RegressionL2Loss(ObjectiveFunction):
     """L2 loss (regression_objective.hpp:90-185)."""
 
+    rowwise_gradients = True
+
     def __init__(self, config: Config):
         super().__init__(config)
         self.sqrt = bool(config.reg_sqrt)

@@ -23,6 +23,8 @@ def _check_interval(label, name):
 class CrossEntropy(ObjectiveFunction):
     """Straight cross-entropy (xentropy_objective.hpp:38-140)."""
 
+    rowwise_gradients = True
+
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
         _check_interval(self.label_np, self.name())
@@ -61,6 +63,8 @@ class CrossEntropy(ObjectiveFunction):
 class CrossEntropyLambda(ObjectiveFunction):
     """Alternative parameterization with weight-as-trials
     (xentropy_objective.hpp:146-275)."""
+
+    rowwise_gradients = True
 
     def init(self, metadata, num_data):
         super().init(metadata, num_data)

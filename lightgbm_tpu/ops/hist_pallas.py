@@ -25,12 +25,19 @@ gpu_tree_learner.h:75-77):
     col  F+4..7        hess f32 bytes
     col  F+8           bagging/count indicator (0/1)
     col  F+9..12       row id (i32 bytes; partition bookkeeping)
+    col  F+13..16      training score (f32; the fused driver's in-row
+                       path alone, ``models/gbdt.py`` ``RowScore``)
+    col  F+17..        the objective's per-row operands (label, weight,
+                       ...), a 4-byte word each (in-row path alone)
     C = round_up(F+13, 128)
 
 Since XLA pads a [N, F] u8 array's minor dim to 128 anyway, these
 payload columns are FREE whenever F % 128 <= 115 — and one buffer
 means the partition kernel moves rows once and the histogram kernel
-issues one DMA stream.
+issues one DMA stream. The score and operand words use bytes that the
+rounding leaves spare and widen no row: a table without room for them
+(``row_words`` less than the words its objective needs) keeps them in
+row order, and the fused driver gathers its gradients instead.
 
 The segment [begin, begin+count) is DYNAMIC — per-leaf cost is
 O(leaf rows), not O(N) (the point of partitioned layout; LightGBM
@@ -64,6 +71,7 @@ from ..utils.matrix_layout import matrix_cols
 
 ALIGN = 8          # Mosaic offset granule for u8 2-D row slices
 RID_OFF = 9        # row-id bytes start at column F + RID_OFF
+WORD_OFF = 13      # in-row score and operand words from column F + WORD_OFF
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
@@ -98,17 +106,26 @@ def build_matrix(binned, blk: int = 2048) -> jnp.ndarray:
     return mat
 
 
-def pack_gh(mat, num_features: int, grad, hess, cnt) -> jnp.ndarray:
+def pack_gh(mat, num_features: int, grad, hess, cnt,
+            score=None) -> jnp.ndarray:
     """Write the gh payload columns for rows [0, len(grad)): each f32
     as its four bytes, low byte first, then the 0/1 count. Bitcasts, not
     shifted byte planes: XLA may lay each plane out as an [n, 1] column
     padded to 128 lanes (9 GB at 6.5 M rows in the mesh learner's
-    program, compiled for the v5e)."""
-    payload = jnp.concatenate([
+    program, compiled for the v5e). ``score`` (f32): the in-row score
+    word too, in the same write (the row ids between are written back
+    as they are): one pass over the rows, where two writes are two."""
+    f = num_features
+    parts = [
         jax.lax.bitcast_convert_type(grad.astype(jnp.float32), jnp.uint8),
         jax.lax.bitcast_convert_type(hess.astype(jnp.float32), jnp.uint8),
-        (cnt > 0).astype(jnp.uint8)[:, None]], axis=1)     # [n, 9]
-    return jax.lax.dynamic_update_slice(mat, payload, (0, num_features))
+        (cnt > 0).astype(jnp.uint8)[:, None]]              # [n, 9]
+    if score is not None:
+        n = grad.shape[0]
+        parts += [mat[:n, f + RID_OFF:f + WORD_OFF],
+                  jax.lax.bitcast_convert_type(score, jnp.uint8)]
+    return jax.lax.dynamic_update_slice(
+        mat, jnp.concatenate(parts, axis=1), (0, f))
 
 
 def extract_row_ids(mat, num_features: int, n: int) -> jnp.ndarray:
@@ -116,6 +133,30 @@ def extract_row_ids(mat, num_features: int, n: int) -> jnp.ndarray:
     bytes, low byte first, read as one word."""
     col = num_features + RID_OFF
     return jax.lax.bitcast_convert_type(mat[:n, col:col + 4], jnp.int32)
+
+
+def row_words(num_features: int) -> int:
+    """The 4-byte words a row has spare after its payload: room for the
+    in-row score and the objective's per-row operands."""
+    return (matrix_cols(num_features) - num_features - WORD_OFF) // 4
+
+
+def read_row_words(mat, num_features: int, n: int,
+                   count: int) -> jnp.ndarray:
+    """The ``count`` u32 words from column ``F + WORD_OFF`` of rows
+    [0, n) -> ``[n, count]``, one bitcast like ``extract_row_ids``."""
+    col = num_features + WORD_OFF
+    return jax.lax.bitcast_convert_type(
+        mat[:n, col:col + 4 * count].reshape(n, count, 4), jnp.uint32)
+
+
+def write_row_words(mat, num_features: int, words) -> jnp.ndarray:
+    """Write ``words`` ``[n, count]`` (4-byte dtype) into rows [0, n)
+    from column ``F + WORD_OFF``, in place."""
+    n, count = words.shape
+    return jax.lax.dynamic_update_slice(
+        mat, jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(
+            n, 4 * count), (0, num_features + WORD_OFF))
 
 
 # the widest table whose whole rows go through ONE per-feature

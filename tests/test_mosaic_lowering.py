@@ -236,6 +236,42 @@ def test_full_fused_training_block_lowers_for_tpu(leaves, f):
                 jnp.int32(0), m=4).lower(lowering_platforms=("tpu",))
 
 
+@pytest.mark.parametrize("objective", ["binary", "regression"])
+def test_fused_block_with_the_score_in_the_rows_lowers_for_tpu(objective):
+    """The fused block whose rows carry the score and the labels
+    (``models/gbdt.py`` ``RowScore``): the words read as bitcasts, the
+    one write of gradient, hessian, count, row id and score, the
+    block's rebuild scatter-set, around the compiled kernels."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.data import Dataset
+    from lightgbm_tpu.learner.partitioned import PartitionedTreeLearner
+    from lightgbm_tpu.models.gbdt import GBDT, RowScore, _fused_iter_block
+
+    rng = np.random.RandomState(0)
+    X = rng.randn(512, 28).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float64)
+    cfg = Config.from_params({
+        "objective": objective, "num_leaves": 255,
+        "tree_learner": "partitioned", "verbosity": -1})
+    ds = Dataset.from_numpy(X, cfg, label=y)
+    b = GBDT(cfg, ds)
+    ln = PartitionedTreeLearner(ds, cfg, interpret=False)
+    gops = b._grad_operands
+    assert RowScore.fits(ln, 1, b.objective, gops, ln.num_data,
+                         sampled=False)
+    rows = RowScore(ln.num_groups, ln.num_data, gops)
+    mat, pending = rows.seed(ln.mat, b.train_score, gops)
+    fused = jax.jit(
+        functools.partial(_fused_iter_block, learner=ln,
+                          grad_fn=b._grad_fn, bag_fn=None,
+                          valid_data=(), k=1, rows=rows),
+        static_argnames=("m",))
+    text = fused.trace(mat, ln.ws, (b.train_score, pending), (),
+                       jnp.float32(0.1), jnp.int32(0), m=4).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+
+
 def test_categorical_fused_training_block_lowers_for_tpu():
     """The fused block of a table with categorical columns, at the
     benchmark's 40 columns and 255 leaves: the plan keeps it off the

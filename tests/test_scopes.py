@@ -269,19 +269,24 @@ def test_one_block_opens_its_three_spans_in_order(tel, monkeypatch):
 # f32 table goes through the pass as int32 words: three
 # bitcast-converts, two small fusions), and for the gh payload written
 # and the row ids read as bitcasts, not as shifted byte planes
-# (ops/hist_pallas.py pack_gh, extract_row_ids: seven fusions fewer)
+# (ops/hist_pallas.py pack_gh, extract_row_ids: seven fusions fewer),
+# and for the fixture's binary score and labels kept in the matrix rows
+# (models/gbdt.py RowScore: the pack's row gather, its clamps and the
+# scatter-add went; the rows' words read as bitcasts, one write of the
+# payload with the score, the leaf values scaled on the num_leaves
+# table and the block's rebuild scatter-set came; as many fusions)
 PARENT_OPCODES = {
-    "abs": 20, "add": 457, "and": 112, "bitcast": 786,
-    "bitcast-convert": 78, "broadcast": 348, "clamp": 2, "compare": 376,
-    "concatenate": 32, "conditional": 15, "constant": 1097, "convert": 146,
+    "abs": 20, "add": 458, "and": 110, "bitcast": 790,
+    "bitcast-convert": 86, "broadcast": 341, "compare": 371,
+    "concatenate": 31, "conditional": 15, "constant": 1091, "convert": 147,
     "copy": 183, "divide": 12, "dot": 19, "dynamic-slice": 93,
     "dynamic-update-slice": 222, "exponential": 1, "fusion": 473,
-    "gather": 9, "get-tuple-element": 273, "iota": 42, "is-finite": 4,
-    "maximum": 25, "minimum": 13, "multiply": 140, "negate": 121, "not": 4,
-    "or": 13, "pad": 20, "parameter": 1165, "reduce": 14,
+    "gather": 8, "get-tuple-element": 272, "iota": 42, "is-finite": 4,
+    "maximum": 25, "minimum": 13, "multiply": 141, "negate": 122, "not": 4,
+    "or": 14, "pad": 20, "parameter": 1169, "reduce": 15,
     "reduce-window": 8, "remainder": 1, "reverse": 2, "scatter": 2,
-    "select": 350, "shift-left": 2, "shift-right-logical": 31, "sign": 45,
-    "slice": 653, "sort": 1, "subtract": 102, "transpose": 8, "tuple": 42}
+    "select": 348, "shift-left": 3, "shift-right-logical": 32, "sign": 45,
+    "slice": 655, "sort": 1, "subtract": 102, "transpose": 8, "tuple": 42}
 _OPCODE = re.compile(
     r"^\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(?:\([^=]*?\)|\S+)\s+([a-z\-]+)\(")
 

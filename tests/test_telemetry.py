@@ -333,7 +333,6 @@ def _fused_job(on: bool):
     """A small ``Dataset`` + ``Booster`` + the first iteration + two
     fused blocks of 2 trees with telemetry on (ring only) or off:
     ``(records, lowered text of the block, the model's text)``."""
-    import jax.numpy as jnp
     tel = get_telemetry()
     tel.reset()
     if on:
@@ -347,10 +346,8 @@ def _fused_job(on: bool):
         g.train(3)
         g.train(5)
         records = tel.records       # the job's, not the lowering below
-        text = g._fused_jit.lower(
-            g.learner.mat, g.learner.ws, g.train_score,
-            tuple(g.valid_scores), jnp.float32(g.shrinkage_rate),
-            jnp.int32(g.iter), m=2).as_text()
+        text = g._fused_jit.lower(*g._fused_block_args(),
+                                  m=2).as_text()
         return records, text, bst.model_to_string()
     finally:
         tel.reset()

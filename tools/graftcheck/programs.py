@@ -220,10 +220,23 @@ def _b_gbdt_fused_block():
             os.environ.pop("LGBM_TPU_FUSE_ITERS", None)
     bst = _env("booster_fused", make)
     g = bst._gbdt
+    # the fixture's binary objective keeps its score in the rows
+    # (models/gbdt.py RowScore): the carry holds the pending leaf values
     ln = g.learner
+    score, pending = g._fused_block_args()[2]
     return _spec_fn("gbdt_fused_block").lower(
-        ln.mat, ln.ws, g.train_score, (), jnp.float32(0.1),
+        ln.mat, ln.ws, (score, pending), (), jnp.float32(0.1),
         jnp.int32(g.iter), m=2)
+
+
+@builder("seed_row_words")
+def _b_seed_row_words():
+    import jax.numpy as jnp
+    ln = _partitioned_learner()
+    n = ln.num_data
+    return _spec_fn("seed_row_words").lower(
+        ln.mat, jnp.zeros((n, 1), jnp.float32),
+        [jnp.zeros((n,), jnp.float32)] * 2, f=ln.num_groups, n=n)
 
 
 # --- tree traversal / prediction -------------------------------------
